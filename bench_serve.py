@@ -395,6 +395,11 @@ def main(argv=None):
 
     import jax
 
+    # before the first compile: everything a later run can reuse lives
+    # under one root that can be placed from outside
+    from paddle_tpu import compile_cache
+    compile_cache.enable_persistent_cache()
+
     import paddle_tpu as pp
     from paddle_tpu.inference.kv_cache import paged_kv_enabled
     from paddle_tpu.models import LlamaConfig, LlamaForCausalLM

@@ -769,8 +769,9 @@ def _spec_from_eqn(eqn, where: str) -> Optional[KernelSpec]:
 
     args = []
     for k, bm in enumerate(bms[:num_in + num_out]):
-        sd = bm.array_shape_dtype
-        block = tuple(int(b) if isinstance(b, (int, np.integer)) else 1
+        sd = bm.array_aval
+        # Blocked/Element dims carry their size; a Squeezed dim is 1
+        block = tuple(int(getattr(b, "block_size", 1))
                       for b in bm.block_shape)
         cj = bm.index_map_jaxpr
         fn = (map_fn(cj)

@@ -45,7 +45,13 @@ Env knobs:
   PADDLE_TPU_COMPILE_CACHE=1        enable (default off — opt-in, like
                                     PADDLE_TPU_PAGED_KV)
   PADDLE_TPU_COMPILE_CACHE_DIR=path cache directory (default
-                                    ~/.cache/paddle_tpu/executables)
+                                    <cache_root()>/executables)
+  JAX_COMPILATION_CACHE_DIR=path    places :func:`cache_root` — JAX's own
+                                    persistent compilation cache plus
+                                    this cache, the autotune winners and
+                                    the calibration ledger under it —
+                                    from outside (default
+                                    .paddle_tpu_cache in the checkout)
 
 CLI::
 
@@ -69,12 +75,14 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 
-__all__ = ["SCHEMA_VERSION", "enabled", "cache_dir", "backend_fingerprint",
+__all__ = ["SCHEMA_VERSION", "enabled", "cache_root",
+           "enable_persistent_cache", "persistent_cache_counts",
+           "cache_dir", "backend_fingerprint",
            "cache_key", "lookup", "store", "aot_compile_cached",
            "model_config_tag", "cached_entries", "clear_cache",
            "cache_stats", "bundle", "load_bundle", "main"]
 
-SCHEMA_VERSION = 1
+SCHEMA_VERSION = 2
 
 # in-memory layer: a process that stored an entry (or already loaded it)
 # never re-reads / re-deserializes the file
@@ -90,11 +98,57 @@ def enabled() -> bool:
     return os.environ.get("PADDLE_TPU_COMPILE_CACHE", "0") == "1"
 
 
+def cache_root() -> str:
+    """The one directory that carries everything a later process can
+    reuse: JAX's persistent compilation cache, and under it this
+    module's executables, the autotune winners and the calibration
+    ledger.  ``JAX_COMPILATION_CACHE_DIR`` where set; otherwise a fixed
+    git-ignored directory at the root of the checkout (the path is part
+    of JAX's cache key, so it must never move between runs)."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".paddle_tpu_cache")
+
+
+_jax_cache_events = {"requests": 0, "hits": 0, "listening": False}
+
+
+def _on_jax_event(event: str, **_):
+    if event == "/jax/compilation_cache/compile_requests_use_cache":
+        _jax_cache_events["requests"] += 1
+    elif event == "/jax/compilation_cache/cache_hits":
+        _jax_cache_events["hits"] += 1
+
+
+def persistent_cache_counts() -> Dict[str, int]:
+    """Hits and misses of JAX's persistent compilation cache since
+    :func:`enable_persistent_cache` (one request per XLA compile)."""
+    ev = _jax_cache_events
+    return {"hits": ev["hits"], "misses": ev["requests"] - ev["hits"]}
+
+
+def enable_persistent_cache() -> str:
+    """Turn JAX's persistent compilation cache on at :func:`cache_root`
+    — call before the first compile.  Where ``JAX_COMPILATION_CACHE_DIR``
+    is set JAX already points there and no directory is set here.
+    Returns the root."""
+    from jax import monitoring
+    root = cache_root()
+    if not _jax_cache_events["listening"]:
+        monitoring.register_event_listener(_on_jax_event)
+        _jax_cache_events["listening"] = True
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", root)
+    # keep every program, however quick to compile: a cold machine pays
+    # for all of them again
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return root
+
+
 def cache_dir() -> str:
-    return os.environ.get(
-        "PADDLE_TPU_COMPILE_CACHE_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache", "paddle_tpu",
-                     "executables"))
+    return os.environ.get("PADDLE_TPU_COMPILE_CACHE_DIR") or \
+        os.path.join(cache_root(), "executables")
 
 
 def backend_fingerprint() -> str:
@@ -246,8 +300,10 @@ def lookup(key: str, target: str = "fn", root: Optional[str] = None):
         with tracer().span("compile.cache_hit", target=target,
                            key=key[:12]):
             t0 = time.perf_counter()
+            by_id = {d.id: d for d in jax.devices()}
             compiled = se.deserialize_and_load(
-                entry["payload"], entry["in_tree"], entry["out_tree"])
+                entry["payload"], entry["in_tree"], entry["out_tree"],
+                execution_devices=[by_id[i] for i in entry["device_ids"]])
             load_s = time.perf_counter() - t0
     except Exception:
         _count(target, "deserialize_error")
@@ -281,6 +337,17 @@ def _record_hit(target: str, entry: dict, load_s: float):
         pass
 
 
+def _device_ids(compiled) -> List[int]:
+    """Ids of the devices ``compiled`` executes on, in assignment order
+    — a load must name them, or jax loads the executable for every
+    local device."""
+    shardings = jax.tree.leaves((compiled.input_shardings,
+                                 compiled.output_shardings))
+    if not shardings:
+        return [jax.devices()[0].id]
+    return [d.id for d in shardings[0]._device_assignment]
+
+
 def store(key: str, compiled, target: str = "fn", signature: str = "",
           stats: Optional[dict] = None, root: Optional[str] = None) -> bool:
     """Serialize ``compiled`` into the cache.  Unserializable
@@ -299,6 +366,7 @@ def store(key: str, compiled, target: str = "fn", signature: str = "",
         "signature": signature,
         "stats": stats or {},
         "payload": payload,
+        "device_ids": _device_ids(compiled),
         "in_tree": in_tree,
         "out_tree": out_tree,
         "created": time.time(),

@@ -98,7 +98,7 @@ def current_stream(device=None):
 
 def memory_stats(device=None) -> dict:
     """Raw PJRT allocator counters for one device ({} when the backend
-    does not expose them, e.g. tunneled/experimental platforms)."""
+    does not expose them, e.g. the CPU)."""
     devs = jax.devices()
     idx = 0
     if isinstance(device, int):

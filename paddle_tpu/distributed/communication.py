@@ -29,7 +29,7 @@ from typing import Any, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 
 __all__ = [
     "ReduceOp", "all_reduce", "all_gather", "all_to_all", "reduce_scatter",
@@ -38,93 +38,30 @@ __all__ = [
     "axis_size", "vma_of",
 ]
 
-_SHARD_MAP = None
-
-
-def _resolve_shard_map():
-    """The jax shard_map entry point, wherever this jax version keeps
-    it: ``jax.shard_map`` (0.6+) first, then the long-lived
-    ``jax.experimental.shard_map.shard_map`` (0.4.x) — on 0.4.37
-    ``from jax import shard_map`` raises ImportError, which used to
-    take the whole sequence-parallel/MoE/pipeline family down with
-    it."""
-    global _SHARD_MAP
-    if _SHARD_MAP is None:
-        try:
-            from jax import shard_map as sm          # jax >= 0.6
-        except ImportError:
-            from jax.experimental.shard_map import shard_map as sm
-        _SHARD_MAP = sm
-    return _SHARD_MAP
-
-
-def shard_map(fn, mesh=None, in_specs=None, out_specs=None, **kwargs):
-    """Version-compat ``shard_map``: identical signature to jax's, so
-    every SPMD call site in this package (and user code) routes through
-    one resolver instead of guessing the import path per jax release.
-
-    ``legacy_check_rep=False`` relaxes the 0.4.x replication checker
-    ONLY (newer jax tracks varying-manual-axes precisely via pvary, so
-    its check stays on): the old static inference cannot see through a
-    pipelined-backward psum, and rejects out_specs whose values are
-    replicated by construction."""
-    impl = _resolve_shard_map()
-    legacy = kwargs.pop("legacy_check_rep", None)
-    if legacy is not None and "experimental" in getattr(impl,
-                                                        "__module__", ""):
-        kwargs.setdefault("check_rep", legacy)
-    return impl(fn, mesh=mesh, in_specs=in_specs,
-                out_specs=out_specs, **kwargs)
-
-
 def vma_of(x):
-    """The varying-manual-axes set of ``x`` under shard_map tracing, or
-    None on jax builds without ``jax.typeof`` (0.4.x has no vma
-    tracking; on newer jax the bare attribute access RAISES through the
-    deprecation machinery, so every caller must come through here)."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return None
-    return getattr(typeof(x), "vma", None)
+    """The varying-manual-axes set of ``x`` under shard_map tracing."""
+    return jax.typeof(x).vma
 
 
 def pvary(x, axis_name: str):
     """Mark `x` as device-varying over `axis_name` — needed for scan carries
     inside shard_map whose value becomes varying (e.g. after a ppermute)."""
-    vma = vma_of(x)
-    if vma is not None and axis_name in vma:
+    if axis_name in vma_of(x):
         return x  # already varying over this axis
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, (axis_name,), to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, (axis_name,))
-    return x
+    return lax.pcast(x, (axis_name,), to="varying")
 
 
-def pvary_like(x, ref, fallback_axes=()):
+def pvary_like(x, ref):
     """Vary `x` over every manual axis `ref` is varying over — the right
     seed for a scan accumulator that will be combined with `ref` inside a
     shard_map spanning MULTIPLE mesh axes (e.g. ring attention on an
     (sp, tp) mesh: the kv blocks vary over both axes, so the running
-    o/m/l must too, or the scan carry types diverge).  On jax builds
-    without ``jax.typeof`` the ref's axes can't be inspected —
-    ``fallback_axes`` (the axes the caller KNOWS are in play) keep the
-    old pvary behavior there."""
-    if getattr(jax, "typeof", None) is None:
-        missing = tuple(fallback_axes)
-    else:
-        want = vma_of(ref)
-        have = vma_of(x)
-        if not want:
-            return x
-        missing = tuple(a for a in want if have is None or a not in have)
+    o/m/l must too, or the scan carry types diverge)."""
+    have = vma_of(x)
+    missing = tuple(a for a in vma_of(ref) if a not in have)
     if not missing:
         return x
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, missing, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, missing)
-    return x
+    return lax.pcast(x, missing, to="varying")
 
 
 class ReduceOp:
@@ -147,13 +84,9 @@ def _wrap_like(x, ref):
 
 
 def axis_size(axis_name) -> int:
-    """Static size of a bound mesh axis.  ``lax.axis_size`` where this
-    jax has it (0.6+); on 0.4.x a ``psum`` of a python scalar constant-
-    folds to the axis size (and raises NameError when the axis is
-    unbound — same contract)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+    """Static size of a bound mesh axis (raises NameError when the axis
+    is unbound)."""
+    return lax.axis_size(axis_name)
 
 
 def _in_trace(axis_name) -> bool:

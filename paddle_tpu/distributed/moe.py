@@ -513,23 +513,12 @@ def moe_forward_a2a(x, gate_w, w1, b1, w2, b2, *, mesh, top_k: int = 2,
                     capacity=capacity, activation=activation,
                     ep_axis=ep_axis)
 
-    extra = {}
-    if _grouped_moe_enabled():
-        # jax 0.4.x's static replication checker has no rule for
-        # pallas_call; relax it only when the grouped kernel is routed
-        # so the knob-off trace (and its jaxpr) is untouched
-        extra["legacy_check_rep"] = False
     mapped = shard_map(
         fn, mesh=mesh,
         in_specs=(P(ep_axis), P(), P(ep_axis), P(ep_axis), P(ep_axis),
                   P(ep_axis)),
-        out_specs=(P(ep_axis), P(), P()), **extra)
+        out_specs=(P(ep_axis), P(), P()))
     out, aux, dropped = mapped(x2d, gate_w, w1, b1, w2, b2)
-    # couple the scalar outputs into `out`'s dataflow with a zero-weight
-    # term: a caller differentiating only `out` then sends DENSE zero
-    # cotangents into aux/dropped instead of symbolic Zeros, which jax
-    # 0.4.x's shard_map transpose mishandles ('Zero' has no .reshape)
-    out = out + (0.0 * (aux + dropped)).astype(out.dtype)
     if with_stats:
         return out.reshape(shape), aux, dropped
     return out.reshape(shape), aux

@@ -1130,14 +1130,6 @@ def build_pipeline_step_fn(stage_fn, first_fn, last_fn, optimizer, mesh,
         # but its out_spec omits (values already equal across them)
         present = _spec_axes(spec)
         vma = _vma_of(g)
-        if vma is None:
-            # jax 0.4.x: no vma tracking means no auto-inserted psum in
-            # the vjp — grads of params invariant on an axis come back
-            # as RAW per-device partial sums; reduce them explicitly
-            # (the uniform 1/D scale above turns sums into means)
-            for ax in manual - present - set(exclude):
-                g = lax.psum(g, ax)
-            return g
         for ax in manual - present - set(exclude):
             if ax in vma:
                 g = lax.pmean(g, ax)
@@ -1182,7 +1174,7 @@ def build_pipeline_step_fn(stage_fn, first_fn, last_fn, optimizer, mesh,
             norm(g_last)
         for ax in data_axes:
             loss = lax.pmean(loss, ax)
-        vma_l = _vma_of(loss) or ()
+        vma_l = _vma_of(loss)
         for ax in manual - set(data_axes):
             if ax in vma_l:  # e.g. tp: equal across shards, clean vma
                 loss = lax.pmean(loss, ax)
@@ -1203,8 +1195,7 @@ def build_pipeline_step_fn(stage_fn, first_fn, last_fn, optimizer, mesh,
             for n, g in tr.items():
                 vma = _vma_of(g)
                 for ax in data_axes:
-                    # no vma tracking (0.4.x) → partials, always reduce
-                    if ax != fsdp and (vma is None or ax in vma):
+                    if ax != fsdp and ax in vma:
                         g = lax.psum(g, ax)
                 if fsdp:
                     pos = _spec_axis_pos(specs[prefix + n], fsdp)
@@ -1223,31 +1214,16 @@ def build_pipeline_step_fn(stage_fn, first_fn, last_fn, optimizer, mesh,
         for prefix, tr in (("first/", g_first), ("last/", g_last)):
             if tr is not None:
                 for n, g in tr.items():
-                    if _vma_of(g) is None:
-                        # 0.4.x (no vma tracking): pp (psum_tree inside
-                        # pipeline_1f1b) and the data axes (group_reduce
-                        # above) are ALREADY summed — a pessimistic psum
-                        # there would double-count; what remains (e.g.
-                        # tp) is still raw per-device vjp partials, and
-                        # reduce_leaf's unconditional psum closes them
-                        merged[prefix + n] = reduce_leaf(
-                            g, specs[prefix + n],
-                            exclude=(pp_axis,) + tuple(data_axes))
-                    else:
-                        merged[prefix + n] = reduce_leaf(
-                            g, specs[prefix + n])
+                    merged[prefix + n] = reduce_leaf(g, specs[prefix + n])
         return loss, merged
 
     from paddle_tpu.distributed.communication import shard_map
 
     batch_spec = P(None, data_axes) if data_axes else P()
-    # grads ARE replicated over the data axes (group_reduce psums them)
-    # but jax 0.4.x's static rep inference can't see through the
-    # pipelined backward — legacy_check_rep only relaxes the old checker
     shmap = shard_map(
         body, mesh=mesh,
         in_specs=(dict(specs), batch_spec, batch_spec),
-        out_specs=(P(), dict(specs)), legacy_check_rep=False)
+        out_specs=(P(), dict(specs)))
 
     def step_impl(params, opt_state, step_count, mb_inputs, mb_labels,
                   lr):

@@ -200,12 +200,9 @@ def _ring_dense(q, k, v, *, axis_name, causal, scale, striped):
     # accumulators must vary over EVERY manual axis the kv blocks vary
     # over (not just the ring axis) — on an (sp, tp) mesh the heads are
     # tp-sharded and the carry types must agree across scan steps
-    o0 = pvary_like(jnp.zeros((b, h, s, d), jnp.float32), qf,
-                    fallback_axes=(axis_name,))
-    m0 = pvary_like(jnp.full((b, h, s, 1), neg, jnp.float32), qf,
-                    fallback_axes=(axis_name,))
-    l0 = pvary_like(jnp.zeros((b, h, s, 1), jnp.float32), qf,
-                    fallback_axes=(axis_name,))
+    o0 = pvary_like(jnp.zeros((b, h, s, d), jnp.float32), qf)
+    m0 = pvary_like(jnp.full((b, h, s, 1), neg, jnp.float32), qf)
+    l0 = pvary_like(jnp.zeros((b, h, s, 1), jnp.float32), qf)
     (o, m, l, _, _), _ = lax.scan(step, (o0, m0, l0, k, v),
                                   jnp.arange(sp))
     safe_l = jnp.where(l > 0, l, 1.0)
@@ -341,10 +338,8 @@ def _ring_flash(q, k, v, *, axis_name, causal, scale):
         return (o_new, l_new, k_nxt, v_nxt), None
 
     from paddle_tpu.distributed.communication import pvary_like
-    o0 = pvary_like(jnp.zeros((b, h, s, d), jnp.float32), q,
-                    fallback_axes=(axis_name,))
-    l0 = pvary_like(jnp.full((b, h, s), -jnp.inf, jnp.float32), q,
-                    fallback_axes=(axis_name,))
+    o0 = pvary_like(jnp.zeros((b, h, s, d), jnp.float32), q)
+    l0 = pvary_like(jnp.full((b, h, s), -jnp.inf, jnp.float32), q)
     (o, _, _, _), _ = lax.scan(step, (o0, l0, k, v), jnp.arange(sp))
     return jnp.swapaxes(o.astype(q.dtype), 1, 2)       # [b,s,h,d]
 
@@ -382,18 +377,18 @@ def ulysses_attention(q, k, v, axis_name: str = "sp", causal: bool = False,
     return swap_out(out)
 
 
-def _wrap_shard_map(fn, mesh, axis_name, seq_axis=1):
+def _wrap_shard_map(fn, mesh, axis_name, seq_axis=1, kernel=False):
     from jax.sharding import PartitionSpec as P
 
     from paddle_tpu.distributed.communication import shard_map
     spec = [None, None, None, None]
     spec[seq_axis] = axis_name
     spec = P(*spec)
-    # every operand is sp-sharded (nothing replicated → no auto-psum to
-    # lose); 0.4.x's rep checker trips on the pvary-less scan carry, so
-    # relax it there only
+    if kernel:
+        from paddle_tpu.ops.pallas.mesh import shard_map_kernel
+        return shard_map_kernel(fn, mesh, (spec, spec, spec), spec)
     return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, legacy_check_rep=False)
+                     out_specs=spec)
 
 
 def make_ring_attention(mesh, axis_name: str = "sp", causal: bool = False,
@@ -402,7 +397,9 @@ def make_ring_attention(mesh, axis_name: str = "sp", causal: bool = False,
     → shard_map'd ring attention."""
     fn = functools.partial(ring_attention, axis_name=axis_name,
                            causal=causal, scale=scale, impl=impl)
-    return _wrap_shard_map(lambda q, k, v: fn(q, k, v), mesh, axis_name)
+    flash = impl == "flash" or (impl is None and ring_flash_enabled())
+    return _wrap_shard_map(lambda q, k, v: fn(q, k, v), mesh, axis_name,
+                           kernel=flash)
 
 
 def make_striped_ring_attention(mesh, axis_name: str = "sp",

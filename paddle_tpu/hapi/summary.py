@@ -93,8 +93,6 @@ def flops(net, input_size, custom_ops=None, print_detail: bool = False):
     x = jnp.zeros(tuple(input_size), dtype)
     lowered = jax.jit(fwd).lower(params, x)
     cost = lowered.compile().cost_analysis()
-    if isinstance(cost, (list, tuple)):   # 0.4.x returns [dict]
-        cost = cost[0] if cost else {}
     n = int(cost.get("flops", 0.0)) if cost else 0
     if print_detail:
         total_p = sum(int(np.prod(a.shape)) for a in params.values())

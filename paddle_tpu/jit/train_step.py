@@ -59,9 +59,9 @@ def _resolve_plan(shardings, mesh, param_specs, batch_spec):
 def _ordered_after(x, token):
     """``x`` pinned to issue after ``token`` via optimization_barrier —
     the link of the collective-overlap prefetch chain.  The barrier is a
-    forward scheduling constraint only; 0.4.x has no differentiation
-    rule for it, so the VJP passes the cotangent straight through (the
-    backward's gather/reduce-scatter schedule is XLA's to pick)."""
+    forward scheduling constraint only, so the VJP passes the cotangent
+    straight through (the backward's gather/reduce-scatter schedule is
+    XLA's to pick)."""
     return jax.lax.optimization_barrier((x, token))[0]
 
 
@@ -147,7 +147,9 @@ class CompiledStepBase:
 
     def _init_step_state(self, optimizer, params, param_sh=None):
         """Place params on their shardings and derive optimizer state
-        (each state leaf shaped like its param inherits the sharding)."""
+        (each state leaf shaped like its param inherits the sharding;
+        every other leaf, and the step counter, is replicated over the
+        mesh — nothing is left on the default device alone)."""
         self.optimizer = optimizer
         self._param_sh = param_sh
         # copy defensively: the step donates its buffers to XLA, and
@@ -162,15 +164,37 @@ class CompiledStepBase:
                       for n, a in params.items()}
         self.params = params
         self.opt_state = optimizer.init_state_pytree(params)
-        if param_sh is not None:
-            self.opt_state = {
-                n: jax.tree.map(
-                    lambda a, _sh=param_sh[n], _p=params[n]: jax.device_put(
-                        a, _sh)
-                    if hasattr(a, "shape") and a.shape == _p.shape else a,
-                    st)
-                for n, st in self.opt_state.items()}
         self.step_count = jnp.zeros((), jnp.int32)
+        if param_sh:
+            self.opt_state = {n: self._place_state(n, st)
+                              for n, st in self.opt_state.items()}
+            self.step_count = jax.device_put(self.step_count,
+                                             self._replicated())
+
+    def _replicated(self):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        return NamedSharding(next(iter(self._param_sh.values())).mesh, P())
+
+    def _place_state(self, name, state):
+        """One param's optimizer state on the mesh: leaves of the param's
+        shape share its sharding, the rest are replicated."""
+        shape = tuple(self.params[name].shape)
+        return jax.tree.map(
+            lambda a: jax.device_put(
+                jnp.asarray(a), self._param_sh[name]
+                if jnp.shape(a) == shape else self._replicated()), state)
+
+    def _state_out_shardings(self):
+        """``out_shardings`` that hand params, optimizer state and step
+        counter back exactly as they went in, so the layout is a fixed
+        point of the step (XLA would otherwise pick its own for the
+        outputs, and an AOT-compiled step then rejects its own result).
+        None without a mesh."""
+        if not self._param_sh:
+            return None
+        sh_of = lambda tree: jax.tree.map(lambda a: a.sharding, tree)
+        return (None, sh_of(self.params), sh_of(self.opt_state),
+                self.step_count.sharding)
 
     def _dispatch_fn(self, *step_args):
         """The callable that executes this step — subclasses may return
@@ -206,21 +230,19 @@ class CompiledStepBase:
 
     def set_state_dict(self, state):
         import numpy as np
+        self.step_count = jnp.asarray(state["step"], jnp.int32)
         if self._param_sh:
             put = lambda n, a: jax.device_put(jnp.asarray(a),
                                               self._param_sh[n])
-            # opt-state leaves shaped like their param share its sharding
-            put_st = lambda n, st: jax.tree.map(
-                lambda a: jax.device_put(jnp.asarray(a), self._param_sh[n])
-                if np.shape(a) == tuple(self.params[n].shape)
-                else jnp.asarray(a), st)
+            put_st = self._place_state
+            self.step_count = jax.device_put(self.step_count,
+                                             self._replicated())
         else:
             put = lambda n, a: jnp.asarray(a)
             put_st = lambda n, st: jax.tree.map(jnp.asarray, st)
         self.params = {n: put(n, a) for n, a in state["params"].items()}
         self.opt_state = {n: put_st(n, st)
                           for n, st in state["opt_state"].items()}
-        self.step_count = jnp.asarray(state["step"], jnp.int32)
         if "rng_key" in state and hasattr(self, "_key"):
             self._key = jnp.asarray(np.asarray(state["rng_key"]),
                                     jnp.uint32)
@@ -381,6 +403,11 @@ class TrainStep(CompiledStepBase):
                 else None
         else:
             param_sh = self._batch_sh = None
+        # mesh axes the batch dim is sharded over (() = replicated)
+        lead = self._batch_sh.spec[0] if self._batch_sh is not None \
+            and len(self._batch_sh.spec) else None
+        self._batch_axes = () if lead is None else \
+            (lead,) if isinstance(lead, str) else tuple(lead)
 
         # compute/collective overlap (ISSUE 15): express the per-layer
         # FSDP weight all-gathers as an explicit, layer-ordered prefetch
@@ -426,7 +453,8 @@ class TrainStep(CompiledStepBase):
         self.last_sdc_verdict = None
 
         self._init_step_state(optimizer, params, param_sh)
-        self._jitted = jax.jit(self._step_impl, donate_argnums=(0, 1, 2))
+        self._jitted = jax.jit(self._step_impl, donate_argnums=(0, 1, 2),
+                               out_shardings=self._state_out_shardings())
         # AOT path (device-profiler tentpole): compile(batch) stores the
         # explicit lower().compile() executable here; calls whose batch
         # signature matches dispatch through it (no retrace hazard, and
@@ -488,7 +516,14 @@ class TrainStep(CompiledStepBase):
             token = nxt if nxt is not None else token
         return out
 
-    def _step_impl(self, params, opt_state, step_count, batch, key, lr):
+    def _step_impl(self, *step_args):
+        # the trace declares the step's mesh: Pallas kernels meet it as
+        # ops/pallas/mesh.py decides (XLA cannot partition them)
+        from paddle_tpu.ops.pallas.mesh import step_mesh
+        with step_mesh(self.mesh, self._batch_axes):
+            return self._step_body(*step_args)
+
+    def _step_body(self, params, opt_state, step_count, batch, key, lr):
         model, opt = self.model, self.optimizer
 
         def loss_of_trainable(train_params, frozen_params, mb, k):

@@ -115,12 +115,19 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         # win in ISOLATED microbenches (benchmarks/pallas_kernels_bench
         # .py) — a documented niche: standalone attention grads without
         # a surrounding fusable step.
+        import functools
+
+        from paddle_tpu.ops.pallas import mesh
         from paddle_tpu.ops.pallas.flash_attention import (flash_attention,
                                                            flash_bwd_env)
         pb = flash_bwd_env()
-        return flash_attention(query, key, value, causal=is_causal,
-                               scale=scale,
-                               pallas_bwd=False if pb is None else pb)
+        attn = functools.partial(flash_attention, causal=is_causal,
+                                 scale=scale,
+                                 pallas_bwd=False if pb is None else pb)
+        if mesh.current() is not None:
+            # inside a sharded step: per shard of batch and heads
+            return mesh.over_batch_and_heads(attn, query, key, value)
+        return attn(query, key, value)
     dk = None
     if use_dropout:
         from paddle_tpu.core import functional as _cf

@@ -57,8 +57,8 @@ Env knobs:
   PADDLE_TPU_CALIBRATION=1        enable the ledger feeders + calibrated
                                   consumers (default off: zero behavior
                                   change, like PADDLE_TPU_COMPILE_CACHE)
-  PADDLE_TPU_CALIBRATION_DIR=path ledger directory (default
-                                  ~/.cache/paddle_tpu/calibration)
+  PADDLE_TPU_CALIBRATION_DIR=path ledger directory (default calibration/
+                                  under compile_cache.cache_root())
 """
 
 from __future__ import annotations
@@ -93,10 +93,11 @@ def enabled() -> bool:
 
 
 def ledger_dir() -> str:
-    return os.environ.get(
-        "PADDLE_TPU_CALIBRATION_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache", "paddle_tpu",
-                     "calibration"))
+    env = os.environ.get("PADDLE_TPU_CALIBRATION_DIR")
+    if env:
+        return env
+    from paddle_tpu.compile_cache import cache_root
+    return os.path.join(cache_root(), "calibration")
 
 
 def ledger_path() -> str:

@@ -97,7 +97,10 @@ def detect_roofline(device=None, fallback: Optional[Tuple[float, float]]
             break
     if peak is None:
         if getattr(device, "platform", "") == "tpu":
-            peak, bw = TPU_ROOFLINES["v5p"]
+            raise ValueError(
+                f"no roofline for TPU device_kind {device.device_kind!r}: "
+                f"add its published peaks to TPU_ROOFLINES "
+                f"(known: {sorted(TPU_ROOFLINES)})")
         else:
             peak, bw = fallback if fallback is not None else _HOST_ROOFLINE
     env_peak = os.environ.get("PADDLE_TPU_PEAK_FLOPS")

@@ -40,12 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU backend only; tests on CPU use interpret mode
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_TPU_PL = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAVE_TPU_PL = False
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["fused_softmax_cross_entropy", "fused_ce_enabled",
            "fused_ce_eligible"]
@@ -54,14 +49,22 @@ _NEG_INF = -1e30
 
 
 def fused_ce_enabled() -> bool:
-    """Routing gate: env wins, else auto = TPU backend only (interpret
-    mode off-TPU is for tests, not the hot path)."""
+    """Routing gate: env wins, else auto = TPU backend only, outside a
+    sharded step (interpret mode off-TPU is for tests, not the hot
+    path)."""
     env = os.environ.get("PADDLE_TPU_FUSED_CE", "").strip().lower()
     if env in ("0", "false", "off", "no"):
         return False
     if env in ("1", "true", "on", "yes"):
         return True
-    return jax.default_backend() == "tpu"
+    if jax.default_backend() != "tpu":
+        return False
+    from paddle_tpu.ops.pallas import mesh
+    if mesh.current() is not None:
+        # sharded logits: XLA cannot partition a Mosaic kernel
+        mesh.record_route("fused_ce", "xla")
+        return False
+    return True
 
 
 def fused_ce_eligible(t: int, v: int) -> bool:
@@ -111,7 +114,7 @@ def _fwd_pallas(x, lbl_col, *, block_t, block_v, interpret):
     nv = v // block_v
 
     params = {}
-    if _HAVE_TPU_PL and not interpret:
+    if not interpret:
         params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"))
 
@@ -161,7 +164,7 @@ def _bwd_pallas(x, lbl_col, lse, g_col, *, block_t, block_v, interpret):
     nv = v // block_v
 
     params = {}
-    if _HAVE_TPU_PL and not interpret:
+    if not interpret:
         params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"))
 

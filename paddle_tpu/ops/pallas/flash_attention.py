@@ -36,12 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU backend only; tests on CPU use interpret mode
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_TPU_PL = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAVE_TPU_PL = False
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention", "flash_bwd_env"]
 
@@ -66,6 +61,14 @@ def _bwd_path_counter():
         labelnames=("path",))
 
 _NEG_INF = -1e30
+
+
+def _out_struct(shape, dtype, *operands):
+    """An ``out_shape`` that varies over every manual mesh axis its
+    operands vary over — ``pallas_call`` under ``shard_map`` demands the
+    ``vma`` of each output (outside ``shard_map`` the set is empty)."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -148,7 +151,7 @@ def _fwd_pallas(q, k, v, *, scale, causal, block_q, block_k,
     ]
 
     params = {}
-    if _HAVE_TPU_PL and not interpret:
+    if not interpret:
         params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"))
@@ -171,8 +174,8 @@ def _fwd_pallas(q, k, v, *, scale, causal, block_q, block_k,
                          lambda b_, h, i, j: (b_, h, i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, hq, s, d), q.dtype),
-            jax.ShapeDtypeStruct((b, hq, nq, 1, block_q), jnp.float32),
+            _out_struct((b, hq, s, d), q.dtype, q, k, v),
+            _out_struct((b, hq, nq, 1, block_q), jnp.float32, q, k, v),
         ],
         scratch_shapes=scratch,
         interpret=interpret,
@@ -317,7 +320,7 @@ def _bwd_pallas(res, g, *, scale, causal, block_q, block_k, interpret):
     delta5 = delta.reshape(b, hq, nq, 1, block_q)
 
     params = {}
-    if _HAVE_TPU_PL and not interpret:
+    if not interpret:
         params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"))
@@ -342,7 +345,7 @@ def _bwd_pallas(res, g, *, scale, causal, block_q, block_k, interpret):
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, d),
                                lambda b_, h, i, j: (b_, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hq, s, d), q.dtype),
+        out_shape=_out_struct((b, hq, s, d), q.dtype, q, k, v, g),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
         **params,
@@ -378,8 +381,8 @@ def _bwd_pallas(res, g, *, scale, causal, block_q, block_k, interpret):
                          lambda b_, g_, j, t: (b_, g_, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, hk, s, d), k.dtype),
-            jax.ShapeDtypeStruct((b, hk, s, d), v.dtype),
+            _out_struct((b, hk, s, d), k.dtype, q, k, v, g),
+            _out_struct((b, hk, s, d), v.dtype, q, k, v, g),
         ],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],

@@ -82,12 +82,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU backend only; tests on CPU use interpret mode
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_TPU_PL = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAVE_TPU_PL = False
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["fused_rmsnorm_qkv", "fused_mlp", "fused_ffn",
            "fused_decoder_block", "fused_block_enabled",
@@ -111,7 +106,8 @@ def fused_block_tier() -> str:
     lowering everywhere), ``"fused"`` (the PR-8 per-segment kernels —
     rmsnorm+QKV and MLP), ``"decoder"`` (additionally route eligible
     llama decoder layers through the whole-block megakernel).  Unset =
-    auto: ``"fused"`` on a TPU backend, ``"off"`` elsewhere — the
+    auto: ``"fused"`` on a TPU backend outside a sharded step
+    (``ops/pallas/mesh.py``), ``"off"`` elsewhere — the
     decoder tier is opt-in only, so existing knob values reproduce
     their previous jaxprs exactly.  ``"measured"`` resolves the
     decoder-vs-per-segment choice per shape from the measurement
@@ -125,7 +121,15 @@ def fused_block_tier() -> str:
         return "measured"
     if env in ("1", "true", "on", "yes"):
         return "fused"
-    return "fused" if jax.default_backend() == "tpu" else "off"
+    if jax.default_backend() != "tpu":
+        return "off"
+    from paddle_tpu.ops.pallas import mesh
+    if mesh.current() is not None:
+        # the step shards these kernels' weights, and XLA cannot
+        # partition a Mosaic kernel: the XLA path, which it can
+        mesh.record_route("fused_block", "xla")
+        return "off"
+    return "fused"
 
 
 def fused_block_enabled() -> bool:
@@ -319,7 +323,7 @@ def _qkv_pallas(x2d, wn, wq, wk, wv, *, eps, block_t, block_o, interpret,
                       jax.ShapeDtypeStruct((t, 1), jnp.float32)]
 
     params = {}
-    if _HAVE_TPU_PL and not interpret:
+    if not interpret:
         params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"))
 
@@ -556,7 +560,7 @@ def _mlp_pallas(x2d, weights, biases, *, act, gated, block_t, block_f,
         args.append(b2.reshape(1, d))
 
     params = {}
-    if _HAVE_TPU_PL and not interpret:
+    if not interpret:
         params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"))
 
@@ -984,7 +988,7 @@ def _decoder_pallas(x, wn1, wq, wk, wv, cos, sin, wo, wn2, wg, wu, wd, *,
     inner = D0 + nf
 
     params = {}
-    if _HAVE_TPU_PL and not interpret:
+    if not interpret:
         params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"))
 

@@ -46,12 +46,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU backend only; tests on CPU use interpret mode
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_TPU_PL = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAVE_TPU_PL = False
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["grouped_expert_ffn", "grouped_expert_ffn_pallas",
            "grouped_expert_ffn_reference", "grouped_moe_enabled",
@@ -168,7 +163,7 @@ def grouped_expert_ffn_pallas(x, w1, b1, w2, b2, counts, *, act,
     nf = h // block_f
 
     params = {}
-    if _HAVE_TPU_PL and not interpret:
+    if not interpret:
         params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
 

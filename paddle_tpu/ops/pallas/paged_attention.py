@@ -34,12 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU backend only; tests on CPU use interpret mode
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_TPU_PL = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAVE_TPU_PL = False
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["paged_decode_attention", "paged_decode_eligible",
            "paged_attention_env", "record_path"]
@@ -62,7 +57,7 @@ def paged_decode_eligible(head_dim: int, block_size: int, dtype) -> bool:
     env = paged_attention_env()
     if env is False:
         return False
-    if jax.default_backend() != "tpu" or not _HAVE_TPU_PL:
+    if jax.default_backend() != "tpu":
         return False
     if jnp.dtype(dtype) not in (jnp.dtype(jnp.float32),
                                 jnp.dtype(jnp.bfloat16)):

@@ -39,12 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU backend only; tests on CPU use interpret mode
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_TPU_PL = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAVE_TPU_PL = False
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["quant_matmul", "quant_matmul_pallas", "quant_matmul_reference",
            "quant_matmul_eligible", "quant_matmul_env", "record_path",
@@ -81,7 +76,7 @@ def quant_matmul_eligible(t: int, k: int, n: int, x_dtype) -> bool:
     env = quant_matmul_env()
     if env is False:
         return False
-    if jax.default_backend() != "tpu" or not _HAVE_TPU_PL:
+    if jax.default_backend() != "tpu":
         return False
     s = str(jnp.dtype(x_dtype))
     q = 16 if ("bfloat16" in s or "float16" in s) else 8
@@ -164,7 +159,7 @@ def quant_matmul_pallas(x2d, qw, scale, *, block_t=None, block_n=None,
     if t % block_t or n % block_n:
         block_t, block_n = _default_quant_blocks(t, n)
     params = {}
-    if _HAVE_TPU_PL and not interpret:
+    if not interpret:
         params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"))
     return pl.pallas_call(

@@ -17,7 +17,7 @@ import paddle_tpu as pp
 class TestDeviceMemoryStats:
     def test_api_shape(self):
         # reference: paddle.device.cuda.memory_allocated surface; values may
-        # be 0 where the backend exposes no stats (CPU/tunneled platforms)
+        # be 0 where the backend exposes no stats (the CPU)
         assert isinstance(pp.device.memory_allocated(), int)
         assert isinstance(pp.device.max_memory_allocated(), int)
         assert isinstance(pp.device.memory_stats(), dict)

@@ -116,8 +116,10 @@ class TestKeys:
             import sys
             sys.path.insert(0, %r)
             sys.path.insert(0, %r)
-            from _jax_platform import force_cpu_default
-            force_cpu_default(min_devices=8)
+            import os
+            os.environ["JAX_PLATFORMS"] = "cpu"
+            os.environ["XLA_FLAGS"] = \
+                "--xla_force_host_platform_device_count=8"
             import numpy as np
             import jax.numpy as jnp
             import paddle_tpu as pp
@@ -150,6 +152,61 @@ class TestKeys:
 
 
 # ---------------------------------------------------- entry validation
+class TestCacheRoot:
+    """One root, placeable from outside, for everything a later process
+    can reuse."""
+
+    def _paths(self):
+        from paddle_tpu.observability import calibration
+        from paddle_tpu.ops.pallas import autotune
+        return (cc.cache_dir(), autotune.cache_path(),
+                calibration.ledger_dir())
+
+    def test_fixed_path_in_the_checkout_when_unset(self, monkeypatch):
+        for var in ("JAX_COMPILATION_CACHE_DIR",
+                    "PADDLE_TPU_COMPILE_CACHE_DIR",
+                    "PADDLE_TPU_AUTOTUNE_CACHE",
+                    "PADDLE_TPU_CALIBRATION_DIR"):
+            monkeypatch.delenv(var, raising=False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        root = os.path.join(repo, ".paddle_tpu_cache")
+        assert cc.cache_root() == root == cc.cache_root()   # never moves
+        assert self._paths() == (
+            os.path.join(root, "executables"),
+            os.path.join(root, "autotune.json"),
+            os.path.join(root, "calibration"))
+        with open(os.path.join(repo, ".gitignore")) as f:
+            assert ".paddle_tpu_cache/" in f.read().split()
+
+    def test_env_places_the_root_and_no_other_dir_is_set(self, tmp_path,
+                                                         monkeypatch):
+        import jax
+        for var in ("PADDLE_TPU_COMPILE_CACHE_DIR",
+                    "PADDLE_TPU_AUTOTUNE_CACHE",
+                    "PADDLE_TPU_CALIBRATION_DIR"):
+            monkeypatch.delenv(var, raising=False)
+        updates = {}
+        monkeypatch.setattr(jax.config, "update",
+                            lambda k, v: updates.__setitem__(k, v))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert cc.enable_persistent_cache() == str(tmp_path)
+        assert "jax_compilation_cache_dir" not in updates
+        assert all(p.startswith(str(tmp_path)) for p in self._paths())
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        root = cc.enable_persistent_cache()
+        assert updates["jax_compilation_cache_dir"] == root
+        assert root.endswith(".paddle_tpu_cache")
+
+    def test_counts_come_from_jax_cache_events(self):
+        before = cc.persistent_cache_counts()
+        cc._on_jax_event("/jax/compilation_cache/compile_requests_use_cache")
+        cc._on_jax_event("/jax/compilation_cache/compile_requests_use_cache")
+        cc._on_jax_event("/jax/compilation_cache/cache_hits")
+        after = cc.persistent_cache_counts()
+        assert after["hits"] == before["hits"] + 1
+        assert after["misses"] == before["misses"] + 1
+
+
 class TestInvalidation:
     def _store_one(self, cache_env):
         f = jax.jit(lambda x: x * 3 + 1)
@@ -421,8 +478,8 @@ class TestElasticRestart:
             sys.path.insert(0, %r)
             sys.path.insert(0, %r)
             os.environ["JAX_PLATFORMS"] = "cpu"
-            from _jax_platform import force_cpu_default
-            force_cpu_default(min_devices=8)
+            os.environ["XLA_FLAGS"] = \
+                "--xla_force_host_platform_device_count=8"
             import numpy as np
             import paddle_tpu as pp
             from paddle_tpu.distributed import ElasticAgent

@@ -350,8 +350,7 @@ class TestDecoderCostModel:
         def fused(*a):
             # use_pallas + explicit blocks forced: the trace is abstract
             # (no VMEM runs), measuring the kernel's call-level byte
-            # accounting at widths the VMEM gate rejects for execution —
-            # the protocol RESULTS.md records for the on-chip sweep
+            # accounting at widths the VMEM gate rejects for execution
             return FB.fused_decoder_block(
                 a[0], *a[1:], num_heads=nh, num_kv_heads=nkvh,
                 epsilon=EPS, use_pallas=True, autotune=False,

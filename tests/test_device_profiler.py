@@ -92,6 +92,19 @@ class TestSignature:
             signature_of([jnp.ones(2)])
 
 
+def test_detect_roofline_unknown_tpu_kind_raises():
+    """A TPU the table does not know is an error; the CPU keeps the host
+    roofline that ranks segments."""
+    import types
+    new = types.SimpleNamespace(platform="tpu", device_kind="TPU v9x")
+    with pytest.raises(ValueError, match="TPU v9x"):
+        detect_roofline(new)
+    v5e = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert detect_roofline(v5e) == (197e12, 819e9)
+    cpu = types.SimpleNamespace(platform="cpu", device_kind="cpu")
+    assert detect_roofline(cpu)[0] < 1e12
+
+
 def test_detect_roofline_env_override(monkeypatch):
     monkeypatch.setenv("PADDLE_TPU_PEAK_FLOPS", "123e12")
     monkeypatch.setenv("PADDLE_TPU_HBM_BW", "456e9")
@@ -454,8 +467,29 @@ class TestBenchCompare:
         prev = {"value": 0.50, "detail": {"step_time_s": 0.30}}
         assert bench.compare_records(cur, prev, tolerance=0.05) == []
 
-    def test_prev_record_reads_artifacts(self):
+    def test_prev_record_reads_artifacts(self, tmp_path):
+        import json
+
         import bench
-        prev = bench._prev_record()
-        # the repo ships BENCH_r01..r05; the newest parsed payload wins
-        assert prev is not None and prev["value"] == pytest.approx(0.5148)
+        # the newest round with a parsed value wins; a failed round (no
+        # parsed payload) and an unreadable file are passed over
+        (tmp_path / "BENCH_r01.json").write_text(json.dumps({"rc": 1}))
+        (tmp_path / "BENCH_r02.json").write_text(json.dumps(
+            {"parsed": {"value": 0.41, "detail": {}}}))
+        (tmp_path / "BENCH_r03.json").write_text(json.dumps(
+            {"parsed": {"value": 0.52, "detail": {"step_time_s": 0.3}}}))
+        (tmp_path / "BENCH_r04.json").write_text("{truncated")
+        prev = bench._prev_record(str(tmp_path))
+        assert prev is not None and prev["value"] == pytest.approx(0.52)
+        assert bench._prev_record(str(tmp_path / "nowhere")) is None
+
+    def test_unknown_tpu_kind_has_no_peak(self):
+        """A TPU the tables do not know is an error, not a v5p."""
+        import types
+
+        import bench
+        v5e = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+        new = types.SimpleNamespace(platform="tpu", device_kind="TPU v9x")
+        assert bench._peak_flops(v5e) == 197e12
+        with pytest.raises(ValueError, match="TPU v9x"):
+            bench._peak_flops(new)

@@ -1,13 +1,9 @@
 """Driver-contract coverage: entry() compiles, dryrun_multichip shards the
 full train step over an 8-device mesh (conftest forces the virtual CPU mesh)."""
 
-import sys
-
 import jax
 import numpy as np
 import pytest
-
-sys.path.insert(0, "/root/repo")
 
 
 def test_entry_compiles():

@@ -233,6 +233,43 @@ class TestAutotuneCache:
         got3 = at.autotune("op", "k1", [(16,), (32,), (64,)], bench, (16,))
         assert tuple(got3) == (32,) and len(calls) == 3
 
+    def test_sweep_inside_a_jit_trace_measures_and_failures_are_counted(
+            self, tmp_path, monkeypatch, capsys):
+        """First use of a shape is inside a jit trace: the sweep still
+        runs on concrete values.  A failing candidate is counted and
+        named; a sweep where every candidate fails raises."""
+        from paddle_tpu.observability import default_registry
+        from paddle_tpu.ops.pallas import autotune as at
+        monkeypatch.setenv("PADDLE_TPU_AUTOTUNE_CACHE",
+                           str(tmp_path / "c.json"))
+        at.clear_cache()
+        from paddle_tpu.ops.pallas.flash_attention import flash_attention
+        inner = jax.jit(lambda q: jnp.sum(flash_attention(
+            q, q, q, interpret=True, autotune=False)))
+
+        def bench(c):   # a jitted Pallas kernel on concrete arrays
+            if c == (1,):
+                raise ValueError("mosaic says no")
+            q = jnp.ones((1, 128, 1, 128), jnp.float32)
+            return abs(float(np.asarray(inner(q)))) * c[0]
+
+        def traced(x):
+            assert tuple(at.autotune("op", "kt", [(1,), (2,), (3,)],
+                                     bench, (9,))) == (2,)
+            return x + 1
+
+        failed = lambda: dict(
+            ("/".join(k), c.value()) for k, c in default_registry().get(
+                "paddle_tpu_autotune_cache_total").series()
+        ).get("op/candidate_failed", 0)
+        before = failed()
+        jax.jit(traced)(1.0)
+        assert failed() == before + 1
+        assert "candidate (1,) failed: ValueError: mosaic says no" in \
+            capsys.readouterr().err
+        with pytest.raises(RuntimeError, match="all 2 candidates failed"):
+            at.autotune("op", "kf", [(1,), (2,)], lambda c: 1 / 0, (9,))
+
     def test_disabled_uses_default(self, tmp_path, monkeypatch):
         from paddle_tpu.ops.pallas import autotune as at
         monkeypatch.setenv("PADDLE_TPU_AUTOTUNE_CACHE",
@@ -428,7 +465,6 @@ class TestMaskValue:
         mesh = sp_mesh(sp)
         spec = P(None, "sp", None, None)
         fn = shard_map(striped_ring_attention, mesh=mesh,
-                       in_specs=(spec, spec, spec), out_specs=spec,
-                       legacy_check_rep=False)
+                       in_specs=(spec, spec, spec), out_specs=spec)
         out = np.asarray(fn(qb, kb, vb), np.float32)
         assert np.isfinite(out).all()
