@@ -1,0 +1,190 @@
+"""BENCHMARK.json against the benchmark's contract, and the harness's
+promise that a new cell is new files and new entries only."""
+
+import hashlib
+import json
+import math
+import os
+import re
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+WIDTH = re.compile(r"(hidden|intermediate|latent|state|proj).*size|_dim$|"
+                   r"_rank$|head_dim|expand|experts_per_tok")
+
+
+@pytest.fixture(scope="module")
+def bench():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def _cells_of(metric, bench):
+    return metric.get("workloads", [w["name"] for w in bench["workloads"]])
+
+
+def test_keys_names_and_units(bench):
+    assert set(bench) == {"command", "paths", "run_seconds", "configs",
+                          "workloads", "end_to_end", "per_layer"}
+    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) < 64 * 1024
+    assert 1 <= bench["run_seconds"] <= 51
+    line = lambda s: 1 <= len(s) <= 200 and "\n" not in s and "\t" not in s
+    assert all(line(w) for w in bench["command"])
+    for c in bench["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert NAME.match(c["name"]) and line(c["why"]) and line(c["source"])
+        assert len(c["reduced"]) <= 16
+        assert all(NAME.match(k) and not WIDTH.search(k)
+                   for k in c["reduced"])
+    for w in bench["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert all(NAME.match(w[k]) for k in ("name", "config", "traffic"))
+        assert w["chips"] in (1, 4) and line(w["why"])
+    for group, keys in (("end_to_end", {"name", "unit", "better", "bound",
+                                        "source"}),
+                        ("per_layer", {"name", "unit", "better", "source",
+                                       "layer", "moves"})):
+        for m in bench[group]:
+            assert set(m) - {"workloads"} == keys, m
+            assert NAME.match(m["name"]) and UNIT.match(m["unit"]), m
+            assert m["better"] in ("lower", "higher")
+    names = [m["name"] for g in ("end_to_end", "per_layer")
+             for m in bench[g]]
+    assert len(names) == len(set(names))
+    for m in bench["end_to_end"]:
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.1
+    for m in bench["per_layer"]:
+        assert m["source"] in ("device_trace", "program_span",
+                               "program_counter", "host_clock")
+        assert line(m["layer"])
+
+
+def test_files_exist_and_metrics_connect(bench):
+    cells = {w["name"]: w for w in bench["workloads"]}
+    configs = {c["name"]: c for c in bench["configs"]}
+    assert len({c["file"] for c in bench["configs"]}) == len(configs)
+    assert {w["config"] for w in cells.values()} == set(configs)
+    pairs = [(w["config"], w["traffic"]) for w in cells.values()]
+    assert len(pairs) == len(set(pairs))
+    for c in configs.values():
+        assert any(c["file"].startswith(p + "/") for p in bench["paths"])
+        with open(os.path.join(ROOT, c["file"])) as f:
+            conf = json.load(f)
+        assert conf["source"] == c["source"]
+        # every cut is listed, and is a cut of the published value
+        assert set(conf["published"]) == set(c["reduced"])
+        assert all(conf[k] != v for k, v in conf["published"].items())
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    assert "setup_s" in e2e and "workloads" not in e2e["setup_s"]
+    for name, w in cells.items():
+        assert os.path.isfile(os.path.join(
+            ROOT, "perf", "traffic", w["traffic"] + ".json"))
+        mine = [m["name"] for m in e2e.values()
+                if name in _cells_of(m, bench)]
+        assert len(mine) >= 2 and "setup_s" in mine
+        assert any(name in _cells_of(m, bench) for m in bench["per_layer"])
+    for m in bench["per_layer"]:
+        assert os.path.isfile(os.path.join(
+            ROOT, "perf", "layer_metrics", m["name"] + ".py")), m["name"]
+        assert m["moves"] in e2e
+        for cell in _cells_of(m, bench):
+            assert cell in _cells_of(e2e[m["moves"]], bench), (m, cell)
+
+
+def test_four_chip_share(bench):
+    four = sum(w["chips"] == 4 for w in bench["workloads"])
+    assert four <= max(1, math.floor(0.25 * len(bench["workloads"])))
+
+
+def _run(root, *args, **env):
+    e = dict(os.environ, JAX_PLATFORMS="cpu", **env)
+    return subprocess.run([sys.executable, "perf/run.py", *args], cwd=root,
+                          env=e, capture_output=True, text=True, timeout=120)
+
+
+def _digest(root):
+    out = {}
+    for d, _, files in os.walk(root):
+        for f in files:
+            p = os.path.join(d, f)
+            with open(p, "rb") as fh:
+                out[os.path.relpath(p, root)] = \
+                    hashlib.sha256(fh.read()).hexdigest()
+    return out
+
+
+@pytest.fixture()
+def copy(tmp_path, bench):
+    """BENCHMARK.json and the files under ``paths``, nothing else."""
+    root = tmp_path / "checkout"
+    root.mkdir()
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
+    for p in bench["paths"]:
+        shutil.copytree(os.path.join(ROOT, p), root / p,
+                        ignore=shutil.ignore_patterns(
+                            ".cache", ".trace", "__pycache__"))
+    return root
+
+
+def test_a_new_cell_is_new_files_and_entries_only(copy, bench):
+    before = _digest(copy)
+    conf = json.load(open(copy / "perf/configs/internlm2-1.8b.L4.json"))
+    conf["num_hidden_layers"] = 2
+    (copy / "perf/configs/new-model.L2.json").write_text(json.dumps(conf))
+    (copy / "perf/traffic/new-mix.json").write_text(json.dumps({
+        "kind": "train", "generator": "new_batches",
+        "params": {"batch": 2, "seq": 128},
+        "system": {"adamw": {}}, "limits": {}}))
+    (copy / "perf/generators/new_batches.py").write_text(
+        "def batch(params, cfg, seed, step):\n    return {}\n")
+    (copy / "perf/layer_metrics/new_metric.train.py").write_text(
+        "def read(obs):\n    return 1.0\n")
+    b = json.loads(json.dumps(bench))
+    b["configs"].append({"name": "new-model.L2", "source": conf["source"],
+                         "file": "perf/configs/new-model.L2.json",
+                         "reduced": ["num_hidden_layers"], "why": "test"})
+    b["workloads"].append({"name": "new-cell", "config": "new-model.L2",
+                           "traffic": "new-mix", "chips": 1, "why": "test"})
+    for m in b["end_to_end"]:
+        if m["name"] == "train_tokens_per_s_per_chip":
+            m["workloads"].append("new-cell")
+    b["per_layer"].append({
+        "name": "new_metric.train", "unit": "count", "better": "lower",
+        "source": "program_counter", "layer": "trainer",
+        "moves": "train_tokens_per_s_per_chip", "workloads": ["new-cell"]})
+    (copy / "BENCHMARK.json").write_text(json.dumps(b))
+    r = _run(copy, "--list")
+    assert r.returncode == 0, r.stderr
+    line = [ln for ln in r.stdout.splitlines() if ln.startswith("new-cell:")]
+    assert line and "perf/configs/new-model.L2.json" in line[0]
+    assert "generator new_batches" in line[0]
+    assert "new_metric.train" in line[0]
+    after = _digest(copy)
+    changed = {k for k in before if after.get(k) != before[k]}
+    assert changed == {"BENCHMARK.json"}, changed
+
+
+def test_no_tpu_no_result(tmp_path):
+    r = _run(ROOT, "--workload", "train-1chip", "--seed", "1", "--seconds",
+             "1", "--trace", "0",
+             JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    assert r.returncode == 2
+    assert "refusing to run" in r.stderr and "'tpu'" in r.stderr
+    assert not any(ln.startswith("{") for ln in r.stdout.splitlines())
+
+
+def test_no_program_no_result(copy, tmp_path):
+    r = _run(copy, "--workload", "train-1chip", "--seed", "1", "--seconds",
+             "1", "--trace", "0",
+             JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    assert r.returncode != 0
+    assert "not in this checkout" in r.stderr
+    assert not any(ln.startswith("{") for ln in r.stdout.splitlines())
