@@ -42,7 +42,7 @@ from __future__ import annotations
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -88,8 +88,13 @@ def _serving_metrics():
             "paddle_tpu_serving_decode_token_seconds",
             "per-token decode latency (chunk wall time / tokens in "
             "chunk)", buckets=_TOKEN_BUCKETS),
-        "steps": reg.counter("paddle_tpu_serving_decode_steps_total",
-                             "compiled decode dispatches"),
+        "itl": reg.histogram(
+            "paddle_tpu_serving_inter_token_seconds",
+            "per-token gap a client saw: time between two emissions of "
+            "one request / tokens in the later one, observed per token "
+            "at retirement (a prefill chunk that lands between two of a "
+            "request's tokens shows here, not in the decode latency)",
+            buckets=_TOKEN_BUCKETS),
         "timeouts": reg.counter(
             "paddle_tpu_serving_timeouts_total",
             "requests retired with status=timeout (deadline expired "
@@ -215,6 +220,9 @@ class _Request:
     admitted_at: float = 0.0        # perf_counter at slot admission
     first_token_at: float = 0.0     # perf_counter when prefill emitted
     retired_at: float = 0.0         # perf_counter at retirement
+    # (perf_counter, tokens) of every emission: one stamp per engine
+    # step that gave this request tokens, shared by the whole batch
+    token_stamps: List[Tuple[float, int]] = field(default_factory=list)
     prefix_reused: int = 0          # prompt tokens served from the
     #                                 prefix cache (paged engine)
     spec_proposed: int = 0          # speculative drafts proposed
@@ -245,13 +253,18 @@ class RequestStatus(str):
     comparison keeps working) but additionally carries the request's
     lifecycle timing fields and trace id, so a client staring at its
     own timeout can tell queued-too-long from decoded-too-slowly
-    without server logs."""
+    without server logs.  ``token_times`` is the request's emissions,
+    ``[(perf_counter, tokens), ...]`` — the first is the first token,
+    the gaps between them are what the client waited between tokens
+    (``timings`` keeps its float-only :data:`TIMING_KEYS` schema)."""
 
     def __new__(cls, status: str, timings: Optional[Dict[str, float]]
-                = None, trace_id: Optional[str] = None):
+                = None, trace_id: Optional[str] = None,
+                token_times: Sequence[Tuple[float, int]] = ()):
         obj = super().__new__(cls, status)
         obj.timings = dict(timings or {})
         obj.trace_id = trace_id
+        obj.token_times = list(token_times)
         return obj
 
 
@@ -1101,38 +1114,49 @@ class ContinuousBatchingEngine:
 
     def _admit(self, slot: int, req: _Request):
         from paddle_tpu.generation import StaticCache  # noqa: F401
+        tr = self._tracer
         Lp = len(req.prompt)
-        Lb = self._bucket(Lp)
-        req.admitted_at = time.perf_counter()
-        if req.router_t0 is not None and not req.parked_s:
-            # once a session has been parked, admission latency is
-            # resume latency (resume_s), not routing latency
-            req.route_s = req.admitted_at - req.router_t0
-        ids = np.zeros((1, Lb), np.int32)
-        ids[0, :Lp] = req.prompt
-        cfgm = self.model.config
-        shape1 = (1, self.max_len, cfgm.num_key_value_heads, cfgm.head_dim)
-        # k and v must be DISTINCT buffers (the prefill donates its cache
-        # argument; an aliased pair would be donated twice)
-        kv1 = [(jnp.zeros(shape1, self._dtype), jnp.zeros(shape1,
-                                                          self._dtype))
-               for _ in range(cfgm.num_hidden_layers)]
-        sub = self._next_key()
+        with tr.span("serving.admit", rid=req.rid, slot=slot):
+            Lb = self._bucket(Lp)
+            req.admitted_at = time.perf_counter()
+            if req.router_t0 is not None and not req.parked_s:
+                # once a session has been parked, admission latency is
+                # resume latency (resume_s), not routing latency
+                req.route_s = req.admitted_at - req.router_t0
+        with tr.span("serving.build"):
+            ids = np.zeros((1, Lb), np.int32)
+            ids[0, :Lp] = req.prompt
+            cfgm = self.model.config
+            shape1 = (1, self.max_len, cfgm.num_key_value_heads,
+                      cfgm.head_dim)
+            # k and v must be DISTINCT buffers (the prefill donates its
+            # cache argument; an aliased pair would be donated twice)
+            kv1 = [(jnp.zeros(shape1, self._dtype),
+                    jnp.zeros(shape1, self._dtype))
+                   for _ in range(cfgm.num_hidden_layers)]
+            sub = self._next_key()
         # prefill child span under the request's root: covers the
         # bucketed forward AND the slot insert (both block admission)
         prefill = self._prefill_compiled.get(Lb, self._prefill)
-        with self._tracer.span("serving.prefill", parent=req.span,
-                               rid=req.rid, bucket=Lb, prompt_len=Lp):
-            first, caches1 = prefill(self._keep, self._quant,
-                                     jnp.asarray(ids), kv1,
-                                     jnp.asarray(Lp, jnp.int32),
-                                     sub)
-            insert = self._insert_compiled or self._insert
-            self._caches = insert(self._caches, caches1,
-                                  jnp.asarray(slot, jnp.int32))
-            first = int(first)
+        with tr.span("serving.prefill", parent=req.span,
+                     rid=req.rid, bucket=Lb, prompt_len=Lp):
+            with tr.span("serving.dispatch"):
+                first, caches1 = prefill(self._keep, self._quant,
+                                         jnp.asarray(ids), kv1,
+                                         jnp.asarray(Lp, jnp.int32),
+                                         sub)
+                insert = self._insert_compiled or self._insert
+                self._caches = insert(self._caches, caches1,
+                                      jnp.asarray(slot, jnp.int32))
+            with tr.span("serving.sync"):
+                first = int(first)
+        with tr.span("serving.emit"):
+            self._emit_first_unpaged(slot, req, first, Lp, Lb)
+
+    def _emit_first_unpaged(self, slot, req, first, Lp, Lb):
         req.first_token_at = time.perf_counter()
         req.out.append(first)
+        req.token_stamps.append((req.first_token_at, 1))
         m = self._metrics
         m["admissions"].inc()
         m["tokens"].inc()                       # the prefill's first token
@@ -1342,6 +1366,10 @@ class ContinuousBatchingEngine:
         if not req.first_token_at:
             req.first_token_at = float(h.get("first_token_at") or now)
         req.out = list(out_prev)
+        if not req.token_stamps:
+            # emitted elsewhere (the prefill replica, a migrated
+            # session): one stamp at the first token's time
+            req.token_stamps.append((req.first_token_at, len(out_prev)))
         if req.resume_at:
             req.resume_s += now - req.resume_at
             req.resume_at = 0.0
@@ -1646,30 +1674,40 @@ class ContinuousBatchingEngine:
         chunk samples the request's first token at the true last prompt
         position and registers the prompt's full blocks in the prefix
         trie (so the NEXT request with this prompt prefix skips them)."""
+        tr = self._tracer
         req = self._active[slot]
-        start = self._prefilling[slot]
-        Lp = len(req.prompt)
-        C = self._chunk
-        n = min(C, Lp - start)
-        ids = np.zeros((1, C), np.int32)
-        ids[0, :n] = req.prompt[start:start + n]
-        final = (start + n) == Lp
-        last_idx = (Lp - 1 - start) if final else 0
-        sub = self._next_key()
+        with tr.span("serving.build"):
+            start = self._prefilling[slot]
+            Lp = len(req.prompt)
+            C = self._chunk
+            n = min(C, Lp - start)
+            ids = np.zeros((1, C), np.int32)
+            ids[0, :n] = req.prompt[start:start + n]
+            final = (start + n) == Lp
+            last_idx = (Lp - 1 - start) if final else 0
+            sub = self._next_key()
         prefill = self._prefill_chunk_compiled or self._prefill_chunk_fn
-        m = self._metrics
         pool = self._pool
-        with self._tracer.span("serving.prefill", parent=req.span,
-                               rid=req.rid, chunk_start=start, tokens=n):
-            first, (pool.kpools, pool.vpools, pool.kscales,
-                    pool.vscales) = prefill(
-                self._keep, self._quant, jnp.asarray(ids),
-                pool.kpools, pool.vpools, pool.kscales, pool.vscales,
-                jnp.asarray(self._bt[slot:slot + 1]),
-                jnp.asarray([start], jnp.int32),
-                jnp.asarray(last_idx, jnp.int32), sub)
+        with tr.span("serving.prefill", parent=req.span,
+                     rid=req.rid, chunk_start=start, tokens=n):
+            with tr.span("serving.dispatch"):
+                first, (pool.kpools, pool.vpools, pool.kscales,
+                        pool.vscales) = prefill(
+                    self._keep, self._quant, jnp.asarray(ids),
+                    pool.kpools, pool.vpools, pool.kscales, pool.vscales,
+                    jnp.asarray(self._bt[slot:slot + 1]),
+                    jnp.asarray([start], jnp.int32),
+                    jnp.asarray(last_idx, jnp.int32), sub)
             if final:
-                first = int(first)
+                # a chunk that is not the last leaves nothing to wait
+                # for: the device runs it while the host goes on
+                with tr.span("serving.sync"):
+                    first = int(first)
+        with tr.span("serving.emit"):
+            self._emit_chunk(slot, req, first, start, n, final)
+
+    def _emit_chunk(self, slot, req, first, start, n, final):
+        Lp, C, m = len(req.prompt), self._chunk, self._metrics
         self._prefilling[slot] = start + n
         m["chunks"].inc()
         if C > n:
@@ -1694,8 +1732,11 @@ class ContinuousBatchingEngine:
         if req.resume_at:
             # recompute fallback finished its re-prefill: the session
             # is decoding again — that replay wall time is resume_s
+            # (the replayed token keeps the stamp of its first emission)
             req.resume_s += now - req.resume_at
             req.resume_at = 0.0
+        else:
+            req.token_stamps.append((now, 1))
         req.out.append(first)
         m["tokens"].inc()
         if req.mode == "prefill_only":
@@ -1735,52 +1776,73 @@ class ContinuousBatchingEngine:
     def _decode_step_paged(self, decoding: List[int]):
         """One fused K-step decode over every decoding slot (the paged
         analog of the tail of _step_inner)."""
-        active = np.zeros((self.slots,), bool)
-        active[decoding] = True
-        self._ensure_writable_span(decoding, self.steps_per_sync)
-        pos = np.where(active, self._pos, 0).astype(np.int32)
-        # non-decoding rows (free OR mid-prefill) get a zeroed block-
-        # table row: their masked write lands in the scratch block, not
-        # in a real sequence's (possibly shared) block 0
-        bt = np.where(active[:, None], self._bt, 0)
-        chunk_reqs = [self._active[i] for i in decoding]
-        sub = self._next_key()
+        tr = self._tracer
+        with tr.span("serving.build"):
+            active = np.zeros((self.slots,), bool)
+            active[decoding] = True
+            self._ensure_writable_span(decoding, self.steps_per_sync)
+            pos = np.where(active, self._pos, 0).astype(np.int32)
+            # non-decoding rows (free OR mid-prefill) get a zeroed
+            # block-table row: their masked write lands in the scratch
+            # block, not in a real sequence's (possibly shared) block 0
+            bt = np.where(active[:, None], self._bt, 0)
+            sub = self._next_key()
         t0 = time.perf_counter()
         decode = self._decode_compiled or self._decode_paged
         pool = self._pool
         with self._recorder.instrumented("serving.decode"):
-            (toks, pool.kpools, pool.vpools, pool.kscales,
-             pool.vscales) = decode(
-                self._keep, self._quant, pool.kpools, pool.vpools,
-                pool.kscales, pool.vscales, jnp.asarray(bt),
-                jnp.asarray(self._last_tok), jnp.asarray(pos),
-                jnp.asarray(active), sub)
-            toks = np.asarray(toks)                     # [B, K]
-        chunk_dt = time.perf_counter() - t0
-        K = toks.shape[1]
-        for r in chunk_reqs:
-            self._tracer.add_span("serving.decode_step", t0,
-                                  t0 + chunk_dt, parent=r.span,
-                                  rid=r.rid, tokens=K)
+            with tr.span("serving.dispatch"):
+                (toks, pool.kpools, pool.vpools, pool.kscales,
+                 pool.vscales) = decode(
+                    self._keep, self._quant, pool.kpools, pool.vpools,
+                    pool.kscales, pool.vscales, jnp.asarray(bt),
+                    jnp.asarray(self._last_tok), jnp.asarray(pos),
+                    jnp.asarray(active), sub)
+            with tr.span("serving.sync"):
+                toks = np.asarray(toks)                 # [B, K]
+        with tr.span("serving.emit"):
+            self._emit_decoded(
+                decoding, [toks[i] for i in decoding], t0, toks.shape[1])
+
+    def _emit_decoded(self, slots_: List[int], rows, t0: float,
+                      per_slot: Optional[int] = None):
+        """The token loop after a decode dispatch: ``rows[n]`` are the
+        tokens slot ``slots_[n]`` got, in order.  ONE clock reading
+        stamps the whole batch's emission; a request that reaches eos
+        or its budget retires here.  The decode-latency histogram gets
+        the dispatch's wall time over the tokens a slot hauled:
+        ``per_slot``, or the mean over the slots when they differ."""
+        now = time.perf_counter()
         emitted = 0
-        for i in decoding:
+        for i, row in zip(slots_, rows):
             req = self._active[i]
-            for j in range(K):
-                t = int(toks[i, j])
+            n = 0
+            done = False
+            for t in row:
+                t = int(t)
                 req.out.append(t)
-                emitted += 1
+                n += 1
                 self._pos[i] += 1
                 self._budget[i] -= 1
                 self._last_tok[i] = t
                 if (self.eos is not None and t == self.eos) \
                         or self._budget[i] <= 0:
-                    self._retire(i)
+                    # mid-chunk finish: the device generated (and
+                    # cached) the rest of the chunk; those rows are
+                    # unreachable for any successor
+                    done = True
                     break
-        m = self._metrics
-        m["steps"].inc()
+            req.token_stamps.append((now, n))
+            emitted += n
+            if done:
+                self._retire(i)
         if emitted:
+            m = self._metrics
             m["tokens"].inc(emitted)
-            m["decode"].observe(chunk_dt / K)
+            # one host interaction covers every active slot in
+            # parallel: a slot's token costs wall time / its haul
+            m["decode"].observe(
+                (now - t0) / (per_slot or emitted / len(slots_)))
 
     def _spec_decode_step(self, decoding: List[int]):
         """n-gram speculative decode: draft from each request's own
@@ -1789,92 +1851,103 @@ class ContinuousBatchingEngine:
         chain plus one bonus token.  Greedy-equivalent by construction:
         position j's argmax is conditioned only on tokens the chain has
         already validated."""
+        tr = self._tracer
         k = self.spec_tokens
         S = k + 1
-        active = np.zeros((self.slots,), bool)
-        active[decoding] = True
-        toks = np.zeros((self.slots, S), np.int32)
-        proposed = np.zeros((self.slots,), np.int64)
-        for i in decoding:
-            req = self._active[i]
-            toks[i, 0] = self._last_tok[i]
-            hist = np.concatenate([req.prompt,
-                                   np.asarray(req.out, np.int32)])
-            draft = _ngram_propose(hist, k, self._spec_ngram)
-            if draft is not None:
-                n = len(draft)
-                toks[i, 1:1 + n] = draft
-                toks[i, 1 + n:] = draft[-1]   # static-shape pad; unused
-                proposed[i] = n
-        self._ensure_writable_span(decoding, S)
-        pos = np.where(active, self._pos, 0).astype(np.int32)
-        bt = np.where(active[:, None], self._bt, 0)
+        with tr.span("serving.build"):
+            active = np.zeros((self.slots,), bool)
+            active[decoding] = True
+            toks = np.zeros((self.slots, S), np.int32)
+            proposed = np.zeros((self.slots,), np.int64)
+            for i in decoding:
+                req = self._active[i]
+                toks[i, 0] = self._last_tok[i]
+                hist = np.concatenate([req.prompt,
+                                       np.asarray(req.out, np.int32)])
+                draft = _ngram_propose(hist, k, self._spec_ngram)
+                if draft is not None:
+                    n = len(draft)
+                    toks[i, 1:1 + n] = draft
+                    toks[i, 1 + n:] = draft[-1]  # static-shape pad; unused
+                    proposed[i] = n
+            self._ensure_writable_span(decoding, S)
+            pos = np.where(active, self._pos, 0).astype(np.int32)
+            bt = np.where(active[:, None], self._bt, 0)
         t0 = time.perf_counter()
         verify = self._spec_verify_compiled or self._spec_verify
         pool = self._pool
         with self._recorder.instrumented("serving.decode"):
-            (greedy, pool.kpools, pool.vpools, pool.kscales,
-             pool.vscales) = verify(
-                self._keep, self._quant, pool.kpools, pool.vpools,
-                pool.kscales, pool.vscales, jnp.asarray(bt),
-                jnp.asarray(toks), jnp.asarray(pos),
-                jnp.asarray(active))
-            greedy = np.asarray(greedy)                 # [B, S]
-        chunk_dt = time.perf_counter() - t0
-        m = self._metrics
-        emitted_total = 0
-        for i in decoding:
-            req = self._active[i]
-            n = int(proposed[i])
-            a = 0
-            while a < n and greedy[i, a] == toks[i, a + 1]:
-                a += 1
-            # a accepted drafts + the bonus token the verify computed at
-            # the last validated position (rejected rows' KV is stale
-            # but masked — the write head rolls back over it)
-            emitted = [int(t) for t in toks[i, 1:1 + a]] + \
-                [int(greedy[i, a])]
-            req.spec_proposed += n
-            req.spec_accepted += a
-            if n:
-                m["spec"].labels(kind="proposed").inc(n)
-                if a:
-                    m["spec"].labels(kind="accepted").inc(a)
-            self._tracer.add_span("serving.decode_step", t0,
-                                  t0 + chunk_dt, parent=req.span,
-                                  rid=req.rid, tokens=len(emitted),
-                                  drafts=n, accepted=a)
-            for t in emitted:
-                req.out.append(t)
-                emitted_total += 1
-                self._pos[i] += 1
-                self._budget[i] -= 1
-                self._last_tok[i] = t
-                if (self.eos is not None and t == self.eos) \
-                        or self._budget[i] <= 0:
-                    self._retire(i)
-                    break
-        m["steps"].inc()
-        if emitted_total:
-            m["tokens"].inc(emitted_total)
-            # wall time per token, averaged over the per-slot haul
-            m["decode"].observe(
-                chunk_dt * len(decoding) / emitted_total)
+            with tr.span("serving.dispatch"):
+                (greedy, pool.kpools, pool.vpools, pool.kscales,
+                 pool.vscales) = verify(
+                    self._keep, self._quant, pool.kpools, pool.vpools,
+                    pool.kscales, pool.vscales, jnp.asarray(bt),
+                    jnp.asarray(toks), jnp.asarray(pos),
+                    jnp.asarray(active))
+            with tr.span("serving.sync"):
+                greedy = np.asarray(greedy)             # [B, S]
+        with tr.span("serving.emit"):
+            m = self._metrics
+            rows = []
+            for i in decoding:
+                req = self._active[i]
+                n = int(proposed[i])
+                a = 0
+                while a < n and greedy[i, a] == toks[i, a + 1]:
+                    a += 1
+                # a accepted drafts + the bonus token the verify
+                # computed at the last validated position (rejected
+                # rows' KV is stale but masked — the write head rolls
+                # back over it)
+                rows.append([*toks[i, 1:1 + a], greedy[i, a]])
+                req.spec_proposed += n
+                req.spec_accepted += a
+                if n:
+                    m["spec"].labels(kind="proposed").inc(n)
+                    if a:
+                        m["spec"].labels(kind="accepted").inc(a)
+            self._emit_decoded(decoding, rows, t0)
 
-    def _step_inner_paged(self) -> bool:
+    def _schedule_head(self, step_span):
+        """What an engine step starts with, inside the caller's
+        ``serving.schedule`` span: the fault point and the step span's
+        counts.  Returns the number of active slots."""
         from paddle_tpu.robustness import fault_point
-        fault_point("serving.engine_step",
-                    active=sum(r is not None for r in self._active),
+        n_active = sum(r is not None for r in self._active)
+        fault_point("serving.engine_step", active=n_active,
                     queued=len(self._queue))
-        if self._auto_park_s is not None:
-            # deadline-aware session scheduling: park the most patient
-            # active session when queued work is slot-starved; bring
-            # auto-parked sessions back once the queue drains
-            self._maybe_auto_park()
-        free = [i for i, r in enumerate(self._active) if r is None]
+        step_span.set_attribute("active", n_active)
+        step_span.set_attribute("queued", len(self._queue))
+        return n_active
+
+    def _step_inner_paged(self, step_span) -> bool:
+        tr = self._tracer
+        with tr.span("serving.schedule"):
+            self._schedule_head(step_span)
+            if self._auto_park_s is not None:
+                # deadline-aware session scheduling: park the most
+                # patient active session when queued work is
+                # slot-starved; bring auto-parked sessions back once
+                # the queue drains
+                self._maybe_auto_park()
+            free = [i for i, r in enumerate(self._active) if r is None]
+            # a failed admission leaves the slots as they are, so who
+            # decodes is settled here, in the schedule's own span
+            decoding = [i for i, r in enumerate(self._active)
+                        if r is not None and i not in self._prefilling]
+            step_span.set_attribute("decoding", len(decoding))
         if free and self._queue:
-            if self._admit_paged(free[0], self._queue[0]):
+            req = self._queue[0]
+            with tr.span("serving.admit", rid=req.rid) as sp:
+                admitted = self._admit_paged(free[0], req)
+                # no blocks: not admitted, or admitted and retired at once
+                seq = self._seq[free[0]] if admitted else None
+                sp.set_attribute("prefix_tokens_reused", req.prefix_reused)
+                sp.set_attribute("blocks",
+                                 len(seq.bids) if seq is not None else 0)
+            if admitted:
                 self._queue.popleft()
+                step_span.set_attribute("ran", "admit")
                 return True
             # allocator dry: the request stays queued (add_request
             # already rejected anything the empty pool couldn't hold, so
@@ -1882,8 +1955,6 @@ class ContinuousBatchingEngine:
             # blocks eventually; deadlines still bound the wait)
         if all(r is None for r in self._active):
             return bool(self._queue)
-        decoding = [i for i, r in enumerate(self._active)
-                    if r is not None and i not in self._prefilling]
         # chunked prefill interleaves with decode: alternate dispatches
         # so a kilotoken prompt can't stall in-flight requests' TPOT,
         # and an idle decode pool can't starve TTFT
@@ -1891,13 +1962,16 @@ class ContinuousBatchingEngine:
             not decoding or self._interleave_decode)
         self._interleave_decode = not self._interleave_decode
         if do_chunk:
+            step_span.set_attribute("ran", "prefill_chunk")
             self._prefill_chunk_step(min(self._prefilling))
             return True
         if not decoding:
             return True
         if self.spec_tokens:
+            step_span.set_attribute("ran", "spec")
             self._spec_decode_step(decoding)
         else:
+            step_span.set_attribute("ran", "decode")
             self._decode_step_paged(decoding)
         return True
 
@@ -1919,7 +1993,14 @@ class ContinuousBatchingEngine:
         trace_id = req.span.trace_id if req.span is not None else None
         timings = _request_timings(req)
         self._status[req.rid] = RequestStatus(
-            status, timings=timings, trace_id=trace_id)
+            status, timings=timings, trace_id=trace_id,
+            token_times=req.token_stamps)
+        # the gap a client saw before each token after its first
+        itl = self._metrics["itl"]
+        for (t_prev, _), (t, n) in zip(req.token_stamps,
+                                       req.token_stamps[1:]):
+            for _ in range(n):
+                itl.observe((t - t_prev) / n)
         while len(self._status) > 8192:   # bounded, like everything else
             self._status.pop(next(iter(self._status)))
         # a recompute-resumed session folded generated tokens into its
@@ -1952,6 +2033,8 @@ class ContinuousBatchingEngine:
         if req.span is not None:
             req.span.set_attribute("status", status)
             req.span.set_attribute("generated", len(req.out))
+            req.span.set_attribute("token_stamps",
+                                   list(req.token_stamps))
             req.span.end(end_time=req.retired_at)
 
     def _count_slo(self, req: _Request):
@@ -2105,81 +2188,60 @@ class ContinuousBatchingEngine:
         """One scheduling step.  Returns False when nothing is left.
         Engine-step exceptions fail the in-flight batch without killing
         the engine (see :meth:`_recover`)."""
-        self._expire()
-        try:
-            out = self._step_inner_paged() if self.paged \
-                else self._step_inner()
-        except Exception as e:  # KeyboardInterrupt etc. still propagate
-            self._recover(e)
-            return bool(self._queue) or \
-                any(r is not None for r in self._active)
-        self._error_streak = 0
-        return out
+        tr = self._tracer
+        # one span per step, whatever the batch: its children are the
+        # phases the device waits for the host in (schedule, admit,
+        # build, dispatch, sync, emit), each opened where the work is
+        with tr.span("serving.step", root_eligible=False,
+                     ran="none") as sp:
+            with tr.span("serving.schedule"):
+                self._expire()
+            try:
+                out = self._step_inner_paged(sp) if self.paged \
+                    else self._step_inner(sp)
+            except Exception as e:  # KeyboardInterrupt etc. propagate
+                self._recover(e)
+                return bool(self._queue) or \
+                    any(r is not None for r in self._active)
+            self._error_streak = 0
+            return out
 
-    def _step_inner(self) -> bool:
-        from paddle_tpu.robustness import fault_point
-        fault_point("serving.engine_step",
-                    active=sum(r is not None for r in self._active),
-                    queued=len(self._queue))
-        free = [i for i, r in enumerate(self._active) if r is None]
+    def _step_inner(self, step_span) -> bool:
+        tr = self._tracer
+        with tr.span("serving.schedule"):
+            n_active = self._schedule_head(step_span)
+            free = [i for i, r in enumerate(self._active) if r is None]
         if free and self._queue:
+            step_span.set_attribute("ran", "admit")
             self._admit(free[0], self._queue.popleft())
             return True
-        if all(r is None for r in self._active):
+        if not n_active:
             return bool(self._queue)
-        active = np.array([r is not None for r in self._active])
-        # inactive slots decode at the last row with a discarded output —
-        # their write lands on max_len-1 which no active sequence can
-        # reach (add_request enforces prompt+new <= max_len <= row max)
-        pos = np.where(active, self._pos, self.max_len - 1).astype(np.int32)
-        chunk_reqs = [r for r in self._active if r is not None]
-        sub = self._next_key()
+        step_span.set_attribute("decoding", n_active)
+        step_span.set_attribute("ran", "decode")
+        with tr.span("serving.build"):
+            active = np.array([r is not None for r in self._active])
+            # inactive slots decode at the last row with a discarded
+            # output — their write lands on max_len-1 which no active
+            # sequence can reach (add_request enforces prompt+new <=
+            # max_len <= row max)
+            pos = np.where(active, self._pos,
+                           self.max_len - 1).astype(np.int32)
+            sub = self._next_key()
         t0 = time.perf_counter()
         decode = self._decode_compiled or self._decode
         with self._recorder.instrumented("serving.decode"):
-            toks, self._caches = decode(
-                self._keep, self._quant, self._caches,
-                jnp.asarray(self._last_tok), jnp.asarray(pos),
-                jnp.asarray(active), sub)
-            toks = np.asarray(toks)                     # [B, K]
-        chunk_dt = time.perf_counter() - t0
-        K = toks.shape[1]
-        # one retroactive decode-step span per request in the chunk:
-        # the fused dispatch is shared, but each request's trace shows
-        # its own slice of the timeline (same endpoints, K tokens)
-        for r in chunk_reqs:
-            self._tracer.add_span("serving.decode_step", t0,
-                                  t0 + chunk_dt, parent=r.span,
-                                  rid=r.rid, tokens=K)
-        emitted = 0
-        for i, req in enumerate(self._active):
-            if req is None:
-                continue
-            for j in range(K):
-                t = int(toks[i, j])
-                req.out.append(t)
-                emitted += 1
-                self._pos[i] += 1
-                self._budget[i] -= 1
-                self._last_tok[i] = t
-                if (self.eos is not None and t == self.eos) \
-                        or self._budget[i] <= 0:
-                    # mid-chunk finish: the device generated (and cached)
-                    # the rest of the chunk; those rows are unreachable
-                    # for any successor (reuse prefills from row 0 and
-                    # the causal bound hides rows past the write head)
-                    self._retire(i)
-                    break
-            else:
-                continue
-        m = self._metrics
-        m["steps"].inc()
-        if emitted:
-            m["tokens"].inc(emitted)
-            # per-token latency: one host interaction covers K sequential
-            # device steps over all active slots — a slot's token costs
-            # chunk time / K (the batch dimension is parallel)
-            m["decode"].observe(chunk_dt / K)
+            with tr.span("serving.dispatch"):
+                toks, self._caches = decode(
+                    self._keep, self._quant, self._caches,
+                    jnp.asarray(self._last_tok), jnp.asarray(pos),
+                    jnp.asarray(active), sub)
+            with tr.span("serving.sync"):
+                toks = np.asarray(toks)                 # [B, K]
+        with tr.span("serving.emit"):
+            decoding = np.flatnonzero(active).tolist()
+            self._emit_decoded(
+                decoding, [toks[i] for i in decoding], t0, toks.shape[1])
         return True
 
     def run(self):

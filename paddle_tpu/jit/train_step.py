@@ -524,7 +524,7 @@ class TrainStep(CompiledStepBase):
             return self._step_body(*step_args)
 
     def _step_body(self, params, opt_state, step_count, batch, key, lr):
-        model, opt = self.model, self.optimizer
+        model = self.model
 
         def loss_of_trainable(train_params, frozen_params, mb, k):
             full = dict(frozen_params)
@@ -568,13 +568,21 @@ class TrainStep(CompiledStepBase):
                               train_p)
             (loss, grads), _ = jax.lax.scan(
                 one_micro, (jnp.zeros((), jnp.float32), g0), (micro, keys))
+        # everything after the backward pass carries one name in a
+        # device trace: the grad norm, the update, the guard's select
+        with jax.named_scope("optimizer"):
+            return self._update(params, opt_state, step_count, train_p,
+                                frozen_p, loss, grads, lr)
+
+    def _update(self, params, opt_state, step_count, train_p, frozen_p,
+                loss, grads, lr):
         # global grad norm for the telemetry gauge: one vdot per leaf —
         # noise next to the backward pass it rides on
         gnorm = jnp.sqrt(sum(
             (jnp.vdot(g, g).real for g in jax.tree.leaves(grads)),
             start=jnp.zeros((), jnp.float32)))
         step_count = step_count + 1
-        new_train, new_state = opt.apply_gradients(
+        new_train, new_state = self.optimizer.apply_gradients(
             train_p, grads,
             {n: opt_state[n] for n in train_p}, step_count, lr=lr)
         new_params = dict(frozen_p)
@@ -698,8 +706,8 @@ class TrainStep(CompiledStepBase):
 
     def __call__(self, batch):
         # step span: children cover h2d placement, the compiled dispatch
-        # (with the accum scan as a nested level), and the step-guard's
-        # device sync — a slow step names its slow phase in the trace
+        # and the step-guard's device sync — a slow step names its slow
+        # phase in the trace, and in a device trace (tracing.py)
         with self._tracer.span("train.step", step=self._host_steps,
                                accum=self._accum_steps):
             return self._call_traced(batch)
@@ -749,18 +757,11 @@ class TrainStep(CompiledStepBase):
                 "PADDLE_TPU_STRAGGLER_DELAY_S", "0.05")))
         with self._recorder.instrumented("train.step",
                                          step=self._host_steps):
+            # with accum_steps > 1 the microbatch scan runs on the
+            # device as ONE program inside this dispatch
             with self._tracer.span("train.dispatch",
                                    microbatches=self._accum_steps):
-                if self._accum_steps > 1:
-                    # the scan runs on device as ONE program; this child
-                    # span marks the accumulated region so the trace
-                    # shows dispatch time is microbatch work, not gap
-                    with self._tracer.span("train.accum_microbatches",
-                                           n=self._accum_steps):
-                        loss, gnorm, skip_code = self._run_jitted(batch,
-                                                                  sub)
-                else:
-                    loss, gnorm, skip_code = self._run_jitted(batch, sub)
+                loss, gnorm, skip_code = self._run_jitted(batch, sub)
         dt = time.perf_counter() - t0
         self._host_steps += 1
         m = self._metrics

@@ -20,6 +20,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from paddle_tpu.nn import functional as F
@@ -276,22 +277,31 @@ class LlamaDecoderLayer(Layer):
             y = _fused_decoder(self, x, rope_cos, rope_sin)
             if y is not None:
                 return y
-        qkv = _fused_norm_qkv(self, x)
-        if qkv is not None:
-            h = self.self_attn.attend(*qkv, rope_cos, rope_sin,
-                                      attn_mask, cache, position_offset)
-        else:
-            h = self.self_attn(self.input_layernorm(x), rope_cos, rope_sin,
-                               attn_mask, cache, position_offset)
-        new_cache = None
-        if cache is not None:
-            h, new_cache = h
-        # NOT the fused Pallas rms_norm_residual: measured in-model
-        # (bench.py v5e) the custom-kernel call is a fusion barrier that
-        # costs ~2 MFU points vs letting XLA fuse the chain (0.491 vs
-        # 0.514) even though the kernel wins 1.38x in isolation
-        x = x + h
-        x = x + self.mlp(self.post_attention_layernorm(x))
+        # the scope names are what a device trace is read by (``attn``:
+        # norm + QKV + rope + attention + output projection; ``mlp``:
+        # norm + MLP), on the backward operations too
+        # (``transpose(jvp(attn))``); each takes its residual add
+        with jax.named_scope("attn"):
+            qkv = _fused_norm_qkv(self, x)
+            if qkv is not None:
+                h = self.self_attn.attend(*qkv, rope_cos, rope_sin,
+                                          attn_mask, cache,
+                                          position_offset)
+            else:
+                h = self.self_attn(self.input_layernorm(x), rope_cos,
+                                   rope_sin, attn_mask, cache,
+                                   position_offset)
+            new_cache = None
+            if cache is not None:
+                h, new_cache = h
+            # NOT the fused Pallas rms_norm_residual: measured in-model
+            # (bench.py v5e) the custom-kernel call is a fusion barrier
+            # that costs ~2 MFU points vs letting XLA fuse the chain
+            # (0.491 vs 0.514) even though the kernel wins 1.38x in
+            # isolation
+            x = x + h
+        with jax.named_scope("mlp"):
+            x = x + self.mlp(self.post_attention_layernorm(x))
         if cache is not None:
             return x, new_cache
         return x
@@ -322,7 +332,8 @@ class LlamaModel(Layer):
 
     def forward(self, input_ids, attn_mask=None, caches=None,
                 position_offset=0):
-        x = self.embed_tokens(input_ids)
+        with jax.named_scope("embed"):
+            x = self.embed_tokens(input_ids)
         new_caches = [] if caches is not None else None
         for i, layer in enumerate(self.layers):
             cache = caches[i] if caches is not None else None
@@ -331,7 +342,8 @@ class LlamaModel(Layer):
             if caches is not None:
                 x, c = x
                 new_caches.append(c)
-        x = self.norm(x)
+        with jax.named_scope("lm_head_ce"):     # final norm, head, loss
+            x = self.norm(x)
         if caches is not None:
             return x, new_caches
         return x
@@ -354,12 +366,13 @@ class LlamaForCausalLM(Layer):
         new_caches = None
         if caches is not None:
             h, new_caches = h
-        if self.lm_head is None:
-            from paddle_tpu.ops import linalg as L
-            logits = L.matmul(h, self.model.embed_tokens.weight,
-                              transpose_y=True)
-        else:
-            logits = self.lm_head(h)
+        with jax.named_scope("lm_head_ce"):
+            if self.lm_head is None:
+                from paddle_tpu.ops import linalg as L
+                logits = L.matmul(h, self.model.embed_tokens.weight,
+                                  transpose_y=True)
+            else:
+                logits = self.lm_head(h)
         if caches is not None:
             return logits, new_caches
         return logits
@@ -376,10 +389,11 @@ class LlamaForCausalLM(Layer):
         c_softmax_with_cross_entropy)."""
         h = self.model(input_ids)
         d = h.shape[-1]
-        w = self.model.embed_tokens.weight.t() if self.lm_head is None \
-            else self.lm_head.weight
-        return F.fused_linear_cross_entropy(
-            M.reshape(h, [-1, d]), w, M.reshape(labels, [-1]))
+        with jax.named_scope("lm_head_ce"):
+            w = self.model.embed_tokens.weight.t() \
+                if self.lm_head is None else self.lm_head.weight
+            return F.fused_linear_cross_entropy(
+                M.reshape(h, [-1, d]), w, M.reshape(labels, [-1]))
 
     # -- GSPMD sharding rules -------------------------------------------------
     @staticmethod

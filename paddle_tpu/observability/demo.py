@@ -181,8 +181,10 @@ def main(argv=None) -> int:
           f"{len(stamped)} flight-recorder events stamped with trace "
           f"ids -> {args.trace_out}", file=sys.stderr)
     if not {"train.step", "train.dispatch",
-            "serving.request", "serving.prefill",
-            "serving.decode_step", "compile.lower", "compile.xla"} <= names:
+            "serving.request", "serving.prefill", "serving.step",
+            "serving.schedule", "serving.admit", "serving.build",
+            "serving.dispatch", "serving.sync", "serving.emit",
+            "compile.lower", "compile.xla"} <= names:
         print(f"[demo] FAIL: expected spans missing from {sorted(names)}",
               file=sys.stderr)
         return 1

@@ -20,7 +20,7 @@ pieces:
   AOT-compiled executables under ``block_until_ready`` — the portable
   fallback that works on every backend.  ``capture_xla_trace`` wraps
   the real ``jax.profiler`` XPlane capture for offline TensorBoard /
-  Perfetto analysis when the platform supports it.  Each timed segment
+  Perfetto analysis, and raises where the platform's profiler fails.  Each timed segment
   becomes a ``device.<name>`` child span of the enclosing step span, so
   the Perfetto export shows host and device time in one view.
 
@@ -51,6 +51,7 @@ import dataclasses
 import os
 import threading
 import time
+import warnings
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -302,32 +303,38 @@ def aot_compile(fn: Callable, *args, target: str = "fn",
 
 
 def capture_xla_trace(fn: Callable[[], Any],
-                      logdir: Optional[str] = None) -> Optional[str]:
-    """Best-effort ``jax.profiler`` XPlane capture around ``fn()`` —
-    the full-fidelity device trace (HLO timelines, per-fusion device
-    time) for offline TensorBoard/Perfetto analysis.  Returns the
-    logdir holding the capture, or None when the platform profiler is
-    unavailable (the :class:`DeviceProfiler` numbers never depend on
-    it — segment timing is the portable path)."""
+                      logdir: Optional[str] = None) -> str:
+    """``jax.profiler`` XPlane capture around ``fn()`` — the
+    full-fidelity device trace (HLO timelines, per-fusion device time;
+    the tracer's spans on its host plane) for offline analysis.
+    Returns the logdir holding the capture.  A profiler that cannot
+    start or stop, or that wrote no ``.xplane.pb``, raises
+    ``RuntimeError`` with the cause: the caller decides whether its own
+    numbers can do without the capture."""
     import glob
     import tempfile
     if logdir is None:
         logdir = tempfile.mkdtemp(prefix="paddle_tpu_xla_trace_")
     try:
         jax.profiler.start_trace(logdir)
-    except Exception:
-        return None
+    except Exception as e:
+        raise RuntimeError(
+            f"jax.profiler could not start a trace in {logdir}: {e}") from e
     try:
         out = fn()
         jax.block_until_ready(out)
     finally:
         try:
             jax.profiler.stop_trace()
-        except Exception:
-            return None
-    hits = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
-                     recursive=True)
-    return logdir if hits else None
+        except Exception as e:
+            raise RuntimeError(
+                f"jax.profiler could not stop the trace in {logdir}: "
+                f"{e}") from e
+    if not glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                     recursive=True):
+        raise RuntimeError(f"jax.profiler wrote no .xplane.pb under "
+                           f"{logdir}")
+    return logdir
 
 
 # -- segment timing + roofline-gap attribution -------------------------------
@@ -621,8 +628,13 @@ class DeviceProfiler:
                 self._feed_ledger(seg, report)
             if capture_xla and self._segments:
                 seg = self._segments[0]
-                trace_dir = capture_xla_trace(
-                    lambda: seg.fn(*seg.args, **seg.kwargs))
+                try:
+                    trace_dir = capture_xla_trace(
+                        lambda: seg.fn(*seg.args, **seg.kwargs))
+                except RuntimeError as e:
+                    # the segment timings above do not depend on the
+                    # capture: keep them, and say why it is missing
+                    warnings.warn(f"DeviceProfiler: no XLA trace: {e}")
         if reports:
             self._save_ledger()
         return AttributionResult(segments=reports,

@@ -4,11 +4,16 @@ The metrics registry answers *whether* something drifted ("p99 step
 latency rose"); this module answers *where the time went* ("the decode
 chunk for request 17 in generation 3 stalled").  Spans form a tree:
 
-    train.step                      serving.request
-      ├─ train.h2d                    ├─ serving.prefill
-      ├─ train.dispatch               ├─ serving.decode_step ×K
-      │    └─ train.accum_microbatches└─ ...
-      └─ train.guard
+    train.step                serving.request ──(token_stamps at retire)
+      ├─ train.h2d               └─ serving.prefill ×chunks
+      ├─ train.dispatch                ├─ serving.build
+      └─ train.guard                   ├─ serving.dispatch
+                                       └─ serving.sync
+                              serving.step  (one per engine step())
+                                ├─ serving.schedule
+                                ├─ serving.admit
+                                ├─ serving.build / .dispatch / .sync
+                                └─ serving.emit
 
 Every span carries ``trace_id`` / ``span_id`` / ``parent_id``.  Context
 lives on a thread-local stack; worker threads (device prefetch, the
@@ -32,6 +37,15 @@ so a crash dump and a trace can be joined after the fact.  Export is
 Perfetto-compatible chrome-trace JSON (:meth:`Tracer.export_chrome`);
 ``RecordEvent`` host annotations from the profiler are delivered into
 the active span (:func:`on_host_event`) so both views nest in one file.
+
+The device trace's clock: every scoped span (:meth:`Tracer.span`) is
+also entered as a ``jax.profiler.TraceAnnotation`` of the same name
+(:func:`host_annotation`, the one door to the profiler's host plane —
+``profiler.RecordEvent`` goes through it too).  While a profiler session
+is capturing, the span therefore sits on its host thread's line of the
+``.xplane.pb`` beside the device lines, on the profiler's clock, and a
+device-idle gap can be given to the phase the host was in.  The
+annotation carries the name only; attributes stay in the ring.
 """
 
 from __future__ import annotations
@@ -42,12 +56,12 @@ import random
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Dict, List, NamedTuple, Optional
 
 __all__ = ["Span", "SpanContext", "Tracer", "tracer", "trace_span",
            "inject_context", "extract_context", "inject_spans",
-           "extract_spans", "on_host_event"]
+           "extract_spans", "on_host_event", "host_annotation"]
 
 # perf_counter → wall-clock offset, fixed once per process: span
 # timestamps are taken with the cheap monotonic clock but exported as
@@ -60,6 +74,24 @@ _UNSET = object()
 
 def _gen_id() -> str:
     return f"{random.getrandbits(64):016x}"
+
+
+_TraceAnnotation = None     # jax.profiler.TraceAnnotation, on first use
+_NO_ANNOTATION = nullcontext()
+
+
+def host_annotation(name: str):
+    """A region of this thread named ``name`` on the jax profiler's host
+    plane: a context manager that is an atomic check and nothing else
+    while no profiler session is open.  The ONE place the program
+    writes host events into a device trace — ``Tracer.span`` and
+    ``profiler.RecordEvent`` both come here, so a region appears there
+    once.  The name is all it carries (a ``name#k=v#`` suffix would
+    break lookups by name)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation as _TraceAnnotation
+    return _TraceAnnotation(name)
 
 
 class SpanContext(NamedTuple):
@@ -219,7 +251,10 @@ class Tracer:
                    root_eligible: bool = True, **attrs):
         """Begin a span with MANUAL lifetime (caller must ``end()``).
         ``parent`` may be a Span, a SpanContext, None (force a new
-        trace), or omitted (inherit the thread's current context)."""
+        trace), or omitted (inherit the thread's current context).
+        Such a span may end in another call on another thread (a serving
+        request, an elastic generation), so unlike :meth:`span` it is
+        not written into the profiler's host plane."""
         if not self.enabled:
             return _NOOP
         if parent is _UNSET:
@@ -244,7 +279,9 @@ class Tracer:
              **attrs):
         """Scoped span: pushed on this thread's stack (children created
         inside auto-parent to it), ended on exit; an escaping exception
-        is stamped into the ``error`` attribute before re-raising."""
+        is stamped into the ``error`` attribute before re-raising.  A
+        sampled span is also a :func:`host_annotation` for its duration
+        (the device trace's clock)."""
         s = self.start_span(name, parent=parent,
                             root_eligible=root_eligible, **attrs)
         if s is _NOOP:
@@ -253,7 +290,8 @@ class Tracer:
         stack = self._stack()
         stack.append(s)
         try:
-            yield s
+            with host_annotation(name) if s.sampled else _NO_ANNOTATION:
+                yield s
         except BaseException as e:
             s.set_attribute("error", type(e).__name__)
             raise
@@ -264,8 +302,9 @@ class Tracer:
     def add_span(self, name: str, t0: float, t1: float, parent=_UNSET,
                  root_eligible: bool = True, **attrs):
         """Record an ALREADY-FINISHED region (perf_counter endpoints) —
-        for work whose duration is known only after the fact, like the
-        per-request slice of a fused decode chunk."""
+        for work whose duration is known only after the fact (a
+        ``RecordEvent`` delivered by :func:`on_host_event`).  It has no
+        live interval, so it writes no profiler annotation."""
         s = self.start_span(name, parent=parent,
                             root_eligible=root_eligible, **attrs)
         if s is _NOOP:
@@ -293,6 +332,17 @@ class Tracer:
             items = [s for s in items if s["name"] == name]
         if last is not None:
             items = items[-last:]
+        return items
+
+    def finished_roots(self, name: Optional[str] = None) -> List[dict]:
+        """The finished ROOT spans still kept (the newest 512), oldest
+        first.  Roots have a ring of their own, so a request's
+        ``serving.request`` span (with its ``token_stamps``) survives
+        however many step spans pass through the main ring after it."""
+        with self._lock:
+            items = list(self._roots)
+        if name is not None:
+            items = [s for s in items if s["name"] == name]
         return items
 
     def clear(self):

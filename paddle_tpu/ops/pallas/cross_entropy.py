@@ -138,6 +138,7 @@ def _fwd_pallas(x, lbl_col, *, block_t, block_v, interpret):
             pltpu.VMEM((block_t, 1), jnp.float32),
             pltpu.VMEM((block_t, 1), jnp.float32),
         ],
+        name="fused_ce_fwd",
         interpret=interpret,
         **params,
     )(x, lbl_col)
@@ -179,6 +180,7 @@ def _bwd_pallas(x, lbl_col, lse, g_col, *, block_t, block_v, interpret):
         ],
         out_specs=pl.BlockSpec((block_t, block_v), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((t, v), x.dtype),
+        name="fused_ce_bwd",
         interpret=interpret,
         **params,
     )(x, lbl_col, lse, g_col)

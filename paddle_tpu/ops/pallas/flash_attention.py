@@ -178,6 +178,7 @@ def _fwd_pallas(q, k, v, *, scale, causal, block_q, block_k,
             _out_struct((b, hq, nq, 1, block_q), jnp.float32, q, k, v),
         ],
         scratch_shapes=scratch,
+        name="flash_fwd",
         interpret=interpret,
         **params,
     )(q, k, v)
@@ -347,6 +348,7 @@ def _bwd_pallas(res, g, *, scale, causal, block_q, block_k, interpret):
                                lambda b_, h, i, j: (b_, h, i, 0)),
         out_shape=_out_struct((b, hq, s, d), q.dtype, q, k, v, g),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        name="flash_bwd_dq",
         interpret=interpret,
         **params,
     )(q, k, v, g, lse5, delta5)
@@ -386,6 +388,7 @@ def _bwd_pallas(res, g, *, scale, causal, block_q, block_k, interpret):
         ],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
+        name="flash_bwd_dkv",
         interpret=interpret,
         **params,
     )(q, k, v, g, lse5, delta5)

@@ -341,6 +341,7 @@ def _qkv_pallas(x2d, wn, wq, wk, wv, *, eps, block_t, block_o, interpret,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((block_t, d), jnp.float32)],
+        name="rmsnorm_qkv",
         interpret=interpret,
         **params,
     )(x2d, wn.reshape(1, d), wq, wk, wv)
@@ -572,6 +573,7 @@ def _mlp_pallas(x2d, weights, biases, *, act, gated, block_t, block_f,
         out_specs=pl.BlockSpec((block_t, d), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((t, d), x2d.dtype),
         scratch_shapes=[pltpu.VMEM((block_t, d), jnp.float32)],
+        name="fused_mlp",
         interpret=interpret,
         **params,
     )(*args)
@@ -1026,6 +1028,7 @@ def _decoder_pallas(x, wn1, wq, wk, wv, cos, sin, wo, wn2, wg, wu, wd, *,
             pltpu.VMEM((bt, hd), jnp.float32),  # per-head softmax acc
             pltpu.VMEM((bt, d), jnp.float32),   # MLP down accumulator
         ],
+        name="fused_decoder",
         interpret=interpret,
         **params,
     )(x, wn1.reshape(1, d), wq, wk, wv, cos, sin, wo,

@@ -181,6 +181,7 @@ def grouped_expert_ffn_pallas(x, w1, b1, w2, b2, counts, *, act,
         out_specs=pl.BlockSpec((1, block_c, d), lambda g, i, j: (g, i, 0)),
         out_shape=jax.ShapeDtypeStruct((G, C, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_c, d), jnp.float32)],
+        name="grouped_matmul",
         interpret=interpret,
         **params,
     )(x, w1, b1.reshape(E, 1, h), w2, b2.reshape(E, 1, d),

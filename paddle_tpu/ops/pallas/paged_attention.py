@@ -214,6 +214,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, lengths,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, h, hd), q.dtype),
+        name="paged_attention",
         interpret=interpret,
         **params,
     )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),

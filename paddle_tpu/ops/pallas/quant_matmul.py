@@ -172,6 +172,7 @@ def quant_matmul_pallas(x2d, qw, scale, *, block_t=None, block_n=None,
         ],
         out_specs=pl.BlockSpec((block_t, block_n), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((t, n), x2d.dtype),
+        name="quant_matmul",
         interpret=interpret,
         **params,
     )(x2d, qw, scale.reshape(1, n))

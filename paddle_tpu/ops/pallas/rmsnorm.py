@@ -75,6 +75,7 @@ def _fwd_pallas(x2d, res2d, w, *, eps, block_rows, interpret):
             jax.ShapeDtypeStruct((rows, d), x2d.dtype),
             jax.ShapeDtypeStruct((rows, 1), jnp.float32),
         ],
+        name="rmsnorm",
         interpret=interpret,
     )(*args)
     return y, h, inv

@@ -142,13 +142,13 @@ class RecordEvent:
         self._t0 = None
 
     def begin(self):
+        # the device trace through the tracer's one door; the tracer's
+        # ring gets the region when it ends (_deliver -> on_host_event,
+        # an add_span, which writes no second annotation)
+        from paddle_tpu.observability.tracing import host_annotation
         self._t0 = time.perf_counter()
-        try:
-            import jax.profiler
-            self._ann = jax.profiler.TraceAnnotation(self.name)
-            self._ann.__enter__()
-        except Exception:
-            self._ann = None
+        self._ann = host_annotation(self.name)
+        self._ann.__enter__()
 
     def end(self):
         if self._ann is not None:

@@ -1,0 +1,9 @@
+"""Device ms a train step spends under the scope ``attn`` (norm, QKV, rope,
+attention, output projection, residual), forward and backward, own time as
+``trace_reduce.op_totals`` counts it; an operation fused across scopes
+goes to the first of ``program_spans.SCOPES``."""
+from perf import program_spans
+
+
+def read(obs):
+    return program_spans.scope_ms_per_step(obs, "attn")

@@ -1,0 +1,9 @@
+"""Device-idle time under the engine's ``serving.sync`` spans (device done,
+host not yet back with the tokens), % of the traced window: each idle gap
+of device 0 goes to the program's leaf span that covers most of it
+(perf/program_spans.py)."""
+from perf import program_spans
+
+
+def read(obs):
+    return program_spans.idle_share_under(obs, "serving.sync")

@@ -227,14 +227,17 @@ class TestServingTracing:
         assert len(requests) == 2
         by_trace = {r["trace_id"]: r for r in requests}
         prefills = tr.finished_spans(name="serving.prefill")
-        decodes = tr.finished_spans(name="serving.decode_step")
-        assert len(prefills) == 2 and decodes
-        for child in prefills + decodes:
+        assert len(prefills) == 2
+        for child in prefills:
             root = by_trace[child["trace_id"]]
             assert child["parent_id"] == root["span_id"]
         for r in requests:
             assert r["attrs"]["status"] == "ok"
             assert r["attrs"]["generated"] == 3
+            # decode leaves no span per request: the root carries one
+            # (stamp, tokens) per emission instead
+            stamps = r["attrs"]["token_stamps"]
+            assert sum(n for _, n in stamps) == 3 and len(stamps) > 1
         # retirement events are stamped with the request trace ids
         retires = [e for e in flight_recorder().snapshot()
                    if e["kind"] == "serving.retire"
@@ -279,9 +282,10 @@ class TestTrainStepTracing:
         root = by_name["train.step"]
         for child in ("train.h2d", "train.dispatch", "train.guard"):
             assert by_name[child]["parent_id"] == root["span_id"]
-        accum = by_name["train.accum_microbatches"]
-        assert accum["parent_id"] == by_name["train.dispatch"]["span_id"]
-        # >= 3 nesting levels: step -> dispatch -> accum
+        # the microbatch scan is ONE program inside the dispatch: no
+        # span of its own, the count rides the dispatch span
+        assert by_name["train.dispatch"]["attrs"]["microbatches"] == 2
+        assert "train.accum_microbatches" not in by_name
 
     def test_record_event_nests_under_active_span(self, tr):
         from paddle_tpu import profiler as prof
@@ -615,3 +619,253 @@ class TestPrometheusBuckets:
         summary = fam["series"][0]["summary"]
         assert summary["count"] == 10
         assert {"p50", "p90", "p99"} <= set(summary)
+
+
+# ------------------------------- the tracer on the device trace's clock
+def _host_events(logdir):
+    """{name: [(start_ns, end_ns)]} of one capture's host planes."""
+    import glob
+
+    import jax
+    (path,) = glob.glob(f"{logdir}/plugins/profile/*/*.xplane.pb")
+    out = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    out.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+    return out
+
+
+class TestProfilerBridge:
+    def test_spans_and_record_event_reach_the_host_plane_once(
+            self, tr, tmp_path):
+        """Inside a profiler session a nested Tracer.span pair sits on
+        the .xplane.pb's host plane by name, child inside parent, and a
+        RecordEvent — which takes the same door — appears once."""
+        import jax
+        from paddle_tpu import profiler as prof
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with tr.span("bridge.outer", rid=7):
+                with tr.span("bridge.inner"):
+                    pass
+                with prof.RecordEvent("bridge.annotated"):
+                    pass
+            tr.add_span("bridge.after_the_fact", 0.0, 1.0)
+        finally:
+            jax.profiler.stop_trace()
+        ev = _host_events(tmp_path)
+        ((o0, o1),) = ev["bridge.outer"]     # the name alone, no #rid=7#
+        ((i0, i1),) = ev["bridge.inner"]
+        assert o0 <= i0 <= i1 <= o1
+        assert len(ev["bridge.annotated"]) == 1
+        assert "bridge.after_the_fact" not in ev
+        # and once in the ring, under the span that was active
+        (s,) = tr.finished_spans(name="bridge.annotated")
+        assert s["parent_id"] == tr.finished_spans(
+            name="bridge.outer")[0]["span_id"]
+
+    def test_disabled_tracer_writes_nothing(self, tmp_path):
+        import jax
+        off = Tracer(sample=0.0)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with off.span("bridge.off") as s:
+                assert s.context is None
+        finally:
+            jax.profiler.stop_trace()
+        assert "bridge.off" not in _host_events(tmp_path)
+        assert off.finished_spans() == []
+
+    def test_roots_outlive_the_ring(self):
+        """A request's root (and its token_stamps) must still be there
+        after 10 000 later step spans have flushed the main ring."""
+        t = Tracer(capacity=4096, sample=1.0)
+        root = t.start_span("serving.request", rid=3)
+        root.set_attribute("token_stamps", [(1.0, 1), (2.0, 1)])
+        root.end()
+        for _ in range(10_000):
+            with t.span("serving.step", root_eligible=False):
+                pass
+        assert not t.finished_spans(name="serving.request")
+        (kept,) = t.finished_roots("serving.request")
+        assert kept["attrs"]["token_stamps"] == [(1.0, 1), (2.0, 1)]
+        assert t.finished_roots("serving.step") == []
+
+
+PHASES = ("serving.schedule", "serving.admit", "serving.build",
+          "serving.dispatch", "serving.sync", "serving.emit")
+ENGINES = {"slots": dict(prefill_buckets=(16,)),
+           "paged": dict(paged_kv=True, kv_block_size=8, prefill_chunk=16,
+                         prefill_buckets=(16,))}
+
+
+def _run_engine(model, n_requests, **kw):
+    from paddle_tpu.inference.serving import ContinuousBatchingEngine
+    eng = ContinuousBatchingEngine(model, slots=4, max_len=64, **kw)
+    rng = np.random.default_rng(1)
+    rids = [eng.add_request(rng.integers(0, 128, (5,)), max_new_tokens=6)
+            for _ in range(n_requests)]
+    eng.run()
+    return eng, rids
+
+
+def _children_by_step(tr):
+    """[(step span, [child span names])] in the order the steps ran."""
+    spans = tr.finished_spans()
+    steps = [s for s in spans if s["name"] == "serving.step"]
+    kids = {s["span_id"]: [] for s in steps}
+    for s in spans:
+        if s["parent_id"] in kids:
+            kids[s["parent_id"]].append(s["name"])
+    return [(s, sorted(kids[s["span_id"]])) for s in steps]
+
+
+@pytest.mark.parametrize("kind", sorted(ENGINES))
+class TestEngineStepPhases:
+    def test_spans_per_step_do_not_grow_with_the_batch(self, tr,
+                                                       tiny_model, kind):
+        per_batch = {}
+        for n in (1, 4):
+            tr.clear()
+            _run_engine(tiny_model, n, **ENGINES[kind])
+            steps = _children_by_step(tr)
+            decodes = [k for s, k in steps
+                       if s["attrs"]["ran"] == "decode"
+                       and s["attrs"]["decoding"] == n]
+            assert decodes, [s["attrs"] for s, _ in steps]
+            per_batch[n] = {tuple(k) for k in decodes}
+            # no span hangs off a request per decode step
+            assert not tr.finished_spans(name="serving.decode_step")
+        assert per_batch[1] == per_batch[4]
+        (names,) = per_batch[4]
+        assert set(names) == {"serving.schedule", "serving.build",
+                              "serving.dispatch", "serving.sync",
+                              "serving.emit"}
+
+    def test_every_phase_parents_to_a_step(self, tr, tiny_model, kind):
+        _run_engine(tiny_model, 4, **ENGINES[kind])
+        steps = _children_by_step(tr)
+        seen = {name for _, kids in steps for name in kids}
+        assert set(PHASES) <= seen, seen
+        ran = {s["attrs"]["ran"] for s, _ in steps}
+        assert {"admit", "decode"} <= ran
+        assert ("prefill_chunk" in ran) == (kind == "paged")
+        for s, _ in steps:
+            assert {"active", "queued"} <= set(s["attrs"])
+        # steps are not roots of the slowest-traces table
+        assert not tr.finished_roots("serving.step")
+        # a chunk's dispatch and sync sit inside the request's prefill
+        prefill_ids = {s["span_id"]
+                       for s in tr.finished_spans(name="serving.prefill")}
+        inside = {s["name"] for s in tr.finished_spans()
+                  if s["parent_id"] in prefill_ids}
+        assert inside == {"serving.dispatch", "serving.sync"}
+
+    def test_token_stamps_on_root_and_status(self, tr, tiny_model, kind):
+        from paddle_tpu.observability import default_registry
+        itl = default_registry().get(
+            "paddle_tpu_serving_inter_token_seconds")
+        before = sum(c.count() for _, c in itl.series()) if itl else 0
+        eng, rids = _run_engine(tiny_model, 4, **ENGINES[kind])
+        roots = {r["attrs"]["rid"]: r
+                 for r in tr.finished_roots("serving.request")}
+        for rid in rids:
+            st = eng.request_status(rid)
+            stamps = roots[rid]["attrs"]["token_stamps"]
+            assert stamps == st.token_times
+            assert sum(n for _, n in stamps) == st.timings["generated"] == 6
+            times = [t for t, _ in stamps]
+            assert times == sorted(times)
+            assert times[0] == st.timings["first_token"]
+            assert 0 <= st.timings["retired"] - times[-1] < 0.5
+        itl = default_registry().get(
+            "paddle_tpu_serving_inter_token_seconds")
+        # one observation per token after a request's first
+        assert sum(c.count() for _, c in itl.series()) - before == 4 * 5
+
+
+# ---------------------------------------------- stable names on the device
+SCOPES = ("embed", "attn", "mlp", "lm_head_ce")
+
+
+def _has_scope(text, scope):
+    import re
+    return re.search(r'op_name="[^"]*(?<![\w])%s(?![\w])' % scope, text)
+
+
+class TestStableNames:
+    def test_train_step_carries_every_scope_forward_and_backward(
+            self, tiny_model):
+        import re
+        from paddle_tpu.jit import TrainStep
+        opt = pp.optimizer.AdamW(learning_rate=1e-2,
+                                 parameters=tiny_model.parameters())
+        step = TrainStep(tiny_model, opt)
+        ids = np.zeros((2, 8), np.int32)
+        step.compile({"input_ids": ids, "labels": ids})
+        text = step._compiled.as_text()
+        for scope in SCOPES + ("optimizer",):
+            assert _has_scope(text, scope), scope
+        for scope in ("attn", "mlp", "lm_head_ce"):
+            assert re.search(r"transpose\(jvp\(%s\)\)" % scope, text), scope
+
+    def test_engine_programs_carry_the_model_scopes(self, tiny_model):
+        from paddle_tpu.inference.serving import ContinuousBatchingEngine
+        eng = ContinuousBatchingEngine(tiny_model, slots=2, max_len=64,
+                                       **ENGINES["paged"])
+        eng.aot_warmup()
+        for prog in (eng._decode_compiled, eng._prefill_chunk_compiled):
+            text = prog.as_text()
+            for scope in SCOPES:
+                assert _has_scope(text, scope), scope
+
+
+def _pallas_call_names():
+    """[(file, line, name or None)] of every pl.pallas_call site."""
+    import ast
+    import glob
+    import os
+    import paddle_tpu.ops.pallas as pkg
+    sites = []
+    for path in sorted(glob.glob(
+            os.path.join(os.path.dirname(pkg.__file__), "*.py"))):
+        for node in ast.walk(ast.parse(open(path).read())):
+            if isinstance(node, ast.Call) and \
+                    getattr(node.func, "attr", "") == "pallas_call":
+                name = [k.value.value for k in node.keywords
+                        if k.arg == "name"
+                        and isinstance(k.value, ast.Constant)]
+                sites.append((os.path.basename(path), node.lineno,
+                              name[0] if name else None))
+    return sites
+
+
+KERNEL_NAMES = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                "rmsnorm_qkv", "fused_mlp", "fused_decoder",
+                "paged_attention", "rmsnorm", "fused_ce_fwd",
+                "fused_ce_bwd", "grouped_matmul", "quant_matmul")
+
+
+@pytest.mark.parametrize("kernel", KERNEL_NAMES)
+def test_every_pallas_call_site_is_named_once(kernel):
+    sites = _pallas_call_names()
+    assert len(sites) == len(KERNEL_NAMES) and \
+        all(name for _, _, name in sites), sites
+    assert [name for _, _, name in sites].count(kernel) == 1
+
+
+def test_capture_xla_trace_raises_with_the_cause(tmp_path, monkeypatch):
+    import jax
+    from paddle_tpu.observability.device_profiler import capture_xla_trace
+
+    def refuse(logdir):
+        raise OSError("no profiler on this platform")
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    with pytest.raises(RuntimeError, match="no profiler on this platform"):
+        capture_xla_trace(lambda: 0, logdir=str(tmp_path))
+    monkeypatch.undo()
+    assert capture_xla_trace(lambda: jax.numpy.ones(4) + 1,
+                             logdir=str(tmp_path)) == str(tmp_path)
