@@ -155,7 +155,12 @@ class ArgSpec:
     ``resident`` marks constant-map args that are fetched once and stay
     in VMEM (single-buffered in the footprint); ``dma_once`` opts into
     the fused-block clamped-map invariant check (each block DMA'd at
-    most once per inner sweep)."""
+    most once per inner sweep).  ``dma_grid`` marks an operand the
+    kernel leaves in HBM (``memory_space=pl.ANY``) and copies by hand,
+    one ``block`` a DMA, into scratch the spec lists: it takes no VMEM
+    of its own, and its index map is evaluated over the grid with the
+    extents of the loops that issue the copies appended (``()`` when
+    the map is not analysable)."""
 
     name: str
     shape: Tuple[int, ...]
@@ -165,6 +170,7 @@ class ArgSpec:
     is_output: bool = False
     dma_once: bool = False
     resident: bool = False
+    dma_grid: Optional[Tuple[int, ...]] = None
 
 
 @dataclasses.dataclass
@@ -215,9 +221,12 @@ def block_bytes(shape: Sequence[int], dtype) -> int:
 
 def footprint_bytes(spec: KernelSpec) -> int:
     """Modelled VMEM bytes: streamed blocks ×2 (double-buffered DMA),
-    resident/full-array blocks ×1, scratch ×1, scalar prefetch ×1."""
+    resident/full-array blocks ×1, scratch ×1, scalar prefetch ×1;
+    an operand the kernel DMAs by hand lands in scratch."""
     total = 0
     for a in spec.args:
+        if a.dma_grid is not None:
+            continue
         mult = 1 if (a.resident or tuple(a.block) == tuple(a.shape)) else 2
         total += mult * block_bytes(a.block, a.dtype)
     for s in spec.scratch:
@@ -441,12 +450,16 @@ def verify_kernel(spec: KernelSpec,
 
     G = int(np.prod(spec.grid, dtype=np.int64)) if spec.grid else 0
     if G and G <= _MAX_GRID_POINTS:
-        coords = _grid_coords(tuple(int(g) for g in spec.grid))
+        grid = tuple(int(g) for g in spec.grid)
+        coords = _grid_coords(grid)
         for a in spec.args:
             if a.index_map is None:
                 continue
+            # a hand-DMA'd operand is walked by the kernel's own loops
+            at = (_grid_coords(grid + tuple(a.dma_grid))
+                  if a.dma_grid else coords)
             try:
-                idx = _eval_map(a, coords, spec.scalar_prefetch)
+                idx = _eval_map(a, at, spec.scalar_prefetch)
             except Exception as e:  # maps may need runtime-only values
                 out.append(_d(
                     Severity.INFO, MAP_UNEVALUATED,
@@ -455,7 +468,7 @@ def verify_kernel(spec: KernelSpec,
                     where=spec.where))
                 continue
             if idx is not None:
-                out.extend(_map_diags(spec, a, idx, coords))
+                out.extend(_map_diags(spec, a, idx, at))
     elif G:
         out.append(_d(
             Severity.INFO, MAP_UNEVALUATED,
@@ -695,7 +708,9 @@ def _catalog_entries() -> List[Dict[str, Any]]:
             gm.verify_static(g, c, d, h, dtype=dtype))
     for B, h, hd, kvh, bs, nb, mb, dtype, quant in (
             (8, 16, 128, 8, 16, 128, 16, "bfloat16", False),
-            (8, 16, 128, 8, 16, 128, 16, "bfloat16", True)):
+            (8, 16, 128, 8, 16, 128, 16, "bfloat16", True),
+            # the benchmark's serve-chat: 32 slots x 2576 tokens
+            (32, 32, 128, 8, 16, 3073, 161, "bfloat16", False)):
         add("paged_decode",
             f"B{B} h{h}/{kvh} d{hd} bs{bs} {dtype}"
             + (" int8-kv" if quant else ""),
@@ -777,10 +792,12 @@ def _spec_from_eqn(eqn, where: str) -> Optional[KernelSpec]:
         fn = (map_fn(cj)
               if len(cj.jaxpr.invars) == len(grid) else None)
         is_out = k >= num_in
+        space = str(getattr(bm.transformed_block_aval, "memory_space", ""))
         args.append(ArgSpec(
             name=(f"out{k - num_in}" if is_out else f"in{k}"),
             shape=tuple(int(s) for s in sd.shape), block=block,
-            index_map=fn, dtype=sd.dtype, is_output=is_out))
+            index_map=fn, dtype=sd.dtype, is_output=is_out,
+            dma_grid=() if space in ("any", "hbm") else None))
 
     scratch = []
     n_scratch = int(getattr(gm, "num_scratch_operands", 0))

@@ -802,7 +802,7 @@ def paged_cache_attention(q, k, v, cache: PagedCache, position_offset,
     from paddle_tpu.ops.pallas import paged_attention as PA
     uq = unwrap(q)
     if attn_mask is None and S == 1 and \
-            PA.paged_decode_eligible(kp.shape[-1], bs, uq.dtype):
+            PA.paged_decode_eligible(kp.shape[-1], bs, uq.dtype, pool=kp):
         PA.record_path("pallas")
         lengths = qpos[:, 0] + 1
         if quant:

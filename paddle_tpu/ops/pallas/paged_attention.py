@@ -6,23 +6,38 @@ each sequence's keys/values as fixed-size token blocks scattered through
 per-row block table.  The XLA fallback gathers the whole logical table
 back to HBM-contiguous form every step — correct, but it re-materializes
 ``max_len`` rows per layer per token.  This kernel reads the pools
-**in place**: the block table rides in as scalar prefetch
-(``PrefetchScalarGridSpec``), the K/V ``BlockSpec`` index maps chase it
-(``bt[b, j]`` picks the physical block each grid step DMAs), and an
-online-softmax accumulator in VMEM scratch walks the sequence's logical
-blocks.  Nothing is gathered; blocks past the row's length are skipped
-entirely (``pl.when``), so decode reads exactly the live KV bytes.
+**in place** and its work is what is live: the grid is over rows, the
+pools stay in HBM (``memory_space=pl.ANY``), the block table and the
+lengths ride in as scalar prefetch, and row b walks
+``cdiv(lengths[b], chunk)`` iterations of a run-time-bounded loop.  An
+iteration takes a chunk of C consecutive table entries: one
+``make_async_copy`` per live physical block of K and of V into a VMEM
+buffer ``[C·block_size, kv_heads, head_dim]``, started together and
+waited together, with two buffers so the next chunk (or the next row's
+first) is in flight while this one is multiplied; an online-softmax
+state in fp32 walks the chunks.  A table entry past a row's last live
+block is never read, so a long table costs a short row nothing (the
+grid of one block a step that this replaces paid a step for every
+entry, live or not).
 
-GQA is handled in-kernel: q heads reshape to ``[kv_heads, group, hd]``
-and both matmuls run batched over kv heads, so KV blocks stream once per
-group (the same trick the flash kernel plays in its grid).
+C comes from the shapes the call sees (``chunk_blocks``): whole blocks
+within 256 tokens whose four buffers fit a VMEM budget.  The
+``pallas_call`` sits behind one ``jax.jit``, so a program whose layers
+call it at identical shapes lowers ONE kernel body (the Pallas -> Mosaic
+lowering is paid on every start, warm compile cache or not), and its
+trip counts are run-time scalars, so there is one decode program
+whatever the live lengths.
+
+GQA is handled in-kernel without a transpose: a chunk is multiplied as
+``[T·kv_heads, hd]`` rows against all q heads at once and the mask keeps
+each q head's own kv head.
 
 Eligibility mirrors the flash kernel's Mosaic constraints: TPU backend,
 lane-aligned ``head_dim % 128 == 0``, sublane-aligned
-``block_size % 8 == 0``.  Elsewhere the engine's ``jnp.take`` gather
-fallback runs (``paddle_tpu_paged_attention_path_total{path=...}``
-records the trace-time choice).  ``PADDLE_TPU_PAGED_ATTN=0`` forces the
-fallback.
+``block_size % 8 == 0``, kv heads that fill 32-bit sublane words.
+Elsewhere the engine's ``jnp.take`` gather fallback runs
+(``paddle_tpu_paged_attention_path_total{path=...}`` records the
+trace-time choice).  ``PADDLE_TPU_PAGED_ATTN=0`` forces the fallback.
 """
 
 from __future__ import annotations
@@ -52,8 +67,13 @@ def paged_attention_env():
     return raw.strip().lower() in ("1", "true", "yes", "on")
 
 
-def paged_decode_eligible(head_dim: int, block_size: int, dtype) -> bool:
-    """Trace-time routing decision for the decode (s == 1) path."""
+def paged_decode_eligible(head_dim: int, block_size: int, dtype,
+                          pool=None) -> bool:
+    """Trace-time routing decision for the decode (s == 1) path.
+    ``pool`` (the K pool, where the caller has it): a block is DMA'd as
+    ``[block_size, kv_heads, head_dim]`` and Mosaic slices an HBM array
+    on whole 32-bit sublane words only, so the kv heads must fill them
+    (one bf16 kv head does not)."""
     env = paged_attention_env()
     if env is False:
         return False
@@ -61,6 +81,9 @@ def paged_decode_eligible(head_dim: int, block_size: int, dtype) -> bool:
         return False
     if jnp.dtype(dtype) not in (jnp.dtype(jnp.float32),
                                 jnp.dtype(jnp.bfloat16)):
+        return False
+    if pool is not None and \
+            pool.shape[2] * jnp.dtype(pool.dtype).itemsize % 4:
         return False
     return head_dim % 128 == 0 and block_size % 8 == 0
 
@@ -78,72 +101,196 @@ def record_path(path: str):
         pass
 
 
-def _decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                   acc_ref, m_ref, l_ref, *, block_size, kv_heads, group,
-                   head_dim, scale, ks_ref=None, vs_ref=None):
-    """Grid (batch, max_blocks); the block axis is innermost/sequential so
-    VMEM scratch carries the online-softmax state across a row's blocks.
-    Quantized pools (``ks_ref/vs_ref`` given) dequantize AT THE BLOCK
-    LOAD: the int8 tile and its ``[bs, kvh]`` scales widen in VMEM
-    registers — the fp16/bf16 KV never exists in HBM."""
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    nb = pl.num_programs(1)
+# One chunk of a row's KV is at most this many tokens: wide enough that
+# the score tile fills whole lanes and a DMA burst amortises its issue,
+# small enough that a short row wastes little masked compute.
+_CHUNK_TOKENS = 256
+# ... and the two K and two V chunk buffers stay under this much VMEM.
+_CHUNK_VMEM_BYTES = 4 << 20
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+
+def chunk_blocks(block_size, kv_heads, head_dim, max_blocks, itemsize):
+    """Physical blocks per chunk, from what the call can see: the most
+    whole blocks within ``_CHUNK_TOKENS`` whose double-buffered K and V
+    fit ``_CHUNK_VMEM_BYTES``, never more than the table holds."""
+    per_block = 2 * 2 * block_size * kv_heads * head_dim * itemsize
+    return max(1, min(_CHUNK_TOKENS // block_size,
+                      _CHUNK_VMEM_BYTES // per_block, max_blocks))
+
+
+def _scale_lanes(kv_heads):
+    """Mosaic cannot slice an HBM array whose minor dim is under a lane
+    width: an int8 pool's scale blocks ``[bs, kvh]`` reach the kernel
+    with their kv heads padded to whole lanes."""
+    return -(-kv_heads // 128) * 128
+
+
+def _decode_kernel(bt_ref, len_ref, q_ref, *refs, scale, quant):
+    """Grid (batch,), sequential.  Row b walks ``cdiv(lengths[b], T)``
+    chunks of T = C·block_size tokens; a chunk's live blocks are copied
+    HBM -> VMEM by the kernel's own DMAs (one per physical block, named
+    by the block table), into one of two buffers, so the next chunk —
+    this row's, or the next row's first — is in flight while this one
+    is multiplied.  ``slot_ref`` carries the buffer parity from row to
+    row.
+
+    A chunk is multiplied as it lies, ``[T·kvh, hd]`` with a token's kv
+    heads in consecutive rows: every q head meets every (token, kv head)
+    row in one matmul and the mask keeps a q head's own kv head, so no
+    K or V tile is transposed; the masked probabilities are exact zeros
+    in the second matmul.  Quantized pools dequantize AT THE LOAD: the
+    int8 chunk and its ``[T, kvh]`` scales widen in VMEM registers — the
+    fp16/bf16 KV never exists in HBM."""
+    if quant:
+        (k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref,
+         kbuf, vbuf, ksbuf, vsbuf, sem, slot_ref) = refs
+        streams = ((k_hbm, kbuf), (v_hbm, vbuf),
+                   (ks_hbm, ksbuf), (vs_hbm, vsbuf))
+    else:
+        k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, slot_ref = refs
+        streams = ((k_hbm, kbuf), (v_hbm, vbuf))
+    b = pl.program_id(0)
+    rows = pl.num_programs(0)
+    bs = k_hbm.shape[1]
+    _, T, kv_heads, head_dim = kbuf.shape
+    C = T // bs
+    heads = q_ref.shape[1]
+    group = heads // kv_heads
+
+    def each_copy(row, i, slot, do):
+        """``do`` every DMA of chunk i of ``row``: live blocks only."""
+        live = jnp.minimum(pl.cdiv(len_ref[row], bs) - i * C, C)
+
+        def block(c, carry):
+            blk = bt_ref[row, i * C + c]
+            for pool, buf in streams:
+                do(pltpu.make_async_copy(
+                    pool.at[blk], buf.at[slot, pl.ds(c * bs, bs)],
+                    sem.at[slot]))
+            return carry
+
+        jax.lax.fori_loop(0, live, block, 0)
+
+    def start(row, i, slot):
+        each_copy(row, i, slot, lambda cp: cp.start())
+
+    @pl.when(b == 0)
+    def _first():
+        # a dead tail of a buffer is masked out of the scores, but its
+        # values still meet a zero probability: they must be finite
+        for _, buf in streams:
+            buf[...] = jnp.zeros_like(buf)
+        slot_ref[0] = 0
+        start(0, 0, 0)
 
     plen = len_ref[b]                     # valid tokens in this row
+    # a zero-length row still takes its turn, so the buffer parity and
+    # the prefetch chain never skip a row
+    n = jnp.maximum(pl.cdiv(plen, T), 1)
+    slot0 = slot_ref[0]
+    q = q_ref[0]                                       # [h, hd]
 
-    @pl.when(j * block_size < plen)
-    def _compute():
-        q = q_ref[0].reshape(kv_heads, group, head_dim)
-        if ks_ref is not None:
-            ks = jnp.swapaxes(ks_ref[0], 0, 1)[..., None]  # [kvh, bs, 1]
-            vs = jnp.swapaxes(vs_ref[0], 0, 1)[..., None]
-            k = (jnp.swapaxes(k_ref[0], 0, 1).astype(jnp.float32)
-                 * ks).astype(q.dtype)                 # [kvh, bs, hd]
-            v = (jnp.swapaxes(v_ref[0], 0, 1).astype(jnp.float32)
-                 * vs).astype(q.dtype)
-        else:
-            k = jnp.swapaxes(k_ref[0], 0, 1)           # [kvh, bs, hd]
-            v = jnp.swapaxes(v_ref[0], 0, 1)           # [kvh, bs, hd]
+    def chunk(i, carry):
+        m_prev, l_prev, acc = carry
+        slot = (slot0 + i) % 2
+
+        @pl.when(i + 1 < n)
+        def _next_chunk():
+            start(b, i + 1, 1 - slot)
+
+        @pl.when((i + 1 == n) & (b + 1 < rows))
+        def _next_row():
+            start(b + 1, 0, 1 - slot)
+
+        each_copy(b, i, slot, lambda cp: cp.wait())
+        k, v = kbuf[slot], vbuf[slot]                  # [T, kvh, hd]
+        if quant:
+            ks = ksbuf[slot][:, :kv_heads][..., None]  # [T, kvh, 1]
+            vs = vsbuf[slot][:, :kv_heads][..., None]
+            k = (k.astype(jnp.float32) * ks).astype(q.dtype)
+            v = (v.astype(jnp.float32) * vs).astype(q.dtype)
+        k = k.reshape(T * kv_heads, head_dim)
+        v = v.reshape(T * kv_heads, head_dim)
         s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale  # [kvh, g, bs]
-        kpos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (kv_heads, group, block_size), 2)
-        s = jnp.where(kpos < plen, s, _NEG_INF)
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [h, T*kvh]
+        # column c is token c // kvh of the chunk under kv head c % kvh
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        live = ((i * T + col // kv_heads) < plen) & \
+            ((col % kv_heads) == (row // group))
+        s = jnp.where(live, s, _NEG_INF)
 
-        m_prev = m_ref[:]                              # [kvh, g, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)                         # [kvh, g, bs]
+        p = jnp.where(live, jnp.exp(s - m_new), 0.0)   # [h, T*kvh]
         corr = jnp.exp(m_prev - m_new)
-        l_ref[:] = corr * l_ref[:] + jnp.sum(p, axis=-1, keepdims=True)
+        l_new = corr * l_prev + jnp.sum(p, axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)        # [kvh, g, hd]
-        acc_ref[:] = acc_ref[:] * corr + pv
-        m_ref[:] = m_new
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)        # [h, hd]
+        return m_new, l_new, acc * corr + pv
 
-    @pl.when(j == nb - 1)
-    def _finish():
-        safe_l = jnp.maximum(l_ref[:], 1e-30)
-        out = (acc_ref[:] / safe_l).reshape(
-            kv_heads * group, head_dim)
-        o_ref[0] = out.astype(o_ref.dtype)
+    _, l, acc = jax.lax.fori_loop(0, n, chunk, (
+        jnp.full((heads, 1), _NEG_INF, jnp.float32),
+        jnp.zeros((heads, 1), jnp.float32),
+        jnp.zeros((heads, head_dim), jnp.float32)))
+    slot_ref[0] = (slot0 + n) % 2
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
-def _decode_kernel_quant(bt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref,
-                         vs_ref, o_ref, acc_ref, m_ref, l_ref, **kw):
-    """Positional adapter: the quantized variant's extra scale inputs
-    sit between the pools and the output in pallas_call order."""
-    _decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                   acc_ref, m_ref, l_ref, ks_ref=ks_ref, vs_ref=vs_ref,
-                   **kw)
+@functools.lru_cache(maxsize=None)
+def _kernel(scale, quant):
+    """One kernel object per static configuration, so jax's trace cache
+    sees the same function at every call site and in every program."""
+    return functools.partial(_decode_kernel, scale=scale, quant=quant)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "chunk", "interpret"))
+def _paged_decode(q, k_pool, v_pool, block_table, lengths, k_scale, v_scale,
+                  *, scale, chunk, interpret):
+    """The ``pallas_call`` behind ONE jit: a program that calls it at
+    identical shapes from every layer traces it once and lowers one
+    kernel body that the layers share."""
+    B, h, hd = q.shape
+    _, bs, kvh, _ = k_pool.shape
+    quant = k_scale is not None
+    T = chunk * bs
+
+    row = pl.BlockSpec((1, h, hd), lambda b, bt, ln: (b, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    operands = [q, k_pool, v_pool]
+    scratch = [pltpu.VMEM((2, T, kvh, hd), k_pool.dtype),
+               pltpu.VMEM((2, T, kvh, hd), v_pool.dtype)]
+    if quant:
+        lanes = _scale_lanes(kvh)
+        pad = ((0, 0), (0, 0), (0, lanes - kvh))
+        operands += [jnp.pad(k_scale.astype(jnp.float32), pad),
+                     jnp.pad(v_scale.astype(jnp.float32), pad)]
+        scratch += [pltpu.VMEM((2, T, lanes), jnp.float32),
+                    pltpu.VMEM((2, T, lanes), jnp.float32)]
+    scratch += [pltpu.SemaphoreType.DMA((2,)), pltpu.SMEM((1,), jnp.int32)]
+
+    params = {}
+    if not interpret:
+        # sequential: the buffer parity and the prefetched first chunk
+        # pass from one row to the next
+        params["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",))
+
+    return pl.pallas_call(
+        _kernel(scale, quant),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=[row] + [hbm] * (len(operands) - 1),
+            out_specs=row,
+            scratch_shapes=scratch),
+        out_shape=jax.ShapeDtypeStruct((B, h, hd), q.dtype),
+        name="paged_attention",
+        interpret=interpret,
+        **params,
+    )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
+      *operands)
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_table, lengths,
@@ -158,67 +305,20 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, lengths,
     allocation); lengths: ``[B]`` int32 — row b attends positions
     ``< lengths[b]`` (the current token's KV must already be written).
     ``k_scale/v_scale`` (``[num_blocks, block_size, kv_heads]`` fp32)
-    mark an int8-quantized pool: blocks dequantize at the load, chased
-    by the same block-table index maps.  Returns ``[B, heads, hd]``."""
+    mark an int8-quantized pool: a block's scales ride the same DMAs
+    and dequantize at the load.  Table entries past a row's last live
+    block are never read.  Returns ``[B, heads, hd]``."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    B, h, hd = q.shape
-    nb, bs, kvh, _ = k_pool.shape
-    mb = block_table.shape[1]
-    group = h // kvh
+    hd = q.shape[-1]
+    _, bs, kvh, _ = k_pool.shape
     if scale is None:
         scale = 1.0 / (hd ** 0.5)
-    quant = k_scale is not None
-
-    kw = dict(block_size=bs, kv_heads=kvh, group=group, head_dim=hd,
-              scale=scale)
-    kernel = functools.partial(
-        _decode_kernel_quant if quant else _decode_kernel, **kw)
-
-    in_specs = [
-        pl.BlockSpec((1, h, hd), lambda b, j, bt, ln: (b, 0, 0)),
-        pl.BlockSpec((1, bs, kvh, hd),
-                     lambda b, j, bt, ln: (bt[b, j], 0, 0, 0)),
-        pl.BlockSpec((1, bs, kvh, hd),
-                     lambda b, j, bt, ln: (bt[b, j], 0, 0, 0)),
-    ]
-    operands = [q, k_pool, v_pool]
-    if quant:
-        in_specs += [
-            pl.BlockSpec((1, bs, kvh),
-                         lambda b, j, bt, ln: (bt[b, j], 0, 0)),
-            pl.BlockSpec((1, bs, kvh),
-                         lambda b, j, bt, ln: (bt[b, j], 0, 0)),
-        ]
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, mb),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h, hd), lambda b, j, bt, ln: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((kvh, group, hd), jnp.float32),
-            pltpu.VMEM((kvh, group, 1), jnp.float32),
-            pltpu.VMEM((kvh, group, 1), jnp.float32),
-        ],
-    )
-
-    params = {}
-    if not interpret:
-        params["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
-
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, h, hd), q.dtype),
-        name="paged_attention",
-        interpret=interpret,
-        **params,
-    )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      *operands)
+    chunk = chunk_blocks(bs, kvh, hd, block_table.shape[1],
+                         jnp.dtype(k_pool.dtype).itemsize)
+    return _paged_decode(q, k_pool, v_pool, block_table, lengths, k_scale,
+                         v_scale, scale=float(scale), chunk=chunk,
+                         interpret=bool(interpret))
 
 
 # ---------------------------------------------------------------------------
@@ -227,47 +327,55 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, lengths,
 
 def verify_static(B, h, hd, kvh, bs, nb, mb, dtype="bfloat16",
                   quant=False):
-    """Static Mosaic-legality findings for the paged decode kernel.
-    The block-table scalar-prefetch operand is synthesized (row b's
-    logical block j lives at physical block ``(b*mb + j) % nb``) so the
-    pool index maps evaluate concretely over the whole (B, mb) grid."""
+    """Static Mosaic-legality findings for the paged decode kernel: a
+    grid over rows, the pools left in HBM and copied a physical block a
+    DMA into the two chunk buffers, which are the kernel's VMEM.  The
+    block-table scalar-prefetch operand is synthesized (row b's logical
+    block j lives at physical block ``(b*mb + j) % nb``) and every row
+    is full, so the pool maps evaluate concretely over every copy the
+    walk can issue."""
     import numpy as np
     from paddle_tpu.analysis import kernel_verify as kv
     dtype = str(dtype)
-    group = h // kvh
     bt = (np.arange(B, dtype=np.int32)[:, None] * mb
           + np.arange(mb, dtype=np.int32)[None, :]) % nb
     lengths = np.full((B,), mb * bs, dtype=np.int32)
+    pool_dtype = "int8" if quant else dtype
+    T = bs * chunk_blocks(bs, kvh, hd, mb, kv.itemsize(pool_dtype))
     pool4 = (nb, bs, kvh, hd)
     pool_map = lambda b, j, bt, ln: (bt[b, j], 0, 0, 0)
-    row_map = lambda b, j, bt, ln: (b, 0, 0)
+    row_map = lambda b, bt, ln: (b, 0, 0)
     args = [
         kv.ArgSpec("q", (B, h, hd), (1, h, hd), row_map, dtype),
         kv.ArgSpec("k_pool", pool4, (1, bs, kvh, hd), pool_map,
-                   "int8" if quant else dtype),
+                   pool_dtype, dma_grid=(mb,)),
         kv.ArgSpec("v_pool", pool4, (1, bs, kvh, hd), pool_map,
-                   "int8" if quant else dtype),
+                   pool_dtype, dma_grid=(mb,)),
     ]
+    scratch = [kv.ScratchSpec("k_chunks", (2, T, kvh, hd), pool_dtype),
+               kv.ScratchSpec("v_chunks", (2, T, kvh, hd), pool_dtype)]
     if quant:
+        lanes = _scale_lanes(kvh)
         scale_map = lambda b, j, bt, ln: (bt[b, j], 0, 0)
         args += [
-            kv.ArgSpec("k_scale", (nb, bs, kvh), (1, bs, kvh), scale_map,
-                       "float32"),
-            kv.ArgSpec("v_scale", (nb, bs, kvh), (1, bs, kvh), scale_map,
-                       "float32"),
+            kv.ArgSpec("k_scale", (nb, bs, lanes), (1, bs, lanes),
+                       scale_map, "float32", dma_grid=(mb,)),
+            kv.ArgSpec("v_scale", (nb, bs, lanes), (1, bs, lanes),
+                       scale_map, "float32", dma_grid=(mb,)),
         ]
+        scratch += [kv.ScratchSpec("k_scale_chunks", (2, T, lanes),
+                                   "float32"),
+                    kv.ScratchSpec("v_scale_chunks", (2, T, lanes),
+                                   "float32")]
     args.append(kv.ArgSpec("o", (B, h, hd), (1, h, hd), row_map, dtype,
                            is_output=True))
     spec = kv.KernelSpec(
-        name="paged_decode", grid=(B, mb), args=args,
-        scratch=[kv.ScratchSpec("acc", (kvh, group, hd), "float32"),
-                 kv.ScratchSpec("m", (kvh, group, 1), "float32"),
-                 kv.ScratchSpec("l", (kvh, group, 1), "float32")],
-        dimension_semantics=("parallel", "arbitrary"),
+        name="paged_decode", grid=(B,), args=args, scratch=scratch,
+        dimension_semantics=("arbitrary",),
         scalar_prefetch=(bt, lengths),
-        needs_fp32_acc=True,
-        scale_pairs=[("k_scale", "k_pool"),
-                     ("v_scale", "v_pool")] if quant else [],
+        # the softmax state and the accumulator are loop-carried fp32
+        # values (preferred_element_type), not scratch
+        needs_fp32_acc=True, acc_inline=True,
         where=f"paged_decode[B={B} h={h}/{kvh} hd={hd} bs={bs} nb={nb} "
-              f"mb={mb} {dtype}{' int8-kv' if quant else ''}]")
+              f"mb={mb} chunk={T} {dtype}{' int8-kv' if quant else ''}]")
     return kv.verify_kernel(spec)

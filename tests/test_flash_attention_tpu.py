@@ -153,6 +153,83 @@ def test_paged_decode_compiles(one_chip):
                                           jnp.int32), S((slots,), jnp.int32))
 
 
+def test_paged_decode_lowers_one_kernel_body_for_twelve_layers(one_chip):
+    """serve-chat's decode attention (32 slots, a 2576-token table, 3073
+    blocks of 16, Mistral-7B heads) called by twelve layers at identical
+    shapes: the kernel sits behind one jit, so the program lowers one
+    kernel body that the layers share (the Pallas -> Mosaic lowering is
+    paid on every start) and still compiles to twelve custom calls."""
+    from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
+    layers, slots, block, table, blocks = 12, 32, 16, 161, 3073
+    h, hk, d = 32, 8, 128
+
+    def decode(q, pools, bt, lengths):
+        for kp, vp in pools:
+            q = paged_decode_attention(q, kp, vp, bt, lengths,
+                                       interpret=False)
+        return q
+
+    S = lambda shape, dt=BF16: jax.ShapeDtypeStruct(shape, dt,
+                                                    sharding=one_chip)
+    pool = S((blocks, block, hk, d))
+    lowered = jax.jit(decode).lower(
+        S((slots, h, d)), [(pool, pool)] * layers,
+        S((slots, table), jnp.int32), S((slots,), jnp.int32))
+    assert lowered.as_text().count("@tpu_custom_call") == 1
+    assert lowered.compile().as_text().count(
+        'custom_call_target="tpu_custom_call"') == layers
+
+
+@pytest.mark.parametrize("dtype", ["float32", "int8"])
+def test_paged_decode_compiles_other_pools(one_chip, dtype):
+    """The same walk over a float32 pool and over an int8 pool whose
+    scales ride the block DMAs, at serve-chat's shapes."""
+    from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
+    slots, block, table, blocks, h, hk, d = 32, 16, 161, 3073, 32, 8, 128
+    quant = dtype == "int8"
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def decode(q, kp, vp, bt, lengths, ks=None, vs=None):
+        return paged_decode_attention(q, kp, vp, bt, lengths,
+                                      interpret=False, k_scale=ks,
+                                      v_scale=vs)
+
+    pool = S((blocks, block, hk, d), dtype)
+    scales = (S((blocks, block, hk), jnp.float32),) * 2 if quant else ()
+    _compile(decode, S((slots, h, d), BF16 if quant else jnp.float32),
+             pool, pool, S((slots, table), jnp.int32),
+             S((slots,), jnp.int32), *scales)
+
+
+def test_paged_engine_warms_the_targets_it_always_has(monkeypatch):
+    """The walk's trip count is a run-time scalar read from the lengths:
+    a paged engine whose decode step runs the kernel (interpret mode
+    here) acquires one decode program, not one per live length, and
+    serves what the model generates."""
+    import paddle_tpu as pp
+    from paddle_tpu.inference.serving import ContinuousBatchingEngine
+    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+    from paddle_tpu.ops.pallas import paged_attention as PA
+    monkeypatch.setattr(PA, "paged_decode_eligible", lambda *a, **k: True)
+    pp.seed(0)
+    model = LlamaForCausalLM(LlamaConfig.tiny(
+        vocab_size=256, hidden_size=64, intermediate_size=128,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
+        max_position_embeddings=128))
+    eng = ContinuousBatchingEngine(
+        model, slots=2, max_len=64, prefill_buckets=(16, 32),
+        paged_kv=True, kv_block_size=4, prefill_chunk=8)
+    assert sorted(eng.aot_warmup()) == ["serving.decode",
+                                        "serving.prefill_chunk[8]"]
+    prompts = [list(range(3, 24)), [7, 9, 11]]
+    rids = [eng.add_request(p, max_new_tokens=6) for p in prompts]
+    done = eng.run()
+    for rid, p in zip(rids, prompts):
+        ref = model.generate(np.asarray(p, np.int32)[None],
+                             max_new_tokens=6, do_sample=False)
+        assert done[rid][1] == list(np.asarray(ref)[0, len(p):])
+
+
 def test_flash_meets_the_four_chip_mesh_under_shard_map(topo, monkeypatch):
     """XLA cannot partition a Mosaic kernel: on the 2x2 mesh flash compiles
     because ops/pallas/mesh.py runs it per shard of batch (fsdp) and heads

@@ -131,6 +131,20 @@ class TestAdversarialFixtures:
         diags = kv.verify_kernel(spec, record_metric=False)
         assert kv.VMEM_EXCEEDED in error_codes_of(diags)
 
+    def test_hand_dma_operand_is_walked_and_takes_no_vmem(self):
+        # a pool left in HBM: its copies are walked over grid x dma_grid
+        # (the table sends one past the pool), its VMEM is the scratch
+        table = np.arange(8, dtype=np.int32).reshape(2, 4) + 1
+        spec = _spec(grid=(2,), args=[
+            kv.ArgSpec("pool", (8, 8192, 1024), (1, 8192, 1024),
+                       lambda b, j, bt: (bt[b, j], 0, 0), "float32",
+                       dma_grid=(4,)),
+        ], scratch=[kv.ScratchSpec("chunk", (8, 128), "float32")],
+            scalar_prefetch=(table,))
+        assert kv.footprint_bytes(spec) == 8 * 128 * 4 + table.nbytes
+        diags = kv.verify_kernel(spec, record_metric=False)
+        assert error_codes_of(diags) == [kv.OOB_BLOCK], codes_of(diags)
+
     def test_uncovered_output_block(self):
         spec = _spec(grid=(4,), args=[
             kv.ArgSpec("o", (512, 128), (128, 128), lambda i: (0, 0),
@@ -379,6 +393,24 @@ class TestKernelVerifyPass:
             passes=["kernel-verify"])
         msgs = [d.message for d in report.errors()]
         assert any(m.startswith(kv.OOB_BLOCK) for m in msgs), \
+            report.format()
+
+    def test_traced_paged_decode_pools_stay_in_hbm(self):
+        """The paged decode kernel leaves its pools in HBM and DMAs
+        them by hand: the pass must not count them as VMEM blocks."""
+        import paddle_tpu.analysis as analysis
+        from paddle_tpu.ops.pallas.paged_attention import \
+            paged_decode_attention
+        S = jax.ShapeDtypeStruct
+        pool = S((3073, 16, 8, 128), jnp.bfloat16)     # 100 MB each
+        report = analysis.check(
+            lambda q, kp, vp, bt, ln: paged_decode_attention(
+                q, kp, vp, bt, ln, interpret=True),
+            S((32, 32, 128), jnp.bfloat16), pool, pool,
+            S((32, 161), jnp.int32), S((32,), jnp.int32),
+            passes=["kernel-verify"])
+        assert report.by_pass("kernel-verify"), report.format()
+        assert not report.errors() and not report.warnings(), \
             report.format()
 
     def test_program_without_pallas_is_informational(self):

@@ -42,6 +42,47 @@ def _paged_engine(model, **over):
     return ContinuousBatchingEngine(model, **kw)
 
 
+def _kernel_case(B=3, h=4, kvh=2, hd=16, bs=16, mb=40, lengths=(5, 9, 16),
+                 dtype=jnp.float32, quant=False, inactive=(), seed=3):
+    """Operands of one paged decode call: each row's live table entries
+    name blocks of its own, the rest name whatever the draw gave; an
+    ``inactive`` row is as the engine leaves it (length 1, row zeroed)."""
+    rng = np.random.default_rng(seed)
+    nb = 1 + B * mb
+    q = jnp.asarray(rng.normal(size=(B, h, hd)), dtype)
+    kp = rng.normal(size=(nb, bs, kvh, hd))
+    vp = rng.normal(size=(nb, bs, kvh, hd))
+    bt = rng.permutation(np.arange(1, nb)).reshape(B, mb).astype(np.int32)
+    lengths = np.asarray(lengths, np.int32)
+    for b in inactive:
+        bt[b], lengths[b] = 0, 1
+    ks = vs = None
+    if quant:
+        from paddle_tpu.inference.kv_cache import _quantize_kv
+        (kp, ks), (vp, vs) = (_quantize_kv(jnp.asarray(kp, jnp.float32)),
+                              _quantize_kv(jnp.asarray(vp, jnp.float32)))
+    else:
+        kp, vp = jnp.asarray(kp, dtype), jnp.asarray(vp, dtype)
+    return q, kp, vp, jnp.asarray(bt), jnp.asarray(lengths), ks, vs
+
+
+# a chunk is 256 tokens (16 blocks of 16, 32 of 8, 4 of 64) or the table
+_KERNEL_CASES = {
+    "table_shorter_than_a_chunk": dict(bs=4, mb=4, lengths=(5, 9, 16)),
+    "chunk_edges_bs16": dict(B=6, lengths=(1, 255, 256, 257, 600, 640)),
+    "chunk_edges_bs8": dict(B=4, bs=8, mb=70, lengths=(1, 256, 300, 560)),
+    "chunk_edges_bs64": dict(B=4, bs=64, mb=10,
+                             lengths=(64, 255, 257, 640)),
+    "bf16": dict(dtype=jnp.bfloat16, lengths=(17, 256, 513)),
+    "gqa_group_1": dict(h=2, kvh=2, lengths=(3, 300, 640)),
+    "gqa_group_2": dict(h=4, kvh=2, lengths=(3, 300, 640)),
+    "gqa_group_4": dict(h=8, kvh=2, lengths=(3, 300, 640)),
+    "inactive_row_beside_live": dict(B=4, lengths=(400, 1, 31, 1),
+                                     inactive=(1, 3)),
+    "int8_pool": dict(quant=True, lengths=(7, 257, 500)),
+}
+
+
 class TestBlockAllocator:
     def test_alloc_free_roundtrip(self):
         a = BlockAllocator(5)
@@ -275,30 +316,77 @@ class TestPagedAttentionNumerics:
         np.testing.assert_array_equal(np.asarray(unwrap(out_s)),
                                       np.asarray(unwrap(out_p)))
 
-    def test_pallas_kernel_matches_gather_fallback(self):
+    @pytest.mark.parametrize("case", sorted(_KERNEL_CASES))
+    def test_pallas_kernel_matches_gather_fallback(self, case):
+        """The chunked walk (interpret mode) against gather + softmax."""
+        import jax
         from paddle_tpu.ops.pallas.paged_attention import \
             paged_decode_attention
-        rng = np.random.default_rng(3)
-        B, h, kvh, hd, nb, bs, mb = 3, 4, 2, 16, 9, 4, 4
-        q = jnp.asarray(rng.normal(size=(B, h, hd)), jnp.float32)
-        kp = jnp.asarray(rng.normal(size=(nb, bs, kvh, hd)), jnp.float32)
-        vp = jnp.asarray(rng.normal(size=(nb, bs, kvh, hd)), jnp.float32)
-        bt = jnp.asarray(rng.integers(1, nb, size=(B, mb)), jnp.int32)
-        lengths = jnp.asarray([5, 9, 16], jnp.int32)
-        out = paged_decode_attention(q, kp, vp, bt, lengths,
-                                     interpret=True)
+        q, kp, vp, bt, lengths, ks, vs = _kernel_case(**_KERNEL_CASES[case])
+        out = paged_decode_attention(q, kp, vp, bt, lengths, interpret=True,
+                                     k_scale=ks, v_scale=vs)
+        assert out.dtype == q.dtype and out.shape == q.shape
+        B, h, hd = q.shape
+        _, bs, kvh, _ = kp.shape
+        mb = bt.shape[1]
+        if ks is not None:      # the kernel's own dequantization
+            kp = (kp.astype(jnp.float32) * ks[..., None]).astype(q.dtype)
+            vp = (vp.astype(jnp.float32) * vs[..., None]).astype(q.dtype)
         kb = jnp.repeat(kp[bt].reshape(B, mb * bs, kvh, hd),
-                        h // kvh, axis=2)
+                        h // kvh, axis=2).astype(jnp.float32)
         vb = jnp.repeat(vp[bt].reshape(B, mb * bs, kvh, hd),
-                        h // kvh, axis=2)
-        import jax
-        scores = jnp.einsum("bhd,bkhd->bhk", q, kb) / np.sqrt(hd)
+                        h // kvh, axis=2).astype(jnp.float32)
+        scores = jnp.einsum("bhd,bkhd->bhk", q.astype(jnp.float32),
+                            kb) / np.sqrt(hd)
         mask = jnp.arange(mb * bs)[None, None, :] < \
             lengths[:, None, None]
         probs = jax.nn.softmax(jnp.where(mask, scores, -1e30), axis=-1)
         ref = jnp.einsum("bhk,bkhd->bhd", probs, vb)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=1e-5)
+        atol = 2e-2 if q.dtype == jnp.bfloat16 else 1e-5
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref), atol=atol)
+
+    @pytest.mark.parametrize("kvh,dtype,ok", [
+        (8, "bfloat16", True), (2, "bfloat16", True), (1, "float32", True),
+        (1, "bfloat16", False), (2, "int8", False)])
+    def test_pallas_kernel_eligibility_by_pool(self, monkeypatch, kvh,
+                                               dtype, ok):
+        """A DMA'd block is sliced on whole 32-bit sublane words: the
+        kv heads of the pool have to fill them."""
+        import jax
+        from paddle_tpu.ops.pallas.paged_attention import \
+            paged_decode_eligible
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.delenv("PADDLE_TPU_PAGED_ATTN", raising=False)
+        pool = jax.ShapeDtypeStruct((9, 16, kvh, 128), dtype)
+        assert paged_decode_eligible(128, 16, jnp.bfloat16)
+        assert paged_decode_eligible(128, 16, jnp.bfloat16,
+                                     pool=pool) is ok
+
+    @pytest.mark.parametrize("case", ["chunk_edges_bs16", "int8_pool"])
+    def test_pallas_kernel_costs_what_is_live(self, case):
+        """Poison every pool block no live table entry names and point
+        every dead table entry at one: a fetch of either would show."""
+        from paddle_tpu.ops.pallas.paged_attention import \
+            paged_decode_attention
+        q, kp, vp, bt, lengths, ks, vs = _kernel_case(**_KERNEL_CASES[case])
+        clean = paged_decode_attention(q, kp, vp, bt, lengths,
+                                       interpret=True, k_scale=ks,
+                                       v_scale=vs)
+        bs, mb = kp.shape[1], bt.shape[1]
+        live_entry = np.arange(mb)[None, :] * bs < np.asarray(lengths)[:, None]
+        dead = np.setdiff1d(np.arange(kp.shape[0]),
+                            np.asarray(bt)[live_entry])
+        assert dead.size and not live_entry.all()
+        bt = jnp.where(live_entry, bt, int(dead[0]))
+        if ks is None:
+            kp, vp = kp.at[dead].set(jnp.nan), vp.at[dead].set(jnp.nan)
+        else:
+            kp, vp = kp.at[dead].set(127), vp.at[dead].set(127)
+            ks, vs = ks.at[dead].set(jnp.nan), vs.at[dead].set(jnp.nan)
+        out = paged_decode_attention(q, kp, vp, bt, lengths,
+                                     interpret=True, k_scale=ks, v_scale=vs)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(clean))
 
 
 class TestPagedEngineParity:
