@@ -4,6 +4,7 @@ counters, the profiler window, the check's verdict and the result line."""
 from __future__ import annotations
 
 import contextlib
+import functools
 import glob
 import importlib.util
 import json
@@ -62,6 +63,28 @@ def load_generator(traffic, root=ROOT):
     return load_by_path(os.path.join(
         root, "perf", "generators", traffic["generator"] + ".py"),
         "perf_generator")
+
+
+@functools.lru_cache(maxsize=None)
+def _arch_at(path):
+    return load_by_path(path, "perf_arch")
+
+
+def arch_path(cfg):
+    """perf/archs/<name>.py for the configuration's ``arch`` key
+    (``gqa_decoder`` without the key); no such file, no run."""
+    name = cfg.get("arch", "gqa_decoder")
+    path = os.path.join(ROOT, "perf", "archs", name + ".py")
+    if not os.path.isfile(path):
+        raise SystemExit(f"perf: {path} is missing (the configuration "
+                         f"names arch {name!r})")
+    return path
+
+
+def arch_of(cfg):
+    """The configuration's architecture file: the program's model, the
+    leaves, the plain reference and the counts."""
+    return _arch_at(arch_path(cfg))
 
 
 def metrics_of(bench, group, cell_name):
@@ -140,36 +163,6 @@ def watch_cache_misses():
 
 def pallas_calls(compiled) -> int:
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
-
-
-def build_model(cfg, seed, device):
-    """The program's decoder at the configuration's sizes, holding the
-    seed's weights (made by the benchmark, in the type they are served
-    or trained in) in place of its initialiser's."""
-    import jax
-    import jax.numpy as jnp
-    import paddle_tpu as pp
-    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
-    from perf import weights
-    mcfg = LlamaConfig(
-        vocab_size=cfg["vocab_size"], hidden_size=cfg["hidden_size"],
-        intermediate_size=cfg["intermediate_size"],
-        num_hidden_layers=cfg["num_hidden_layers"],
-        num_attention_heads=cfg["num_attention_heads"],
-        num_key_value_heads=cfg["num_key_value_heads"],
-        max_position_embeddings=cfg["max_position_embeddings"],
-        rms_norm_eps=cfg["rms_norm_eps"], rope_theta=cfg["rope_theta"],
-        tie_word_embeddings=cfg["tie_word_embeddings"],
-        dtype=cfg["torch_dtype"])
-    pp.seed(seed_key(seed))
-    with jax.default_device(device):
-        model = LlamaForCausalLM(mcfg)
-        given = weights.make_all(cfg, seed, jnp.dtype(cfg["torch_dtype"]))
-        for name, t in model.state_dict(keep_vars=True).items():
-            t._set_data(given.pop(name))
-    if given:
-        raise KeyError(f"the model has no parameter {sorted(given)}")
-    return model
 
 
 def seed_key(seed):

@@ -1,8 +1,11 @@
-"""Operations and bytes the algorithm needs, from shapes alone.
+"""Operations and bytes the algorithm needs, from shapes alone, and the
+table of peaks they are held against.
 
-Minimal-algorithm counts: causal attention counts the lower triangle only,
-recomputation is never counted, and the embedding lookup is a gather (no
-matmul).  So a share of a peak built on them cannot pass 100 %.
+The counts are the architecture's (perf/archs/<name>.py, by the
+configuration's ``arch``): minimal-algorithm counts, so that a share of a
+peak built on them cannot pass 100 %.  A count may be handed what the
+window observed as keywords (live rows, say); an architecture whose count
+does not turn on them ignores them.
 """
 
 from __future__ import annotations
@@ -10,49 +13,35 @@ from __future__ import annotations
 import json
 import os
 
-
-def _dims(cfg):
-    d, f = cfg["hidden_size"], cfg["intermediate_size"]
-    h, kv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    hd = cfg.get("head_dim") or d // h
-    return d, f, h, kv, hd
+from perf import common
 
 
-def layer_matmul_params(cfg) -> int:
-    d, f, h, kv, hd = _dims(cfg)
-    return 2 * d * h * hd + 2 * d * kv * hd + 3 * d * f
+def layer_matmul_params(cfg, i=0) -> int:
+    return common.arch_of(cfg).layer_matmul_params(cfg, i)
 
 
 def matmul_params(cfg) -> int:
-    """Weights that every token is multiplied by: blocks and the head."""
-    return cfg["num_hidden_layers"] * layer_matmul_params(cfg) + \
-        cfg["hidden_size"] * cfg["vocab_size"]
+    """Weights that every token is multiplied by (FLOPs)."""
+    return common.arch_of(cfg).matmul_params(cfg)
 
 
 def total_params(cfg) -> int:
-    d = cfg["hidden_size"]
-    return matmul_params(cfg) + cfg["vocab_size"] * d + \
-        (2 * cfg["num_hidden_layers"] + 1) * d
+    """Weights held (memory)."""
+    return common.arch_of(cfg).total_params(cfg)
 
 
-def train_flops_per_token(cfg, seq: int) -> float:
-    """Forward + backward: 6 per matmul weight, and causal attention's
-    QK^T and AV (each 2*(s/2)*h*hd a token forward, times 3)."""
-    _, _, h, _, hd = _dims(cfg)
-    attn = 3 * 2 * 2 * (seq / 2) * h * hd * cfg["num_hidden_layers"]
-    return 6.0 * matmul_params(cfg) + attn
+def train_flops_per_token(cfg, seq: int, **observed) -> float:
+    return common.arch_of(cfg).train_flops_per_token(cfg, seq, **observed)
 
 
-def kv_bytes_per_token(cfg, itemsize: int = 2) -> int:
-    _, _, _, kv, hd = _dims(cfg)
-    return 2 * kv * hd * itemsize * cfg["num_hidden_layers"]
+def kv_bytes_per_token(cfg, itemsize: int = 2, **observed) -> int:
+    return common.arch_of(cfg).kv_bytes_per_token(cfg, itemsize, **observed)
 
 
-def decode_step_bytes(cfg, live_kv_tokens: float, itemsize: int = 2) -> float:
-    """Bytes one decode step must read: every block weight and the head
-    once, and the keys and values of the live contexts."""
-    return matmul_params(cfg) * itemsize + \
-        live_kv_tokens * kv_bytes_per_token(cfg, itemsize)
+def decode_step_bytes(cfg, live_kv_tokens: float, itemsize: int = 2,
+                      **observed) -> float:
+    return common.arch_of(cfg).decode_step_bytes(cfg, live_kv_tokens,
+                                                 itemsize, **observed)
 
 
 def peaks(device_kind: str) -> dict:

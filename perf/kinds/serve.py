@@ -32,7 +32,8 @@ def build(cell, seed, device):
     import jax
     from paddle_tpu.inference.serving import ContinuousBatchingEngine
 
-    model = common.build_model(cell["config"], seed, device)
+    cfg = cell["config"]
+    model = common.arch_of(cfg).build(cfg, seed, device)
     with jax.default_device(device):
         engine = dict(cell["traffic"]["system"]["engine"])
         if "prefill_chunk" in engine:

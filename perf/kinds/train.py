@@ -26,11 +26,11 @@ def build(cell, seed, devices):
     import jax
     import paddle_tpu as pp
     from paddle_tpu.jit import TrainStep
-    from paddle_tpu.models import LlamaForCausalLM
 
     cfg, system = cell["config"], cell["traffic"]["system"]
     hp = system["adamw"]
-    model = common.build_model(cfg, seed, devices[0])
+    arch = common.arch_of(cfg)
+    model = arch.build(cfg, seed, devices[0])
     kw = {}
     with jax.default_device(devices[0]):
         opt = pp.optimizer.AdamW(
@@ -42,12 +42,9 @@ def build(cell, seed, devices):
             axes = system["mesh"]
             mesh = Mesh(np.array(devices).reshape(list(axes.values())),
                         tuple(axes))
-            rules = LlamaForCausalLM.partition_specs(
-                model.config, tp_axis="tp", fsdp_axis="fsdp")
             kw = dict(mesh=mesh, batch_spec=P(system["batch_axis"]),
-                      param_specs={
-                          n: LlamaForCausalLM.spec_for(n, rules)
-                          for n in model.state_dict(keep_vars=True)})
+                      param_specs=arch.partition_specs(
+                          model, tp_axis="tp", fsdp_axis="fsdp"))
         step = TrainStep(model, opt, **kw)
     return step
 
@@ -62,13 +59,13 @@ def program_norms(step, cfg, seed, hp):
 
     def grads(state):
         return {n: jnp.sqrt(jnp.sum(jnp.square(state[n]["moment1"])))
-                / (1 - hp["beta1"]) for n, _ in spec}
+                / (1 - hp["beta1"]) for n, _, _ in spec}
 
     def deltas(key, state, params):
         out = {}
-        for i, (n, shape) in enumerate(spec):
+        for i, (n, shape, init) in enumerate(spec):
             now = state[n].get("_master", params[n]).astype(jnp.float32)
-            p0 = weights._leaf(key, i, shape, params[n].dtype)
+            p0 = weights._leaf(key, i, shape, init, params[n].dtype)
             out[n] = jnp.sqrt(jnp.sum(jnp.square(
                 now - p0.astype(jnp.float32))))
         return out

@@ -2,7 +2,8 @@
 teacher-forced float32 forward over its prompt and its served tokens, a
 layer at a time (a layer's weights are made from the seed, used for every
 row, and dropped, so it fits beside the engine), then the head at the
-served positions only.
+served positions only.  The walking is here; the mathematics is the
+architecture's (perf/archs/: ``embed``, ``layer``, ``head``).
 
 Greedy tokens are compared on logits, not by equality: with seeded random
 weights the best and the second-best logit are often a rounding apart.
@@ -16,8 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from perf import weights
-from perf.reference import decoder
+from perf import common, weights
 
 
 def served_logits(cfg, seed, rows, pad_len, pad_out, precision="float32"):
@@ -33,36 +33,40 @@ def served_logits(cfg, seed, rows, pad_len, pad_out, precision="float32"):
             lambda a: a.astype(jnp.float32),
             weights.make_some(cfg, seed, names,
                               jnp.dtype(cfg["torch_dtype"])))
-        emb = make(["model.embed_tokens.weight"])["model.embed_tokens.weight"]
+        arch = common.arch_of(cfg)
+        emb = make([n for n, _, _ in arch.embed_leaves(cfg)])
         x, at = [], []
         for prompt, out in rows:
             n = len(prompt) + len(out)
             ids = np.zeros((1, pad_len), np.int32)
             ids[0, :n] = np.concatenate([prompt, out])
-            x.append(emb[jnp.asarray(ids)])
+            x.append(arch.embed(emb, cfg, jnp.asarray(ids)))
             at.append(len(prompt) - 1 + np.arange(len(out)))
         del emb
 
-        @jax.jit
-        def block(xr, w):
-            cos, sin = decoder.rope_tables(cfg, jnp.arange(xr.shape[1]))
-            return decoder.layer(xr, w, cfg, cos, sin, "", precision)
+        blocks = {}     # layers of one kind are one compiled program
+
+        def block(i):
+            kind = arch.layer_kind(cfg, i)
+            if kind not in blocks:
+                def layer(xr, w):
+                    return arch.layer(xr, w, cfg, i,
+                                      jnp.arange(xr.shape[1]), precision)
+                blocks[kind] = jax.jit(layer)
+            return blocks[kind]
 
         for i in range(cfg["num_hidden_layers"]):
-            names = [n for n, _ in weights.layer_leaves(cfg, i)]
-            w = make(names)
-            w = {n.split(".", 2)[2]: a for n, a in w.items()}
-            x = [block(xr, w) for xr in x]
+            w = make([n for n, _, _ in arch.layer_leaves(cfg, i)])
+            w = {n[len(arch.layer_prefix(i)):]: a for n, a in w.items()}
+            x = [block(i)(xr, w) for xr in x]
             del w
         jax.block_until_ready(x)
         t1 = time.perf_counter()
-        w = make(["model.norm.weight", "lm_head.weight"])
+        w = make([n for n, _, _ in arch.head_leaves(cfg)])
 
         @jax.jit
         def head(h, w):     # weights as arguments: never constants
-            h = decoder.rms_norm(h, w["model.norm.weight"],
-                                 cfg["rms_norm_eps"])
-            return decoder.matmul(h, w["lm_head.weight"], precision)
+            return arch.head(h, w, cfg, precision)
 
         out = []
         for xr, pos in zip(x, at):

@@ -3,10 +3,14 @@
   python3 perf/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 A cell is an entry of BENCHMARK.json's ``workloads``.  Everything that
-belongs to one configuration, one traffic mix or one per-layer metric is a
-file found by its name: perf/configs/, perf/traffic/ (which names the kind
-of cell and its generator), perf/generators/, perf/layer_metrics/.  A new
-cell is new files and new entries; no file here is edited for it.
+belongs to one configuration, one architecture, one traffic mix or one
+per-layer metric is a file found by its name: perf/configs/ (which names
+the architecture; ``gqa_decoder`` where it names none), perf/archs/ (the
+program's model, the seeded leaves, the plain reference and the counts),
+perf/traffic/ (which names the kind of cell and its generator),
+perf/generators/, perf/layer_metrics/.  A new cell, of an architecture the
+benchmark has or of a new one, is new files and new entries; no file here
+is edited for it.
 
 Runs on a TPU only.  Without one, or with fewer chips than the cell asks,
 it prints no result line and exits 2.  ``--list`` prints the cells and the
@@ -52,8 +56,9 @@ def main(argv=None) -> int:
                                cell["traffic"]["generator"] + ".py")
             if not os.path.isfile(gen):
                 raise SystemExit(f"perf: {gen} is missing")
+            arch = os.path.relpath(common.arch_path(cell["config"]), ROOT)
             print(f"{w['name']}: kind {cell['kind']}, chips {w['chips']}, "
-                  f"config {cell['config_file']}, traffic "
+                  f"config {cell['config_file']}, arch {arch}, traffic "
                   f"{cell['traffic_file']}, generator "
                   f"{cell['traffic']['generator']}, layer metrics "
                   f"{','.join(layer)}")
@@ -63,6 +68,7 @@ def main(argv=None) -> int:
     if args.seconds is None:
         args.seconds = float(bench["run_seconds"])
     cell = common.resolve_cell(bench, args.workload, ROOT)
+    common.arch_path(cell["config"])     # no architecture file, no run
     common.use_cache_dir()
     try:
         import paddle_tpu  # noqa: F401

@@ -1,6 +1,8 @@
 """BENCHMARK.json against the benchmark's contract, and the harness's
-promise that a new cell is new files and new entries only."""
+promise that a new cell, of an architecture it has or of a new one, is new
+files and new entries only."""
 
+import argparse
 import hashlib
 import json
 import math
@@ -9,6 +11,7 @@ import re
 import shutil
 import subprocess
 import sys
+import time
 
 import pytest
 
@@ -170,6 +173,133 @@ def test_a_new_cell_is_new_files_and_entries_only(copy, bench):
     after = _digest(copy)
     changed = {k for k in before if after.get(k) != before[k]}
     assert changed == {"BENCHMARK.json"}, changed
+
+
+def _add_cell(copy, bench, name, conf, traffic, moves):
+    """A configuration, a traffic mix and a cell of them, as files and
+    entries of the copy; the cell reports ``moves`` and ``setup_s``."""
+    (copy / f"perf/configs/{name}.json").write_text(json.dumps(conf))
+    (copy / f"perf/traffic/{name}.json").write_text(json.dumps(traffic))
+    b = json.loads(json.dumps(bench))
+    b["configs"].append({"name": name, "source": conf["source"],
+                         "file": f"perf/configs/{name}.json",
+                         "reduced": ["num_hidden_layers"], "why": "test"})
+    b["workloads"].append({"name": name, "config": name, "traffic": name,
+                           "chips": 1, "why": "test"})
+    for m in b["end_to_end"] + b["per_layer"]:
+        if m["name"] in moves:
+            m["workloads"].append(name)
+    (copy / "BENCHMARK.json").write_text(json.dumps(b))
+
+
+TOY = dict(arch="two_kinds", hidden_size=64, intermediate_size=128,
+           num_hidden_layers=4, num_attention_heads=4,
+           num_key_value_heads=2, vocab_size=256,
+           max_position_embeddings=128, torch_dtype="float32")
+TOY_PARAMS = {"rate_per_s": 20.0, "schedule_seed": 1,
+              "prompt": {"median": 20, "sigma": 0.8, "min": 8, "max": 60},
+              "output": {"median": 8, "sigma": 0.7, "min": 2, "max": 16}}
+TOY_ENGINE = {"slots": 4, "max_len": 96, "paged_kv": True,
+              "kv_block_size": 8, "prefill_chunk": 16}
+
+
+@pytest.mark.parametrize("reference", ["its own", "without the odd layers"])
+def test_a_new_architecture_is_new_files_and_entries_only(
+        copy, bench, on_cpu, capsys, monkeypatch, reference):
+    """An architecture whose layers are of two kinds, the odd ones with a
+    1-D leaf that is no gain: an arch file, a configuration that names
+    it, a mix and entries.  ``--list`` names it and the serve rehearsal
+    runs it on the CPU to ``correct: true`` against its own reference —
+    and to false against a reference that knows one kind of layer."""
+    from perf import common
+    from perf.kinds import serve
+    before = _digest(copy)
+    shutil.copy(os.path.join(ROOT, "tests/perf/data/two_kinds_arch.py"),
+                copy / "perf/archs/two_kinds.py")
+    conf = json.load(open(copy / "perf/configs/mistral-7b-v0.3.L12.json"))
+    mix = json.load(open(copy / "perf/traffic/chat-open-0.8.json"))
+    mix["params"], mix["system"] = TOY_PARAMS, {"engine": TOY_ENGINE}
+    _add_cell(copy, bench, "toy", dict(conf, **TOY), mix,
+              ("ttft_p95_ms", "tpot_p95_ms", "serve_tokens_per_s",
+               "cache_misses.setup"))
+    r = _run(copy, "--list")
+    assert r.returncode == 0, r.stderr
+    line = [ln for ln in r.stdout.splitlines() if ln.startswith("toy:")]
+    assert line and "arch perf/archs/two_kinds.py" in line[0]
+    assert all("arch perf/archs/gqa_decoder.py" in ln
+               for ln in r.stdout.splitlines() if not ln.startswith("toy:"))
+
+    # the rehearsal, in this process, finding the copy's files by name
+    monkeypatch.setattr(common, "ROOT", str(copy))
+    monkeypatch.setattr(serve, "WARM_PROMPTS", (20, 9))
+    b = common.load_json(str(copy / "BENCHMARK.json"))
+    cell = common.resolve_cell(b, "toy", str(copy))
+    arch = common.arch_of(cell["config"])
+    assert arch.__file__ == str(copy / "perf/archs/two_kinds.py")
+    kinds = [k for _, _, k in arch.layer_leaves(cell["config"], 1)]
+    assert kinds.count("vector") == 1 and "vector" not in [
+        k for _, _, k in arch.layer_leaves(cell["config"], 0)]
+    if reference != "its own":
+        monkeypatch.setattr(arch, "layer", common.arch_of({}).layer)
+    args = argparse.Namespace(seed=2 ** 31 + 78, seconds=2.0, trace=0)
+    assert serve.run(b, cell, args, time.perf_counter()) == 0
+    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert out["attempted"] == 40 and out["failed"] == 0
+    assert out["correct"] is (reference == "its own")
+    after = _digest(copy)
+    changed = {k for k in before if after.get(k) != before[k]}
+    assert changed == {"BENCHMARK.json"}, changed
+
+
+def test_an_architecture_with_no_file_is_no_run(copy, bench, tmp_path):
+    conf = json.load(open(copy / "perf/configs/internlm2-1.8b.L4.json"))
+    mix = json.load(open(copy / "perf/traffic/lm-16k.json"))
+    _add_cell(copy, bench, "lost", dict(conf, arch="nowhere"), mix,
+              ("train_tokens_per_s_per_chip", "cache_misses.setup"))
+    path = str(copy / "perf/archs/nowhere.py")
+    for args in (("--list",), ("--workload", "lost", "--seed", "1",
+                               "--seconds", "1", "--trace", "0")):
+        r = _run(copy, *args,
+                 JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+        assert r.returncode not in (0, 2) and path in r.stderr
+        assert not any(ln.startswith("{") for ln in r.stdout.splitlines())
+
+
+def _perf_sources():
+    for d, _, files in os.walk(os.path.join(ROOT, "perf")):
+        for f in files:
+            if f.endswith(".py"):
+                p = os.path.join(d, f)
+                yield os.path.relpath(p, ROOT), open(p).read()
+
+
+MODEL = re.compile(r"paddle_tpu\.models|LlamaForCausalLM|LlamaConfig")
+WIDTHS = re.compile(r"intermediate_size|num_attention_heads|"
+                    r"num_key_value_heads|head_dim")
+DECODER = re.compile(r"^.*reference(?:\.| import )decoder.*$", re.M)
+SHARED = re.compile(r"from perf\.reference\.decoder import "
+                    r"(adamw|matmul|_q8)(, (adamw|matmul|_q8))*$")
+
+
+def test_only_an_arch_file_knows_a_model():
+    """Nothing under perf/ outside perf/archs/ imports the program's
+    models, names a model class or reads a width that only some
+    architectures have; perf/reference/decoder.py (gqa_decoder's
+    reference) is reached through perf/archs/gqa_decoder.py alone, its
+    shared ``adamw`` / ``matmul`` controls excepted."""
+    seen = 0
+    for path, text in _perf_sources():
+        if path.startswith("perf/archs/"):
+            continue
+        seen += 1
+        assert not MODEL.search(text), path
+        if path != "perf/reference/decoder.py":
+            assert not WIDTHS.search(text), path
+        for line in DECODER.findall(text):
+            assert SHARED.search(line.strip()), (path, line)
+    assert seen > 40
+    gqa = open(os.path.join(ROOT, "perf/archs/gqa_decoder.py")).read()
+    assert MODEL.search(gqa) and DECODER.search(gqa)
 
 
 def test_no_tpu_no_result(tmp_path):

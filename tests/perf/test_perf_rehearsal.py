@@ -1,7 +1,8 @@
 """Each kind of cell end to end on the CPU at a tiny size, through the
 kinds' real control flow (the look for a chip is skipped here, in the
-test; run.py has no option for it) — and with the timed path broken
-underneath, where ``correct`` must come out false."""
+tests' ``on_cpu`` fixture in conftest.py; run.py has no option for it) —
+and with the timed path broken underneath, where ``correct`` must come out
+false."""
 
 import argparse
 import json
@@ -10,7 +11,7 @@ import time
 
 import pytest
 
-from perf import common, flops, trace_reduce
+from perf import common
 
 TINY = dict(hidden_size=64, intermediate_size=128, num_hidden_layers=2,
             num_attention_heads=4, num_key_value_heads=2, vocab_size=256,
@@ -19,28 +20,6 @@ TINY = dict(hidden_size=64, intermediate_size=128, num_hidden_layers=2,
             # sizes, where a norm averages over millions of elements; a
             # 64-wide bfloat16 model is further from float32 than they allow
             torch_dtype="float32")
-MS = 1e6
-
-
-@pytest.fixture()
-def on_cpu(monkeypatch):
-    import jax
-    from paddle_tpu import compile_cache
-    monkeypatch.setattr(common, "require_device",
-                        lambda chips: jax.devices()[:chips])
-    monkeypatch.setattr(compile_cache, "enable_persistent_cache",
-                        lambda: None)
-    monkeypatch.setattr(flops, "peaks", lambda kind: {
-        "bf16_flops": 197e12, "hbm_bytes_per_s": 819e9})
-    # the profiler runs, but a CPU trace has no device plane: hand-made
-    # intervals stand in, through the same reduction
-    monkeypatch.setattr(trace_reduce, "load", lambda path: trace_reduce.Trace(
-        {"/device:TPU:0": [("fusion.1", 0, 5 * MS), ("fusion.2", 7 * MS,
-                                                     MS)]},
-        {"/device:TPU:0": [("jit_decode_paged(1)", 0, 5 * MS),
-                           ("jit_prefill_chunk(2)", 7 * MS, MS)]},
-        [("bench.engine_step", 0, 6 * MS), ("bench.train_step", 6 * MS,
-                                            3 * MS)]))
 
 
 def _cell(name, **traffic):
