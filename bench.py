@@ -1162,7 +1162,6 @@ def main(argv=None):
     from paddle_tpu.observability import default_registry
     from paddle_tpu.distributed.sharding import overlap_enabled
     from paddle_tpu.ops.pallas.cross_entropy import fused_ce_enabled
-    from paddle_tpu.ops.pallas.flash_attention import flash_bwd_env
     from paddle_tpu.ops.pallas.fused_block import (fused_block_enabled,
                                                    fused_block_tier)
 
@@ -1171,13 +1170,9 @@ def main(argv=None):
         return {"/".join(k) or "all": c.value() for k, c in m.series()} \
             if m is not None else {}
 
-    pb = flash_bwd_env()
     paths = {
         "fused_ce_enabled": bool(fused_ce_enabled()),
         "fused_ce_calls": _series("paddle_tpu_fused_ce_calls_total"),
-        "flash_bwd": "pallas" if pb else ("blockwise" if pb is not None
-                                         else "blockwise(default)"),
-        "flash_bwd_traces": _series("paddle_tpu_flash_bwd_path_total"),
         # which block segments this run compiled fused vs reference, and
         # whether tuned block sizes came from the persistent cache —
         # BENCH trajectories can attribute wins to the exact code path

@@ -2,12 +2,10 @@
 matrix + VERDICT r3 #1/#4).
 
 At long sequence the attention term dominates and the Pallas flash
-kernels must carry the step; this bench measures the FULL train step
-(fwd+bwd+AdamW) per sequence length for BOTH backward implementations —
-the Pallas dq/dkv kernels and the blockwise-jax recompute — and reports
-which one wins in-model, alongside the autotuner's isolated choice.
+kernels (forward, dq, dk/dv) must carry the step; this bench measures
+the FULL train step (fwd+bwd+AdamW) per sequence length.
 
-Prints one JSON line per (seq, backward) plus a summary line per seq.
+Prints one JSON line per seq.
 Run on the TPU chip; falls back to a tiny CPU smoke shape off-TPU.
 
 One process per chip: the parent never touches jax.  Every measurement
@@ -23,7 +21,7 @@ import time
 import numpy as np
 
 
-def run_one(cfg, batch, seq, pallas_bwd, iters=8, warmup=2, remat=False,
+def run_one(cfg, batch, seq, iters=8, warmup=2, remat=False,
             remat_policy=None):
     import jax
     import paddle_tpu as pp
@@ -36,8 +34,6 @@ def run_one(cfg, batch, seq, pallas_bwd, iters=8, warmup=2, remat=False,
     opt = pp.optimizer.AdamW(learning_rate=1e-4,
                              parameters=model.parameters(),
                              multi_precision=True)
-    import os
-    os.environ["PT_FLASH_PALLAS_BWD"] = str(int(pallas_bwd))
     step = TrainStep(model, opt, remat=remat, remat_policy=remat_policy)
     n_params = sum(int(np.prod(a.shape)) for a in step.params.values())
     rng = np.random.default_rng(0)
@@ -83,7 +79,7 @@ def _plans(on_tpu):
     return base, [dict(seq=256, batch=2, remat=False, remat_policy=None)]
 
 
-def _child(seq: int, pb: int):
+def _child(seq: int):
     """One measurement per process: a fresh 584M model + full AdamW state
     twice in one process OOMs the 16G chip (freeing is async).
 
@@ -103,7 +99,7 @@ def _child(seq: int, pb: int):
         pol = os.environ["PT_SEQ_POLICY"]
         plan["remat_policy"] = None if pol == "none" else pol
     cfg = LlamaConfig(max_position_embeddings=seq, **base)
-    mfu, tps, dt = run_one(cfg, plan["batch"], seq, bool(pb),
+    mfu, tps, dt = run_one(cfg, plan["batch"], seq,
                            remat=plan["remat"],
                            remat_policy=plan["remat_policy"])
     print("RESULT " + json.dumps({
@@ -128,47 +124,30 @@ def main():
         ln for ln in listing.stdout.splitlines()
         if ln.startswith("PLANS "))[len("PLANS "):])
     for seq in seqs:
-        per = {}
-        for pb in (True, False):
-            proc = subprocess.run(
-                [sys.executable, __file__, "--child", str(seq),
-                 str(int(pb))],
-                capture_output=True, text=True, timeout=3000)
-            line = next((ln for ln in proc.stdout.splitlines()
-                         if ln.startswith("RESULT ")), None)
-            if line is None:
-                print(json.dumps({
-                    "metric": f"llama_s{seq}_mfu_"
-                              f"{'pallas_bwd' if pb else 'blockwise_bwd'}",
-                    "value": None, "error": proc.stderr[-500:]}),
-                    flush=True)
-                continue
-            r = json.loads(line[len("RESULT "):])
-            per[pb] = r["mfu"]
+        proc = subprocess.run(
+            [sys.executable, __file__, "--child", str(seq)],
+            capture_output=True, text=True, timeout=3000)
+        line = next((ln for ln in proc.stdout.splitlines()
+                     if ln.startswith("RESULT ")), None)
+        if line is None:
             print(json.dumps({
-                "metric": f"llama_s{seq}_mfu_"
-                          f"{'pallas_bwd' if pb else 'blockwise_bwd'}",
-                "value": round(r["mfu"], 4), "unit": "fraction_of_peak",
-                "detail": {"batch": r["batch"], "seq": seq,
-                           "tokens_per_sec_per_chip": round(r["tps"], 1),
-                           "step_time_s": round(r["dt"], 4),
-                           "remat": r["remat"]}}), flush=True)
-        if len(per) == 2:
-            winner = "pallas" if per[True] >= per[False] else "blockwise"
-            print(json.dumps({
-                "metric": f"llama_s{seq}_mfu",
-                "value": round(max(per.values()), 4),
-                "unit": "fraction_of_peak",
-                "detail": {"in_model_winner": winner,
-                           "pallas_bwd_mfu": round(per[True], 4),
-                           "blockwise_bwd_mfu": round(per[False], 4)}}),
-                flush=True)
+                "metric": f"llama_s{seq}_mfu", "value": None,
+                "error": proc.stderr[-500:]}), flush=True)
+            continue
+        r = json.loads(line[len("RESULT "):])
+        print(json.dumps({
+            "metric": f"llama_s{seq}_mfu",
+            "value": round(r["mfu"], 4), "unit": "fraction_of_peak",
+            "detail": {"batch": r["batch"], "seq": seq,
+                       "tokens_per_sec_per_chip": round(r["tps"], 1),
+                       "step_time_s": round(r["dt"], 4),
+                       "remat": r["remat"]}}), flush=True)
 
 
 if __name__ == "__main__":
     import sys
     if len(sys.argv) > 1 and sys.argv[1] == "--child":
-        _child(int(sys.argv[2]), int(sys.argv[3]))
+        _child(int(sys.argv[2]))
     elif len(sys.argv) > 1 and sys.argv[1] == "--plans":
         _list_plans()
     else:

@@ -41,7 +41,6 @@ PATH_COUNTERS = (
     "paddle_tpu_fused_block_path_total",
     "paddle_tpu_fused_ce_calls_total",
     "paddle_tpu_paged_attention_path_total",
-    "paddle_tpu_flash_bwd_path_total",
     "paddle_tpu_kernel_mesh_route_total",
     "paddle_tpu_autotune_cache_total",
 )
@@ -169,7 +168,9 @@ def routed_on(before):
             elif name.endswith("paged_attention_path_total") and \
                     k == "pallas":
                 on.add("paged_decode")
-            elif name.endswith("flash_bwd_path_total"):
+            elif (name.endswith("autotune_cache_total") or
+                  name.endswith("kernel_mesh_route_total")) and \
+                    k.startswith("flash/"):
                 on.add("flash")
     return on
 

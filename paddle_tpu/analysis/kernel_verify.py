@@ -558,10 +558,10 @@ def candidate_findings(op: str, shape: Tuple, cand: Tuple
     if op == "flash":
         from paddle_tpu.ops.pallas import flash_attention as fa
         b, s, h, hk, d, dtype, causal = shape
-        bq, bk, pallas_bwd = cand
-        parts = ("fwd", "bwd") if pallas_bwd else ("fwd",)
+        bq, bk = cand    # the forward's; the backward's tiles follow a
+        # rule of the shape alone and cannot tell candidates apart
         return fa.verify_static(b, s, h, hk, d, dtype=dtype, causal=causal,
-                                block_q=bq, block_k=bk, parts=parts)
+                                block_q=bq, block_k=bk, parts=("fwd",))
     if op == "fused_ce":
         from paddle_tpu.ops.pallas import cross_entropy as ce
         t, v, dtype = shape
@@ -659,8 +659,9 @@ def _catalog_entries() -> List[Dict[str, Any]]:
             lambda b=b, s=s, h=h, hk=hk, d=d, dtype=dtype, causal=causal:
             fa.verify_static(b, s, h, hk, d, dtype=dtype, causal=causal,
                              parts=("fwd",)))
+        tile, q_span, k_span = fa.bwd_tiles(s, d, h // hk, itemsize(dtype))
         add("flash_bwd", f"b{b} s{s} h{h}/{hk} d{d} {dtype}",
-            f"bq{bq} bk{bk}",
+            f"tile{tile} spans{q_span}/{k_span}",
             lambda b=b, s=s, h=h, hk=hk, d=d, dtype=dtype, causal=causal:
             fa.verify_static(b, s, h, hk, d, dtype=dtype, causal=causal,
                              parts=("bwd",)))

@@ -63,8 +63,8 @@ def _use_pallas(q_shape, head_dim):
     # dense path materializes the [b, h, s, s] score tensor per layer and
     # the remat policy keeps those dot outputs live (at the bench model's
     # shapes the dense variant fails to even compile on a 16 GB chip).
-    # Backward-implementation and block-size choice are autotuned
-    # (ops/pallas/autotune.py); at 8k+ flash also wins outright (6.4x).
+    # The forward's block sizes are autotuned (ops/pallas/autotune.py);
+    # at 8k+ flash also wins outright (6.4x).
     return head_dim % 128 == 0 and q_shape[1] >= 128 and \
         q_shape[1] % 128 == 0
 
@@ -102,28 +102,12 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
             _use_pallas(query.shape, query.shape[-1]):
         # no try/except: a lowering break in the flagship kernel must
         # surface, not silently fall back (round-1 lesson).
-        # Backward implementation: blockwise-jax recompute, pinned from
-        # IN-MODEL measurement on v5e (bench.py +
-        # benchmarks/llama_seq_bench.py, full train step, both variants):
-        #   b4/s2048: 0.514 vs 0.461   b2/s4096: 0.404 vs 0.361
-        #   b1/s8192 (remat): 0.241 vs 0.218
-        # — no crossover up to 8k: XLA fuses the recompute chain into the
-        # surrounding step better than the separate dq + dkv Pallas
-        # dispatches (two extra HBM passes over q/k/v/g).  The Pallas
-        # backward kernels remain available (pallas_bwd=True /
-        # PADDLE_TPU_FLASH_BWD=1, legacy alias PT_FLASH_PALLAS_BWD) and
-        # win in ISOLATED microbenches (benchmarks/pallas_kernels_bench
-        # .py) — a documented niche: standalone attention grads without
-        # a surrounding fusable step.
         import functools
 
         from paddle_tpu.ops.pallas import mesh
-        from paddle_tpu.ops.pallas.flash_attention import (flash_attention,
-                                                           flash_bwd_env)
-        pb = flash_bwd_env()
+        from paddle_tpu.ops.pallas.flash_attention import flash_attention
         attn = functools.partial(flash_attention, causal=is_causal,
-                                 scale=scale,
-                                 pallas_bwd=False if pb is None else pb)
+                                 scale=scale)
         if mesh.current() is not None:
             # inside a sharded step: per shard of batch and heads
             return mesh.over_batch_and_heads(attn, query, key, value)

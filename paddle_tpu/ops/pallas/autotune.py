@@ -298,14 +298,10 @@ def _put(op_name: str, key: str, value):
 
 # -- flash attention ---------------------------------------------------------
 
-def _flash_candidates(s: int, d: int, dtype: str,
-                      pallas_bwd=None) -> list:
-    """(block_q, block_k, pallas_bwd) candidates: block sizes bounded by
-    the VMEM working set, crossed with the two backward implementations
-    (Pallas dq/dkv kernels vs the blockwise-jax recompute) — the variant
-    choice is part of the tuning space, reference auto_tune_base style.
-    A caller-pinned ``pallas_bwd`` constrains that dimension (no point
-    benching a variant the call site will never use)."""
+def _flash_candidates(s: int, d: int, dtype: str) -> list:
+    """(block_q, block_k) candidates of the FORWARD, bounded by the VMEM
+    working set.  The backward's tiles follow a rule read from the shapes
+    (``flash_attention.bwd_tiles``) and are no part of the sweep."""
     blocks = []
     sizes = (128, 256) if s < 4096 else (128, 256, 512)
     for bq in sizes:
@@ -318,31 +314,26 @@ def _flash_candidates(s: int, d: int, dtype: str,
                     + 2 * bq * d * 4)                  # fp32 accumulators
             if vmem < 10 * (1 << 20):
                 blocks.append((bq, bk))
-    blocks = blocks or [(min(128, s), min(128, s))]
-    pbs = (True, False) if pallas_bwd is None else (bool(pallas_bwd),)
-    return [(bq, bk, pb) for bq, bk in blocks for pb in pbs]
+    return blocks or [(min(128, s), min(128, s))]
 
 
-def flash_key(b, s, h, hk, d, dtype, causal, pallas_bwd=None,
-              backend=None, interpret=None):
-    pb_tag = "x" if pallas_bwd is None else str(int(bool(pallas_bwd)))
-    return (f"b{b}s{s}h{h}k{hk}d{d}{dtype}c{int(causal)}pb{pb_tag}"
+def flash_key(b, s, h, hk, d, dtype, causal, backend=None, interpret=None):
+    return (f"b{b}s{s}h{h}k{hk}d{d}{dtype}c{int(causal)}"
             f"@{backend or backend_tag(interpret)}")
 
 
 def flash_block_sizes(b: int, s: int, h: int, hk: int, d: int,
-                      dtype: str, causal: bool,
-                      pallas_bwd=None) -> Tuple[int, int, bool]:
-    """Measured (block_q, block_k, pallas_bwd) for this shape (the last
-    entry echoes ``pallas_bwd`` when the caller pinned it)."""
-    default = (min(128, s), min(128, s),
-               True if pallas_bwd is None else bool(pallas_bwd))
-    cands = _flash_candidates(s, d, dtype, pallas_bwd)
+                      dtype: str, causal: bool) -> Tuple[int, int]:
+    """Measured (block_q, block_k) of the forward for this shape; each
+    candidate is timed through one forward + backward, as a step runs
+    it."""
+    default = (min(128, s), min(128, s))
+    cands = _flash_candidates(s, d, dtype)
     cands, _ = _verify_prune("flash", (b, s, h, hk, d, dtype, causal),
                              cands)
     if len(cands) == 1:
         return tuple(cands[0])
-    key = flash_key(b, s, h, hk, d, dtype, causal, pallas_bwd)
+    key = flash_key(b, s, h, hk, d, dtype, causal)
 
     def bench(blocks):
         import jax
@@ -352,7 +343,7 @@ def flash_block_sizes(b: int, s: int, h: int, hk: int, d: int,
 
         from paddle_tpu.ops.pallas.flash_attention import flash_attention
 
-        bq, bk, pb = blocks
+        bq, bk = blocks
         iters = 8
         rng = np.random.default_rng(0)
         dt = jnp.dtype(dtype)
@@ -366,8 +357,7 @@ def flash_block_sizes(b: int, s: int, h: int, hk: int, d: int,
             # host latency cannot bias the sweep
             def loss(args):
                 o = flash_attention(*args, causal=causal, block_q=bq,
-                                    block_k=bk, pallas_bwd=pb,
-                                    autotune=False)
+                                    block_k=bk, autotune=False)
                 return jnp.sum(o.astype(jnp.float32) ** 2)
 
             def body(i, carry):
@@ -945,9 +935,8 @@ def _sweep_one(op, shape, dry_run, backend):
     if op == "flash":
         b, s, h, hk, d, dtype, causal = shape
         cands = _flash_candidates(s, d, dtype)
-        default = (min(128, s), min(128, s), True)
-        key = flash_key(b, s, h, hk, d, dtype, causal, None,
-                        backend=backend)
+        default = (min(128, s), min(128, s))
+        key = flash_key(b, s, h, hk, d, dtype, causal, backend=backend)
         _, npruned = _verify_prune(op, shape, cands)
         if not dry_run:
             return key, flash_block_sizes(b, s, h, hk, d, dtype, causal), \

@@ -11,11 +11,14 @@ blockwise kernel:
   GQA/MQA is handled in the grid itself: the K/V BlockSpec index map sends
   q-head h to kv-head h // (hq // hk), so KV tiles are fetched once per
   group instead of materializing repeated heads in HBM.
-* backward: blockwise recompute from the saved logsumexp (flash-attention-2
-  style) expressed in JAX with grouped-GQA einsums and left to XLA to fuse —
-  dQ/dK/dV each come from one scan over blocks, so backward memory is
-  O(seq·block), not O(seq²), and dK/dV sum over the query group without
-  ever materializing repeated KV.
+* backward: two Pallas kernels that recompute the probabilities from the
+  saved logsumexp (flash-attention-2 style).  dk/dv take a k tile a grid
+  step and walk the q tiles of the whole query group inside the kernel
+  (GQA without repeated heads); dq takes a q tile and walks the k tiles.
+  The walked side sits in VMEM a span at a time and the walk's bounds
+  are run-time scalars, so a causal call neither multiplies nor copies a
+  tile above the diagonal.  Backward memory is O(seq), and the tiles
+  follow a rule read from the shapes (``bwd_tiles``), not a sweep.
 
 Mosaic legality notes (the round-1 kernel broke here): every output block's
 last two dims must be (divisible by 8, divisible by 128) or equal to the
@@ -30,7 +33,6 @@ kernels see [batch, heads, seq, head_dim].
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -38,27 +40,7 @@ from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention", "flash_bwd_env"]
-
-
-def flash_bwd_env():
-    """Backward-implementation override from the environment:
-    ``PADDLE_TPU_FLASH_BWD=1`` forces the Pallas dq/dkv kernels, ``0``
-    the blockwise-jax recompute; unset → None (autotuner / call site
-    decides).  ``PT_FLASH_PALLAS_BWD`` is honored as a legacy alias."""
-    raw = os.environ.get("PADDLE_TPU_FLASH_BWD",
-                         os.environ.get("PT_FLASH_PALLAS_BWD"))
-    if raw is None:
-        return None
-    return raw.strip().lower() in ("1", "true", "yes", "on")
-
-
-def _bwd_path_counter():
-    from paddle_tpu.observability import default_registry
-    return default_registry().counter(
-        "paddle_tpu_flash_bwd_path_total",
-        "flash-attention backward implementation chosen at trace time",
-        labelnames=("path",))
+__all__ = ["flash_attention", "bwd_tiles"]
 
 _NEG_INF = -1e30
 
@@ -185,140 +167,247 @@ def _fwd_pallas(q, k, v, *, scale, causal, block_q, block_k,
     return out, lse5.reshape(b, hq, s)
 
 
-# -- backward: Pallas kernels (flash-attn-2 equations) -----------------------
+# -- backward: two Pallas kernels (flash-attn-2 equations) -------------------
 #
-# Both kernels work in TRANSPOSED score space (s_T[k, q] instead of
-# s[q, k]): the per-ROW softmax statistics (lse, delta) then enter as
-# [1, block_q] row vectors that broadcast over the k dimension with no
-# in-kernel transpose/relayout, and every contraction is a dot_general the
-# MXU handles directly.
+# One side of the score tile is the grid's (a k tile for dk/dv, a q tile
+# for dq); the other side is WALKED inside the kernel, a tile at a time,
+# over a span of rows that the grid pipeline keeps in VMEM.  The walk's
+# bounds are run-time scalars read from the tile's position, so a causal
+# call multiplies no tile above the diagonal, masks only the tiles the
+# diagonal crosses, and — the span's index map clamps to the last span
+# the diagonal reaches — copies none either.  Every product runs on the
+# MXU with operands of the inputs' dtype and float32 accumulation (``p``
+# and ``ds`` are cast for it, as the forward casts ``p``); the softmax
+# statistics, ``p``, ``dp - delta`` and the accumulators stay float32, and
+# the softmax scale meets the float32 accumulators once, at the flush.
+#
+# dk/dv work in TRANSPOSED score space (s_T[k, q]): the per-row
+# statistics enter as [1, tile] rows that broadcast down the k dimension
+# and ``p_T @ g`` / ``ds_T @ q`` are plain products.  dq works in score
+# space proper (``ds @ k`` is then plain too) and turns its two rows of
+# statistics into columns once a grid step.
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
-                   acc_ref, *, block_q, block_k, scale, causal):
-    """Grid: (b, hq, nq, nk); k inner — dq accumulates across k blocks."""
-    qi = pl.program_id(2)
-    kj = pl.program_id(3)
-    nk = pl.num_programs(3)
+_BWD_TILE = 512                # rows of a score tile, either side
+_BWD_SPAN_BYTES = 8 << 20      # the walked side's blocks, double-buffered
 
-    @pl.when(kj == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def _compute():
-        q = q_ref[0, 0]                                 # [bq, d]
-        k = k_ref[0, 0]                                 # [bk, d]
-        v = v_ref[0, 0]
-        g = g_ref[0, 0]
-        lse_row = lse_ref[0, 0, 0]                      # [1, bq]
-        delta_row = delta_ref[0, 0, 0]                  # [1, bq]
+def bwd_tiles(s, d, rep, itemsize):
+    """``(tile, q_span, k_span)`` of the backward, read from the shapes
+    the call sees.  ``tile``: the largest of 512 / 256 / 128 rows that
+    divides ``s`` (a shorter sequence is one tile): at 512 a tile's
+    products are microseconds of MXU against a loop step's overhead, and
+    its float32 ``[tile, tile]`` intermediates (1 MiB each) still leave
+    the blocks room inside Mosaic's 16 MiB.  A span is as many whole tiles
+    of the walked side as ``_BWD_SPAN_BYTES`` holds double-buffered — q
+    and g of the ``rep`` heads of a group for dk/dv, k and v for dq: a
+    span that is the whole sequence is copied once a head, a shorter one
+    once a grid step."""
+    tile = next((t for t in (_BWD_TILE, 256, 128) if s % t == 0), s)
 
-        s_t = jax.lax.dot_general(
-            k, q, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [bk, bq]
-        if causal:
-            k_pos = kj * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 0)
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 1)
-            s_t = jnp.where(q_pos >= k_pos, s_t, _NEG_INF)
-        p_t = jnp.exp(s_t - lse_row)                    # [bk, bq]
-        dp_t = jax.lax.dot_general(
-            v, g, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)         # [bk, bq]
-        ds_t = p_t * (dp_t - delta_row) * scale
-        # dq[q, d] = sum_k ds_T[k, q] * k[k, d]
-        acc_ref[:] += jax.lax.dot_general(
-            ds_t, k, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def span(row_bytes):
+        n = max(1, min(s // tile, _BWD_SPAN_BYTES // (row_bytes * tile)))
+        while (s // tile) % n:
+            n -= 1
+        return n * tile
 
-    if causal:
-        @pl.when(kj * block_k <= qi * block_q + (block_q - 1))
-        def _():
-            _compute()
-    else:
-        _compute()
+    return (tile, span(2 * 2 * rep * d * itemsize),
+            span(2 * 2 * d * itemsize))
 
-    @pl.when(kj == nk - 1)
-    def _flush():
-        dq_ref[0, 0] = acc_ref[:].astype(dq_ref.dtype)
+
+def _dkv_maps(tile_k, q_span, causal, xp=jnp):
+    """Index maps of the dk/dv kernel's grid ``(b, kv head, k tile,
+    q span)``: ``(walked, own, stat)``.  A causal k tile starts at the
+    span that holds its diagonal; earlier grid steps name that span too,
+    so nothing is copied for them."""
+    def first(j, m):
+        if not causal:
+            return m
+        return xp.maximum(m, (j * tile_k) // q_span)
+    return (lambda b_, g_, j, m: (b_, g_, first(j, m), 0),
+            lambda b_, g_, j, m: (b_, g_, j, 0),
+            lambda b_, g_, j, m: (b_, g_, first(j, m), 0, 0))
+
+
+def _dq_maps(rep, tile_q, k_span, causal, xp=jnp):
+    """Index maps of the dq kernel's grid ``(b, q head, q tile, k
+    span)``: ``(own, walked, stat)``.  A causal q tile ends at the span
+    that holds its diagonal; later grid steps name that span too."""
+    def last(i, m):
+        if not causal:
+            return m
+        return xp.minimum(m, ((i + 1) * tile_q - 1) // k_span)
+    return (lambda b_, h, i, m: (b_, h, i, 0),
+            lambda b_, h, i, m: (b_, h // rep, last(i, m), 0),
+            lambda b_, h, i, m: (b_, h, i, 0, 0))
+
+
+def _walk(lo, hi, step):
+    """``step(i)`` for ``i`` in ``[lo, hi)``: a loop whose bounds are
+    run-time scalars and whose state lives in refs."""
+    def body(i, carry):
+        step(i)
+        return carry
+    jax.lax.fori_loop(lo, hi, body, 0)
+
+
+def _positions(rows, cols, row0, col0):
+    """``(row position, column position)`` of a ``[rows, cols]`` tile
+    whose corner sits at ``(row0, col0)``."""
+    shape = (rows, cols)
+    return (row0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0),
+            col0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *, block_q, block_k,
-                    nq, rep, scale, causal):
-    """Grid: (b, hk, nk, rep*nq); inner axis walks every (group head,
-    q block) pair — dk/dv accumulate over the whole query group, so
-    repeated KV heads are never materialized (GQA)."""
-    kj = pl.program_id(2)
-    t = pl.program_id(3)
-    nt = pl.num_programs(3)
-    qi = t % nq
+                    dk_ref, dv_ref, dk_acc, dv_acc, *, tile_q, tile_k,
+                    q_span, rep, scale, causal):
+    """Grid ``(b, kv head, k tile, q span)``; the q span's tiles of every
+    head of the group are walked here, dk/dv accumulating over the whole
+    query group, so repeated KV heads are never materialized (GQA)."""
+    j = pl.program_id(2)
+    m = pl.program_id(3)
+    n_t = q_span // tile_q
+    k0 = j * tile_k
 
-    @pl.when(t == 0)
+    @pl.when(m == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def _compute():
-        q = q_ref[0, 0]                                 # [bq, d]
-        k = k_ref[0, 0]                                 # [bk, d]
-        v = v_ref[0, 0]
-        g = g_ref[0, 0]
-        lse_row = lse_ref[0, 0, 0]                      # [1, bq]
-        delta_row = delta_ref[0, 0, 0]
+    k = k_ref[0, 0]                                     # [tk, d]
+    v = v_ref[0, 0]
 
+    def tile(r, t, masked):
+        rows = pl.ds(pl.multiple_of(t * tile_q, tile_q), tile_q)
+        q = q_ref[0, r, rows, :]                        # [tq, d]
+        g = g_ref[0, r, rows, :]
         s_t = jax.lax.dot_general(
             k, q, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [bk, bq]
-        if causal:
-            k_pos = kj * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 0)
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 1)
+            preferred_element_type=jnp.float32) * scale  # [tk, tq]
+        if masked:
+            k_pos, q_pos = _positions(tile_k, tile_q, k0,
+                                      m * q_span + t * tile_q)
             s_t = jnp.where(q_pos >= k_pos, s_t, _NEG_INF)
-        p_t = jnp.exp(s_t - lse_row)
+        p_t = jnp.exp(s_t - lse_ref[0, r, t])           # rows [1, tq]
         # dv[k, d] = sum_q p_T[k, q] * g[q, d]
         dv_acc[:] += jax.lax.dot_general(
-            p_t.astype(jnp.float32), g.astype(jnp.float32),
-            (((1,), (0,)), ((), ())),
+            p_t.astype(g.dtype), g, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp_t = jax.lax.dot_general(
             v, g, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds_t = p_t * (dp_t - delta_row) * scale
-        # dk[k, d] = sum_q ds_T[k, q] * q[q, d]
+            preferred_element_type=jnp.float32)         # [tk, tq]
+        ds_t = p_t * (dp_t - delta_ref[0, r, t])
+        # dk[k, d] = scale * sum_q ds_T[k, q] * q[q, d]
         dk_acc[:] += jax.lax.dot_general(
-            ds_t, q.astype(jnp.float32), (((1,), (0,)), ((), ())),
+            ds_t.astype(q.dtype), q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     if causal:
-        @pl.when(kj * block_k <= qi * block_q + (block_q - 1))
-        def _():
-            _compute()
+        # first tile the diagonal reaches; first tile wholly below it
+        t0 = m * n_t
+        lo = jnp.clip(k0 // tile_q - t0, 0, n_t)
+        full = jnp.clip((k0 + tile_k + tile_q - 2) // tile_q - t0, lo, n_t)
     else:
-        _compute()
+        lo = full = 0
 
-    @pl.when(t == nt - 1)
+    def head(r):
+        if causal:
+            _walk(lo, full, lambda t: tile(r, t, True))
+        _walk(full, n_t, lambda t: tile(r, t, False))
+
+    _walk(0, rep, head)
+
+    @pl.when(m == pl.num_programs(3) - 1)
     def _flush():
-        dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
+        dk_ref[0, 0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _bwd_pallas(res, g, *, scale, causal, block_q, block_k, interpret):
-    """Pallas flash backward: dq from one kernel (k inner), dk/dv from a
-    second (query-group inner).  lse/delta ride as [b,hq,nq,1,bq] so each
-    q block's statistics arrive as a [1, bq] row vector."""
-    q, k, v, out, lse = res      # q,out [b,hq,s,d]; k,v [b,hk,s,d]
+def _column(row_ref, tile):
+    """A ``[1, tile]`` row of statistics as a ``[tile, 1]`` column."""
+    return jnp.transpose(jnp.broadcast_to(row_ref[0, 0, 0],
+                                          (128, tile)))[:, :1]
+
+
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
+                   acc_ref, *, tile_q, tile_k, k_span, scale, causal):
+    """Grid ``(b, q head, q tile, k span)``; the k span's tiles are
+    walked here and dq accumulates across them and across spans."""
+    i = pl.program_id(2)
+    m = pl.program_id(3)
+    n_u = k_span // tile_k
+    q0 = i * tile_q
+
+    @pl.when(m == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    q = q_ref[0, 0]                                     # [tq, d]
+    g = g_ref[0, 0]
+    lse = _column(lse_ref, tile_q)                      # [tq, 1]
+    delta = _column(delta_ref, tile_q)
+
+    def tile(u, masked):
+        rows = pl.ds(pl.multiple_of(u * tile_k, tile_k), tile_k)
+        k = k_ref[0, 0, rows, :]                        # [tk, d]
+        v = v_ref[0, 0, rows, :]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [tq, tk]
+        if masked:
+            q_pos, k_pos = _positions(tile_q, tile_k, q0,
+                                      m * k_span + u * tile_k)
+            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+        p = jnp.exp(s - lse)
+        dp = jax.lax.dot_general(
+            g, v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)         # [tq, tk]
+        ds = p * (dp - delta)
+        # dq[q, d] = scale * sum_k ds[q, k] * k[k, d]
+        acc_ref[:] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    if causal:
+        # tiles wholly below the diagonal, then those it crosses
+        u0 = m * n_u
+        full = jnp.clip((q0 + 1) // tile_k - u0, 0, n_u)
+        hi = jnp.clip((q0 + tile_q - 1) // tile_k + 1 - u0, full, n_u)
+    else:
+        full = hi = n_u
+
+    _walk(0, full, lambda u: tile(u, False))
+    if causal:
+        _walk(full, hi, lambda u: tile(u, True))
+
+    @pl.when(m == pl.num_programs(3) - 1)
+    def _flush():
+        dq_ref[0, 0] = (acc_ref[:] * scale).astype(dq_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "causal", "tile_q", "tile_k", "q_span", "k_span", "interpret"))
+def _bwd_pallas(q, k, v, out, lse, g, *, scale, causal, interpret,
+                tile_q=None, tile_k=None, q_span=None, k_span=None):
+    """(dq, dk, dv) from the two kernels.  q, out, g: [b, hq, s, d]; k, v:
+    [b, hk, s, d]; lse: [b, hq, s].  Tiles and spans come from
+    :func:`bwd_tiles` unless a test names them.  Behind ONE jit: a step
+    whose layers call it at identical shapes traces it once and lowers one
+    body of each kernel that the layers share."""
     b, hq, s, d = q.shape
     hk = k.shape[1]
     rep = hq // hk
-    nq = pl.cdiv(s, block_q)
-    nk = pl.cdiv(s, block_k)
+    tile, qs, ks = bwd_tiles(s, d, rep, q.dtype.itemsize)
+    tile_q, tile_k = tile_q or tile, tile_k or tile
+    q_span, k_span = q_span or max(qs, tile_q), k_span or max(ks, tile_k)
 
+    # delta_i = sum_d(dO * O) — rowwise (flash-attn-2 eq. 4); it and lse
+    # ride as [b, hq, s / tile_q, 1, tile_q]: a q tile's statistics are a
+    # lane-dense row
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)                              # [b, hq, s]
-    lse5 = lse.reshape(b, hq, nq, 1, block_q)
-    delta5 = delta.reshape(b, hq, nq, 1, block_q)
+                    axis=-1)
+    stat5 = (b, hq, s // tile_q, 1, tile_q)
+    lse5, delta5 = lse.reshape(stat5), delta.reshape(stat5)
 
     params = {}
     if not interpret:
@@ -326,68 +415,50 @@ def _bwd_pallas(res, g, *, scale, causal, block_q, block_k, interpret):
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"))
 
+    own, walked, stat = _dq_maps(rep, tile_q, k_span, causal)
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, block_q=block_q, block_k=block_k,
-                          scale=scale, causal=causal),
-        grid=(b, hq, nq, nk),
+        functools.partial(_bwd_dq_kernel, tile_q=tile_q, tile_k=tile_k,
+                          k_span=k_span, scale=scale, causal=causal),
+        grid=(b, hq, s // tile_q, s // k_span),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda b_, h, i, j: (b_, h, i, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, h, i, j: (b_, h // rep, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, h, i, j: (b_, h // rep, j, 0)),
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda b_, h, i, j: (b_, h, i, 0)),
-            pl.BlockSpec((1, 1, 1, 1, block_q),
-                         lambda b_, h, i, j: (b_, h, i, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 1, block_q),
-                         lambda b_, h, i, j: (b_, h, i, 0, 0)),
+            pl.BlockSpec((1, 1, tile_q, d), own),
+            pl.BlockSpec((1, 1, k_span, d), walked),
+            pl.BlockSpec((1, 1, k_span, d), walked),
+            pl.BlockSpec((1, 1, tile_q, d), own),
+            pl.BlockSpec((1, 1, 1, 1, tile_q), stat),
+            pl.BlockSpec((1, 1, 1, 1, tile_q), stat),
         ],
-        out_specs=pl.BlockSpec((1, 1, block_q, d),
-                               lambda b_, h, i, j: (b_, h, i, 0)),
+        out_specs=pl.BlockSpec((1, 1, tile_q, d), own),
         out_shape=_out_struct((b, hq, s, d), q.dtype, q, k, v, g),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((tile_q, d), jnp.float32)],
         name="flash_bwd_dq",
         interpret=interpret,
         **params,
     )(q, k, v, g, lse5, delta5)
 
+    walked, own, stat = _dkv_maps(tile_k, q_span, causal)
+    n_t = q_span // tile_q
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, block_q=block_q,
-                          block_k=block_k, nq=nq, rep=rep, scale=scale,
+        functools.partial(_bwd_dkv_kernel, tile_q=tile_q, tile_k=tile_k,
+                          q_span=q_span, rep=rep, scale=scale,
                           causal=causal),
-        grid=(b, hk, nk, rep * nq),
+        grid=(b, hk, s // tile_k, s // q_span),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda b_, g_, j, t: (b_, g_ * rep + t // nq,
-                                               t % nq, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, g_, j, t: (b_, g_, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, g_, j, t: (b_, g_, j, 0)),
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda b_, g_, j, t: (b_, g_ * rep + t // nq,
-                                               t % nq, 0)),
-            pl.BlockSpec((1, 1, 1, 1, block_q),
-                         lambda b_, g_, j, t: (b_, g_ * rep + t // nq,
-                                               t % nq, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 1, block_q),
-                         lambda b_, g_, j, t: (b_, g_ * rep + t // nq,
-                                               t % nq, 0, 0)),
+            pl.BlockSpec((1, rep, q_span, d), walked),
+            pl.BlockSpec((1, 1, tile_k, d), own),
+            pl.BlockSpec((1, 1, tile_k, d), own),
+            pl.BlockSpec((1, rep, q_span, d), walked),
+            pl.BlockSpec((1, rep, n_t, 1, tile_q), stat),
+            pl.BlockSpec((1, rep, n_t, 1, tile_q), stat),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, g_, j, t: (b_, g_, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, g_, j, t: (b_, g_, j, 0)),
-        ],
+        out_specs=[pl.BlockSpec((1, 1, tile_k, d), own),
+                   pl.BlockSpec((1, 1, tile_k, d), own)],
         out_shape=[
             _out_struct((b, hk, s, d), k.dtype, q, k, v, g),
             _out_struct((b, hk, s, d), v.dtype, q, k, v, g),
         ],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((tile_k, d), jnp.float32),
+                        pltpu.VMEM((tile_k, d), jnp.float32)],
         name="flash_bwd_dkv",
         interpret=interpret,
         **params,
@@ -395,86 +466,23 @@ def _bwd_pallas(res, g, *, scale, causal, block_q, block_k, interpret):
     return dq, dk, dv
 
 
-# -- backward: blockwise recompute in JAX (flash-attn-2 equations) -----------
-
-def _bwd_blockwise(res, g, *, scale, causal, block_k):
-    """Memory-efficient backward: scan over K/V blocks; recompute P from
-    q,k and the saved logsumexp.  Grouped-GQA einsums keep KV at hk heads;
-    dK/dV sum over the query group (r axis) inside the contraction.  All
-    matmuls MXU-shaped; XLA fuses the elementwise chain."""
-    q, k, v, out, lse = res      # q,out [b,hq,s,d]; k,v [b,hk,s,d]
-    b, hq, s, d = q.shape
-    hk = k.shape[1]
-    rep = hq // hk
-    g = g.astype(jnp.float32)
-    qf = q.astype(jnp.float32).reshape(b, hk, rep, s, d)
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
-    of = out.astype(jnp.float32)
-    gg = g.reshape(b, hk, rep, s, d)
-    lse_g = lse.reshape(b, hk, rep, s)
-
-    # delta_i = sum_d(dO * O) — rowwise (flash-attn-2 eq. 4)
-    delta = jnp.sum(g * of, axis=-1).reshape(b, hk, rep, s)
-
-    nk = s // block_k
-    kb = kf.reshape(b, hk, nk, block_k, d)
-    vb = vf.reshape(b, hk, nk, block_k, d)
-
-    q_pos = jnp.arange(s)
-
-    def one_block(j):
-        kj = kb[:, :, j]                               # [b, hk, bk, d]
-        vj = vb[:, :, j]
-        sij = jnp.einsum("bgrqd,bgkd->bgrqk", qf, kj) * scale
-        if causal:
-            k_pos = j * block_k + jnp.arange(block_k)
-            mask = q_pos[:, None] >= k_pos[None, :]
-            sij = jnp.where(mask[None, None, None], sij, _NEG_INF)
-        pij = jnp.exp(sij - lse_g[..., None])          # [b,g,r,q,bk]
-        dv_j = jnp.einsum("bgrqk,bgrqd->bgkd", pij, gg)
-        dp = jnp.einsum("bgrqd,bgkd->bgrqk", gg, vj)
-        ds = pij * (dp - delta[..., None]) * scale
-        dq_contrib = jnp.einsum("bgrqk,bgkd->bgrqd", ds, kj)
-        dk_j = jnp.einsum("bgrqk,bgrqd->bgkd", ds, qf)
-        return dq_contrib, dk_j, dv_j
-
-    def scan_body(dq_acc, j):
-        dq_c, dk_j, dv_j = one_block(j)
-        return dq_acc + dq_c, (dk_j, dv_j)
-
-    dq, (dks, dvs) = jax.lax.scan(scan_body, jnp.zeros_like(qf),
-                                  jnp.arange(nk))
-    dq = dq.reshape(b, hq, s, d)
-    dk = jnp.moveaxis(dks, 0, 2).reshape(b, hk, s, d)
-    dv = jnp.moveaxis(dvs, 0, 2).reshape(b, hk, s, d)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash_core(q, k, v, scale, causal, block_q, block_k, interpret,
-                pallas_bwd):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_core(q, k, v, scale, causal, block_q, block_k, interpret):
     out, _ = _flash_fwd(q, k, v, scale, causal, block_q, block_k,
-                        interpret, pallas_bwd)
+                        interpret)
     return out
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
-               pallas_bwd):
+def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
     out, lse = _fwd_pallas(q, k, v, scale=scale, causal=causal,
                            block_q=block_q, block_k=block_k,
                            interpret=interpret)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(scale, causal, block_q, block_k, interpret, pallas_bwd,
-               res, g):
-    if pallas_bwd:
-        return _bwd_pallas(res, g, scale=scale, causal=causal,
-                           block_q=block_q, block_k=block_k,
-                           interpret=interpret)
-    return _bwd_blockwise(res, g, scale=scale, causal=causal,
-                          block_k=block_k)
+def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
+    return _bwd_pallas(*res, g, scale=scale, causal=causal,
+                       interpret=interpret)
 
 
 _flash_core.defvjp(_flash_fwd, _flash_bwd)
@@ -482,19 +490,16 @@ _flash_core.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention(q, k, v, causal: bool = False, scale=None,
                     block_q: int = None, block_k: int = None,
-                    interpret: bool = None, pallas_bwd: bool = None,
-                    autotune: bool = None):
+                    interpret: bool = None, autotune: bool = None):
     """q: [batch, seq, heads, head_dim]; k,v: [batch, seq, kv_heads,
     head_dim] (paddle layout).  Requires seq divisible by the block sizes
     (callers pad; the model stack keeps seq a multiple of 128 for MXU
     efficiency anyway) and heads % kv_heads == 0.
 
-    block_q/block_k — and the backward implementation, when
-    ``pallas_bwd`` is left None — default to the autotuner's cached
-    choice on TPU (measured once per shape, persisted — reference analog:
-    phi/kernels/autotune/auto_tune_base.h); elsewhere min(128, s) blocks
-    and the Pallas backward.  ``pallas_bwd=False`` forces the
-    blockwise-jax backward, True the Pallas dq/dkv kernels."""
+    block_q/block_k are the FORWARD's blocks and default to the
+    autotuner's cached choice on TPU (measured once per shape, persisted
+    — reference analog: phi/kernels/autotune/auto_tune_base.h); elsewhere
+    min(128, s).  The backward's tiles follow :func:`bwd_tiles`."""
     b, s, h, d = q.shape
     hk = k.shape[2]
     if h % hk:
@@ -505,46 +510,33 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
         interpret = jax.default_backend() != "tpu"
     if autotune is None:
         autotune = not interpret
-    if pallas_bwd is None:
-        pallas_bwd = flash_bwd_env()
-    if block_q is None or block_k is None or pallas_bwd is None:
+    if block_q is None or block_k is None:
         if autotune and not interpret:
             from paddle_tpu.ops.pallas.autotune import flash_block_sizes
-            bq_t, bk_t, pb_t = flash_block_sizes(
-                b, s, h, hk, d, str(q.dtype), bool(causal),
-                pallas_bwd=pallas_bwd)
+            bq_t, bk_t = flash_block_sizes(
+                b, s, h, hk, d, str(q.dtype), bool(causal))
             block_q = block_q or bq_t
             block_k = block_k or bk_t
-            if pallas_bwd is None:
-                pallas_bwd = pb_t
         else:
             block_q = block_q or min(128, s)
             block_k = block_k or min(128, s)
-            if pallas_bwd is None:
-                pallas_bwd = True
     block_q = min(block_q, s)
     block_k = min(block_k, s)
     if s % block_q or s % block_k:
         raise ValueError(f"seq {s} must be divisible by block sizes "
                          f"({block_q},{block_k})")
 
-    # trace-time telemetry: which backward this compile will run
-    _bwd_path_counter().labels(
-        path="pallas" if pallas_bwd else "blockwise").inc()
-
     def to_bhsd(x):
         return jnp.swapaxes(x, 1, 2)
 
     out = _flash_core(to_bhsd(q), to_bhsd(k), to_bhsd(v), float(scale),
-                      bool(causal), block_q, block_k, bool(interpret),
-                      bool(pallas_bwd))
+                      bool(causal), block_q, block_k, bool(interpret))
     return jnp.swapaxes(out, 1, 2)
-
 
 # ---------------------------------------------------------------------------
 # static verification (analysis/kernel_verify) — the fwd / bwd-dq /
-# bwd-dkv pallas_calls described as KernelSpecs, same grids and index
-# maps the real calls install.
+# bwd-dkv pallas_calls described as KernelSpecs, same grids and (for the
+# backward, the very same) index maps the real calls install.
 
 
 def _fwd_verify_spec(b, s, h, hk, d, bq, bk, dtype):
@@ -579,73 +571,69 @@ def _fwd_verify_spec(b, s, h, hk, d, bq, bk, dtype):
               f"{dtype}]")
 
 
-def _bwd_dq_verify_spec(b, s, h, hk, d, bq, bk, dtype):
+def _bwd_verify_specs(b, s, h, hk, d, dtype, causal):
+    """The dq and dk/dv launches at the rule's tiles and spans, through
+    the index maps the real calls install."""
+    import numpy as np
+
     from paddle_tpu.analysis import kernel_verify as kv
     rep = h // hk
-    nq, nk = s // bq, s // bk
-    q4, kv4, stat5 = (b, h, s, d), (b, hk, s, d), (b, h, nq, 1, bq)
-    qmap = lambda b_, h_, i, j: (b_, h_, i, 0)
-    kmap = lambda b_, h_, i, j: (b_, h_ // rep, j, 0)
-    smap = lambda b_, h_, i, j: (b_, h_, i, 0, 0)
-    return kv.KernelSpec(
-        name="flash_bwd_dq", grid=(b, h, nq, nk),
+    tile, q_span, k_span = bwd_tiles(s, d, rep, kv.itemsize(dtype))
+    n_t = q_span // tile
+    q4, kv4, stat5 = (b, h, s, d), (b, hk, s, d), (b, h, s // tile, 1, tile)
+    sem = ("parallel", "parallel", "parallel", "arbitrary")
+    where = (f"[b={b} s={s} h={h}/{hk} d={d} tile={tile} "
+             f"spans={q_span}/{k_span} {dtype}]")
+
+    own, walked, stat = _dq_maps(rep, tile, k_span, causal, xp=np)
+    dq = kv.KernelSpec(
+        name="flash_bwd_dq", grid=(b, h, s // tile, s // k_span),
         args=[
-            kv.ArgSpec("q", q4, (1, 1, bq, d), qmap, dtype),
-            kv.ArgSpec("k", kv4, (1, 1, bk, d), kmap, dtype),
-            kv.ArgSpec("v", kv4, (1, 1, bk, d), kmap, dtype),
-            kv.ArgSpec("g", q4, (1, 1, bq, d), qmap, dtype),
-            kv.ArgSpec("lse", stat5, (1, 1, 1, 1, bq), smap, "float32"),
-            kv.ArgSpec("delta", stat5, (1, 1, 1, 1, bq), smap, "float32"),
-            kv.ArgSpec("dq", q4, (1, 1, bq, d), qmap, dtype,
+            kv.ArgSpec("q", q4, (1, 1, tile, d), own, dtype),
+            kv.ArgSpec("k", kv4, (1, 1, k_span, d), walked, dtype),
+            kv.ArgSpec("v", kv4, (1, 1, k_span, d), walked, dtype),
+            kv.ArgSpec("g", q4, (1, 1, tile, d), own, dtype),
+            kv.ArgSpec("lse", stat5, (1, 1, 1, 1, tile), stat, "float32"),
+            kv.ArgSpec("delta", stat5, (1, 1, 1, 1, tile), stat, "float32"),
+            kv.ArgSpec("dq", q4, (1, 1, tile, d), own, dtype,
                        is_output=True),
         ],
-        scratch=[kv.ScratchSpec("acc", (bq, d), "float32")],
-        dimension_semantics=("parallel", "parallel", "parallel",
-                             "arbitrary"),
-        needs_fp32_acc=True,
-        where=f"flash_bwd_dq[b={b} s={s} h={h}/{hk} d={d} bq={bq} "
-              f"bk={bk} {dtype}]")
+        scratch=[kv.ScratchSpec("acc", (tile, d), "float32")],
+        dimension_semantics=sem, needs_fp32_acc=True,
+        where="flash_bwd_dq" + where)
 
-
-def _bwd_dkv_verify_spec(b, s, h, hk, d, bq, bk, dtype):
-    from paddle_tpu.analysis import kernel_verify as kv
-    rep = h // hk
-    nq, nk = s // bq, s // bk
-    q4, kv4, stat5 = (b, h, s, d), (b, hk, s, d), (b, h, nq, 1, bq)
-    qmap = lambda b_, g_, j, t: (b_, g_ * rep + t // nq, t % nq, 0)
-    kmap = lambda b_, g_, j, t: (b_, g_, j, 0)
-    smap = lambda b_, g_, j, t: (b_, g_ * rep + t // nq, t % nq, 0, 0)
-    return kv.KernelSpec(
-        name="flash_bwd_dkv", grid=(b, hk, nk, rep * nq),
+    walked, own, stat = _dkv_maps(tile, q_span, causal, xp=np)
+    dkv = kv.KernelSpec(
+        name="flash_bwd_dkv", grid=(b, hk, s // tile, s // q_span),
         args=[
-            kv.ArgSpec("q", q4, (1, 1, bq, d), qmap, dtype),
-            kv.ArgSpec("k", kv4, (1, 1, bk, d), kmap, dtype),
-            kv.ArgSpec("v", kv4, (1, 1, bk, d), kmap, dtype),
-            kv.ArgSpec("g", q4, (1, 1, bq, d), qmap, dtype),
-            kv.ArgSpec("lse", stat5, (1, 1, 1, 1, bq), smap, "float32"),
-            kv.ArgSpec("delta", stat5, (1, 1, 1, 1, bq), smap, "float32"),
-            kv.ArgSpec("dk", kv4, (1, 1, bk, d), kmap, dtype,
+            kv.ArgSpec("q", q4, (1, rep, q_span, d), walked, dtype),
+            kv.ArgSpec("k", kv4, (1, 1, tile, d), own, dtype),
+            kv.ArgSpec("v", kv4, (1, 1, tile, d), own, dtype),
+            kv.ArgSpec("g", q4, (1, rep, q_span, d), walked, dtype),
+            kv.ArgSpec("lse", stat5, (1, rep, n_t, 1, tile), stat,
+                       "float32"),
+            kv.ArgSpec("delta", stat5, (1, rep, n_t, 1, tile), stat,
+                       "float32"),
+            kv.ArgSpec("dk", kv4, (1, 1, tile, d), own, dtype,
                        is_output=True),
-            kv.ArgSpec("dv", kv4, (1, 1, bk, d), kmap, dtype,
+            kv.ArgSpec("dv", kv4, (1, 1, tile, d), own, dtype,
                        is_output=True),
         ],
-        scratch=[kv.ScratchSpec("dk_acc", (bk, d), "float32"),
-                 kv.ScratchSpec("dv_acc", (bk, d), "float32")],
-        dimension_semantics=("parallel", "parallel", "parallel",
-                             "arbitrary"),
-        needs_fp32_acc=True,
-        where=f"flash_bwd_dkv[b={b} s={s} h={h}/{hk} d={d} bq={bq} "
-              f"bk={bk} {dtype}]")
+        scratch=[kv.ScratchSpec("dk_acc", (tile, d), "float32"),
+                 kv.ScratchSpec("dv_acc", (tile, d), "float32")],
+        dimension_semantics=sem, needs_fp32_acc=True,
+        where="flash_bwd_dkv" + where)
+    return dq, dkv
 
 
 def verify_static(b, s, h, hk, d, dtype="bfloat16", causal=True,
                   block_q=None, block_k=None, parts=("fwd", "bwd")):
     """Static Mosaic-legality findings for the flash kernels at this
-    shape/config.  ``parts`` selects fwd and/or the two Pallas backward
-    kernels; defaults mirror :func:`flash_attention`'s non-autotuned
-    block choice (min(128, s))."""
+    shape/config.  ``parts`` selects the forward (at ``block_q`` /
+    ``block_k``, defaulting like :func:`flash_attention`'s non-autotuned
+    choice, min(128, s)) and/or the two backward kernels (at the tiles
+    :func:`bwd_tiles` gives this shape)."""
     from paddle_tpu.analysis import kernel_verify as kv
-    del causal  # masking happens in-kernel; the layout is causal-agnostic
     dtype = str(dtype)
     bq = min(int(block_q or min(128, s)), s)
     bk = min(int(block_k or min(128, s)), s)
@@ -654,8 +642,6 @@ def verify_static(b, s, h, hk, d, dtype="bfloat16", causal=True,
         diags += kv.verify_kernel(_fwd_verify_spec(b, s, h, hk, d, bq, bk,
                                                    dtype))
     if "bwd" in parts:
-        diags += kv.verify_kernel(_bwd_dq_verify_spec(b, s, h, hk, d, bq,
-                                                      bk, dtype))
-        diags += kv.verify_kernel(_bwd_dkv_verify_spec(b, s, h, hk, d, bq,
-                                                       bk, dtype))
+        for spec in _bwd_verify_specs(b, s, h, hk, d, dtype, causal):
+            diags += kv.verify_kernel(spec)
     return diags

@@ -24,8 +24,8 @@ grouped FFN as ONE ``pallas_call``:
   in every dispatch path, so MoE outputs are unchanged), which makes
   the kernel's semantics block-size independent and gives the jnp
   reference an exact contract to oracle against;
-* custom VJP: backward is the plain-JAX masked einsum chain (the
-  ``_bwd_blockwise`` idiom), with a ``float0`` cotangent for counts.
+* custom VJP: backward is the plain-JAX masked einsum chain, left to
+  XLA, with a ``float0`` cotangent for counts.
 
 Routing is trace-time and OFF by default: ``PADDLE_TPU_GROUPED_MOE=1``
 flips ``_expert_ffn`` to this kernel (interpret mode off-TPU); unset or
@@ -227,9 +227,9 @@ def _grouped_fwd(x, w1, b1, w2, b2, counts, act, block_c, block_f,
 
 
 def _grouped_bwd(act, block_c, block_f, interpret, res, dy):
-    # recompute the masked einsum chain in plain JAX (the flash
-    # _bwd_blockwise idiom): rows past counts carry zero cotangent and
-    # zero input, so padded slots contribute nothing to any grad
+    # recompute the masked einsum chain in plain JAX: rows past counts
+    # carry zero cotangent and zero input, so padded slots contribute
+    # nothing to any grad
     x, w1, b1, w2, b2, counts = res
     G, C, d = x.shape
     E = w1.shape[0]

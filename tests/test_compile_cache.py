@@ -407,7 +407,7 @@ class TestBundle:
                            str(tmp_path / "at.json"))
         monkeypatch.setenv("PADDLE_TPU_AUTOTUNE_SEED", "0")
         at.reload()
-        at._put("flash", "bundle-test-key@cpu-interpret", (128, 128, True))
+        at._put("flash", "bundle-test-key@cpu-interpret", (128, 128))
         at._save()
 
         f = jax.jit(lambda x: x * 2)

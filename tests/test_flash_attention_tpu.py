@@ -71,22 +71,56 @@ def _sumsq(*xs):
     return sum(jnp.sum(x.astype(jnp.float32) ** 2) for x in xs)
 
 
-@pytest.mark.parametrize("pallas_bwd", [False, True],
-                         ids=["blockwise_bwd", "pallas_bwd"])
 @pytest.mark.parametrize("widths", sorted(WIDTHS))
-def test_flash_fwd_bwd_compiles(one_chip, widths, pallas_bwd):
+def test_flash_fwd_bwd_compiles(one_chip, widths):
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
     _, _, _, h, hk, d = WIDTHS[widths]
 
     def step(q, k, v):
         loss = lambda q, k, v: _sumsq(flash_attention(
-            q, k, v, causal=True, pallas_bwd=pallas_bwd, autotune=False,
-            interpret=False))
+            q, k, v, causal=True, autotune=False, interpret=False))
         return jax.value_and_grad(loss, (0, 1, 2))(q, k, v)
 
     S = lambda heads: jax.ShapeDtypeStruct((BATCH, SEQ, heads, d), BF16,
                                            sharding=one_chip)
     _compile(step, S(h), S(hk), S(hk))
+
+
+# (batch, seq, q heads, kv heads, dtype, causal): the backward's two
+# kernels at the shapes that reach them — train-1chip's own
+# (internlm2: 16Q/8KV) and mistral's 32Q/8KV at the cell's b4·s4096, MHA,
+# the encoder families' non-causal call, float32, a sequence of one tile
+BWD_SHAPES = {
+    "train_1chip_16q8kv": (4, 4096, 16, 8, BF16, True),
+    "mistral_32q8kv": (4, 4096, 32, 8, BF16, True),
+    "mha_16q16kv": (4, 2048, 16, 16, BF16, True),
+    "non_causal": (4, 2048, 16, 8, BF16, False),
+    "float32": (2, 4096, 16, 8, jnp.float32, True),
+    "one_tile_s384": (8, 384, 8, 4, BF16, True),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(BWD_SHAPES))
+def test_flash_backward_kernels_compile(one_chip, shape):
+    """The whole backward is the two kernels: both are in the program by
+    name, and no float32 score tensor ([..., s, tile]) lives outside
+    them (the compiled backward holds no temporary of that size)."""
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    b, s, h, hk, dt, causal = BWD_SHAPES[shape]
+    d = 128
+
+    def step(q, k, v):
+        loss = lambda q, k, v: _sumsq(flash_attention(
+            q, k, v, causal=causal, autotune=False, interpret=False))
+        return jax.grad(loss, (0, 1, 2))(q, k, v)
+
+    S = lambda heads: jax.ShapeDtypeStruct((b, s, heads, d), dt,
+                                           sharding=one_chip)
+    compiled = _compile(step, S(h), S(hk), S(hk))
+    text = compiled.as_text()
+    assert "flash_bwd_dq" in text and "flash_bwd_dkv" in text
+    score_bytes = b * h * s * 128 * 4        # one [b, h, s, 128] float32
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * score_bytes
 
 
 @pytest.mark.parametrize("widths", sorted(WIDTHS))
@@ -245,8 +279,7 @@ def test_flash_meets_the_four_chip_mesh_under_shard_map(topo, monkeypatch):
     S = lambda heads: jax.ShapeDtypeStruct((BATCH, SEQ, heads, d), BF16,
                                            sharding=sh)
     attn = lambda q, k, v: flash_attention(
-        q, k, v, causal=True, pallas_bwd=False, autotune=False,
-        interpret=False)
+        q, k, v, causal=True, autotune=False, interpret=False)
 
     def step(q, k, v):
         def loss(q, k, v):

@@ -170,9 +170,8 @@ class TestFusedCrossEntropyRouting:
 # ---------------------------------------------------------------------------
 
 class TestFlashBackwardVsNaive:
-    @pytest.mark.parametrize("pallas_bwd", [True, False])
     @pytest.mark.parametrize("causal", [True, False])
-    def test_grads_match_naive_attention(self, pallas_bwd, causal):
+    def test_grads_match_naive_attention(self, causal):
         from paddle_tpu.ops.pallas.flash_attention import flash_attention
         from paddle_tpu.nn.functional.attention import _sdpa_reference
         rng = np.random.default_rng(4)
@@ -182,8 +181,7 @@ class TestFlashBackwardVsNaive:
         v = jnp.asarray(rng.standard_normal((b, s, hk, d)), jnp.float32)
 
         def loss_flash(*a):
-            return (flash_attention(*a, causal=causal,
-                                    pallas_bwd=pallas_bwd)
+            return (flash_attention(*a, causal=causal)
                     .astype(jnp.float32) ** 2).mean()
 
         def loss_ref(*a):
@@ -194,19 +192,6 @@ class TestFlashBackwardVsNaive:
         gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
         for a, b_ in zip(gf, gr):
             assert float(jnp.abs(a - b_).max()) < 1e-4
-
-    def test_flash_bwd_env_knob(self, monkeypatch):
-        from paddle_tpu.ops.pallas.flash_attention import flash_bwd_env
-        monkeypatch.delenv("PADDLE_TPU_FLASH_BWD", raising=False)
-        monkeypatch.delenv("PT_FLASH_PALLAS_BWD", raising=False)
-        assert flash_bwd_env() is None
-        monkeypatch.setenv("PADDLE_TPU_FLASH_BWD", "1")
-        assert flash_bwd_env() is True
-        monkeypatch.setenv("PADDLE_TPU_FLASH_BWD", "0")
-        assert flash_bwd_env() is False
-        monkeypatch.delenv("PADDLE_TPU_FLASH_BWD")
-        monkeypatch.setenv("PT_FLASH_PALLAS_BWD", "yes")  # legacy alias
-        assert flash_bwd_env() is True
 
 
 # ---------------------------------------------------------------------------

@@ -133,21 +133,6 @@ class TestPallasFlashAttention:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-4, atol=1e-5)
 
-    def test_pallas_bwd_equals_blockwise_bwd(self):
-        from paddle_tpu.ops.pallas.flash_attention import flash_attention
-        q, k, v = make_qkv(s=128, h=4, kv_heads=2, d=64)
-
-        def loss(pb):
-            return lambda q, k, v: (flash_attention(
-                q, k, v, causal=True, interpret=True,
-                pallas_bwd=pb) ** 2).sum()
-
-        gp = jax.grad(loss(True), argnums=(0, 1, 2))(q, k, v)
-        gb = jax.grad(loss(False), argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(gp, gb):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-5, atol=1e-6)
-
 
 class TestFusedRMSNorm:
     def _ref(self, x, w, res, eps=1e-5):
@@ -283,8 +268,8 @@ class TestAutotuneCache:
     def test_flash_candidates_respect_vmem(self):
         from paddle_tpu.ops.pallas.autotune import _flash_candidates
         cands = _flash_candidates(8192, 128, "bfloat16")
-        assert (128, 128, True) in cands and (128, 128, False) in cands
-        assert all(bq * bk * 4 < 10 * (1 << 20) for bq, bk, _ in cands)
+        assert (128, 128) in cands and (512, 512) in cands
+        assert all(bq * bk * 4 < 10 * (1 << 20) for bq, bk in cands)
 
 
 # ---------------------------------------------------------------------------
