@@ -13,11 +13,13 @@ the trajectory the way training's ``--compare`` does.
 
 The workload models the fleet case the paged KV cache exists for: every
 request shares a system-prompt prefix (``--shared-prefix``) and appends
-a short unique suffix, so with ``PADDLE_TPU_PAGED_KV=1`` the prefix
-prefills once and later requests reuse its blocks (watch
-``prefix_hit_tokens``).  ``--check-equivalence`` replays the workload
-through the slot-contiguous engine and asserts token-for-token greedy
-identity — the paged path must be a pure memory/scheduling optimization.
+a short unique suffix, so the prefix prefills once and later requests
+reuse its blocks (watch ``prefix_hit_tokens``).  ``--check-equivalence``
+replays the workload one request at a time through the plain engine
+(bf16, no prefix cache, no speculation, no quantization — the engine
+tier-1 holds to ``generate()``) and asserts token-for-token greedy
+identity: prefix reuse, speculation and routing must be pure
+memory/scheduling optimizations.
 """
 
 from __future__ import annotations
@@ -53,16 +55,16 @@ def _next_serve_round(here):
     return max(rounds, default=0) + 1
 
 
-def _build_engine(model, args, paged, quant_weights="0", quant_kv="0"):
+def _build_engine(model, args, quant_weights="0", quant_kv="0",
+                  prefix_cache=True):
     from paddle_tpu.inference.serving import ContinuousBatchingEngine
     return ContinuousBatchingEngine(
         model, slots=args.slots, max_len=args.max_len,
         prefill_buckets=(args.max_len // 2,),
         steps_per_sync=args.steps_per_sync if not args.spec else 1,
-        paged_kv=paged,
         kv_block_size=args.block_size,
-        prefill_chunk=args.chunk,
-        spec_decode=args.spec if paged else 0,
+        prefill_chunk=args.chunk, prefix_cache=prefix_cache,
+        spec_decode=args.spec,
         quant_weights=quant_weights, quant_kv=quant_kv)
 
 
@@ -78,7 +80,7 @@ def _build_router(model, args, quant_weights="0", quant_kv="0"):
     from paddle_tpu.inference.router import ServingRouter
     ek = dict(slots=args.slots, max_len=args.max_len,
               prefill_buckets=(args.max_len // 2,),
-              steps_per_sync=1, paged_kv=True,
+              steps_per_sync=1,
               kv_block_size=args.block_size, prefill_chunk=args.chunk,
               quant_weights=quant_weights, quant_kv=quant_kv)
     dk = dict(steps_per_sync=args.decode_sync if not args.spec else 1,
@@ -189,7 +191,7 @@ def _session_drill(model, args, vocab, qw_mode="0", qkv_mode="0"):
     bps = -(-(Lp + max_new) // bs)             # blocks per session
     num_blocks = 1 + slots * bps + 2           # ~slots sessions fit
     kw = dict(slots=slots, max_len=64, prefill_buckets=(32,),
-              paged_kv=True, kv_block_size=bs, prefill_chunk=16,
+              kv_block_size=bs, prefill_chunk=16,
               num_kv_blocks=num_blocks,
               quant_weights=qw_mode, quant_kv=qkv_mode)
     tier = KVTierManager(store=LocalStore())
@@ -325,9 +327,9 @@ def main(argv=None):
     ap.add_argument("--suffix-max", type=int, default=12)
     ap.add_argument("--block-size", type=int, default=8)
     ap.add_argument("--chunk", type=int, default=16,
-                    help="chunked-prefill width (paged mode)")
+                    help="chunked-prefill width")
     ap.add_argument("--spec", type=int, default=0,
-                    help="n-gram speculative draft length (paged only)")
+                    help="n-gram speculative draft length")
     ap.add_argument("--steps-per-sync", type=int, default=1)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--workload", choices=("random", "text"),
@@ -341,7 +343,7 @@ def main(argv=None):
                          "PADDLE_TPU_QUANT_WEIGHTS)")
     ap.add_argument("--quant-kv", default=None, choices=("int8",),
                     help="int8 paged-KV pools (default: "
-                         "PADDLE_TPU_QUANT_KV; forces --paged)")
+                         "PADDLE_TPU_QUANT_KV)")
     ap.add_argument("--parity-floor", type=float, default=0.98,
                     help="--check-equivalence under quantization: "
                          "minimum greedy token-match rate vs the bf16 "
@@ -349,13 +351,10 @@ def main(argv=None):
     ap.add_argument("--logit-tol", type=float, default=0.10,
                     help="max relative logit error vs bf16 the parity "
                          "gate tolerates")
-    ap.add_argument("--paged", dest="paged", action="store_true",
-                    default=None, help="force paged KV on "
-                    "(default: PADDLE_TPU_PAGED_KV)")
-    ap.add_argument("--no-paged", dest="paged", action="store_false")
     ap.add_argument("--check-equivalence", action="store_true",
-                    help="replay through the slot-contiguous engine and "
-                         "assert greedy outputs are identical")
+                    help="replay through the plain engine (bf16, no "
+                         "prefix cache, no speculation) and assert "
+                         "greedy outputs are identical")
     ap.add_argument("--emit", metavar="PATH",
                     help="write the artifact ('auto' → next "
                          "BENCH_serve_rNN.json beside this script)")
@@ -401,7 +400,6 @@ def main(argv=None):
     compile_cache.enable_persistent_cache()
 
     import paddle_tpu as pp
-    from paddle_tpu.inference.kv_cache import paged_kv_enabled
     from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 
     # quant knobs resolve ONCE here, then ride explicitly into every
@@ -410,9 +408,6 @@ def main(argv=None):
     from paddle_tpu.quantization.serving import quant_weights_mode
     qw_mode = quant_weights_mode(args.quant_weights)
     qkv_mode = quant_kv_mode(args.quant_kv)
-    paged = paged_kv_enabled() if args.paged is None else args.paged
-    if qkv_mode:
-        paged = True            # int8 pools are a paged-engine feature
     dev = jax.devices()[0]
     pp.seed(args.seed)
     if dev.platform == "tpu":
@@ -429,9 +424,7 @@ def main(argv=None):
     model = LlamaForCausalLM(cfg)
 
     prompts, arrivals = _workload(args, cfg.vocab_size)
-    if args.fleet:
-        paged = True            # the fleet handoff rides paged blocks
-    eng = _build_engine(model, args, paged,
+    eng = _build_engine(model, args,
                         quant_weights=qw_mode or "0",
                         quant_kv=qkv_mode or "0")
     # explicit AOT warmup outside the timed window: compiles (or, with
@@ -554,7 +547,6 @@ def main(argv=None):
         "generated_tokens": total_tokens,
         "ttft_p50_s": ttft["p50"], "ttft_p99_s": ttft["p99"],
         "tpot_p50_s": tpot["p50"], "tpot_p99_s": tpot["p99"],
-        "paged": bool(paged),
         "spec_decode": args.spec,
         "steps_per_sync": args.steps_per_sync,
         "workload": args.workload,
@@ -614,17 +606,16 @@ def main(argv=None):
             "serve_decode", (args.slots, args.max_len),
             measured_s=float(tpot["p50"]), provenance="bench_serve")
     detail["calibration"] = calibration.bench_detail()
-    if paged:
-        detail["kv_blocks_total"] = eng._num_blocks - 1
-        detail["kv_blocks_peak_used"] = eng._blocks_used_peak
-        detail["kv_block_utilization"] = round(
-            eng._blocks_used_peak / max(1, eng._num_blocks - 1), 4)
-        detail["kv_events"] = {
-            "evictions": _series("paddle_tpu_serving_kv_evictions_total"),
-            "cow": _series("paddle_tpu_serving_kv_cow_copies_total"),
-            "alloc_failures": _series(
-                "paddle_tpu_serving_kv_alloc_failures_total"),
-        }
+    detail["kv_blocks_total"] = eng._num_blocks - 1
+    detail["kv_blocks_peak_used"] = eng._blocks_used_peak
+    detail["kv_block_utilization"] = round(
+        eng._blocks_used_peak / max(1, eng._num_blocks - 1), 4)
+    detail["kv_events"] = {
+        "evictions": _series("paddle_tpu_serving_kv_evictions_total"),
+        "cow": _series("paddle_tpu_serving_kv_cow_copies_total"),
+        "alloc_failures": _series(
+            "paddle_tpu_serving_kv_alloc_failures_total"),
+    }
     if qw_mode or qkv_mode:
         # the quantized-serving capacity/accuracy ledger: blocks ratio
         # is the tentpole's measured capacity claim (int8 pools hold
@@ -635,10 +626,9 @@ def main(argv=None):
         detail["quant"] = {
             "weights": qw_mode,
             "kv": qkv_mode,
-            "kv_blocks_ratio": (round((eng._num_blocks - 1)
-                                      / base_blocks, 4)
-                                if paged else None),
-            "kv_pool_bytes": eng._pool.nbytes if paged else None,
+            "kv_blocks_ratio": round((eng._num_blocks - 1)
+                                     / base_blocks, 4),
+            "kv_pool_bytes": eng._pool.nbytes,
             "quant_paths": _series(
                 "paddle_tpu_quant_kernel_path_total"),
             "token_match_rate": None,
@@ -652,8 +642,9 @@ def main(argv=None):
     }
 
     if args.check_equivalence:
-        # replay sequentially through the slot-contiguous bf16 engine.
-        # Unquantized: paged/routed greedy decode must be token-for-
+        # replay sequentially through the plain bf16 engine (no prefix
+        # cache, no speculation).  Unquantized: prefix-reusing /
+        # speculative / routed greedy decode must be token-for-
         # token IDENTICAL.  Quantized: the accuracy-parity gate — the
         # greedy token-match rate must clear --parity-floor and the
         # weight-quant logit error must stay under --logit-tol, so
@@ -664,7 +655,7 @@ def main(argv=None):
         if serving_eng is not eng:
             eng.close()
         base_eng = _build_engine(model, argparse.Namespace(
-            **{**vars(args), "spec": 0}), paged=False)
+            **{**vars(args), "spec": 0}), prefix_cache=False)
         quant = bool(qw_mode or qkv_mode)
         mismatches = 0
         matched = total = 0
@@ -689,13 +680,13 @@ def main(argv=None):
             if got != ours:
                 mismatches += 1
                 if not quant:
-                    print(f"EQUIVALENCE MISMATCH req {i}: paged="
+                    print(f"EQUIVALENCE MISMATCH req {i}: served="
                           f"{ours} baseline={got}", file=sys.stderr)
         match_rate = matched / total if total else 0.0
         result["detail"]["equivalence"] = {
             "checked": len(rids), "mismatches": mismatches,
             "token_match_rate": round(match_rate, 4)}
-        if paged and args.shared_prefix >= 2 * args.block_size and \
+        if args.shared_prefix >= 2 * args.block_size and \
                 reused_tokens < 1:
             print("EQUIVALENCE: expected >=1 prefix-cache hit on the "
                   "shared-prompt workload, saw none", file=sys.stderr)
@@ -731,7 +722,7 @@ def main(argv=None):
             print(json.dumps(result))
             return 1
         else:
-            print(f"equivalence ok: {len(rids)} requests, paged == "
+            print(f"equivalence ok: {len(rids)} requests, served == "
                   f"baseline, prefix_hit_tokens={reused_tokens}",
                   file=sys.stderr)
 

@@ -4,7 +4,7 @@ One process, through the classes a user imports:
 
   python chip_smoke.py            one TPU chip: *train* (TrainStep + AdamW at
                                   the shape bench.py uses on a chip) and *serve*
-                                  (ContinuousBatchingEngine(paged_kv=True) over
+                                  (ContinuousBatchingEngine over
                                   Llama-3-8B at its published widths, depth cut)
   python chip_smoke.py --chips 4  four chips: the sharded TrainStep (fsdp 2 x
                                   tp 2) at Llama-3-8B widths and the un-sharded
@@ -278,7 +278,7 @@ def train_phase(sz, seed, on_tpu):
 
 
 def serve_phase(sz, seed, on_tpu):
-    """ContinuousBatchingEngine(paged_kv=True): warm-up, more requests
+    """ContinuousBatchingEngine: warm-up, more requests
     than slots, greedy tokens against the plain full-context forward."""
     import jax
     import jax.numpy as jnp
@@ -305,7 +305,7 @@ def serve_phase(sz, seed, on_tpu):
     # the first step exception raises: a compile error must not retire
     # its batch as "error" and let run() return as if it had served
     eng = ContinuousBatchingEngine(
-        model, slots=sz["slots"], max_len=sz["max_len"], paged_kv=True,
+        model, slots=sz["slots"], max_len=sz["max_len"],
         kv_block_size=sz["block"], prefill_buckets=(sz["chunk"],),
         prefill_chunk=sz["chunk"], max_consecutive_errors=1)
     errors0 = series("paddle_tpu_serving_engine_errors_total").get("all", 0)

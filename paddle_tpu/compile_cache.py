@@ -2,8 +2,8 @@
 
 Production fleets restart constantly — elastic drills it, serving
 replicas scale up under load — and every restart used to re-trace and
-re-compile every executable: TrainStep, decode, every prefill bucket /
-chunk, the spec-verify forward.  This module makes compiled XLA
+re-compile every executable: TrainStep, decode, the prefill chunk,
+the spec-verify forward.  This module makes compiled XLA
 executables a *shippable artifact*: ``jax.experimental.
 serialize_executable`` bytes in a content-addressed on-disk cache, so a
 fresh process deserialize-and-loads in milliseconds instead of paying
@@ -42,8 +42,7 @@ needs to go from empty disk to first token without a single XLA
 compile.
 
 Env knobs:
-  PADDLE_TPU_COMPILE_CACHE=1        enable (default off — opt-in, like
-                                    PADDLE_TPU_PAGED_KV)
+  PADDLE_TPU_COMPILE_CACHE=1        enable (default off — opt-in)
   PADDLE_TPU_COMPILE_CACHE_DIR=path cache directory (default
                                     <cache_root()>/executables)
   JAX_COMPILATION_CACHE_DIR=path    places :func:`cache_root` — JAX's own
@@ -94,7 +93,7 @@ _mem: Dict[str, Any] = {}
 def enabled() -> bool:
     """Opt-in: ``PADDLE_TPU_COMPILE_CACHE=1``.  Default off — loading a
     serialized binary is semantically identical to recompiling, but the
-    knob keeps cold-start behaviour explicit, like PADDLE_TPU_PAGED_KV."""
+    knob keeps cold-start behaviour explicit."""
     return os.environ.get("PADDLE_TPU_COMPILE_CACHE", "0") == "1"
 
 

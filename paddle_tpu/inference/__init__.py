@@ -12,9 +12,9 @@ that drives the same artifact through the PJRT C API) for embedding in
 C++ services, matching the reference's C++ serving story.
 
 LLM serving lives in the sibling modules: ``serving.py`` (the
-continuous-batching engine) and ``kv_cache.py`` (the paged KV
-allocator, prefix cache, and paged attention path behind
-``PADDLE_TPU_PAGED_KV``) — see ``inference/README.md``.
+continuous-batching engine) and ``kv_cache.py`` (its paged KV
+allocator, prefix cache, and paged attention path) — see
+``inference/README.md``.
 """
 
 from __future__ import annotations

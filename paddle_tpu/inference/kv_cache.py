@@ -34,10 +34,9 @@ rebuilds the memory path vLLM-style around fixed-size **token blocks**:
   fallback elsewhere.  Numerics match ``static_cache_attention`` exactly:
   the gather preserves values bitwise and the extra masked positions
   contribute exact zeros, so greedy decode is token-for-token identical
-  to the slot-contiguous engine.
+  to ``generation.generate`` over a ``StaticCache``.
 
-The serving engine wires this behind ``PADDLE_TPU_PAGED_KV``
-(``inference/serving.py``); ``=0`` keeps the slot-contiguous path.
+This is the serving engine's only KV cache (``inference/serving.py``).
 """
 
 from __future__ import annotations
@@ -54,18 +53,8 @@ import jax.numpy as jnp
 
 __all__ = ["BlockAllocator", "SequenceBlocks", "PrefixCache",
            "PagedKVPool", "PagedCache", "paged_cache_attention",
-           "paged_kv_enabled", "quant_kv_mode", "serialize_handoff",
+           "quant_kv_mode", "serialize_handoff",
            "deserialize_handoff"]
-
-
-def paged_kv_enabled(default: bool = False) -> bool:
-    """The ``PADDLE_TPU_PAGED_KV`` knob.  Unset → `default` (off: the
-    slot-contiguous engine stays the shipped path until the paged one
-    has a perf trajectory)."""
-    raw = os.environ.get("PADDLE_TPU_PAGED_KV")
-    if raw is None:
-        return default
-    return raw.strip().lower() in ("1", "true", "yes", "on")
 
 
 def quant_kv_mode(explicit: Optional[str] = None) -> Optional[str]:

@@ -235,12 +235,8 @@ class ServingRouter:
         self._prefill_kwargs = dict(prefill_kwargs or {})
         self._decode_kwargs = dict(decode_kwargs or {})
         self.disaggregated = prefill_replicas > 0
-        if self.disaggregated:
-            # the handoff is a paged-block transfer; the whole fleet
-            # must agree on the block geometry
-            self._engine_kwargs.setdefault("paged_kv", True)
-            if not self._engine_kwargs.get("paged_kv", True):
-                raise ValueError("disaggregation requires paged_kv=True")
+        # the handoff is a paged-block transfer; the whole fleet must
+        # agree on the block geometry
         self._block_size = int(self._engine_kwargs.get("kv_block_size",
                                                        16))
         self._max_queue = max_queue
@@ -268,7 +264,6 @@ class ServingRouter:
             raise ValueError("session_checkpoint_steps requires "
                              "kv_tier=")
         if kv_tier is not None:
-            self._engine_kwargs.setdefault("paged_kv", True)
             self._engine_kwargs.setdefault("kv_tier", kv_tier)
         self._parked_sessions: Dict[int, "_FleetRequest"] = {}
 
@@ -1321,7 +1316,7 @@ def _build_worker_engine(args):
     model = LlamaForCausalLM(cfg)
     return ContinuousBatchingEngine(
         model, slots=args.slots, max_len=args.max_len,
-        prefill_buckets=(args.max_len // 2,), paged_kv=True,
+        prefill_buckets=(args.max_len // 2,),
         kv_block_size=args.block_size, prefill_chunk=args.chunk,
         role=args.role if args.role in ("prefill", "decode") else "mixed")
 

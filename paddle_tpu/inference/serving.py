@@ -3,38 +3,32 @@
 Reference role: the AnalysisPredictor serving loop
 (inference/api/analysis_predictor.cc) + the fused_multi_transformer
 decode path — rebuilt TPU-style: ONE compiled per-token decode step over
-a fixed pool of batch slots, plus one compiled prefill executable per
-prompt-length bucket.  New requests join as running sequences finish
-(slot reuse); every slot decodes at its own position (per-row KV write +
-causal bound + RoPE gather — ``static_cache_attention``'s vector-offset
-path).
+a fixed pool of batch slots, plus ONE compiled prefill executable that
+serves every chunk of every prompt.  New requests join as running
+sequences finish (slot reuse); every slot decodes at its own position.
 
-Prefill bucketing: a prompt is right-padded to the smallest bucket.
-Causality makes the padding invisible — pad positions sit to the RIGHT
-of every real token, so no real query attends to them; the first
-generated token reads the logits at the TRUE last prompt position, and
-decode then overwrites the pad rows one per step (the causal bound
-``kpos <= pos`` keeps not-yet-overwritten pads masked).
+The KV cache is paged (``inference/kv_cache.py``): fixed-size token
+blocks with a refcounted free list, a prefix trie so requests sharing a
+system prompt map to the same physical blocks (prefill once,
+copy-on-write on divergence), and a block-table attention path (Pallas
+kernel where eligible, a gather elsewhere — the CPU runs that one).  On
+top of it: **chunked prefill** (a prompt advances one
+``prefill_chunk``-sized piece per engine step, interleaved with decode
+so in-flight TTFT/TPOT don't stall; the last chunk is right-padded, and
+causality makes the padding invisible — pad positions sit to the RIGHT
+of every real token, the first generated token reads the logits at the
+TRUE last prompt position, and decode then overwrites the pad rows) and
+**n-gram speculative decoding** (``spec_decode=k`` drafts from the
+request's own history and verifies all drafts in ONE batched forward;
+greedy-equivalence guaranteed — accepted tokens are exactly what
+step-by-step argmax would emit).  Greedy outputs are token-for-token
+those of ``generation.generate``, the reference the tests hold the
+engine to.
 
 Weight-only int8: ``int8_weights=True`` stores every 2-D matmul weight
 as int8 with a per-output-channel fp32 scale and dequantizes INSIDE the
 compiled step (XLA fuses the convert+scale into the matmul prologue), so
 decode — a bandwidth-bound workload — reads half the bytes.
-
-Paged KV mode (``PADDLE_TPU_PAGED_KV=1`` / ``paged_kv=True``): the
-slot-contiguous per-slot cache is replaced by the block/paged allocator
-in ``inference/kv_cache.py`` — fixed-size token blocks with a refcounted
-free list, a prefix trie so requests sharing a system prompt map to the
-same physical blocks (prefill once, copy-on-write on divergence), and a
-block-table attention path (Pallas kernel where eligible).  On top of
-the paged cache: **chunked prefill** (long prompts advance one
-``prefill_chunk``-sized piece per engine step, interleaved with decode
-so in-flight TTFT/TPOT don't stall) and **n-gram speculative decoding**
-(``spec_decode=k`` drafts from the request's own history and verifies
-all drafts in ONE batched forward; greedy-equivalence guaranteed —
-accepted tokens are exactly what step-by-step argmax would emit).
-``PADDLE_TPU_PAGED_KV=0`` (the default) keeps the exact previous
-engine; greedy outputs are token-for-token identical either way.
 """
 
 from __future__ import annotations
@@ -73,13 +67,9 @@ def _serving_metrics():
         "tokens": reg.counter("paddle_tpu_serving_tokens_total",
                               "tokens generated (prefill first token + "
                               "decode)"),
-        "bucket": reg.counter(
-            "paddle_tpu_serving_prefill_bucket_total",
-            "prefill admissions per bucket; fit=exact means the prompt "
-            "needed no padding", labelnames=("bucket", "fit")),
         "pad_tokens": reg.counter(
             "paddle_tpu_serving_prefill_pad_tokens_total",
-            "prompt positions wasted on bucket padding"),
+            "prompt positions wasted on padding a prompt's last chunk"),
         "ttft": reg.histogram(
             "paddle_tpu_serving_ttft_seconds",
             "time from enqueue to first generated token",
@@ -115,15 +105,6 @@ def _serving_metrics():
             "paddle_tpu_serving_slo_total",
             "retired requests judged against the serving latency "
             "targets", labelnames=("kind", "result")),
-    }
-
-
-def _paged_metrics():
-    """Paged-KV instruments, registered only when the paged engine is
-    in use so an unpaged process exposes the exact previous series."""
-    from paddle_tpu.observability import default_registry
-    reg = default_registry()
-    return {
         "prefix_lookups": reg.counter(
             "paddle_tpu_serving_prefix_cache_total",
             "prefix-cache lookups at admission",
@@ -224,7 +205,7 @@ class _Request:
     # step that gave this request tokens, shared by the whole batch
     token_stamps: List[Tuple[float, int]] = field(default_factory=list)
     prefix_reused: int = 0          # prompt tokens served from the
-    #                                 prefix cache (paged engine)
+    #                                 prefix cache
     spec_proposed: int = 0          # speculative drafts proposed
     spec_accepted: int = 0          # speculative drafts accepted
     # fleet routing (ServingRouter): "full" is a normal request;
@@ -320,10 +301,8 @@ def _request_timings(req: "_Request") -> Dict[str, float]:
             0.0, req.retired_at - req.first_token_at - req.parked_s)
     if req.retired_at and req.enqueued_at:
         t["total_s"] = req.retired_at - req.enqueued_at
-    # paged-engine evidence: how much prefill the prefix cache skipped,
-    # and how much of the decode came from accepted speculative drafts
-    # (0 / 0.0 in the unpaged engine — the keys are always present so
-    # clients need no feature detection)
+    # how much prefill the prefix cache skipped, and how much of the
+    # decode came from accepted speculative drafts
     t["prefix_tokens_reused"] = float(req.prefix_reused)
     t["speculative_accept_rate"] = (
         req.spec_accepted / req.spec_proposed if req.spec_proposed
@@ -349,10 +328,17 @@ class ContinuousBatchingEngine:
     greedy by default, or sampled (``do_sample=True`` with
     temperature / top-k / nucleus, the generation module's sampler).
 
-    add_request() enqueues; step() either admits a queued request into a
-    free slot (bucketed prefill) or advances every active slot by one
-    token (single compiled decode step).  finished() yields completed
+    add_request() enqueues; step() admits a queued request into a free
+    slot (reserving its KV blocks), advances one admitted prompt by one
+    prefill chunk, or advances every decoding slot by ``steps_per_sync``
+    tokens (single compiled decode step).  finished() yields completed
     (rid, prompt, tokens) triples.
+
+    ``paged_kv`` and ``prefill_buckets`` are kept for the benchmark's
+    traffic file and harness, which still pass them (ROADMAP D2a):
+    ``paged_kv`` takes ``None`` / ``True`` and nothing reads it;
+    ``prefill_buckets``' largest entry is the chunk width when
+    ``prefill_chunk`` is not given.
     """
 
     def __init__(self, model, slots: int = 8, max_len: int = 1024,
@@ -384,7 +370,6 @@ class ContinuousBatchingEngine:
         self.model = model
         self.slots = slots
         self.max_len = max_len
-        self.buckets = sorted(prefill_buckets)
         self.eos = eos_token_id
         # decode steps fused into ONE device program per host interaction
         # (lax.scan): amortizes host/dispatch latency K-fold — the thing
@@ -392,35 +377,23 @@ class ContinuousBatchingEngine:
         # finishing mid-chunk over-generate < K tokens (truncated by the
         # host; the wasted rows are unreachable for successors, see step())
         self.steps_per_sync = max(1, int(steps_per_sync))
-        # paged-KV mode (kv_cache.py): block allocator + prefix reuse +
-        # chunked prefill + optional n-gram speculative decoding.  The
-        # knob default is OFF: =0 (or unset) keeps the exact previous
-        # slot-contiguous engine.
-        from paddle_tpu.inference.kv_cache import (paged_kv_enabled,
-                                                   quant_kv_mode)
-        self.paged = paged_kv_enabled() if paged_kv is None \
-            else bool(paged_kv)
+        if paged_kv is not None and not paged_kv:
+            raise ValueError(
+                "paged_kv=False: the slot-contiguous engine is gone; the "
+                "paged engine is the only one (drop the argument)")
+        from paddle_tpu.inference.kv_cache import quant_kv_mode
         # quantized paged-KV (PADDLE_TPU_QUANT_KV=int8 / quant_kv=):
         # int8 pools + per-block scales — the pool holds itemsize-ratio
         # MORE blocks at the same payload HBM bytes (2x for bf16, 4x
         # for fp32), which is the capacity claim BENCH_serve records
         self.kv_quant = quant_kv_mode(quant_kv)
-        if self.kv_quant and not self.paged:
-            raise ValueError(
-                "PADDLE_TPU_QUANT_KV / quant_kv= requires the paged KV "
-                "engine (PADDLE_TPU_PAGED_KV=1 or paged_kv=True)")
         self.spec_tokens = max(0, int(spec_decode))
         self._spec_ngram = max(1, int(spec_ngram))
-        if self.spec_tokens:
-            if not self.paged:
-                raise ValueError(
-                    "spec_decode requires the paged KV engine "
-                    "(paged_kv=True or PADDLE_TPU_PAGED_KV=1)")
-            if do_sample:
-                raise ValueError(
-                    "n-gram speculative decoding is greedy-only "
-                    "(accepted tokens must equal step-by-step argmax); "
-                    "do_sample=True is incompatible")
+        if self.spec_tokens and do_sample:
+            raise ValueError(
+                "n-gram speculative decoding is greedy-only "
+                "(accepted tokens must equal step-by-step argmax); "
+                "do_sample=True is incompatible")
         # sampling config shared by prefill + decode (the generation
         # module's _sample: temperature / top-k / nucleus; greedy when
         # do_sample=False).  One key stream serves the whole pool —
@@ -437,11 +410,11 @@ class ContinuousBatchingEngine:
             raise ValueError(
                 f"max_len {max_len} exceeds the model's RoPE table "
                 f"(max_position_embeddings={table})")
-        if self.buckets[-1] >= max_len:
+        largest = max(prefill_buckets)
+        if largest >= max_len:
             raise ValueError(
-                f"largest prefill bucket {self.buckets[-1]} must be < "
-                f"max_len {max_len} (prefill writes bucket rows into the "
-                "per-slot cache)")
+                f"largest prefill bucket {largest} must be < max_len "
+                f"{max_len} (it is the default chunk width)")
         # weight-only quantized serving (quantization.serving tentpole):
         # PADDLE_TPU_QUANT_WEIGHTS=int8|fp8 (or quant_weights=) converts
         # the model's large Linears to QuantedLinear IN PLACE (refcounted
@@ -472,68 +445,53 @@ class ContinuousBatchingEngine:
         self.int8 = int8_weights
 
         cfgm = model.config
-        if not self.paged:
-            kv_shape = (slots, max_len, cfgm.num_key_value_heads,
-                        cfgm.head_dim)
-            self._caches = [
-                (jnp.zeros(kv_shape, self._dtype), jnp.zeros(kv_shape,
-                                                             self._dtype))
-                for _ in range(cfgm.num_hidden_layers)]
+        from paddle_tpu.inference.kv_cache import (BlockAllocator,
+                                                   PagedKVPool,
+                                                   PrefixCache)
+        self._block_size = int(kv_block_size)
+        if self._block_size < 1:
+            raise ValueError(f"kv_block_size must be >= 1, got "
+                             f"{kv_block_size}")
+        self._max_blocks = -(-max_len // self._block_size)
+        # default pool: every slot can hold a worst-case sequence,
+        # plus the reserved scratch block; prefix sharing then turns
+        # the saved blocks into prefix-cache headroom.  An int8-
+        # quantized pool multiplies the block count by the compute
+        # dtype's itemsize — SAME payload HBM bytes, itemsize-ratio
+        # more blocks (the extra blocks become prefix-cache and
+        # concurrency headroom)
+        if num_kv_blocks:
+            self._num_blocks = int(num_kv_blocks)
         else:
-            from paddle_tpu.inference.kv_cache import (BlockAllocator,
-                                                       PagedKVPool,
-                                                       PrefixCache)
-            self._block_size = int(kv_block_size)
-            if self._block_size < 1:
-                raise ValueError(f"kv_block_size must be >= 1, got "
-                                 f"{kv_block_size}")
-            self._max_blocks = -(-max_len // self._block_size)
-            # default pool: every slot can hold a worst-case sequence,
-            # plus the reserved scratch block; prefix sharing then turns
-            # the saved blocks into prefix-cache headroom.  An int8-
-            # quantized pool multiplies the block count by the compute
-            # dtype's itemsize — SAME payload HBM bytes, itemsize-ratio
-            # more blocks (the extra blocks become prefix-cache and
-            # concurrency headroom)
-            if num_kv_blocks:
-                self._num_blocks = int(num_kv_blocks)
-            else:
-                ratio = jnp.dtype(self._dtype).itemsize \
-                    if self.kv_quant else 1
-                self._num_blocks = 1 + ratio * slots * self._max_blocks
-            self._allocator = BlockAllocator(self._num_blocks)
-            self._prefix = PrefixCache(self._block_size, self._allocator) \
-                if prefix_cache else None
-            self._pool = PagedKVPool(
-                cfgm.num_hidden_layers, self._num_blocks,
-                self._block_size, cfgm.num_key_value_heads,
-                cfgm.head_dim, self._dtype, quant=self.kv_quant)
-            # per-slot block table rows; 0 = reserved scratch block
-            self._bt = np.zeros((slots, self._max_blocks), np.int32)
-            self._seq: List[Optional[object]] = [None] * slots
-            self._prefilling: Dict[int, int] = {}  # slot -> next pos
-            self._chunk = int(prefill_chunk) if prefill_chunk \
-                else min(self.buckets[-1], max_len - 1)
-            if not 1 <= self._chunk < max_len:
-                raise ValueError(f"prefill_chunk must be in [1, "
-                                 f"max_len), got {prefill_chunk}")
-            self._interleave_decode = False
-            self._blocks_used_peak = 0
+            ratio = jnp.dtype(self._dtype).itemsize \
+                if self.kv_quant else 1
+            self._num_blocks = 1 + ratio * slots * self._max_blocks
+        self._allocator = BlockAllocator(self._num_blocks)
+        self._prefix = PrefixCache(self._block_size, self._allocator) \
+            if prefix_cache else None
+        self._pool = PagedKVPool(
+            cfgm.num_hidden_layers, self._num_blocks,
+            self._block_size, cfgm.num_key_value_heads,
+            cfgm.head_dim, self._dtype, quant=self.kv_quant)
+        # per-slot block table rows; 0 = reserved scratch block
+        self._bt = np.zeros((slots, self._max_blocks), np.int32)
+        self._seq: List[Optional[object]] = [None] * slots
+        self._prefilling: Dict[int, int] = {}  # slot -> next pos
+        self._chunk = int(prefill_chunk) if prefill_chunk else largest
+        if not 1 <= self._chunk < max_len:
+            raise ValueError(f"prefill_chunk must be in [1, "
+                             f"max_len), got {prefill_chunk}")
+        self._interleave_decode = False
+        self._blocks_used_peak = 0
         # session survivability (kv_tier.py): demoted sessions live in
         # the tier manager; _parked maps rid -> (request, tier key) for
         # sessions this engine still owns the resume of
         self._kv_tier = kv_tier
         self._auto_park_s = auto_park_s
-        if (kv_tier is not None or auto_park_s is not None) \
-                and not self.paged:
-            raise ValueError(
-                "kv_tier / auto_park_s require the paged KV engine "
-                "(paged_kv=True or PADDLE_TPU_PAGED_KV=1)")
         if auto_park_s is not None and kv_tier is None:
             raise ValueError("auto_park_s requires kv_tier=")
         self._parked: Dict[int, tuple] = {}
-        if self.paged and self._kv_tier is not None \
-                and self._prefix is not None:
+        if self._kv_tier is not None and self._prefix is not None:
             # demote-before-free: cold prefix blocks spill to the host
             # tier instead of vanishing; admission promotes them back
             self._prefix.on_evict = self._demote_prefix_node
@@ -570,8 +528,6 @@ class ContinuousBatchingEngine:
         # occupancy gauges are pull-style (read at scrape, zero cost in
         # the serving loop)
         self._metrics = _serving_metrics()
-        if self.paged:
-            self._metrics.update(_paged_metrics())
         # latency targets snapshotted once per engine (env-tunable); a
         # target <= 0 disables that kind's hit/miss counting
         from paddle_tpu.observability.goodput import slo_targets
@@ -612,29 +568,28 @@ class ContinuousBatchingEngine:
                   "serving role this engine plays in a disaggregated "
                   "fleet (value 1 marks the active role)",
                   labelnames=("role",)).labels(role=role).set(1.0)
-        if self.paged:
-            # read through the engine, not a bound allocator: _recover
-            # rebuilds the allocator/prefix objects on error containment
-            reg.gauge("paddle_tpu_serving_kv_blocks_free",
-                      "paged KV blocks on the free list").set_function(
-                lambda e=self: e._allocator.free_blocks)
-            reg.gauge("paddle_tpu_serving_kv_blocks_used",
-                      "paged KV blocks held by sequences or the prefix "
-                      "cache").set_function(
-                lambda e=self: e._allocator.used_blocks)
-            reg.gauge("paddle_tpu_serving_prefix_cache_blocks",
-                      "blocks registered in the prefix trie"
-                      ).set_function(
-                lambda e=self: len(e._prefix)
-                if e._prefix is not None else 0)
-            reg.gauge("paddle_tpu_serving_kv_pool_bytes",
-                      "device bytes held by the paged KV pools "
-                      "(K/V payload + quant scale arrays)"
-                      ).set_function(lambda e=self: e._pool.nbytes)
-            reg.gauge("paddle_tpu_serving_sessions_parked",
-                      "sessions demoted to the KV tier and awaiting "
-                      "resume on this engine").set_function(
-                lambda e=self: len(e._parked))
+        # read through the engine, not a bound allocator: _recover
+        # rebuilds the allocator/prefix objects on error containment
+        reg.gauge("paddle_tpu_serving_kv_blocks_free",
+                  "paged KV blocks on the free list").set_function(
+            lambda e=self: e._allocator.free_blocks)
+        reg.gauge("paddle_tpu_serving_kv_blocks_used",
+                  "paged KV blocks held by sequences or the prefix "
+                  "cache").set_function(
+            lambda e=self: e._allocator.used_blocks)
+        reg.gauge("paddle_tpu_serving_prefix_cache_blocks",
+                  "blocks registered in the prefix trie"
+                  ).set_function(
+            lambda e=self: len(e._prefix)
+            if e._prefix is not None else 0)
+        reg.gauge("paddle_tpu_serving_kv_pool_bytes",
+                  "device bytes held by the paged KV pools "
+                  "(K/V payload + quant scale arrays)"
+                  ).set_function(lambda e=self: e._pool.nbytes)
+        reg.gauge("paddle_tpu_serving_sessions_parked",
+                  "sessions demoted to the KV tier and awaiting "
+                  "resume on this engine").set_function(
+            lambda e=self: len(e._parked))
 
         # serving traces must see eval-mode (dropout off); remembered so
         # close() / context exit can hand the model back for training
@@ -642,169 +597,107 @@ class ContinuousBatchingEngine:
         if self._was_training:
             model.eval()
 
-        from paddle_tpu.core.dispatch import unwrap
-        from paddle_tpu.generation import StaticCache
-
-        def fwd(ps, ids, caches, pos):
-            cc = [StaticCache(k, v) for k, v in caches]
-            logits, new_caches = functional_call(model, ps, ids, None,
-                                                 cc, pos)
-            raw = unwrap(logits).astype(jnp.float32)
-            flat = [(unwrap(c.k), unwrap(c.v)) for c in new_caches]
-            return raw, flat
-
-        dtype = self._dtype
-
         import functools as _ft
 
+        from paddle_tpu.core.dispatch import unwrap
         from paddle_tpu.generation import _sample
+        from paddle_tpu.inference.kv_cache import PagedCache
+        dtype = self._dtype
         gen_cfg = self._gen_cfg
         K = self.steps_per_sync
 
-        if not self.paged:
-            @_ft.partial(jax.jit, donate_argnums=(3,))
-            def prefill(keep, quant, ids, caches1, true_len, key):
-                ps = _dequant(keep, quant, dtype)
-                logits, new_caches = fwd(ps, ids, caches1, 0)
-                first = _sample(logits[0, true_len - 1][None], gen_cfg,
-                                key)[0]
-                return first.astype(jnp.int32), new_caches
+        # kscales/vscales are EMPTY lists on an unquantized pool:
+        # they contribute no jaxpr inputs, so the knob-off programs
+        # are identical to the pre-quantization engine
+        def fwd_paged(ps, ids, kpools, vpools, kscales, vscales,
+                      bt, pos):
+            if kscales:
+                cc = [PagedCache(kk, vv, bt, ks, vs)
+                      for kk, vv, ks, vs in zip(kpools, vpools,
+                                                kscales, vscales)]
+            else:
+                cc = [PagedCache(kk, vv, bt)
+                      for kk, vv in zip(kpools, vpools)]
+            logits, new_caches = functional_call(model, ps, ids,
+                                                 None, cc, pos)
+            raw = unwrap(logits).astype(jnp.float32)
+            return raw, ([unwrap(c.k) for c in new_caches],
+                         [unwrap(c.v) for c in new_caches],
+                         [unwrap(c.k_scale) for c in new_caches]
+                         if kscales else [],
+                         [unwrap(c.v_scale) for c in new_caches]
+                         if kscales else [])
 
-            @_ft.partial(jax.jit, donate_argnums=(0, 1))
-            def insert(cachesB, caches1, slot):
-                out = []
-                for (kb, vb), (k1, v1) in zip(cachesB, caches1):
-                    kb = jax.lax.dynamic_update_slice(
-                        kb, k1.astype(kb.dtype), (slot, 0, 0, 0))
-                    vb = jax.lax.dynamic_update_slice(
-                        vb, v1.astype(vb.dtype), (slot, 0, 0, 0))
-                    out.append((kb, vb))
-                return out
+        # chunked prefill: ONE executable serves every chunk of
+        # every prompt (B=1, fixed width C, per-row [1] position
+        # vector so padded tails clamp safely in the RoPE gather).
+        # Non-final chunks ignore the sampled token; the final
+        # chunk's sample at the true last prompt position is the
+        # request's first generated token.
+        @_ft.partial(jax.jit, donate_argnums=(3, 4, 5, 6))
+        def prefill_chunk(keep, quant, ids, kpools, vpools, kscales,
+                          vscales, bt_row, start, last_idx, key):
+            ps = _dequant(keep, quant, dtype)
+            logits, pools = fwd_paged(ps, ids, kpools, vpools,
+                                      kscales, vscales, bt_row,
+                                      start)
+            first = _sample(logits[0, last_idx][None], gen_cfg,
+                            key)[0]
+            return first.astype(jnp.int32), pools
 
-            def decode(keep, quant, caches, toks, pos, active, key):
-                ps = _dequant(keep, quant, dtype)
+        def decode_paged(keep, quant, kpools, vpools, kscales,
+                         vscales, bt, toks, pos, active, key):
+            ps = _dequant(keep, quant, dtype)
 
-                def one(carry, _):
-                    caches, toks, pos, key = carry
-                    logits, caches = fwd(ps, toks[:, None], caches, pos)
-                    key, sub = jax.random.split(key)
-                    nxt = _sample(logits[:, -1], gen_cfg,
-                                  sub).astype(jnp.int32)
-                    # inactive slots run with pos pinned to the scratch
-                    # row max_len-1 (set by the host) and a frozen token;
-                    # their pos must NOT advance inside the chunk
-                    nxt = jnp.where(active, nxt, toks)
-                    pos = jnp.where(active, pos + 1, pos)
-                    return (caches, nxt, pos, key), nxt
+            def one(carry, _):
+                kpools, vpools, kscales, vscales, toks, pos, key = \
+                    carry
+                logits, (kpools, vpools, kscales, vscales) = \
+                    fwd_paged(ps, toks[:, None], kpools, vpools,
+                              kscales, vscales, bt, pos)
+                key, sub = jax.random.split(key)
+                nxt = _sample(logits[:, -1], gen_cfg,
+                              sub).astype(jnp.int32)
+                # inactive rows: host pins pos=0 and zeroes their
+                # block-table row, so the write lands in the
+                # reserved scratch block
+                nxt = jnp.where(active, nxt, toks)
+                pos = jnp.where(active, pos + 1, pos)
+                return (kpools, vpools, kscales, vscales, nxt, pos,
+                        key), nxt
 
-                (caches, _, _, _), seq = jax.lax.scan(
-                    one, (caches, toks, pos, key), None, length=K)
-                return jnp.swapaxes(seq, 0, 1), caches   # [B, K]
+            (kpools, vpools, kscales, vscales, _, _, _), seq = \
+                jax.lax.scan(
+                    one, (kpools, vpools, kscales, vscales, toks,
+                          pos, key), None, length=K)
+            return (jnp.swapaxes(seq, 0, 1), kpools, vpools,
+                    kscales, vscales)
 
-            self._prefill, self._insert = prefill, insert
-            # raw (unjitted) decode kept for program analysis — the
-            # engine build step can lint the exact fn it will compile
-            self._decode_raw = decode
-            self._decode = jax.jit(decode, donate_argnums=(2,))
-            self._fwd = fwd
-        else:
-            from paddle_tpu.inference.kv_cache import PagedCache
+        # speculative verify: ONE batched forward over
+        # [last_token, draft_1..draft_k] per row; argmax at every
+        # position is exactly what step-by-step greedy would emit,
+        # so the host can accept the longest matching draft prefix
+        # plus one bonus token with zero output drift
+        def spec_verify(keep, quant, kpools, vpools, kscales,
+                        vscales, bt, toks, pos, active):
+            ps = _dequant(keep, quant, dtype)
+            logits, (kpools, vpools, kscales, vscales) = fwd_paged(
+                ps, toks, kpools, vpools, kscales, vscales, bt, pos)
+            return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                    kpools, vpools, kscales, vscales)
 
-            # kscales/vscales are EMPTY lists on an unquantized pool:
-            # they contribute no jaxpr inputs, so the knob-off programs
-            # are identical to the pre-quantization engine
-            def fwd_paged(ps, ids, kpools, vpools, kscales, vscales,
-                          bt, pos):
-                if kscales:
-                    cc = [PagedCache(kk, vv, bt, ks, vs)
-                          for kk, vv, ks, vs in zip(kpools, vpools,
-                                                    kscales, vscales)]
-                else:
-                    cc = [PagedCache(kk, vv, bt)
-                          for kk, vv in zip(kpools, vpools)]
-                logits, new_caches = functional_call(model, ps, ids,
-                                                     None, cc, pos)
-                raw = unwrap(logits).astype(jnp.float32)
-                return raw, ([unwrap(c.k) for c in new_caches],
-                             [unwrap(c.v) for c in new_caches],
-                             [unwrap(c.k_scale) for c in new_caches]
-                             if kscales else [],
-                             [unwrap(c.v_scale) for c in new_caches]
-                             if kscales else [])
-
-            # chunked prefill: ONE executable serves every chunk of
-            # every prompt (B=1, fixed width C, per-row [1] position
-            # vector so padded tails clamp safely in the RoPE gather).
-            # Non-final chunks ignore the sampled token; the final
-            # chunk's sample at the true last prompt position is the
-            # request's first generated token.
-            @_ft.partial(jax.jit, donate_argnums=(3, 4, 5, 6))
-            def prefill_chunk(keep, quant, ids, kpools, vpools, kscales,
-                              vscales, bt_row, start, last_idx, key):
-                ps = _dequant(keep, quant, dtype)
-                logits, pools = fwd_paged(ps, ids, kpools, vpools,
-                                          kscales, vscales, bt_row,
-                                          start)
-                first = _sample(logits[0, last_idx][None], gen_cfg,
-                                key)[0]
-                return first.astype(jnp.int32), pools
-
-            def decode_paged(keep, quant, kpools, vpools, kscales,
-                             vscales, bt, toks, pos, active, key):
-                ps = _dequant(keep, quant, dtype)
-
-                def one(carry, _):
-                    kpools, vpools, kscales, vscales, toks, pos, key = \
-                        carry
-                    logits, (kpools, vpools, kscales, vscales) = \
-                        fwd_paged(ps, toks[:, None], kpools, vpools,
-                                  kscales, vscales, bt, pos)
-                    key, sub = jax.random.split(key)
-                    nxt = _sample(logits[:, -1], gen_cfg,
-                                  sub).astype(jnp.int32)
-                    # inactive rows: host pins pos=0 and zeroes their
-                    # block-table row, so the write lands in the
-                    # reserved scratch block
-                    nxt = jnp.where(active, nxt, toks)
-                    pos = jnp.where(active, pos + 1, pos)
-                    return (kpools, vpools, kscales, vscales, nxt, pos,
-                            key), nxt
-
-                (kpools, vpools, kscales, vscales, _, _, _), seq = \
-                    jax.lax.scan(
-                        one, (kpools, vpools, kscales, vscales, toks,
-                              pos, key), None, length=K)
-                return (jnp.swapaxes(seq, 0, 1), kpools, vpools,
-                        kscales, vscales)
-
-            # speculative verify: ONE batched forward over
-            # [last_token, draft_1..draft_k] per row; argmax at every
-            # position is exactly what step-by-step greedy would emit,
-            # so the host can accept the longest matching draft prefix
-            # plus one bonus token with zero output drift
-            def spec_verify(keep, quant, kpools, vpools, kscales,
-                            vscales, bt, toks, pos, active):
-                ps = _dequant(keep, quant, dtype)
-                logits, (kpools, vpools, kscales, vscales) = fwd_paged(
-                    ps, toks, kpools, vpools, kscales, vscales, bt, pos)
-                return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
-                        kpools, vpools, kscales, vscales)
-
-            self._prefill_chunk_fn = prefill_chunk
-            # raw (unjitted) decode kept for program analysis
-            self._decode_paged_raw = decode_paged
-            self._decode_paged = jax.jit(decode_paged,
-                                         donate_argnums=(2, 3, 4, 5))
-            self._spec_verify = jax.jit(spec_verify,
-                                        donate_argnums=(2, 3, 4, 5))
-            self._prefill_chunk_compiled = None
-            self._spec_verify_compiled = None
-        # AOT executables from aot_warmup(): decode + prefill
-        # executables; dispatch prefers them (no first-request compile
-        # spike)
+        self._prefill_chunk_fn = prefill_chunk
+        # raw (unjitted) decode kept for program analysis
+        self._decode_paged_raw = decode_paged
+        self._decode_paged = jax.jit(decode_paged,
+                                     donate_argnums=(2, 3, 4, 5))
+        self._spec_verify = jax.jit(spec_verify,
+                                    donate_argnums=(2, 3, 4, 5))
+        # AOT executables from aot_warmup(); dispatch prefers them (no
+        # first-request compile spike)
         self._decode_compiled = None
-        self._insert_compiled = None
-        self._prefill_compiled: Dict[int, object] = {}
+        self._prefill_chunk_compiled = None
+        self._spec_verify_compiled = None
 
         from paddle_tpu.analysis import analysis_mode
         mode = analyze if analyze is not None else analysis_mode()
@@ -824,20 +717,19 @@ class ContinuousBatchingEngine:
         return (f"model={compile_cache.model_config_tag(self.model)}"
                 f"|gc={gc.do_sample}:{gc.temperature}:{gc.top_k}"
                 f":{gc.top_p}|K={self.steps_per_sync}"
-                f"|int8={int(self.int8)}|paged={int(self.paged)}"
+                f"|int8={int(self.int8)}"
                 f"|spec={self.spec_tokens}"
                 f"|qw={self.quant_mode or '-'}"
                 f"|qkv={self.kv_quant or '-'}")
 
-    def aot_warmup(self, buckets: Optional[Sequence[int]] = None,
-                   cache_only: bool = False):
+    def aot_warmup(self, cache_only: bool = False):
         """Explicitly compile the serving executables up front — the
-        decode step, one prefill per prompt bucket (plus the admission
-        insert) or the chunked-prefill / spec-verify programs in paged
-        mode — with full compile observability (``compile.lower``/
-        ``compile.xla`` spans, ``paddle_tpu_compile_total{target}``
-        counters, per-executable FLOPs / HBM bytes / peak-memory
-        gauges).  With ``PADDLE_TPU_COMPILE_CACHE=1`` every executable
+        decode step, the chunked-prefill program and, with
+        ``spec_decode``, the spec-verify program — with full compile
+        observability (``compile.lower``/``compile.xla`` spans,
+        ``paddle_tpu_compile_total{target}`` counters, per-executable
+        FLOPs / HBM bytes / peak-memory gauges).  With
+        ``PADDLE_TPU_COMPILE_CACHE=1`` every executable
         is served from (or stored into) the persistent compile cache:
         a warm replica boots to first token with ZERO XLA compiles.
         ``cache_only=True`` adopts cached executables but never pays a
@@ -862,47 +754,6 @@ class ContinuousBatchingEngine:
         toks = jnp.zeros((self.slots,), jnp.int32)
         pos = jnp.zeros((self.slots,), jnp.int32)
         active = jnp.ones((self.slots,), jnp.bool_)
-        if self.paged:
-            self._aot_warmup_paged(warm, toks, pos, active)
-            return stats
-        c = warm(self._decode, self._keep, self._quant, self._caches,
-                 toks, pos, active, self._key, target="serving.decode")
-        if c is not None:
-            self._decode_compiled = c
-        cfgm = self.model.config
-        shape1 = (1, self.max_len, cfgm.num_key_value_heads, cfgm.head_dim)
-
-        def kv1():
-            return [(jnp.zeros(shape1, self._dtype),
-                     jnp.zeros(shape1, self._dtype))
-                    for _ in range(cfgm.num_hidden_layers)]
-
-        # the slot insert is bookkeeping-sized but still an XLA compile
-        # on the first admission — warm it too, so a warm-cache fresh
-        # process admits its first request without any compile
-        c = warm(self._insert, self._caches, kv1(),
-                 jnp.asarray(0, jnp.int32), target="serving.insert")
-        if c is not None:
-            self._insert_compiled = c
-        for b in (buckets or self.buckets):
-            ids = jnp.zeros((1, b), jnp.int32)
-            target = f"serving.prefill[{b}]"
-            c = warm(self._prefill, self._keep, self._quant, ids, kv1(),
-                     jnp.asarray(b, jnp.int32), self._key, target=target)
-            if c is not None:
-                self._prefill_compiled[b] = c
-        return stats
-
-    def _paged_dummies(self):
-        """Zero-filled pool/table/state avals for AOT compile + lint."""
-        kpools = [jnp.zeros_like(p) for p in self._pool.kpools]
-        vpools = [jnp.zeros_like(p) for p in self._pool.vpools]
-        kscales = [jnp.zeros_like(p) for p in self._pool.kscales]
-        vscales = [jnp.zeros_like(p) for p in self._pool.vscales]
-        bt = jnp.zeros((self.slots, self._max_blocks), jnp.int32)
-        return kpools, vpools, kscales, vscales, bt
-
-    def _aot_warmup_paged(self, warm, toks, pos, active):
         kpools, vpools, kscales, vscales, bt = self._paged_dummies()
         c = warm(self._decode_paged, self._keep, self._quant, kpools,
                  vpools, kscales, vscales, bt, toks, pos, active,
@@ -931,6 +782,16 @@ class ContinuousBatchingEngine:
         # one pow-2-bucketed gather/scatter pair per size, compiled now
         # so a fleet's first KV handoff doesn't pay an XLA compile
         self._pool.warm_transfer(self._max_blocks)
+        return stats
+
+    def _paged_dummies(self):
+        """Zero-filled pool/table/state avals for AOT compile + lint."""
+        kpools = [jnp.zeros_like(p) for p in self._pool.kpools]
+        vpools = [jnp.zeros_like(p) for p in self._pool.vpools]
+        kscales = [jnp.zeros_like(p) for p in self._pool.kscales]
+        vscales = [jnp.zeros_like(p) for p in self._pool.vscales]
+        bt = jnp.zeros((self.slots, self._max_blocks), jnp.int32)
+        return kpools, vpools, kscales, vscales, bt
 
     def analyze(self, strict: bool = False, passes=None, options=None):
         """Lint the compiled decode step (the hot serving path) with the
@@ -941,17 +802,11 @@ class ContinuousBatchingEngine:
         toks = jnp.zeros((self.slots,), jnp.int32)
         pos = jnp.zeros((self.slots,), jnp.int32)
         active = jnp.ones((self.slots,), jnp.bool_)
-        if self.paged:
-            kpools, vpools, kscales, vscales, bt = self._paged_dummies()
-            return _analysis.check(
-                self._decode_paged_raw, self._keep, self._quant, kpools,
-                vpools, kscales, vscales, bt, toks, pos, active,
-                self._key, strict=strict, passes=passes, options=options)
-        report = _analysis.check(
-            self._decode_raw, self._keep, self._quant, self._caches,
-            toks, pos, active, self._key, strict=strict, passes=passes,
-            options=options)
-        return report
+        kpools, vpools, kscales, vscales, bt = self._paged_dummies()
+        return _analysis.check(
+            self._decode_paged_raw, self._keep, self._quant, kpools,
+            vpools, kscales, vscales, bt, toks, pos, active,
+            self._key, strict=strict, passes=passes, options=options)
 
     def _next_key(self):
         """Advance the sampling stream — greedy mode skips the split
@@ -974,7 +829,7 @@ class ContinuousBatchingEngine:
         status "timeout".  Raises :class:`QueueFullError` when the
         bounded admission queue is at capacity.
 
-        Fleet-router hooks (both require the paged engine):
+        Fleet-router hooks:
         ``prefill_only=True`` retires the request right after its first
         token with status ``"prefilled"`` and parks the prompt's KV
         blocks for :meth:`export_handoff`; ``handoff=payload`` is the
@@ -983,6 +838,9 @@ class ContinuousBatchingEngine:
         re-anchors TTFT at the router's clock and ``span_parent`` nests
         the request span under the router's (the cross-hop trace)."""
         p = np.asarray(prompt_ids, np.int32).reshape(-1)
+        if len(p) == 0:
+            raise ValueError("empty prompt: the first token is sampled at "
+                             "the last prompt position, and there is none")
         if max_new_tokens < 1:
             raise ValueError(f"max_new_tokens must be >= 1 (the prefill "
                              f"already emits one token); got "
@@ -990,13 +848,9 @@ class ContinuousBatchingEngine:
         if prefill_only and handoff is not None:
             raise ValueError("prefill_only and handoff are the two ends "
                              "of one transfer; a request can't be both")
-        if (prefill_only or handoff is not None) and not self.paged:
-            raise ValueError(
-                "prefill/decode disaggregation needs the paged KV "
-                "engine (paged_kv=True or PADDLE_TPU_PAGED_KV=1)")
         if handoff is not None and \
-                int(handoff.get("block_size", self._block_size if
-                    self.paged else 0)) != self._block_size:
+                int(handoff.get("block_size", self._block_size)) \
+                != self._block_size:
             raise ValueError(
                 f"handoff block_size {handoff.get('block_size')} != "
                 f"engine kv_block_size {self._block_size}")
@@ -1016,11 +870,12 @@ class ContinuousBatchingEngine:
         if prefill_only:
             # prefill writes rows 0..Lp-1 only; the first token is
             # sampled, never cached here — the decode replica writes it
+            span = 0
             if len(p) > self.max_len - 1:
                 raise ValueError(
                     f"prompt {len(p)} exceeds max_len-1 = "
                     f"{self.max_len - 1} (last row is reserved)")
-        elif self.paged and self.spec_tokens:
+        elif self.spec_tokens:
             # spec verify writes up to spec_tokens draft rows past the
             # accepted position; budget that headroom up front
             span = max_new_tokens + self.spec_tokens
@@ -1030,35 +885,23 @@ class ContinuousBatchingEngine:
                     f"spec_decode={self.spec_tokens} draft headroom "
                     f"exceeds max_len-1 = {self.max_len - 1}")
         else:
-            chunks = -(-max_new_tokens // K) * K
-            if len(p) + chunks > self.max_len - 1:
+            span = -(-max_new_tokens // K) * K
+            if len(p) + span > self.max_len - 1:
                 raise ValueError(
                     f"prompt {len(p)} + max_new {max_new_tokens} "
-                    f"(rounded to {chunks} by steps_per_sync={K}) "
+                    f"(rounded to {span} by steps_per_sync={K}) "
                     f"exceeds max_len-1 = {self.max_len - 1} (last row "
                     "is reserved)")
-        if not self.paged and len(p) > self.buckets[-1]:
-            # paged mode has no bucket bound: chunked prefill walks any
-            # prompt that fits the block budget above
-            raise ValueError(f"prompt {len(p)} exceeds largest prefill "
-                             f"bucket {self.buckets[-1]}")
-        if self.paged:
-            # a request the EMPTY pool couldn't hold would starve in the
-            # queue forever — reject at submission, like the bucket and
-            # max_len bounds (transient exhaustion, by contrast, defers
-            # admission and resolves as running slots retire)
-            if prefill_only:
-                span = 0
-            elif self.spec_tokens:
-                span = max_new_tokens + self.spec_tokens
-            else:
-                span = -(-max_new_tokens // K) * K
-            worst = -(-(len(p) + span) // self._block_size)
-            if worst > self._num_blocks - 1:
-                raise ValueError(
-                    f"prompt {len(p)} + generation span {span} needs "
-                    f"{worst} KV blocks but the pool holds "
-                    f"{self._num_blocks - 1}; raise num_kv_blocks")
+        # a request the EMPTY pool couldn't hold would starve in the
+        # queue forever — reject at submission, like the max_len bound
+        # (transient exhaustion, by contrast, defers admission and
+        # resolves as running slots retire)
+        worst = -(-(len(p) + span) // self._block_size)
+        if worst > self._num_blocks - 1:
+            raise ValueError(
+                f"prompt {len(p)} + generation span {span} needs "
+                f"{worst} KV blocks but the pool holds "
+                f"{self._num_blocks - 1}; raise num_kv_blocks")
         rid = self._next_rid
         self._next_rid += 1
         timeout = timeout_s if timeout_s is not None \
@@ -1106,79 +949,8 @@ class ContinuousBatchingEngine:
             + sum(1 for req, _k in self._parked.values()
                   if req.auto_parked)
 
-    def _bucket(self, n: int) -> int:
-        for b in self.buckets:
-            if n <= b:
-                return b
-        raise ValueError(n)
-
-    def _admit(self, slot: int, req: _Request):
-        from paddle_tpu.generation import StaticCache  # noqa: F401
-        tr = self._tracer
-        Lp = len(req.prompt)
-        with tr.span("serving.admit", rid=req.rid, slot=slot):
-            Lb = self._bucket(Lp)
-            req.admitted_at = time.perf_counter()
-            if req.router_t0 is not None and not req.parked_s:
-                # once a session has been parked, admission latency is
-                # resume latency (resume_s), not routing latency
-                req.route_s = req.admitted_at - req.router_t0
-        with tr.span("serving.build"):
-            ids = np.zeros((1, Lb), np.int32)
-            ids[0, :Lp] = req.prompt
-            cfgm = self.model.config
-            shape1 = (1, self.max_len, cfgm.num_key_value_heads,
-                      cfgm.head_dim)
-            # k and v must be DISTINCT buffers (the prefill donates its
-            # cache argument; an aliased pair would be donated twice)
-            kv1 = [(jnp.zeros(shape1, self._dtype),
-                    jnp.zeros(shape1, self._dtype))
-                   for _ in range(cfgm.num_hidden_layers)]
-            sub = self._next_key()
-        # prefill child span under the request's root: covers the
-        # bucketed forward AND the slot insert (both block admission)
-        prefill = self._prefill_compiled.get(Lb, self._prefill)
-        with tr.span("serving.prefill", parent=req.span,
-                     rid=req.rid, bucket=Lb, prompt_len=Lp):
-            with tr.span("serving.dispatch"):
-                first, caches1 = prefill(self._keep, self._quant,
-                                         jnp.asarray(ids), kv1,
-                                         jnp.asarray(Lp, jnp.int32),
-                                         sub)
-                insert = self._insert_compiled or self._insert
-                self._caches = insert(self._caches, caches1,
-                                      jnp.asarray(slot, jnp.int32))
-            with tr.span("serving.sync"):
-                first = int(first)
-        with tr.span("serving.emit"):
-            self._emit_first_unpaged(slot, req, first, Lp, Lb)
-
-    def _emit_first_unpaged(self, slot, req, first, Lp, Lb):
-        req.first_token_at = time.perf_counter()
-        req.out.append(first)
-        req.token_stamps.append((req.first_token_at, 1))
-        m = self._metrics
-        m["admissions"].inc()
-        m["tokens"].inc()                       # the prefill's first token
-        m["bucket"].labels(bucket=str(Lb),
-                           fit="exact" if Lp == Lb else "padded").inc()
-        if Lb > Lp:
-            m["pad_tokens"].inc(Lb - Lp)
-        origin = req.router_t0 or req.enqueued_at
-        if origin:
-            m["ttft"].observe(time.perf_counter() - origin)
-        self._recorder.record("serving.admit", rid=req.rid, slot=slot,
-                              prompt_len=Lp, bucket=Lb)
-        self._active[slot] = req
-        self._pos[slot] = Lp          # decode writes OVER the pad rows
-        self._budget[slot] = req.max_new_tokens - 1
-        self._last_tok[slot] = first
-        if (self.eos is not None and first == self.eos) \
-                or self._budget[slot] <= 0:
-            self._retire(slot)
-
-    # -- paged-KV scheduling (PADDLE_TPU_PAGED_KV=1) --------------------------
-    def _admit_paged(self, slot: int, req: _Request) -> bool:
+    # -- scheduling ----------------------------------------------------------
+    def _admit(self, slot: int, req: _Request) -> bool:
         """Reserve blocks for `slot` (prefix-cache hits arrive as shared
         refs — those tokens never re-prefill) and mark it prefilling.
         Returns False on allocator exhaustion: the request stays queued
@@ -1275,7 +1047,7 @@ class ContinuousBatchingEngine:
         any leading blocks this replica's prefix cache already holds),
         and enter decode directly — the handoff is a copy, never a
         recompute.  Returns False on allocator exhaustion, exactly like
-        :meth:`_admit_paged` (the request stays queued)."""
+        :meth:`_admit` (the request stays queued)."""
         from paddle_tpu.inference.kv_cache import SequenceBlocks
         from paddle_tpu.robustness import fault_fires
         h = req.handoff
@@ -1464,9 +1236,9 @@ class ContinuousBatchingEngine:
         queued, or mid-prefill).  ``detach=True`` hands resume ownership
         to the caller (the router): the engine forgets the request
         entirely."""
-        if not self.paged or self._kv_tier is None:
-            raise ValueError("park() requires the paged engine with a "
-                             "kv_tier= manager attached")
+        if self._kv_tier is None:
+            raise ValueError("park() requires a kv_tier= manager "
+                             "attached")
         slot = next((i for i, r in enumerate(self._active)
                      if r is not None and r.rid == rid), None)
         if slot is None or slot in self._prefilling:
@@ -1562,7 +1334,7 @@ class ContinuousBatchingEngine:
         makes replica death survivable (the router fetches these for
         its survivors).  ``key_of(rid)`` maps engine rids to fleet-wide
         tier keys; None skips a session.  Returns sessions shipped."""
-        if not self.paged or self._kv_tier is None:
+        if self._kv_tier is None:
             return 0
         shipped = 0
         for slot, req in enumerate(self._active):
@@ -1773,35 +1545,45 @@ class ContinuousBatchingEngine:
                     self._metrics["cow"].inc()
                     self._bt[i, idx] = seq.bids[idx]
 
-    def _decode_step_paged(self, decoding: List[int]):
-        """One fused K-step decode over every decoding slot (the paged
-        analog of the tail of _step_inner)."""
+    def _run_batched(self, program, decoding: List[int], toks, span: int,
+                     *key):
+        """Upload, call and copy back ONE batched decode-shaped program
+        (the fused decode, the speculative verify): ``toks`` is its
+        token array, ``span`` the positions it writes from each decoding
+        slot's write head, ``key`` the sampling key where it takes one.
+        Returns the program's first output on the host and the clock
+        reading the dispatch started at.  The only place the engine
+        hands its pools to a batched program and takes them back."""
         tr = self._tracer
         with tr.span("serving.build"):
             active = np.zeros((self.slots,), bool)
             active[decoding] = True
-            self._ensure_writable_span(decoding, self.steps_per_sync)
+            self._ensure_writable_span(decoding, span)
             pos = np.where(active, self._pos, 0).astype(np.int32)
             # non-decoding rows (free OR mid-prefill) get a zeroed
             # block-table row: their masked write lands in the scratch
             # block, not in a real sequence's (possibly shared) block 0
             bt = np.where(active[:, None], self._bt, 0)
-            sub = self._next_key()
         t0 = time.perf_counter()
-        decode = self._decode_compiled or self._decode_paged
         pool = self._pool
         with self._recorder.instrumented("serving.decode"):
             with tr.span("serving.dispatch"):
-                (toks, pool.kpools, pool.vpools, pool.kscales,
-                 pool.vscales) = decode(
+                (out, pool.kpools, pool.vpools, pool.kscales,
+                 pool.vscales) = program(
                     self._keep, self._quant, pool.kpools, pool.vpools,
                     pool.kscales, pool.vscales, jnp.asarray(bt),
-                    jnp.asarray(self._last_tok), jnp.asarray(pos),
-                    jnp.asarray(active), sub)
+                    jnp.asarray(toks), jnp.asarray(pos),
+                    jnp.asarray(active), *key)
             with tr.span("serving.sync"):
-                toks = np.asarray(toks)                 # [B, K]
-        with tr.span("serving.emit"):
-            self._emit_decoded(
+                return np.asarray(out), t0
+
+    def _decode_step(self, decoding: List[int]):
+        """One fused K-step decode over every decoding slot."""
+        toks, t0 = self._run_batched(
+            self._decode_compiled or self._decode_paged, decoding,
+            self._last_tok, self.steps_per_sync, self._next_key())
+        with self._tracer.span("serving.emit"):
+            self._emit_decoded(                         # toks: [B, K]
                 decoding, [toks[i] for i in decoding], t0, toks.shape[1])
 
     def _emit_decoded(self, slots_: List[int], rows, t0: float,
@@ -1855,8 +1637,6 @@ class ContinuousBatchingEngine:
         k = self.spec_tokens
         S = k + 1
         with tr.span("serving.build"):
-            active = np.zeros((self.slots,), bool)
-            active[decoding] = True
             toks = np.zeros((self.slots, S), np.int32)
             proposed = np.zeros((self.slots,), np.int64)
             for i in decoding:
@@ -1870,22 +1650,9 @@ class ContinuousBatchingEngine:
                     toks[i, 1:1 + n] = draft
                     toks[i, 1 + n:] = draft[-1]  # static-shape pad; unused
                     proposed[i] = n
-            self._ensure_writable_span(decoding, S)
-            pos = np.where(active, self._pos, 0).astype(np.int32)
-            bt = np.where(active[:, None], self._bt, 0)
-        t0 = time.perf_counter()
-        verify = self._spec_verify_compiled or self._spec_verify
-        pool = self._pool
-        with self._recorder.instrumented("serving.decode"):
-            with tr.span("serving.dispatch"):
-                (greedy, pool.kpools, pool.vpools, pool.kscales,
-                 pool.vscales) = verify(
-                    self._keep, self._quant, pool.kpools, pool.vpools,
-                    pool.kscales, pool.vscales, jnp.asarray(bt),
-                    jnp.asarray(toks), jnp.asarray(pos),
-                    jnp.asarray(active))
-            with tr.span("serving.sync"):
-                greedy = np.asarray(greedy)             # [B, S]
+        greedy, t0 = self._run_batched(                 # greedy: [B, S]
+            self._spec_verify_compiled or self._spec_verify, decoding,
+            toks, S)
         with tr.span("serving.emit"):
             m = self._metrics
             rows = []
@@ -1920,7 +1687,7 @@ class ContinuousBatchingEngine:
         step_span.set_attribute("queued", len(self._queue))
         return n_active
 
-    def _step_inner_paged(self, step_span) -> bool:
+    def _step_inner(self, step_span) -> bool:
         tr = self._tracer
         with tr.span("serving.schedule"):
             self._schedule_head(step_span)
@@ -1939,7 +1706,7 @@ class ContinuousBatchingEngine:
         if free and self._queue:
             req = self._queue[0]
             with tr.span("serving.admit", rid=req.rid) as sp:
-                admitted = self._admit_paged(free[0], req)
+                admitted = self._admit(free[0], req)
                 # no blocks: not admitted, or admitted and retired at once
                 seq = self._seq[free[0]] if admitted else None
                 sp.set_attribute("prefix_tokens_reused", req.prefix_reused)
@@ -1972,19 +1739,18 @@ class ContinuousBatchingEngine:
             self._spec_decode_step(decoding)
         else:
             step_span.set_attribute("ran", "decode")
-            self._decode_step_paged(decoding)
+            self._decode_step(decoding)
         return True
 
     def _retire(self, slot: int, status: str = "ok"):
         req = self._active[slot]
         self._active[slot] = None
-        if self.paged:
-            self._prefilling.pop(slot, None)
-            seq = self._seq[slot]
-            if seq is not None:
-                seq.release()   # shared prefix blocks stay in the trie
-            self._seq[slot] = None
-            self._bt[slot, :] = 0
+        self._prefilling.pop(slot, None)
+        seq = self._seq[slot]
+        if seq is not None:
+            seq.release()   # shared prefix blocks stay in the trie
+        self._seq[slot] = None
+        self._bt[slot, :] = 0
         self._finish(req, slot=slot, status=status)
 
     def _finish(self, req: _Request, slot: Optional[int] = None,
@@ -2138,34 +1904,25 @@ class ContinuousBatchingEngine:
         for slot, req in enumerate(self._active):
             if req is not None:
                 self._retire(slot, status="error")
-        if self.paged:
-            # the failed donated call may have consumed the pools; the
-            # host bookkeeping may be mid-flight — rebuild both from
-            # scratch (the prefix cache is warm state, safe to drop)
-            from paddle_tpu.inference.kv_cache import (BlockAllocator,
-                                                       PrefixCache)
-            self._allocator = BlockAllocator(self._num_blocks)
-            if self._prefix is not None:
-                self._prefix = PrefixCache(self._block_size,
-                                           self._allocator)
-                if self._kv_tier is not None:
-                    self._prefix.on_evict = self._demote_prefix_node
-            self._pool.reset()
-            self._bt[:] = 0
-            self._seq = [None] * self.slots
-            self._prefilling.clear()
-            # parked handoffs reference the replaced allocator/pool —
-            # they are gone with it (the router's transfer will fail
-            # and fall back to a fresh prefill elsewhere)
-            self._handoff_ready.clear()
-        else:
-            cfgm = self.model.config
-            kv_shape = (self.slots, self.max_len,
-                        cfgm.num_key_value_heads, cfgm.head_dim)
-            self._caches = [
-                (jnp.zeros(kv_shape, self._dtype),
-                 jnp.zeros(kv_shape, self._dtype))
-                for _ in range(cfgm.num_hidden_layers)]
+        # the failed donated call may have consumed the pools; the
+        # host bookkeeping may be mid-flight — rebuild both from
+        # scratch (the prefix cache is warm state, safe to drop)
+        from paddle_tpu.inference.kv_cache import (BlockAllocator,
+                                                   PrefixCache)
+        self._allocator = BlockAllocator(self._num_blocks)
+        if self._prefix is not None:
+            self._prefix = PrefixCache(self._block_size,
+                                       self._allocator)
+            if self._kv_tier is not None:
+                self._prefix.on_evict = self._demote_prefix_node
+        self._pool.reset()
+        self._bt[:] = 0
+        self._seq = [None] * self.slots
+        self._prefilling.clear()
+        # parked handoffs reference the replaced allocator/pool —
+        # they are gone with it (the router's transfer will fail
+        # and fall back to a fresh prefill elsewhere)
+        self._handoff_ready.clear()
         self._pos[:] = 0
         self._budget[:] = 0
         self._last_tok[:] = 0
@@ -2197,52 +1954,13 @@ class ContinuousBatchingEngine:
             with tr.span("serving.schedule"):
                 self._expire()
             try:
-                out = self._step_inner_paged(sp) if self.paged \
-                    else self._step_inner(sp)
+                out = self._step_inner(sp)
             except Exception as e:  # KeyboardInterrupt etc. propagate
                 self._recover(e)
                 return bool(self._queue) or \
                     any(r is not None for r in self._active)
             self._error_streak = 0
             return out
-
-    def _step_inner(self, step_span) -> bool:
-        tr = self._tracer
-        with tr.span("serving.schedule"):
-            n_active = self._schedule_head(step_span)
-            free = [i for i, r in enumerate(self._active) if r is None]
-        if free and self._queue:
-            step_span.set_attribute("ran", "admit")
-            self._admit(free[0], self._queue.popleft())
-            return True
-        if not n_active:
-            return bool(self._queue)
-        step_span.set_attribute("decoding", n_active)
-        step_span.set_attribute("ran", "decode")
-        with tr.span("serving.build"):
-            active = np.array([r is not None for r in self._active])
-            # inactive slots decode at the last row with a discarded
-            # output — their write lands on max_len-1 which no active
-            # sequence can reach (add_request enforces prompt+new <=
-            # max_len <= row max)
-            pos = np.where(active, self._pos,
-                           self.max_len - 1).astype(np.int32)
-            sub = self._next_key()
-        t0 = time.perf_counter()
-        decode = self._decode_compiled or self._decode
-        with self._recorder.instrumented("serving.decode"):
-            with tr.span("serving.dispatch"):
-                toks, self._caches = decode(
-                    self._keep, self._quant, self._caches,
-                    jnp.asarray(self._last_tok), jnp.asarray(pos),
-                    jnp.asarray(active), sub)
-            with tr.span("serving.sync"):
-                toks = np.asarray(toks)                 # [B, K]
-        with tr.span("serving.emit"):
-            decoding = np.flatnonzero(active).tolist()
-            self._emit_decoded(
-                decoding, [toks[i] for i in decoding], t0, toks.shape[1])
-        return True
 
     def run(self):
         """Drain queue + slots; returns {rid: (prompt, tokens)}."""

@@ -244,7 +244,7 @@ def main(argv=None) -> int:
                 "paddle_tpu_serving_tokens_total",
                 "paddle_tpu_serving_ttft_seconds_bucket{le=",
                 "paddle_tpu_serving_decode_token_seconds_bucket{le=",
-                "paddle_tpu_serving_prefill_bucket_total",
+                "paddle_tpu_serving_prefill_chunks_total",
                 "paddle_tpu_compile_total",
                 "paddle_tpu_xla_flops",
                 "paddle_tpu_device_live_bytes",
@@ -407,7 +407,7 @@ def _forensics_phase(args) -> int:
                            max_position_embeddings=128)
     model = LlamaForCausalLM(cfg)
     kw = dict(slots=2, max_len=64, prefill_buckets=(32,),
-              paged_kv=True, kv_block_size=8, prefill_chunk=16)
+              kv_block_size=8, prefill_chunk=16)
 
     # -- engine-side kinds: admit (defer + slot), park, resume, tier,
     # retire, expire — plus the RIGGED SLOW REQUEST: KV-alloc

@@ -322,8 +322,8 @@ class TestServingWarmup:
 
         with _engine(model) as eng:
             stats = eng.aot_warmup()
-            assert set(stats) == {"serving.decode", "serving.insert",
-                                  "serving.prefill[16]"}
+            assert set(stats) == {"serving.decode",
+                                  "serving.prefill_chunk[16]"}
             rid = eng.add_request(prompt, max_new_tokens=6)
             live = eng.run()[rid][1]
 
@@ -335,7 +335,7 @@ class TestServingWarmup:
             assert _counter_total("paddle_tpu_compile_total") == before, \
                 "warm-cache warmup must perform zero XLA compiles"
             assert eng2._decode_compiled is not None
-            assert eng2._insert_compiled is not None
+            assert eng2._prefill_chunk_compiled is not None
             rid = eng2.add_request(prompt, max_new_tokens=6)
             cached = eng2.run()[rid][1]
         assert cached == live, "cached executables changed the tokens"
@@ -347,8 +347,7 @@ class TestServingWarmup:
         def build():
             return ContinuousBatchingEngine(
                 model, slots=2, max_len=64, prefill_buckets=(16,),
-                paged_kv=True, kv_block_size=8, prefill_chunk=16,
-                spec_decode=2)
+                kv_block_size=8, prefill_chunk=16, spec_decode=2)
         rng = np.random.default_rng(2)
         prompt = rng.integers(0, 256, (9,)).astype(np.int32)
         with build() as eng:

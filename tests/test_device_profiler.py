@@ -363,11 +363,11 @@ def test_serving_aot_warmup(tiny_llama):
     with ContinuousBatchingEngine(model, slots=2, max_len=64,
                                   prefill_buckets=(16,)) as eng:
         stats = eng.aot_warmup()
-        assert set(stats) == {"serving.decode", "serving.insert",
-                              "serving.prefill[16]"}
+        assert set(stats) == {"serving.decode",
+                              "serving.prefill_chunk[16]"}
         assert stats["serving.decode"].flops > 0
         assert eng._decode_compiled is not None
-        assert 16 in eng._prefill_compiled
+        assert eng._prefill_chunk_compiled is not None
         rids = [eng.add_request(rng.integers(0, 256, (5,)),
                                 max_new_tokens=4) for _ in range(3)]
         results = eng.run()

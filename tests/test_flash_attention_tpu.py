@@ -252,7 +252,7 @@ def test_paged_engine_warms_the_targets_it_always_has(monkeypatch):
         max_position_embeddings=128))
     eng = ContinuousBatchingEngine(
         model, slots=2, max_len=64, prefill_buckets=(16, 32),
-        paged_kv=True, kv_block_size=4, prefill_chunk=8)
+        kv_block_size=4, prefill_chunk=8)
     assert sorted(eng.aot_warmup()) == ["serving.decode",
                                         "serving.prefill_chunk[8]"]
     prompts = [list(range(3, 24)), [7, 9, 11]]

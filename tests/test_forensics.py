@@ -471,7 +471,7 @@ def tiny_model():
 
 
 ENGINE_KW = dict(slots=2, max_len=64, prefill_buckets=(32,),
-                 paged_kv=True, kv_block_size=8, prefill_chunk=16)
+                 kv_block_size=8, prefill_chunk=16)
 
 
 def _build(model, tier=None, **over):

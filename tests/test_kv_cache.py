@@ -37,7 +37,7 @@ def _reference(model, prompt, n):
 
 def _paged_engine(model, **over):
     kw = dict(slots=2, max_len=64, prefill_buckets=(16, 32),
-              paged_kv=True, kv_block_size=4, prefill_chunk=8)
+              kv_block_size=4, prefill_chunk=8)
     kw.update(over)
     return ContinuousBatchingEngine(model, **kw)
 
@@ -487,7 +487,7 @@ class TestPagedEngineParity:
         prompt = rng.integers(0, 256, (17,))
         eng = ContinuousBatchingEngine(
             tiny_model, slots=1, max_len=20, prefill_buckets=(16,),
-            paged_kv=True, kv_block_size=4, prefill_chunk=16)
+            kv_block_size=4, prefill_chunk=16)
         rid = eng.add_request(prompt, max_new_tokens=2)
         assert eng.run()[rid][1] == _reference(tiny_model, prompt, 2)
 
@@ -505,16 +505,6 @@ class TestPagedEngineParity:
                               max_new_tokens=4)
         out = eng.run()[rid][1]
         assert len(out) == 4 and all(0 <= t < 256 for t in out)
-
-    def test_env_knob_and_default(self, tiny_model, monkeypatch):
-        monkeypatch.delenv("PADDLE_TPU_PAGED_KV", raising=False)
-        eng = ContinuousBatchingEngine(tiny_model, slots=1, max_len=48,
-                                       prefill_buckets=(16,))
-        assert not eng.paged
-        monkeypatch.setenv("PADDLE_TPU_PAGED_KV", "1")
-        eng2 = ContinuousBatchingEngine(tiny_model, slots=1, max_len=48,
-                                        prefill_buckets=(16,))
-        assert eng2.paged
 
     def test_timings_fields_always_present(self, tiny_model):
         eng = ContinuousBatchingEngine(tiny_model, slots=1, max_len=48,
@@ -564,11 +554,7 @@ class TestSpeculativeDecoding:
         rid = eng.add_request(prompt, max_new_tokens=12)
         assert eng.run()[rid][1] == ref[:stop + 1]
 
-    def test_spec_requires_paged_and_greedy(self, tiny_model):
-        with pytest.raises(ValueError, match="paged"):
-            ContinuousBatchingEngine(tiny_model, slots=1, max_len=48,
-                                     prefill_buckets=(16,),
-                                     spec_decode=3)
+    def test_spec_requires_greedy(self, tiny_model):
         with pytest.raises(ValueError, match="greedy"):
             _paged_engine(tiny_model, spec_decode=3, do_sample=True)
 

@@ -334,32 +334,29 @@ class TestServingTelemetry:
         assert _series_value("paddle_tpu_serving_active_slots") == 0
         assert _series_value("paddle_tpu_serving_slots") == 2
 
-    def test_prefill_bucket_hit_rate_labels(self, tiny_model):
+    @pytest.mark.parametrize("prompt_len,chunks,pad", [
+        (16, 1, 0), (10, 1, 6), (37, 3, 11)],
+        ids=["exact", "padded", "three_chunks"])
+    def test_prefill_chunk_and_pad_counters(self, tiny_model, prompt_len,
+                                            chunks, pad):
+        """One dispatch a chunk; only a prompt's last chunk pads, by
+        what it lacks of the chunk width (the bucket counter went with
+        bucketed prefill)."""
         reg = default_registry()
-        bucket = reg.counter("paddle_tpu_serving_prefill_bucket_total",
-                             labelnames=("bucket", "fit"))
-
-        def val(**labels):
-            child = dict(bucket.series()).get(
-                tuple(str(labels[k]) for k in ("bucket", "fit")))
-            return child.value() if child else 0
-
-        exact0, padded0 = val(bucket=16, fit="exact"), \
-            val(bucket=16, fit="padded")
-        pad0 = reg.counter(
-            "paddle_tpu_serving_prefill_pad_tokens_total").value()
+        chunk_c = reg.counter("paddle_tpu_serving_prefill_chunks_total")
+        pad_c = reg.counter("paddle_tpu_serving_prefill_pad_tokens_total")
         from paddle_tpu.inference.serving import ContinuousBatchingEngine
         eng = ContinuousBatchingEngine(tiny_model, slots=2, max_len=64,
-                                       prefill_buckets=(16, 32))
+                                       prefill_buckets=(16,),
+                                       prefix_cache=False)
+        chunks0, pad0 = chunk_c.value(), pad_c.value()
         rng = np.random.default_rng(1)
-        eng.add_request(rng.integers(0, 128, (16,)), max_new_tokens=2)
-        eng.add_request(rng.integers(0, 128, (10,)), max_new_tokens=2)
+        eng.add_request(rng.integers(0, 128, (prompt_len,)),
+                        max_new_tokens=2)
         eng.run()
-        assert val(bucket=16, fit="exact") == exact0 + 1
-        assert val(bucket=16, fit="padded") == padded0 + 1
-        assert reg.counter(
-            "paddle_tpu_serving_prefill_pad_tokens_total").value() \
-            == pad0 + 6
+        assert chunk_c.value() == chunks0 + chunks
+        assert pad_c.value() == pad0 + pad
+        assert reg.get("paddle_tpu_serving_prefill_bucket_total") is None
 
 
 # ------------------------------------------------------ profiler satellites

@@ -61,7 +61,7 @@ def _match_rate(a, b):
 
 
 ENGINE_KW = dict(slots=2, max_len=64, prefill_buckets=(32,),
-                 paged_kv=True, kv_block_size=BS, prefill_chunk=8)
+                 kv_block_size=BS, prefill_chunk=8)
 
 
 def _quantize(w, mode):
@@ -328,10 +328,6 @@ class TestQuantEngine:
         with pytest.raises(ValueError, match="mutually exclusive"):
             ContinuousBatchingEngine(tiny_model, int8_weights=True,
                                      quant_weights="int8", **ENGINE_KW)
-        with pytest.raises(ValueError, match="paged"):
-            ContinuousBatchingEngine(tiny_model, slots=2, max_len=64,
-                                     prefill_buckets=(32,),
-                                     quant_kv="int8")
         assert getattr(tiny_model, "_serving_quant_refs", 0) == 0
 
     def test_env_knobs_reach_engine(self, tiny_model, monkeypatch):

@@ -21,7 +21,7 @@ from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 
 BS = 8          # kv block size used throughout
 ENGINE_KW = dict(slots=2, max_len=64, prefill_buckets=(32,),
-                 paged_kv=True, kv_block_size=BS, prefill_chunk=8)
+                 kv_block_size=BS, prefill_chunk=8)
 
 
 @pytest.fixture(scope="module")
@@ -273,16 +273,26 @@ class TestHandoffTransport:
         np.testing.assert_array_equal(got["kv"]["k"][0],
                                       payload["kv"]["k"][0])
 
-    def test_engine_rejects_disagg_without_paged(self, tiny_model):
+    def test_engine_rejects_malformed_handoff_requests(self, tiny_model):
+        """The default engine takes both ends of a transfer (no engine
+        argument selects it); what it refuses is a request that is both
+        ends at once, or a payload cut to another block size."""
         eng = ContinuousBatchingEngine(tiny_model, slots=1, max_len=64,
                                        prefill_buckets=(16,),
-                                       paged_kv=False)
-        with pytest.raises(ValueError, match="paged"):
+                                       kv_block_size=BS)
+        with pytest.raises(ValueError, match="two ends"):
             eng.add_request(np.arange(8), max_new_tokens=2,
-                            prefill_only=True)
-        with pytest.raises(ValueError, match="paged"):
-            eng.add_request(np.arange(8), max_new_tokens=2,
+                            prefill_only=True,
                             handoff={"block_size": BS})
+        with pytest.raises(ValueError, match="block_size"):
+            eng.add_request(np.arange(8), max_new_tokens=2,
+                            handoff={"block_size": BS * 2})
+        assert not eng.pending
+        rid = eng.add_request(np.arange(1, 9), max_new_tokens=2,
+                              prefill_only=True)
+        eng.run()
+        assert eng.request_status(rid) == "prefilled"
+        eng.discard_handoff(rid)
 
 
 # ------------------------------------------------------------------ chaos

@@ -1,6 +1,10 @@
 """Continuous-batching serving engine (VERDICT r4 Weak #4 / Next #6):
-slot reuse, bucketed prefill, per-slot positions, int8 weight-only mode
-— all CPU-runnable, parity-checked against model.generate."""
+slot reuse, chunked prefill, per-slot positions, int8 weight-only mode
+— all CPU-runnable, parity-checked against model.generate.  (The block
+allocator, prefix trie and speculation live in tests/test_kv_cache.py.)"""
+
+import os
+import re
 
 import numpy as np
 import pytest
@@ -41,7 +45,7 @@ class TestContinuousBatching:
     def test_slot_reuse_more_requests_than_slots(self, tiny_model):
         """5 requests through 2 slots: all finish, all match the
         sequential generate oracle, different prompt lengths exercise
-        both prefill buckets."""
+        one-chunk and two-chunk prefills."""
         rng = np.random.default_rng(1)
         prompts = [rng.integers(0, 256, (n,))
                    for n in (5, 13, 17, 9, 30)]
@@ -87,13 +91,27 @@ class TestContinuousBatching:
         assert results[r0][1] == ref[:4]      # stopped AT the eos token
         assert len(results[r1][1]) == 3       # second request ran after
 
-    def test_bucket_overflow_rejected(self, tiny_model):
+    def test_request_bounds_rejected(self, tiny_model):
         eng = ContinuousBatchingEngine(tiny_model, slots=1, max_len=64,
-                                       prefill_buckets=(16,))
-        with pytest.raises(ValueError, match="bucket"):
-            eng.add_request(np.zeros(20, np.int32), max_new_tokens=2)
+                                       prefill_buckets=(16,),
+                                       num_kv_blocks=3)
         with pytest.raises(ValueError, match="reserved"):
             eng.add_request(np.zeros(10, np.int32), max_new_tokens=60)
+        # the empty pool (2 blocks of 16 beside the scratch block) could
+        # never hold it: rejected at submission, not starved in the queue
+        with pytest.raises(ValueError, match="KV blocks"):
+            eng.add_request(np.zeros(30, np.int32), max_new_tokens=8)
+        assert not eng.pending
+
+    def test_empty_prompt_rejected(self, tiny_model):
+        """No last prompt position to sample the first token at: the
+        final chunk would read the logits of a pad row."""
+        eng = ContinuousBatchingEngine(tiny_model, slots=1, max_len=64,
+                                       prefill_buckets=(16,))
+        for empty in ([], np.zeros((0,), np.int32)):
+            with pytest.raises(ValueError, match="empty prompt"):
+                eng.add_request(empty, max_new_tokens=4)
+        assert not eng.pending
 
 
 class TestInt8Serving:
@@ -169,31 +187,85 @@ class TestChunkedDecode:
             tiny_model.eval()
 
 
-class TestPagedKnobRegression:
-    """PADDLE_TPU_PAGED_KV=0 (or unset) must reproduce the exact
-    previous engine; =1 must be token-for-token greedy-identical.
-    (The paged engine's own suite lives in tests/test_kv_cache.py.)"""
+class TestOneEngine:
+    """There is one engine: what ``ContinuousBatchingEngine(model)``
+    builds is the paged engine, held to ``generate()`` token for token,
+    and nothing selects another."""
 
-    def test_default_is_unpaged(self, tiny_model, monkeypatch):
+    @pytest.mark.parametrize("over", [
+        {}, {"steps_per_sync": 4}, {"prefix_cache": False},
+        {"kv_block_size": 8}, {"kv_block_size": 64}],
+        ids=["plain", "sync4", "no_prefix", "block8", "block64"])
+    def test_default_matches_generate_past_the_largest_bucket(
+            self, tiny_model, monkeypatch, over):
+        """No engine argument, no environment: a prompt longer than the
+        largest entry of ``prefill_buckets`` (the old default engine
+        refused it) walks in chunks and equals the reference."""
         monkeypatch.delenv("PADDLE_TPU_PAGED_KV", raising=False)
-        eng = ContinuousBatchingEngine(tiny_model, slots=1, max_len=64,
-                                       prefill_buckets=(16,))
-        assert not eng.paged
-        assert hasattr(eng, "_caches")       # slot-contiguous buffers
-
-    def test_knob_zero_matches_knob_one(self, tiny_model, monkeypatch):
+        from paddle_tpu.inference.kv_cache import PagedKVPool
         rng = np.random.default_rng(40)
-        prompt = rng.integers(0, 256, (12,))
-        outs = {}
-        for knob in ("0", "1"):
-            monkeypatch.setenv("PADDLE_TPU_PAGED_KV", knob)
-            eng = ContinuousBatchingEngine(
-                tiny_model, slots=2, max_len=64, prefill_buckets=(16,))
-            assert eng.paged == (knob == "1")
-            rid = eng.add_request(prompt, max_new_tokens=8)
-            outs[knob] = eng.run()[rid][1]
-        assert outs["0"] == outs["1"]
-        assert outs["0"] == _reference(tiny_model, prompt, 8)
+        prompts = [rng.integers(0, 256, (n,)) for n in (41, 12)]
+        eng = ContinuousBatchingEngine(tiny_model, slots=2, max_len=64,
+                                       prefill_buckets=(8, 16), **over)
+        assert isinstance(eng._pool, PagedKVPool)
+        assert eng._chunk == 16
+        rids = [eng.add_request(p, max_new_tokens=8) for p in prompts]
+        results = eng.run()
+        for rid, p in zip(rids, prompts):
+            assert results[rid][1] == _reference(tiny_model, p, 8), \
+                f"request {rid} (len {len(p)}) diverged"
+
+    def test_no_argument_builds_a_paged_pool(self):
+        """``ContinuousBatchingEngine(model)``, every default: the pool
+        holds each slot's worst case in blocks of 16 beside the scratch
+        block, and the chunk is the largest default bucket."""
+        from paddle_tpu.inference.kv_cache import PagedKVPool
+        pp.seed(0)
+        cfg = LlamaConfig.tiny(vocab_size=64, hidden_size=32,
+                               intermediate_size=64, num_hidden_layers=1,
+                               num_attention_heads=2, num_key_value_heads=1,
+                               max_position_embeddings=1024)
+        model = LlamaForCausalLM(cfg)
+        eng = ContinuousBatchingEngine(model)
+        assert isinstance(eng._pool, PagedKVPool)
+        assert eng._num_blocks == 1 + 8 * 64 and eng._chunk == 256
+        prompt = np.arange(1, 20) % 64
+        rid = eng.add_request(prompt, max_new_tokens=3)
+        assert eng.run()[rid][1] == _reference(model, prompt, 3)
+
+    def test_no_switch(self, tiny_model, monkeypatch):
+        """``paged_kv=False`` raises; the retired environment variable
+        is not read; ``paged_kv=True`` (the benchmark's traffic file
+        still passes it) builds the same engine."""
+        kw = dict(slots=2, max_len=64, prefill_buckets=(16,))
+        with pytest.raises(ValueError, match="paged_kv=False"):
+            ContinuousBatchingEngine(tiny_model, paged_kv=False, **kw)
+        monkeypatch.setenv("PADDLE_TPU_PAGED_KV", "0")
+        eng = ContinuousBatchingEngine(tiny_model, **kw)
+        same = ContinuousBatchingEngine(tiny_model, paged_kv=True, **kw)
+        for e in (eng, same):
+            assert not hasattr(e, "paged") and hasattr(e, "_pool")
+        assert eng._cache_extra() == same._cache_extra()
+        prompt = np.random.default_rng(41).integers(0, 256, (12,))
+        rid = eng.add_request(prompt, max_new_tokens=8)
+        assert eng.run()[rid][1] == _reference(tiny_model, prompt, 8)
+
+    def test_source_holds_one_engine(self):
+        """The fork does not grow back: no engine switch, no second
+        step, and one place that hands the pools to a batched program."""
+        root = os.path.join(os.path.dirname(__file__), "..", "paddle_tpu")
+        with open(os.path.join(root, "inference", "serving.py")) as f:
+            src = f.read()
+        for gone in ("self.paged", "paged_kv_enabled", "PADDLE_TPU_PAGED_KV",
+                     "_step_inner_paged", "_emit_first_unpaged",
+                     "_insert_compiled", "StaticCache"):
+            assert gone not in src, gone
+        with open(os.path.join(root, "inference", "kv_cache.py")) as f:
+            assert "PADDLE_TPU_PAGED_KV" not in f.read()
+        # pools are donated to a program and taken back in two places:
+        # the one-row chunk and the batched dispatch both steps share
+        assert len(re.findall(r"pool\.vscales\) = ", src)) == 2
+        assert src.count("self._run_batched(") == 2
 
 
 class TestSampling:

@@ -697,9 +697,11 @@ class TestProfilerBridge:
 
 PHASES = ("serving.schedule", "serving.admit", "serving.build",
           "serving.dispatch", "serving.sync", "serving.emit")
-ENGINES = {"slots": dict(prefill_buckets=(16,)),
-           "paged": dict(paged_kv=True, kv_block_size=8, prefill_chunk=16,
-                         prefill_buckets=(16,))}
+# the engine a user gets with no engine argument, and the blocks the
+# other tests cut it to
+ENGINES = {"default": dict(prefill_buckets=(16,)),
+           "block8": dict(kv_block_size=8, prefill_chunk=16,
+                          prefill_buckets=(16,))}
 
 
 def _run_engine(model, n_requests, **kw):
@@ -752,7 +754,7 @@ class TestEngineStepPhases:
         assert set(PHASES) <= seen, seen
         ran = {s["attrs"]["ran"] for s, _ in steps}
         assert {"admit", "decode"} <= ran
-        assert ("prefill_chunk" in ran) == (kind == "paged")
+        assert "prefill_chunk" in ran
         for s, _ in steps:
             assert {"active", "queued"} <= set(s["attrs"])
         # steps are not roots of the slowest-traces table
@@ -815,7 +817,7 @@ class TestStableNames:
     def test_engine_programs_carry_the_model_scopes(self, tiny_model):
         from paddle_tpu.inference.serving import ContinuousBatchingEngine
         eng = ContinuousBatchingEngine(tiny_model, slots=2, max_len=64,
-                                       **ENGINES["paged"])
+                                       **ENGINES["block8"])
         eng.aot_warmup()
         for prog in (eng._decode_compiled, eng._prefill_chunk_compiled):
             text = prog.as_text()
