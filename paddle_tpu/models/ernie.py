@@ -187,10 +187,11 @@ class ErnieForMaskedLM(Layer):
                         transpose_y=True)
 
     def loss(self, input_ids, labels, ignore_index: int = -100):
-        """Masked-token CE via the fused chunked lm-head+CE — the
-        [T, V] fp32 logits are never materialized (same memory trick as
-        the Llama objective; positions with label==ignore_index, the
-        unmasked 85%, contribute neither loss nor gradient)."""
+        """Masked-token CE via the fused lm-head+CE, walked a chunk of
+        rows at a time — the [T, V] fp32 logits are never materialized
+        (same memory trick as the Llama objective; positions with
+        label==ignore_index, the unmasked 85%, contribute neither loss
+        nor gradient)."""
         h = self._features(input_ids)
         d = h.shape[-1]
         return F.fused_linear_cross_entropy(

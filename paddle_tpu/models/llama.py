@@ -383,10 +383,11 @@ class LlamaForCausalLM(Layer):
         return _gen(self, input_ids, generation_config, **kwargs)
 
     def loss(self, input_ids, labels):
-        """Next-token cross-entropy via the fused chunked lm-head+CE —
-        the [T, V] fp32 logits are never materialized, which is what
-        bounds single-chip batch size (reference role: fused
-        c_softmax_with_cross_entropy)."""
+        """Next-token cross-entropy via the fused lm-head+CE, walked a
+        chunk of rows at a time: the [T, V] fp32 logits are never
+        materialized, which is what bounds single-chip batch size, and
+        under differentiation the same walk makes dh and dW (reference
+        role: fused c_softmax_with_cross_entropy)."""
         h = self.model(input_ids)
         d = h.shape[-1]
         with jax.named_scope("lm_head_ce"):

@@ -199,8 +199,9 @@ class MoEForCausalLM(Layer):
         return self.lm_head(self.model(input_ids))
 
     def loss(self, input_ids, labels):
-        """Fused chunked lm-head CE (the [T, V] fp32 logits are never
-        materialized — same objective path as Llama) + alpha *
+        """Fused lm-head CE walked a chunk of rows at a time (the [T, V]
+        fp32 logits are never materialized — same objective path as
+        Llama) + alpha *
         load-balance aux (reference: gate loss added in moe/utils)."""
         h = self.model(input_ids)
         d = h.shape[-1]

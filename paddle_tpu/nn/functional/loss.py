@@ -342,124 +342,162 @@ def square_error_cost(input, label):
 
 
 @eager_op
-def fused_linear_cross_entropy(hidden, weight, labels, chunk_size=8192,
+def fused_linear_cross_entropy(hidden, weight, labels, chunk_size=None,
                                reduction="mean", ignore_index=-100):
-    """Fused lm-head + softmax cross-entropy over vocab chunks.
+    """Fused lm-head + softmax cross-entropy over token chunks.
 
     Reference role: the fused softmax-with-cross-entropy kernels
     (phi/kernels/fusion, fused c_softmax_with_cross_entropy) — the lm-head
-    logits [T, V] are never materialized in fp32: the forward scans vocab
-    chunks with an online logsumexp, the backward recomputes each chunk's
-    probabilities and accumulates dh / dW on the fly (the chunked-CE
-    memory trick; trades one extra lm-head matmul for O(T*V) activation
-    memory, which is what bounds single-chip batch size).
+    logits [T, V] are never materialized: a ``lax.scan`` walks chunks of
+    rows, and a chunk against the whole head gives those rows' complete
+    logits, hence their logsumexp, their probabilities, their rows of dh
+    and their share of dW in one visit.  With ``reduction`` "mean" / "sum"
+    the per-token weight is known in the forward pass and the upstream
+    cotangent is one scalar, so under differentiation the forward walk
+    makes dh and dW itself (three head products a chunk, no logits
+    recomputed) and the backward only scales them; the undifferentiated
+    call runs the loss half alone (one product).  ``reduction="none"``
+    learns its per-token cotangents only in the backward, so the same walk
+    runs again there.
 
     hidden: [T, d] (flatten batch x seq first); weight: [d, V];
     labels: [T] int (ignore_index entries contribute no loss/grad).
-    Differentiable wrt hidden and weight.
+    chunk_size: ROWS a chunk (it counted vocab columns when the walk was
+    over the vocabulary).  None, as every model passes, is the largest
+    power of two whose float32 logits chunk stays within 1 GiB, never more
+    than T: 2048 rows at V 92544, 8192 at V 32768.
+    The products take the operands in their own dtype with float32
+    accumulation; statistics, probabilities and the dW accumulator are
+    float32.  Differentiable wrt hidden and weight.
     """
     lbl = jnp.asarray(labels).astype(jnp.int32)
     mask = lbl != ignore_index
     safe = jnp.where(mask, lbl, 0)
-    per_tok = _fused_ce(hidden, weight, safe, chunk_size)
-    # zeroing outside the custom_vjp also zeroes the pad cotangents, so
-    # ignored tokens contribute neither loss nor dh/dW
-    per_tok = jnp.where(mask, per_tok, 0.0)
+    rows = _ce_chunk_rows(hidden.shape[0], weight.shape[1], chunk_size)
+    if reduction == "none":
+        # zeroing outside the custom_vjp also zeroes the pad cotangents, so
+        # ignored tokens contribute neither loss nor dh/dW
+        return jnp.where(
+            mask, _fused_ce_per_token(hidden, weight, safe, rows), 0.0)
+    omega = mask.astype(jnp.float32)
     if reduction == "mean":
-        return per_tok.sum() / jnp.maximum(mask.sum(), 1)
-    return _reduce(per_tok, reduction)
+        omega = omega / jnp.maximum(mask.sum(), 1)
+    return _fused_ce_reduced(hidden, weight, safe, omega, rows)
 
 
 from functools import partial as _partial  # noqa: E402
 
+_CE_LOGITS_BYTES = 1 << 30
+
+
+def _ce_chunk_rows(t, v, chunk_size=None):
+    """Rows of one chunk of the token walk."""
+    if chunk_size is None:
+        chunk_size = 1 << ((_CE_LOGITS_BYTES // (4 * v)).bit_length() - 1)
+    return max(1, min(int(chunk_size), t))
+
+
+def _ce_chunk(h_c, w, lbl_c, om_c, want_grads):
+    """One chunk of rows against the whole head: the rows' weighted losses
+    and, when wanted, their dh [r, d] and their share of dW [d, V] under
+    the per-token weights om_c (all float32)."""
+    logits = jnp.dot(h_c, w, preferred_element_type=jnp.float32)   # [r, V]
+    m = logits.max(axis=1)
+    lse = m + jnp.log(jnp.exp(logits - m[:, None]).sum(axis=1))
+    hit = lbl_c[:, None] == jnp.arange(w.shape[1])[None, :]
+    gold = jnp.where(hit, logits, 0.0).sum(axis=1)
+    # a weightless row (ignored, padded) reads 0 whatever its logits hold
+    loss = jnp.where(om_c != 0, (lse - gold) * om_c, 0.0)
+    if not want_grads:
+        return loss
+    delta = ((jnp.exp(logits - lse[:, None]) - hit)
+             * om_c[:, None]).astype(w.dtype)
+    dh_c = jax.lax.dot_general(delta, w, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+    dw_c = jax.lax.dot_general(h_c, delta, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+    return loss, dh_c, dw_c
+
+
+def _ce_walk(h, w, lbl, omega, rows, want_grads):
+    """Scan the chunk body over the rows: the weighted per-token losses
+    [T] and, when wanted, float32 dh [T, d] and dW [d, V].
+
+    Chunk i holds rows i, i + n, i + 2n, ...: any partition of the rows is
+    valid (the loss is a sum over tokens), and this one leaves a batch
+    sharding of the row axis on the rows of every chunk, where contiguous
+    chunks would each live on one shard.  Rows padded up to a chunk
+    multiple carry weight 0."""
+    dt = jnp.result_type(h.dtype, w.dtype)
+    h, w = h.astype(dt), w.astype(dt)
+    t = h.shape[0]
+    n = -(-t // rows)
+
+    def chunks(x):
+        x = jnp.pad(x, ((0, n * rows - t),) + ((0, 0),) * (x.ndim - 1))
+        return jnp.swapaxes(x.reshape((rows, n) + x.shape[1:]), 0, 1)
+
+    def unchunk(x):
+        x = jnp.swapaxes(x, 0, 1)
+        return x.reshape((n * rows,) + x.shape[2:])[:t]
+
+    xs = (chunks(h), chunks(lbl), chunks(omega))
+    if not want_grads:
+        _, loss = jax.lax.scan(
+            lambda c, x: (c, _ce_chunk(x[0], w, x[1], x[2], False)),
+            None, xs)
+        return unchunk(loss)
+
+    def step(dw, x):
+        loss, dh_c, dw_c = _ce_chunk(x[0], w, x[1], x[2], True)
+        return dw + dw_c, (loss, dh_c)
+
+    dw, (loss, dh) = jax.lax.scan(
+        step, jnp.zeros(w.shape, jnp.float32), xs)
+    return unchunk(loss), unchunk(dh), dw
+
+
+@_partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _fused_ce_reduced(h, w, lbl, omega, rows):
+    """sum_t omega_t * loss_t, omega being the mask ("sum") or mask / count
+    ("mean"): known in the forward pass, so the forward can make dh, dW."""
+    return _ce_walk(h, w, lbl, omega, rows, False).sum()
+
+
+def _fused_ce_reduced_fwd(h, w, lbl, omega, rows):
+    # the gradients for a unit cotangent, from the visit that made the loss
+    loss, dh, dw = _ce_walk(h, w, lbl, omega, rows, True)
+    return loss.sum(), (dh.astype(h.dtype), dw.astype(w.dtype))
+
+
+def _fused_ce_reduced_bwd(rows, res, g):
+    # one scalar scales both (the constant 1 under value_and_grad, which
+    # XLA folds)
+    dh, dw = ((g * x.astype(jnp.float32)).astype(x.dtype) for x in res)
+    return dh, dw, None, None
+
+
+_fused_ce_reduced.defvjp(_fused_ce_reduced_fwd, _fused_ce_reduced_bwd)
+
 
 @_partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _fused_ce(h, w, lbl, chunk_size):
-    lse, gold = _fused_ce_scan(h, w, lbl, chunk_size)
-    return lse - gold
+def _fused_ce_per_token(h, w, lbl, rows):
+    return _ce_walk(h, w, lbl, jnp.ones(lbl.shape, jnp.float32), rows,
+                    False)
 
 
-def _padded_weight(w, chunk_size):
-    """Pad the vocab axis up to a chunk multiple (no relayout — steps
-    dynamic_slice their chunk out; padding columns are masked)."""
-    v = w.shape[1]
-    n = -(-v // chunk_size)
-    pad = n * chunk_size - v
-    wp = w if pad == 0 else jnp.pad(w, ((0, 0), (0, pad)),
-                                    constant_values=0.0)
-    return wp, n
+def _fused_ce_per_token_fwd(h, w, lbl, rows):
+    return _fused_ce_per_token(h, w, lbl, rows), (h, w, lbl)
 
 
-def _take_chunk(wp, ci, chunk_size):
-    return jax.lax.dynamic_slice(wp, (0, ci * chunk_size),
-                                 (wp.shape[0], chunk_size))
+def _fused_ce_per_token_bwd(rows, res, g):
+    # per-token cotangents are the weights of the walk: a recompute
+    h, w, lbl = res
+    _, dh, dw = _ce_walk(h, w, lbl, g, rows, True)
+    return dh.astype(h.dtype), dw.astype(w.dtype), None
 
 
-def _fused_ce_scan(h, w, lbl, chunk_size):
-    """Online logsumexp over vocab chunks; also gathers the gold logit."""
-    hf = h.astype(jnp.float32)
-    wp, n = _padded_weight(w, chunk_size)
-    v = w.shape[1]
-
-    def step(carry, ci):
-        m, s, gold = carry
-        wchunk = _take_chunk(wp, ci, chunk_size)
-        logits = hf @ wchunk.astype(jnp.float32)       # [T, c]
-        base = ci * chunk_size
-        col = jnp.arange(chunk_size)[None, :] + base
-        valid = col < v
-        logits = jnp.where(valid, logits, -jnp.inf)
-        cm = jnp.maximum(m, logits.max(axis=1))
-        s = s * jnp.exp(m - cm) + jnp.exp(logits - cm[:, None]).sum(axis=1)
-        local = lbl[:, None] - base
-        hit = (local == jnp.arange(chunk_size)[None, :]) & valid
-        gold = gold + jnp.where(hit, logits, 0.0).sum(axis=1)
-        return (cm, s, gold), None
-
-    t = hf.shape[0]
-    init = (jnp.full((t,), -jnp.inf, jnp.float32),
-            jnp.zeros((t,), jnp.float32), jnp.zeros((t,), jnp.float32))
-    (m, s, gold), _ = jax.lax.scan(step, init, jnp.arange(n))
-    return m + jnp.log(s), gold
-
-
-def _fused_ce_fwd(h, w, lbl, chunk_size):
-    lse, gold = _fused_ce_scan(h, w, lbl, chunk_size)
-    return lse - gold, (h, w, lbl, lse)
-
-
-def _fused_ce_bwd(chunk_size, res, g):
-    h, w, lbl, lse = res
-    hf = h.astype(jnp.float32)
-    wp, n = _padded_weight(w, chunk_size)
-    v = w.shape[1]
-    gf = g.astype(jnp.float32)
-
-    def step(carry, ci):
-        dh, dw = carry
-        wchunk = _take_chunk(wp, ci, chunk_size).astype(jnp.float32)
-        logits = hf @ wchunk                           # [T, c]
-        base = ci * chunk_size
-        col = jnp.arange(chunk_size)[None, :] + base
-        valid = col < v
-        p = jnp.where(valid, jnp.exp(logits - lse[:, None]), 0.0)
-        local = lbl[:, None] - base
-        onehot = ((local == jnp.arange(chunk_size)[None, :]) & valid) \
-            .astype(jnp.float32)
-        delta = (p - onehot) * gf[:, None]             # [T, c]
-        dh = dh + delta @ wchunk.T
-        dw_chunk = hf.T @ delta                        # [d, c]
-        dw = jax.lax.dynamic_update_slice(
-            dw, dw_chunk, (0, ci * chunk_size))
-        return (dh, dw), None
-
-    dh0 = jnp.zeros_like(hf)
-    dw0 = jnp.zeros(wp.shape, jnp.float32)
-    (dh, dw), _ = jax.lax.scan(step, (dh0, dw0), jnp.arange(n))
-    return dh.astype(h.dtype), dw[:, :v].astype(w.dtype), None
-
-
-_fused_ce.defvjp(_fused_ce_fwd, _fused_ce_bwd)
+_fused_ce_per_token.defvjp(_fused_ce_per_token_fwd, _fused_ce_per_token_bwd)
 
 
 # recompute the public surface to include the fused loss above

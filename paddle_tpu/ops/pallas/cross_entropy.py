@@ -16,10 +16,12 @@ and the one-hot backward reads it again.  Here neither survives:
   ``[T, V]``-sized arrays in the whole fwd+bwd are the caller's logits
   and their cotangent, both in the caller's dtype (bf16 in training).
 
-Distinct from ``F.fused_linear_cross_entropy`` (which fuses the lm-head
-matmul and re-materializes logits chunkwise): this kernel takes logits
-that already exist and removes the fp32 softmax intermediate — it is the
-automatic fast path under plain ``F.cross_entropy``.
+Distinct from ``F.fused_linear_cross_entropy`` (which owns the lm-head
+matmul: it walks chunks of rows against the whole head, so only one
+``[rows, vocab]`` chunk of logits ever exists, and makes dh and dW in the
+same visit): this kernel takes logits that already exist and removes the
+fp32 softmax intermediate — it is the automatic fast path under plain
+``F.cross_entropy``.
 
 Mosaic legality (see flash_attention.py): per-token columns ride as
 ``[T, 1]`` arrays with ``(block_t, 1)`` blocks — trailing dims

@@ -444,6 +444,164 @@ class TestFusedLinearCrossEntropy:
         assert float(jnp.abs(gh[5]).sum()) == 0.0
         assert float(jnp.abs(gh[0]).sum()) > 0.0
 
+    # -- the token walk: T 13 is no multiple of the 4-row chunk, V 200 no
+    # multiple of 128
+
+    @staticmethod
+    def _case(seed, dtype=np.float32, ignore=False, transposed=False,
+              T=13, d=16, V=200):
+        rng = np.random.default_rng(seed)
+        h = jnp.asarray(rng.normal(size=(T, d)), dtype)
+        w = jnp.asarray(rng.normal(size=(V, d) if transposed else (d, V))
+                        * 0.2, dtype)
+        lbl = rng.integers(0, V, T)
+        if ignore:
+            lbl[[1, 6, T - 1]] = -100
+        cot = jnp.asarray(rng.uniform(0.5, 1.5, T), jnp.float32)
+        return h, w, jnp.asarray(lbl), cot
+
+    @pytest.mark.parametrize("transposed", [False, True],
+                             ids=["w", "embedding_t"])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["float32", "bfloat16"])
+    @pytest.mark.parametrize("ignore", [False, True],
+                             ids=["all_labels", "ignore_index"])
+    @pytest.mark.parametrize("reduction", ["mean", "sum", "none"])
+    def test_token_walk_matches_materialised_logits(
+            self, reduction, ignore, dtype, transposed):
+        from paddle_tpu.nn.functional.loss import (cross_entropy,
+                                                   fused_linear_cross_entropy)
+        h, w, lbl, cot = self._case(10, dtype, ignore, transposed)
+        head = (lambda b: b.T) if transposed else (lambda b: b)
+
+        def scalar(per_token_or_loss):
+            # "none" gets a cotangent that differs by token
+            return (per_token_or_loss * cot).sum() \
+                if reduction == "none" else per_token_or_loss
+
+        def ref(a, b):
+            logits = a.astype(jnp.float32) @ head(b).astype(jnp.float32)
+            return scalar(cross_entropy(logits, lbl, reduction=reduction))
+
+        def fused(a, b):
+            return scalar(fused_linear_cross_entropy(
+                a, head(b), lbl, chunk_size=4, reduction=reduction))
+
+        (l_r, (gh_r, gw_r)) = jax.value_and_grad(ref, argnums=(0, 1))(h, w)
+        (l_f, (gh_f, gw_f)) = jax.value_and_grad(fused, argnums=(0, 1))(h, w)
+        assert gh_f.dtype == h.dtype and gw_f.dtype == w.dtype
+        assert gw_f.shape == w.shape
+        # bf16: the reference's gradients are float32 products rounded
+        # once, the walk's are products of a bf16 delta
+        tol = 1e-5 if dtype == jnp.float32 else 2e-2
+        np.testing.assert_allclose(float(l_f), float(l_r), rtol=tol)
+        for got, want in ((gh_f, gh_r), (gw_f, gw_r)):
+            want = np.asarray(want, np.float32)
+            np.testing.assert_allclose(
+                np.asarray(got, np.float32), want, rtol=tol,
+                atol=tol * np.abs(want).max())
+
+    @pytest.mark.parametrize("reduction", ["mean", "sum"])
+    def test_upstream_scale_scales_both_gradients(self, reduction):
+        from paddle_tpu.nn.functional.loss import fused_linear_cross_entropy
+        h, w, lbl, _ = self._case(11, ignore=True)
+        f = lambda s: jax.grad(
+            lambda a, b: s * fused_linear_cross_entropy(
+                a, b, lbl, chunk_size=4, reduction=reduction),
+            argnums=(0, 1))(h, w)
+        for one, three in zip(f(1.0), f(3.0)):
+            assert float(jnp.abs(one).max()) > 0
+            np.testing.assert_allclose(np.asarray(three),
+                                       3.0 * np.asarray(one), rtol=1e-6)
+
+    @pytest.mark.parametrize("reduction", ["mean", "sum", "none"])
+    def test_every_label_ignored(self, reduction):
+        from paddle_tpu.nn.functional.loss import fused_linear_cross_entropy
+        h, w, _, _ = self._case(12)
+        lbl = jnp.full((h.shape[0],), -100)
+        loss, grads = jax.value_and_grad(
+            lambda a, b: fused_linear_cross_entropy(
+                a, b, lbl, chunk_size=4, reduction=reduction).sum(),
+            argnums=(0, 1))(h, w)
+        assert float(loss) == 0.0
+        assert all(float(jnp.abs(g).max()) == 0.0 for g in grads)
+
+    @pytest.mark.parametrize("t,v,rows", [
+        (16384, 92544, 2048),       # train-1chip's head: 723 MiB of logits
+        (16384, 32768, 8192),       # 1 GiB exactly
+        (1000, 92544, 1000),        # fewer rows than one chunk: one chunk
+    ])
+    def test_default_rows_by_rule(self, t, v, rows):
+        from paddle_tpu.nn.functional.loss import _ce_chunk_rows
+        assert _ce_chunk_rows(t, v) == rows
+        assert _ce_chunk_rows(t, v, 64) == 64       # a given value is rows
+
+    # -- the mechanism, held by the program's structure (it always engages,
+    # so a counter would be a constant)
+
+    @staticmethod
+    def _walk(jaxpr, scan_len=None):
+        """(equation, length of the innermost enclosing scan) for every
+        equation, sub-jaxprs included."""
+        for eqn in jaxpr.eqns:
+            yield eqn, scan_len
+            inner = eqn.params.get("length") \
+                if eqn.primitive.name == "scan" else scan_len
+            for p in eqn.params.values():
+                for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from \
+                            TestFusedLinearCrossEntropy._walk(sub, inner)
+
+    @pytest.mark.parametrize("what,dots", [
+        ("value_and_grad_mean", 3), ("value_and_grad_sum", 3),
+        ("primal_mean", 1), ("value_and_grad_none", 4)])
+    def test_head_products_and_no_full_logits(self, what, dots):
+        from paddle_tpu.nn.functional.loss import fused_linear_cross_entropy
+        T, d, V, rows = 24, 16, 200, 8
+        h, w, lbl, _ = self._case(13, T=T, d=d, V=V)
+        reduction = what.rsplit("_", 1)[1]
+        f = lambda a, b: fused_linear_cross_entropy(
+            a, b, lbl, chunk_size=rows, reduction=reduction).sum()
+        if what.startswith("value_and_grad"):
+            f = jax.value_and_grad(f, argnums=(0, 1))
+        eqns = list(self._walk(jax.make_jaxpr(f)(h, w).jaxpr))
+        # every head product sits in a scan over the T / rows chunks: a
+        # reduced loss makes logits, dh and dW in ONE walk and its backward
+        # multiplies nothing; "none" walks again in its backward
+        found = [n for e, n in eqns if e.primitive.name == "dot_general"]
+        assert found == [T // rows] * dots, found
+        scans = [e for e, _ in eqns if e.primitive.name == "scan"]
+        assert len(scans) == (2 if what == "value_and_grad_none" else 1)
+        biggest = max((int(np.prod(v.aval.shape)) for e, _ in eqns
+                       for v in e.outvars if hasattr(v.aval, "shape")),
+                      default=0)
+        assert biggest < T * V, biggest         # no [T, V] value anywhere
+
+    def test_vocab_sharded_head_on_four_devices(self):
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from paddle_tpu.nn.functional.loss import fused_linear_cross_entropy
+        if len(jax.devices()) < 4:
+            pytest.skip("needs four devices")
+        T, d, V = 32, 16, 256
+        h, w, lbl, _ = self._case(14, ignore=True, T=T, d=d, V=V)
+        f = jax.value_and_grad(
+            lambda a, b, c: fused_linear_cross_entropy(a, b, c,
+                                                       chunk_size=8),
+            argnums=(0, 1))
+        want = jax.jit(f)(h, w, lbl)
+        mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2),
+                    ("fsdp", "tp"))
+        put = lambda x, *spec: jax.device_put(
+            x, NamedSharding(mesh, P(*spec)))
+        # rows follow the batch's sharding, the head is vocab-sharded
+        got = jax.jit(f)(put(h, "fsdp", None), put(w, "fsdp", "tp"),
+                         put(lbl, "fsdp"))
+        for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                       rtol=2e-5, atol=1e-7)
+
 
 class TestHub:
     """paddle.hub parity (reference hapi/hub.py), local source scope."""
