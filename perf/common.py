@@ -237,8 +237,10 @@ def read_layer_metrics(bench, cell, obs, root=ROOT):
 
 
 def emit(bench, cell, *, trace, correct, attempted, failed, end_to_end,
-         layer, device, breakdown=None):
-    """The result line: the contract's one JSON object, last on stdout."""
+         layer, device, breakdown=None, check=None):
+    """The result line: the contract's one JSON object, last on stdout,
+    the numbers ``check`` compared last in it (``checks``: each with its
+    limit) and, a line each, last on stderr."""
     dev = f"{device['kind']} x{device['count']}"
     if trace:
         metrics = layer
@@ -257,5 +259,12 @@ def emit(bench, cell, *, trace, correct, attempted, failed, end_to_end,
             "failed": int(failed), "metrics": metrics, "device": device}
     if trace and breakdown:
         line["breakdown"] = breakdown
+    rows = check.rows if check is not None else []
+    line["checks"] = {name: {"value": value, "limit": limit}
+                      for name, value, limit, _ in rows}
     sys.stdout.flush()
     print(json.dumps(line), flush=True)
+    for name, value, limit, ok in rows:
+        print(f"check {name}: {value:.6g} (limit {limit:g}) "
+              f"{'ok' if ok else 'FAILED'}", file=sys.stderr)
+    sys.stderr.flush()

@@ -152,6 +152,17 @@ def live_kv_tokens(recs, lo, hi, since="first_token", block=1, n=400):
     return live
 
 
+def live_rows(recs, lo, hi, n=400):
+    """At the same instants: the requests between their first token and
+    their retirement, the rows a decode step carries."""
+    ts = np.linspace(lo, hi, n)
+    live = np.zeros(n)
+    for r in recs:
+        if r["ok"] and r["retired"] > r["first_token"]:
+            live += (ts >= r["first_token"]) & (ts <= r["retired"])
+    return live
+
+
 def run(bench, cell, args, t_start, control=None):
     device = common.require_device(cell["chips"])[0]
     from paddle_tpu import compile_cache
@@ -246,6 +257,8 @@ def run(bench, cell, args, t_start, control=None):
                "untraced_until": trace_at[0] - TRACE_SETTLE_S,
                "live_kv_tokens": float(live_kv_tokens(
                    recs, prof["begin"], prof["end"]).mean()),
+               "live_rows": float(live_rows(
+                   recs, prof["begin"], prof["end"]).mean()),
                "held_kv_tokens_peak": float(live_kv_tokens(
                    recs, 0.0, args.seconds, "admitted",
                    traffic["system"]["engine"]["kv_block_size"]).max()),
@@ -258,5 +271,5 @@ def run(bench, cell, args, t_start, control=None):
                 attempted=len(recs), failed=failed, end_to_end=e2e,
                 layer=layer,
                 device=common.device_info([device], device_extra),
-                breakdown=breakdown)
+                breakdown=breakdown, check=check)
     return 0

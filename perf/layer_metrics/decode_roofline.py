@@ -1,7 +1,8 @@
 """The decode program's share of its memory roofline, %: the bytes a step
-must read (every block weight and the head once, the keys and values of
-the live contexts; perf/flops.py) over the chip's HBM bandwidth, over the
-median device time of the decode program."""
+must move (the architecture's count, perf/flops.py: every block weight
+and the head once, the keys and values of the live contexts, and whatever
+turns on the rows that were decoding) over the chip's HBM bandwidth, over
+the median device time of the decode program."""
 import numpy as np
 
 from perf import flops, trace_reduce
@@ -14,6 +15,7 @@ def read(obs):
     if not ds:
         return None
     need = flops.decode_step_bytes(obs["cell"]["config"],
-                                   obs["live_kv_tokens"])
+                                   obs["live_kv_tokens"],
+                                   live_rows=obs.get("live_rows"))
     least_s = need / obs["peaks"]["hbm_bytes_per_s"]
     return 100.0 * least_s / (float(np.median(ds)) / 1e9)

@@ -33,14 +33,10 @@ SPAN_PREFIXES = ("serving.", "train.")
 PHASES = ("serving.schedule", "serving.admit", "serving.build",
           "serving.dispatch", "serving.sync", "serving.emit")
 OUTSIDE = "unannotated"         # idle_gaps' label for "under no span"
-# an operation fused across two scopes goes to the first of these
+# the program's scopes, for a reader that names no tuple of its own; an
+# operation fused across two scopes goes to the first of the tuple
 SCOPES = ("lm_head_ce", "attn", "mlp", "optimizer", "embed")
 UNSCOPED = "unscoped"
-# a scope is one component of an ``op_name`` path, bare or inside the
-# transformations' wrappers (``transpose(jvp(attn))``); a function or a
-# parameter that happens to hold the word (``jit(mlp)``,
-# ``model.layers_0.mlp.up_proj.weight``) is not
-_SCOPE = re.compile(r"^(?:(?!p?jit\()[a-z_]+\()*(%s)\)*$" % "|".join(SCOPES))
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%([^\s=]+)\s*=")
 _COMPUTATION = re.compile(r"^\s*(?:ENTRY\s+)?%([^\s(]+)\s*\(.*\{\s*$")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
@@ -132,12 +128,24 @@ def idle_share_under(obs, phase):
 
 # -- device time by scope and kernel ------------------------------------------
 
-@functools.lru_cache(maxsize=4)
-def scope_by_instruction(hlo_text):
+@functools.lru_cache(maxsize=None)
+def _scope_pattern(scopes):
+    """A scope is one component of an ``op_name`` path, bare or inside
+    the transformations' wrappers (``transpose(jvp(attn))``); a function
+    or a parameter that happens to hold the word (``jit(mlp)``,
+    ``model.layers_0.mlp.up_proj.weight``) is not."""
+    return re.compile(r"^(?:(?!p?jit\()[a-z_]+\()*(%s)\)*$"
+                      % "|".join(map(re.escape, scopes)))
+
+
+@functools.lru_cache(maxsize=8)
+def scope_by_instruction(hlo_text, scopes=SCOPES):
     """{instruction: scope} from a compiled program's text: the first of
-    SCOPES among the ``op_name``s of the instruction and, for a fusion,
-    of every instruction it holds.  Instructions under no scope are left
-    out; an empty result says the program carries no scope at all."""
+    ``scopes`` (a tuple; an architecture's reader passes its own) among
+    the ``op_name``s of the instruction and, for a fusion, of every
+    instruction it holds.  Instructions under no scope are left out; an
+    empty result says the program carries no scope at all."""
+    scope_of = _scope_pattern(scopes).match
     own, calls, held = {}, {}, defaultdict(set)
     comp = None
     for line in hlo_text.split("\n"):
@@ -151,7 +159,7 @@ def scope_by_instruction(hlo_text):
         found = set()
         for op_name in _OP_NAME.findall(line):
             found.update(hit.group(1) for hit in map(
-                _SCOPE.match, op_name.split("/")) if hit)
+                scope_of, op_name.split("/")) if hit)
         own[m.group(1)] = found
         held[comp] |= found
         called = _CALLS.search(line)
@@ -160,20 +168,20 @@ def scope_by_instruction(hlo_text):
     out = {}
     for name, found in own.items():
         found = found | held.get(calls.get(name), set())
-        for scope in SCOPES:
+        for scope in scopes:
             if scope in found:
                 out[name] = scope
                 break
     return out
 
 
-def program_scopes(obs, program):
+def program_scopes(obs, program, scopes=SCOPES):
     """scope_by_instruction of ``obs["programs"][program]``; None where
-    there is no such program or it carries no scope."""
+    there is no such program or it carries none of ``scopes``."""
     prog = (obs.get("programs") or {}).get(program)
     if prog is None:
         return None
-    return scope_by_instruction(prog.as_text()) or None
+    return scope_by_instruction(prog.as_text(), scopes) or None
 
 
 def kernel_of(name):

@@ -7,7 +7,8 @@ program's arrays, makes its own copy a leaf or a layer at a time from the
 same keys (``make_some``).  Which leaves there are, in which order and
 with which initialiser, is the architecture's to say (perf/archs/): the
 names are the state-dict names of the program's model class, and the
-reference reads the same names.
+reference reads the same names.  An initialiser is one of the three kinds
+here or one of the architecture's own (its ``INITS``).
 """
 
 from __future__ import annotations
@@ -21,11 +22,19 @@ from perf import common
 
 MATRIX_STD = 0.02       # GPT-2 / Llama-style initialiser scale
 NORM_JITTER = 0.1       # norm gains 1 + 0.1 n: an all-ones gain hides it
+KINDS = ("gain", "matrix", "vector")    # _leaf's own; any other name is
+# looked for in the architecture's INITS
 
 
 def leaves(cfg):
-    """[(name, shape, init)] in a fixed order; a leaf's index is its key."""
-    return common.arch_of(cfg).leaves(cfg)
+    """[(name, shape, init)] in a fixed order; a leaf's index is its key.
+    ``init`` is a kind of ``_leaf``'s or, for a kind of the architecture's
+    own (``INITS = {kind: fn(key, shape) -> float32 array}`` in its
+    file), that function."""
+    arch = common.arch_of(cfg)
+    own = getattr(arch, "INITS", {})
+    return [(n, s, k if k in KINDS else own.get(k, k))
+            for n, s, k in arch.leaves(cfg)]
 
 
 def base_key(seed: int):
@@ -35,13 +44,16 @@ def base_key(seed: int):
 
 
 def _leaf(key, index, shape, init, dtype):
-    """``init``: ``gain`` (a norm's, 1 + 0.1 n), ``matrix`` (0.02 n) or
-    ``vector`` (a bias or a sink logit: 0.02 n, whatever its shape)."""
-    n = jax.random.normal(jax.random.fold_in(key, index), shape,
-                          jnp.float32)
+    """``init``: ``gain`` (a norm's, 1 + 0.1 n), ``matrix`` (0.02 n),
+    ``vector`` (a bias or a sink logit: 0.02 n, whatever its shape) or an
+    architecture's own function of the leaf's key and shape."""
+    key = jax.random.fold_in(key, index)
+    if callable(init):
+        return init(key, shape).astype(dtype)
+    n = jax.random.normal(key, shape, jnp.float32)
     if init == "gain":
         return (1.0 + NORM_JITTER * n).astype(dtype)
-    if init not in ("matrix", "vector"):
+    if init not in KINDS:
         raise ValueError(f"unknown initialiser {init!r}")
     return (MATRIX_STD * n).astype(dtype)
 

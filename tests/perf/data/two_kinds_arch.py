@@ -1,9 +1,14 @@
 """A toy architecture for tests/perf: ``gqa_decoder`` whose odd layers
 end in a learned shift of the residual stream, ``x + shift`` — a second
-kind of layer, carrying a 1-D leaf that is no norm gain.  The test copies
-this file to perf/archs/two_kinds.py of a scratch checkout; nothing in
-perf/ knows it.  It serves only (no ``loss``).
+kind of layer, carrying a 1-D leaf that is no norm gain, drawn by an
+initialiser of this file's own (``INITS``) and added under a scope of
+this file's own (``shift``; its decode count turns on the live rows).
+The tests copy this file into perf/archs/ of a scratch checkout; nothing
+in perf/ knows it.  It serves only (no ``loss``).
 """
+
+import jax
+import jax.numpy as jnp
 
 from perf import common, weights
 
@@ -16,11 +21,13 @@ layer_matmul_params, matmul_params = \
     _base.layer_matmul_params, _base.matmul_params
 train_flops_per_token = _base.train_flops_per_token
 kv_bytes_per_token = _base.kv_bytes_per_token
-decode_step_bytes = _base.decode_step_bytes
+
+# 0.02 log U(1, 16): no normal draw gives it, and weights.py has no such
+INITS = {"log_uniform": lambda key, shape: weights.MATRIX_STD * jnp.log(
+    jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))}
 
 
 def build(cfg, seed, device):
-    import jax
     import paddle_tpu as pp
     from paddle_tpu.models import LlamaForCausalLM
     from paddle_tpu.models.llama import LlamaDecoderLayer
@@ -33,9 +40,10 @@ def build(cfg, seed, device):
 
         def forward(self, x, *args, **kwargs):
             y = super().forward(x, *args, **kwargs)
-            if isinstance(y, tuple):        # (hidden, the layer's cache)
-                return y[0] + self.shift, y[1]
-            return y + self.shift
+            with jax.named_scope("shift"):
+                if isinstance(y, tuple):    # (hidden, the layer's cache)
+                    return y[0] + self.shift, y[1]
+                return y + self.shift
 
     mcfg = _base.program_config(cfg)
     pp.seed(common.seed_key(seed))
@@ -57,7 +65,7 @@ def layer_leaves(cfg, i):
     out = _base.layer_leaves(cfg, i)
     if i % 2:
         out.append((layer_prefix(i) + "shift", (cfg["hidden_size"],),
-                    "vector"))
+                    "log_uniform"))
     return out
 
 
@@ -75,4 +83,13 @@ def layer(x, w, cfg, i, positions, precision="float32"):
 
 def total_params(cfg):
     return _base.total_params(cfg) + \
+        cfg["num_hidden_layers"] // 2 * cfg["hidden_size"]
+
+
+def decode_step_bytes(cfg, live_kv_tokens, itemsize=2, *, live_rows,
+                      **observed):
+    """The base's, and each live row's hidden state read and written
+    once by every shifted layer."""
+    return _base.decode_step_bytes(cfg, live_kv_tokens, itemsize) + \
+        live_rows * 2 * itemsize * \
         cfg["num_hidden_layers"] // 2 * cfg["hidden_size"]

@@ -134,3 +134,98 @@ def test_reference_rows_over_the_chips_give_the_one_at_a_time_walk():
         train_steps.follow(
             cfg, 9, [{k: v[:3] for k, v in b.items()} for b in batches], HP,
             sharding=train._leaf_sharding(jax.devices()[:4]))
+
+
+# -- what an architecture file may say beyond gqa_decoder's ---------------------
+
+@pytest.fixture()
+def toy_arch(monkeypatch):
+    """data/two_kinds_arch.py, for every configuration that names
+    ``two_kinds`` (no such file is under perf/archs/)."""
+    toy = common.load_by_path(os.path.join(DATA, "two_kinds_arch.py"),
+                              "toy_arch")
+    real = common.arch_of
+    monkeypatch.setattr(common, "arch_of", lambda cfg: toy if cfg.get(
+        "arch") == "two_kinds" else real(cfg))
+    return toy
+
+
+def test_decode_roofline_hands_the_count_the_live_rows(toy_arch):
+    """gqa_decoder's count ignores the live rows, so the accepted cell
+    reads what it read from ``live_kv_tokens`` alone; a count that turns
+    on them is handed them."""
+    from perf import trace_reduce as tr
+    read = common.load_by_path(os.path.join(
+        ROOT, "perf", "layer_metrics", "decode_roofline.py"),
+        "perf_layer_metric").read
+    name, tpu, ms = "mistral-7b-v0.3.L12", "/device:TPU:0", 1e6
+    cfg = _config(name)
+    obs = {"cell": {"config": cfg}, "live_kv_tokens": 12200.0,
+           "peaks": {"hbm_bytes_per_s": 819e9},
+           "trace": tr.Trace({tpu: [("fusion.1", 0, 8 * ms)]},
+                             {tpu: [("jit_decode_paged(1)", 0, 8 * ms)]},
+                             [("bench.engine_step", 0, 9 * ms)])}
+    share = lambda nbytes: 100.0 * nbytes / 819e9 / 8e-3
+    want = share(FROZEN[name]["decode_step_bytes_12200"])
+    assert read(obs) == read(dict(obs, live_rows=20.0)) == \
+        pytest.approx(want)
+    toy = dict(obs, cell={"config": dict(cfg, arch="two_kinds")})
+    few, many = (read(dict(toy, live_rows=n)) for n in (4.0, 20.0))
+    # a row's hidden state read and written by each of the 6 odd layers
+    assert few == pytest.approx(want + share(4 * 2 * 2 * 6 * 4096))
+    assert many - few == pytest.approx(share(16 * 2 * 2 * 6 * 4096))
+    with pytest.raises(TypeError):      # it needs them: none is no zero
+        read(toy)
+
+
+def test_live_rows_are_the_requests_between_first_token_and_retirement():
+    from perf.kinds import serve
+    req = lambda a, b, ok=True: {"ok": ok, "first_token": a, "retired": b}
+    recs = [req(0.0, 0.5), req(0.2, 1.0), req(0.3, 0.3), req(0.0, 1.0, False)]
+    rows = serve.live_rows(recs, 0.0, 1.0, n=11)    # 0.0, 0.1, ... 1.0
+    assert rows.tolist() == [1, 1, 2, 2, 2, 2, 1, 1, 1, 1, 1]
+    # the instants and the requests live_kv_tokens counts
+    held = serve.live_kv_tokens(
+        [dict(r, prompt=[0] * 8, tokens=[0] * 4) for r in recs[:2]],
+        0.0, 1.0, n=11)
+    assert ((held > 0) == (rows > 0)).all()
+
+
+@pytest.mark.parametrize("kind,ok", [("log_uniform", True), ("matrix", True),
+                                     ("uniform", False)])
+def test_an_initialiser_can_be_the_architectures(toy_arch, kind, ok):
+    """``weights.leaves`` hands ``_leaf`` the architecture's own function
+    for a kind ``weights.py`` has not; the three kinds stay ``_leaf``'s,
+    and a kind neither knows is an error.  The key stays the leaf's index
+    in ``leaves(cfg)``."""
+    import jax.numpy as jnp
+    cfg = dict(_config("mistral-7b-v0.3.L12"), arch="two_kinds",
+               **SMALL["mistral-7b-v0.3.L12"])
+    name = "model.layers_1.shift"
+    toy_arch.layer_leaves = lambda cfg, i, real=toy_arch.layer_leaves: [
+        (n, s, kind if n.endswith(".shift") else k)
+        for n, s, k in real(cfg, i)]
+    spec = {n: (i, s, k) for i, (n, s, k) in enumerate(weights.leaves(cfg))}
+    assert [n for n in spec if n.endswith(".shift")] == [name]   # 3 layers
+    index, shape, init = spec[name]
+    if not ok:
+        with pytest.raises(ValueError, match="unknown initialiser 'uniform'"):
+            weights.make_some(cfg, SEED, [name], jnp.float32)
+        return
+    got = np.asarray(weights.make_some(cfg, SEED, [name], jnp.float32)[name])
+    one = np.asarray(weights._leaf(weights.base_key(SEED), index, shape,
+                                   init, jnp.float32))
+    assert got.shape == (64,)
+    np.testing.assert_allclose(got, one, rtol=1e-6)     # jitted or not
+    if kind == "matrix":
+        assert init == "matrix" and abs(got.mean()) < 0.01
+    else:       # 0.02 log U(1, 16): positive, below 0.02 log 16
+        assert init is toy_arch.INITS[kind]
+        assert 0 <= got.min() and got.max() <= 0.02 * np.log(16.0)
+        assert got.std() > 0.01
+    # its neighbours are what they were without the kind
+    near = "model.layers_1.mlp.down_proj.weight"
+    np.testing.assert_allclose(
+        weights.make_some(cfg, SEED, [near], jnp.float32)[near],
+        weights._leaf(weights.base_key(SEED), spec[near][0], spec[near][1],
+                      "matrix", jnp.float32), rtol=1e-6, atol=1e-9)

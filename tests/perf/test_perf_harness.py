@@ -1,8 +1,11 @@
 """BENCHMARK.json against the benchmark's contract, and the harness's
 promise that a new cell, of an architecture it has or of a new one, is new
-files and new entries only."""
+files and new entries only — on the accepted benchmark and on one that
+already holds an accepted cell of another architecture (conftest.py's
+``tree``, ``bench`` and ``copy``)."""
 
 import argparse
+import functools
 import hashlib
 import json
 import math
@@ -23,20 +26,14 @@ WIDTH = re.compile(r"(hidden|intermediate|latent|state|proj).*size|_dim$|"
                    r"_rank$|head_dim|expand|experts_per_tok")
 
 
-@pytest.fixture(scope="module")
-def bench():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        return json.load(f)
-
-
 def _cells_of(metric, bench):
     return metric.get("workloads", [w["name"] for w in bench["workloads"]])
 
 
-def test_keys_names_and_units(bench):
+def test_keys_names_and_units(bench, tree):
     assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
-    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) < 64 * 1024
+    assert os.path.getsize(os.path.join(tree, "BENCHMARK.json")) < 64 * 1024
     assert 1 <= bench["run_seconds"] <= 51
     line = lambda s: 1 <= len(s) <= 200 and "\n" not in s and "\t" not in s
     assert all(line(w) for w in bench["command"])
@@ -70,7 +67,7 @@ def test_keys_names_and_units(bench):
         assert line(m["layer"])
 
 
-def test_files_exist_and_metrics_connect(bench):
+def test_files_exist_and_metrics_connect(bench, tree):
     cells = {w["name"]: w for w in bench["workloads"]}
     configs = {c["name"]: c for c in bench["configs"]}
     assert len({c["file"] for c in bench["configs"]}) == len(configs)
@@ -79,7 +76,7 @@ def test_files_exist_and_metrics_connect(bench):
     assert len(pairs) == len(set(pairs))
     for c in configs.values():
         assert any(c["file"].startswith(p + "/") for p in bench["paths"])
-        with open(os.path.join(ROOT, c["file"])) as f:
+        with open(os.path.join(tree, c["file"])) as f:
             conf = json.load(f)
         assert conf["source"] == c["source"]
         # every cut is listed, and is a cut of the published value
@@ -89,14 +86,14 @@ def test_files_exist_and_metrics_connect(bench):
     assert "setup_s" in e2e and "workloads" not in e2e["setup_s"]
     for name, w in cells.items():
         assert os.path.isfile(os.path.join(
-            ROOT, "perf", "traffic", w["traffic"] + ".json"))
+            tree, "perf", "traffic", w["traffic"] + ".json"))
         mine = [m["name"] for m in e2e.values()
                 if name in _cells_of(m, bench)]
         assert len(mine) >= 2 and "setup_s" in mine
         assert any(name in _cells_of(m, bench) for m in bench["per_layer"])
     for m in bench["per_layer"]:
         assert os.path.isfile(os.path.join(
-            ROOT, "perf", "layer_metrics", m["name"] + ".py")), m["name"]
+            tree, "perf", "layer_metrics", m["name"] + ".py")), m["name"]
         assert m["moves"] in e2e
         for cell in _cells_of(m, bench):
             assert cell in _cells_of(e2e[m["moves"]], bench), (m, cell)
@@ -122,19 +119,6 @@ def _digest(root):
                 out[os.path.relpath(p, root)] = \
                     hashlib.sha256(fh.read()).hexdigest()
     return out
-
-
-@pytest.fixture()
-def copy(tmp_path, bench):
-    """BENCHMARK.json and the files under ``paths``, nothing else."""
-    root = tmp_path / "checkout"
-    root.mkdir()
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
-    for p in bench["paths"]:
-        shutil.copytree(os.path.join(ROOT, p), root / p,
-                        ignore=shutil.ignore_patterns(
-                            ".cache", ".trace", "__pycache__"))
-    return root
 
 
 def test_a_new_cell_is_new_files_and_entries_only(copy, bench):
@@ -175,86 +159,94 @@ def test_a_new_cell_is_new_files_and_entries_only(copy, bench):
     assert changed == {"BENCHMARK.json"}, changed
 
 
-def _add_cell(copy, bench, name, conf, traffic, moves):
-    """A configuration, a traffic mix and a cell of them, as files and
-    entries of the copy; the cell reports ``moves`` and ``setup_s``."""
-    (copy / f"perf/configs/{name}.json").write_text(json.dumps(conf))
-    (copy / f"perf/traffic/{name}.json").write_text(json.dumps(traffic))
-    b = json.loads(json.dumps(bench))
-    b["configs"].append({"name": name, "source": conf["source"],
-                         "file": f"perf/configs/{name}.json",
-                         "reduced": ["num_hidden_layers"], "why": "test"})
-    b["workloads"].append({"name": name, "config": name, "traffic": name,
-                           "chips": 1, "why": "test"})
-    for m in b["end_to_end"] + b["per_layer"]:
-        if m["name"] in moves:
-            m["workloads"].append(name)
-    (copy / "BENCHMARK.json").write_text(json.dumps(b))
+def _arch_files_named(root, listing):
+    """{cell: (the arch file its ``--list`` line names, the one its
+    configuration names: the ``arch`` key, ``gqa_decoder`` without
+    one)}, read from the files of the checkout at ``root``."""
+    b = json.load(open(os.path.join(root, "BENCHMARK.json")))
+    files = {c["name"]: c["file"] for c in b["configs"]}
+    out = {}
+    for w in b["workloads"]:
+        conf = json.load(open(os.path.join(root, files[w["config"]])))
+        line, = [ln for ln in listing.splitlines()
+                 if ln.startswith(w["name"] + ":")]
+        out[w["name"]] = (re.search(r" arch (\S+),", line).group(1),
+                          f"perf/archs/{conf.get('arch', 'gqa_decoder')}.py")
+    return out
 
 
-TOY = dict(arch="two_kinds", hidden_size=64, intermediate_size=128,
-           num_hidden_layers=4, num_attention_heads=4,
-           num_key_value_heads=2, vocab_size=256,
-           max_position_embeddings=128, torch_dtype="float32")
-TOY_PARAMS = {"rate_per_s": 20.0, "schedule_seed": 1,
-              "prompt": {"median": 20, "sigma": 0.8, "min": 8, "max": 60},
-              "output": {"median": 8, "sigma": 0.7, "min": 2, "max": 16}}
-TOY_ENGINE = {"slots": 4, "max_len": 96, "paged_kv": True,
-              "kv_block_size": 8, "prefill_chunk": 16}
-
-
-@pytest.mark.parametrize("reference", ["its own", "without the odd layers"])
+@pytest.mark.parametrize("reference,trace", [
+    ("its own", 0), ("its own", 1), ("without the odd layers", 0)],
+    ids=["its own", "its own, traced", "without the odd layers"])
 def test_a_new_architecture_is_new_files_and_entries_only(
-        copy, bench, on_cpu, capsys, monkeypatch, reference):
+        copy, bench, cells, on_cpu, capsys, monkeypatch, tmp_path,
+        reference, trace):
     """An architecture whose layers are of two kinds, the odd ones with a
-    1-D leaf that is no gain: an arch file, a configuration that names
-    it, a mix and entries.  ``--list`` names it and the serve rehearsal
-    runs it on the CPU to ``correct: true`` against its own reference —
-    and to false against a reference that knows one kind of layer."""
+    1-D leaf that is no gain, drawn by an initialiser of the arch file's
+    own and added under a scope of its own: an arch file, a configuration
+    that names it, a mix, a reader that passes its own scopes, and
+    entries.  ``--list`` names, for every cell, the arch file its
+    configuration names, and the serve rehearsal runs the cell on the CPU
+    to ``correct: true`` against its own reference — and to false against
+    a reference that knows one kind of layer.  Traced, the reader finds
+    the scope in the compiled decode program and the decode roofline's
+    count is handed the live rows it turns on."""
     from perf import common
     from perf.kinds import serve
     before = _digest(copy)
-    shutil.copy(os.path.join(ROOT, "tests/perf/data/two_kinds_arch.py"),
-                copy / "perf/archs/two_kinds.py")
-    conf = json.load(open(copy / "perf/configs/mistral-7b-v0.3.L12.json"))
-    mix = json.load(open(copy / "perf/traffic/chat-open-0.8.json"))
-    mix["params"], mix["system"] = TOY_PARAMS, {"engine": TOY_ENGINE}
-    _add_cell(copy, bench, "toy", dict(conf, **TOY), mix,
-              ("ttft_p95_ms", "tpot_p95_ms", "serve_tokens_per_s",
-               "cache_misses.setup"))
+    shutil.copy(os.path.join(ROOT, "tests/perf/data/shift_ops.tpot.py"),
+                copy / "perf/layer_metrics/shift_ops.tpot.py")
+    b = cells.add_toy(copy, bench, "toy", "toy_kinds", (
+        "ttft_p95_ms", "tpot_p95_ms", "serve_tokens_per_s",
+        "cache_misses.setup", "decode_roofline"))
+    b["per_layer"].append({
+        "name": "shift_ops.tpot", "unit": "count", "better": "lower",
+        "source": "program_counter", "layer": "kernels",
+        "moves": "tpot_p95_ms", "workloads": ["toy"]})
+    (copy / "BENCHMARK.json").write_text(json.dumps(b))
     r = _run(copy, "--list")
     assert r.returncode == 0, r.stderr
-    line = [ln for ln in r.stdout.splitlines() if ln.startswith("toy:")]
-    assert line and "arch perf/archs/two_kinds.py" in line[0]
-    assert all("arch perf/archs/gqa_decoder.py" in ln
-               for ln in r.stdout.splitlines() if not ln.startswith("toy:"))
+    named = _arch_files_named(copy, r.stdout)
+    assert set(named) == {w["name"] for w in b["workloads"]}
+    assert all(listed == own for listed, own in named.values()), named
+    assert named["toy"][0] == "perf/archs/toy_kinds.py"
+    assert named["serve-chat"][0] == "perf/archs/gqa_decoder.py"
 
     # the rehearsal, in this process, finding the copy's files by name
     monkeypatch.setattr(common, "ROOT", str(copy))
+    monkeypatch.setattr(common, "read_layer_metrics", functools.partial(
+        common.read_layer_metrics, root=str(copy)))
+    monkeypatch.setattr(common, "TRACE_DIR", str(tmp_path / "trace"))
     monkeypatch.setattr(serve, "WARM_PROMPTS", (20, 9))
-    b = common.load_json(str(copy / "BENCHMARK.json"))
+    monkeypatch.setattr(serve, "TRACE_SECONDS", 0.8)
+    monkeypatch.setattr(serve, "TRACE_SETTLE_S", 0.2)
     cell = common.resolve_cell(b, "toy", str(copy))
     arch = common.arch_of(cell["config"])
-    assert arch.__file__ == str(copy / "perf/archs/two_kinds.py")
+    assert arch.__file__ == str(copy / "perf/archs/toy_kinds.py")
     kinds = [k for _, _, k in arch.layer_leaves(cell["config"], 1)]
-    assert kinds.count("vector") == 1 and "vector" not in [
+    assert kinds.count("log_uniform") == 1 and "log_uniform" not in [
         k for _, _, k in arch.layer_leaves(cell["config"], 0)]
     if reference != "its own":
         monkeypatch.setattr(arch, "layer", common.arch_of({}).layer)
-    args = argparse.Namespace(seed=2 ** 31 + 78, seconds=2.0, trace=0)
+    args = argparse.Namespace(seed=2 ** 31 + 78, seconds=2.0, trace=trace)
     assert serve.run(b, cell, args, time.perf_counter()) == 0
     out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert out["attempted"] == 40 and out["failed"] == 0
     assert out["correct"] is (reference == "its own")
+    if trace:
+        assert set(out["metrics"]) == {"cache_misses.setup",
+                                       "decode_roofline", "shift_ops.tpot"}
+        assert out["metrics"]["shift_ops.tpot"]["value"] >= 2   # two layers
     after = _digest(copy)
     changed = {k for k in before if after.get(k) != before[k]}
     assert changed == {"BENCHMARK.json"}, changed
 
 
-def test_an_architecture_with_no_file_is_no_run(copy, bench, tmp_path):
+def test_an_architecture_with_no_file_is_no_run(copy, bench, cells,
+                                                tmp_path):
     conf = json.load(open(copy / "perf/configs/internlm2-1.8b.L4.json"))
     mix = json.load(open(copy / "perf/traffic/lm-16k.json"))
-    _add_cell(copy, bench, "lost", dict(conf, arch="nowhere"), mix,
+    cells.add(copy, bench, "lost", dict(conf, arch="nowhere"), mix,
               ("train_tokens_per_s_per_chip", "cache_misses.setup"))
     path = str(copy / "perf/archs/nowhere.py")
     for args in (("--list",), ("--workload", "lost", "--seed", "1",
@@ -265,12 +257,12 @@ def test_an_architecture_with_no_file_is_no_run(copy, bench, tmp_path):
         assert not any(ln.startswith("{") for ln in r.stdout.splitlines())
 
 
-def _perf_sources():
-    for d, _, files in os.walk(os.path.join(ROOT, "perf")):
+def _perf_sources(root):
+    for d, _, files in os.walk(os.path.join(root, "perf")):
         for f in files:
             if f.endswith(".py"):
                 p = os.path.join(d, f)
-                yield os.path.relpath(p, ROOT), open(p).read()
+                yield os.path.relpath(p, root), open(p).read()
 
 
 MODEL = re.compile(r"paddle_tpu\.models|LlamaForCausalLM|LlamaConfig")
@@ -281,14 +273,14 @@ SHARED = re.compile(r"from perf\.reference\.decoder import "
                     r"(adamw|matmul|_q8)(, (adamw|matmul|_q8))*$")
 
 
-def test_only_an_arch_file_knows_a_model():
+def test_only_an_arch_file_knows_a_model(tree):
     """Nothing under perf/ outside perf/archs/ imports the program's
     models, names a model class or reads a width that only some
     architectures have; perf/reference/decoder.py (gqa_decoder's
     reference) is reached through perf/archs/gqa_decoder.py alone, its
     shared ``adamw`` / ``matmul`` controls excepted."""
     seen = 0
-    for path, text in _perf_sources():
+    for path, text in _perf_sources(tree):
         if path.startswith("perf/archs/"):
             continue
         seen += 1
@@ -298,7 +290,7 @@ def test_only_an_arch_file_knows_a_model():
         for line in DECODER.findall(text):
             assert SHARED.search(line.strip()), (path, line)
     assert seen > 40
-    gqa = open(os.path.join(ROOT, "perf/archs/gqa_decoder.py")).read()
+    gqa = open(os.path.join(tree, "perf/archs/gqa_decoder.py")).read()
     assert MODEL.search(gqa) and DECODER.search(gqa)
 
 

@@ -58,6 +58,12 @@ def test_train_cell(on_cpu, capsys, name, trace):
     out = _result(capsys)
     assert out["correct"] is True and out["failed"] == 0
     assert out["device"]["count"] == cell["chips"]
+    # each number compared, beside its limit, last in the line; the
+    # second step's loss is printed and not compared (PERF.md section 2)
+    assert list(out)[-1] == "checks" and list(out["checks"]) == [
+        "loss_step1_rel", "grad_norm_worst_leaf", "grad_norm_mean_leaf",
+        "delta_norm_worst_leaf"]
+    assert all(c["value"] <= c["limit"] for c in out["checks"].values())
     want = [m["name"] for m in common.metrics_of(
         bench, "per_layer" if trace else "end_to_end", cell["name"])]
     missing = set(want) - set(out["metrics"])
@@ -91,7 +97,15 @@ def test_train_step_that_leaves_its_state_unchanged_is_not_correct(
     monkeypatch.setattr(train, "build", lambda *a: Frozen(real(*a)))
     bench, cell = _cell("train-1chip", params={"batch": 4, "seq": 32})
     train.run(bench, cell, _args(), time.perf_counter())
-    assert _result(capsys)["correct"] is False
+    captured = capsys.readouterr()
+    out = json.loads(captured.out.strip().splitlines()[-1])
+    assert out["correct"] is False
+    # the parameters' change reads 1: said in the line and on stderr
+    delta = out["checks"]["delta_norm_worst_leaf"]
+    assert delta["value"] == pytest.approx(1.0, abs=1e-3)
+    assert delta["value"] > delta["limit"]
+    assert captured.err.strip().splitlines()[-1].startswith(
+        "check delta_norm_worst_leaf: ") and "FAILED" in captured.err
 
 
 def test_train_part_of_the_batch_left_out_is_not_correct(
@@ -122,8 +136,8 @@ SERVE = dict(
     params={"rate_per_s": 20.0, "schedule_seed": 1,
             "prompt": {"median": 20, "sigma": 0.8, "min": 8, "max": 60},
             "output": {"median": 8, "sigma": 0.7, "min": 2, "max": 16}},
-    system={"engine": {"slots": 4, "max_len": 96, "paged_kv": True,
-                       "kv_block_size": 8, "prefill_chunk": 16}})
+    system={"engine": {"slots": 4, "max_len": 96, "kv_block_size": 8,
+                       "prefill_chunk": 16}})
 
 
 @pytest.mark.parametrize("trace", [0, 1])
@@ -138,6 +152,8 @@ def test_serve_cell(on_cpu, capsys, monkeypatch, trace):
     out = _result(capsys)
     assert out["correct"] is True
     assert out["attempted"] == 40 and out["failed"] == 0
+    assert list(out)[-1] == "checks" and list(out["checks"]) == [
+        "served_gap_max", "served_gap_mean", "failed_share"]
     want = [m["name"] for m in common.metrics_of(
         bench, "per_layer" if trace else "end_to_end", "serve-chat")]
     assert set(want) - set(out["metrics"]) <= {"hbm_peak_gb.tput"}
