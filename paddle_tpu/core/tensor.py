@@ -153,6 +153,8 @@ def _as_jax_array(data, dtype=None):
         return arr
     if isinstance(data, (jnp.ndarray, jax.Array)):
         return data if dtype is None else data.astype(_dtypes.to_jax(dtype))
+    if isinstance(data, jax.ShapeDtypeStruct):
+        return data     # nn.LazyGuard: a shape and a type, no array yet
     if isinstance(data, np.ndarray):
         if dtype is None and data.dtype == np.float64:
             data = data.astype(np.float32)

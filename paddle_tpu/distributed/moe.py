@@ -31,7 +31,8 @@ from paddle_tpu.distributed.mpu import constrain
 __all__ = ["top_k_gating", "NaiveGate", "SwitchGate", "GShardGate",
            "MoELayer", "ExpertFFN", "moe_shard_a2a", "moe_forward_a2a",
            "top_k_gating_indices", "moe_forward_index",
-           "moe_shard_index_a2a", "moe_forward_ragged"]
+           "moe_shard_index_a2a", "moe_forward_ragged",
+           "GatedExpertLayer", "gated_experts_forward"]
 
 
 def top_k_gating(gate_logits, k: int, capacity: int,
@@ -184,6 +185,110 @@ def moe_forward_ragged(x2d, logits, w1, b1, w2, b2, *, E: int, top_k: int,
     wf = w.reshape(-1)[order].astype(x2d.dtype)
     out = jnp.zeros((T, d), x2d.dtype).at[tok].add(ys * wf[:, None])
     return out, _gshard_aux(probs, topi, E, k), jnp.zeros((), jnp.float32)
+
+
+def gated_experts_forward(x2d, router_w, w_in, w_out, *, top_k: int,
+                          local_of, row_valid=None):
+    """The served expert layer: gated bias-free experts, top-k then
+    softmax over the chosen logits, dropless, over the experts held here.
+
+    x2d [T, d]; router_w [d, E]: the router at its published width;
+    w_in [H, d, 2f] (``[gate | up]``) and w_out [H, f, d]: the H experts
+    this chip holds; ``local_of`` [E] int32 (a constant): an expert's
+    index among the held ones, H for one held elsewhere; ``row_valid``
+    [T] bool: rows that are tokens (a padded tail and an inactive decode
+    row route nowhere, so they read no expert).
+
+    Every token is routed over all E experts; the picks that land on a
+    held expert are sorted by expert and go through two grouped products
+    (``lax.ragged_dot``: on the TPU a grouped-matmul kernel that visits
+    only the groups that have rows, so an expert no row chose is not
+    read); a pick that lands elsewhere contributes nothing here, which
+    is the part of the layer's result this chip's experts give.  Nothing
+    is dropped: there is no capacity.  Returns (out [T, d] float32,
+    counts int32 [3]: held experts with at least one row, picks that
+    landed here, picks made)."""
+    T, d = x2d.shape
+    H = w_in.shape[0]
+    logits = jnp.dot(x2d, router_w, preferred_element_type=jnp.float32)
+    topv, topi = jax.lax.top_k(logits, top_k)             # [T, k]
+    gates = jax.nn.softmax(topv, axis=-1)                 # over the chosen
+    loc = jnp.asarray(local_of, jnp.int32)[topi]          # [T, k] in [0, H]
+    if row_valid is not None:
+        loc = jnp.where(row_valid[:, None], loc, H)
+    flat = loc.reshape(-1)                                # token-major
+    order = jnp.argsort(flat)                             # stable; H last
+    # a compare-and-sum, not ``bincount``: that is a scatter-add, one
+    # update at a time on the TPU
+    sizes = jnp.sum(flat[:, None] == jnp.arange(H)[None], axis=0,
+                    dtype=jnp.int32)
+    xs = x2d[order // top_k]                              # [T*k, d]
+    gu = jax.lax.ragged_dot(xs, w_in, sizes)
+    g, u = jnp.split(gu, 2, axis=-1)
+    ys = jax.lax.ragged_dot((jax.nn.silu(g) * u).astype(x2d.dtype), w_out,
+                            sizes, preferred_element_type=jnp.float32)
+    # rows past the held picks are no expert's: ragged_dot leaves them
+    # unspecified, so they are zeroed before the gates see them
+    ys = jnp.where((flat[order] < H)[:, None], ys, 0.0)
+    back = jnp.argsort(order)                             # un-sort
+    out = jnp.einsum("tk,tkd->td", gates, ys[back].reshape(T, top_k, d))
+    picks = T * top_k if row_valid is None \
+        else jnp.sum(row_valid.astype(jnp.int32)) * top_k
+    counts = jnp.stack([jnp.sum(sizes > 0), jnp.sum(sizes),
+                        jnp.asarray(picks, jnp.int32)]).astype(jnp.int32)
+    return out, counts
+
+
+class GatedExpertLayer(Layer):
+    """A router over ``num_experts`` and the experts this chip holds
+    (``held``: their ids; default all), each ``W_out (silu(g) * u)`` with
+    ``[g | u] = W_in h`` and no bias: the form Mixtral, Granite and the
+    DeepSeek family serve.  Inference only (``gated_experts_forward``):
+    dropless, no capacity, no auxiliary loss.  ``MoELayer`` above, with
+    its capacity factor, its five dispatch modes and ``ExpertFFN``'s two
+    biased matrices, is the training path and stays as it is.
+
+    Told which experts it holds, the layer is what expert parallelism
+    asks of a chip: it routes over all experts and computes its own
+    experts' part of the result.  The exchange that would bring other
+    chips' tokens here is not in this layer, and nothing stands in for
+    it."""
+
+    def __init__(self, d_model: int, d_hidden: int, num_experts: int,
+                 top_k: int, held=None, dtype="float32"):
+        super().__init__(dtype=dtype)
+        import numpy as np
+        from paddle_tpu.nn.common_layers import Linear
+        self.top_k = int(top_k)
+        self.num_experts = int(num_experts)
+        self.held = tuple(range(num_experts)) if held is None \
+            else tuple(int(e) for e in held)
+        if len(set(self.held)) != len(self.held) or not all(
+                0 <= e < num_experts for e in self.held):
+            raise ValueError(f"held experts {self.held} are not distinct "
+                             f"ids below {num_experts}")
+        local = np.full((num_experts,), len(self.held), np.int32)
+        local[list(self.held)] = np.arange(len(self.held))
+        self._local_of = local
+        self.router = Linear(d_model, num_experts, bias_attr=False)
+        self.w_in = self.create_parameter(
+            [len(self.held), d_model, 2 * d_hidden])
+        self.w_out = self.create_parameter(
+            [len(self.held), d_hidden, d_model])
+
+    def forward(self, x, row_valid=None):
+        """x [..., d] -> (the held experts' part [..., d] float32,
+        counts int32 [3]); ``row_valid`` [...] bool masks rows."""
+        from paddle_tpu.core.dispatch import unwrap
+        x = unwrap(x)
+        x2d = x.reshape(-1, x.shape[-1])
+        if row_valid is not None:
+            row_valid = unwrap(row_valid).reshape(-1)
+        out, counts = gated_experts_forward(
+            x2d, unwrap(self.router.weight), unwrap(self.w_in),
+            unwrap(self.w_out), top_k=self.top_k,
+            local_of=self._local_of, row_valid=row_valid)
+        return out.reshape(x.shape), counts
 
 
 class NaiveGate(Layer):

@@ -37,6 +37,16 @@ rebuilds the memory path vLLM-style around fixed-size **token blocks**:
   to ``generation.generate`` over a ``StaticCache``.
 
 This is the serving engine's only KV cache (``inference/serving.py``).
+
+A second kind of state lives beside it (:class:`SlotStatePool`): a layer
+with recurrent state (a Mamba-2 mixer's convolution tail and SSM state)
+keeps a fixed-size state **per slot**, not per token.  The engine builds
+block pools for the layers that attend and slot state for the layers
+that recur; a layer's cache is a :class:`PagedCache` or a
+:class:`SlotState`, and :class:`StepInfo` tells every layer which rows
+of a dispatch are real.  Slot state is not paged, shared, exported or
+tiered: prefix sharing, speculative verify, park / resume and handoff
+carry KV blocks only, and the engine refuses them for such a model.
 """
 
 from __future__ import annotations
@@ -53,6 +63,7 @@ import jax.numpy as jnp
 
 __all__ = ["BlockAllocator", "SequenceBlocks", "PrefixCache",
            "PagedKVPool", "PagedCache", "paged_cache_attention",
+           "SlotStatePool", "SlotState", "StepInfo",
            "quant_kv_mode", "serialize_handoff",
            "deserialize_handoff"]
 
@@ -710,6 +721,67 @@ def fetch_handoff(store, key: str) -> Optional[dict]:
 
 
 # -- the paged attention path ------------------------------------------------
+
+class SlotState(NamedTuple):
+    """One recurrent layer's state for every slot: ``conv``
+    ``[slots, d_conv - 1, conv_dim]`` (the convolution's tail, in the
+    model's type) and ``ssm`` ``[slots, heads, head_dim, d_state]``
+    (float32).  Which rows a dispatch touches is in :class:`StepInfo`."""
+    conv: object
+    ssm: object
+
+
+class StepInfo(NamedTuple):
+    """What a dispatch tells every layer, passed as one more entry after
+    the layers' caches: ``valid`` ``[B]`` int32, how many of a row's
+    positions are real (a decode row: 1 or 0; a prefill chunk: the
+    tokens before its padded tail) — a recurrent layer leaves the state
+    of the rest untouched and an expert layer routes them nowhere;
+    ``slot``, the one slot a ``B == 1`` prefill chunk belongs to (None:
+    row ``b`` is slot ``b``); ``moe_counts`` int32 ``[3]``, summed over
+    the expert layers as the forward passes them: held experts with at
+    least one row, picks that landed on a held expert, picks made."""
+    valid: object
+    slot: object = None
+    moe_counts: object = None
+
+
+class SlotStatePool:
+    """Per-slot recurrent state, one :class:`SlotState` a recurrent
+    layer, beside the block pools.  ``shapes`` is the model's
+    ``slot_state_shapes()``: ``[(conv_shape, ssm_shape)]`` a layer,
+    without the slot axis.  The engine donates ``layers`` through its
+    programs as it does the block pools, zeroes a slot at admission
+    (:meth:`reset_slot`: one dispatch for every layer) and rebuilds
+    everything in ``_recover`` (:meth:`reset`)."""
+
+    def __init__(self, slots: int, shapes, dtype):
+        self.slots = int(slots)
+        self._shapes = [(tuple(c), tuple(s)) for c, s in shapes]
+        self._dtype = dtype
+        self.reset()
+        self._zero = jax.jit(
+            lambda layers, slot: jax.tree.map(
+                lambda a: a.at[slot].set(0), layers),
+            donate_argnums=(0,))
+
+    def reset(self):
+        self.layers = [
+            SlotState(jnp.zeros((self.slots,) + c, self._dtype),
+                      jnp.zeros((self.slots,) + s, jnp.float32))
+            for c, s in self._shapes]
+
+    def zeros_like(self):
+        """Zero-filled copies to compile against (``aot_warmup``)."""
+        return jax.tree.map(jnp.zeros_like, self.layers)
+
+    def reset_slot(self, slot: int):
+        self.layers = self._zero(self.layers, jnp.asarray(slot, jnp.int32))
+
+    @property
+    def nbytes(self) -> int:
+        return sum(int(a.nbytes) for st in self.layers for a in st)
+
 
 class PagedCache(NamedTuple):
     """One layer's paged KV view: the physical pools plus this batch's

@@ -467,10 +467,41 @@ class ContinuousBatchingEngine:
                 if self.kv_quant else 1
             self._num_blocks = 1 + ratio * slots * self._max_blocks
         self._allocator = BlockAllocator(self._num_blocks)
+        # two kinds of state (kv_cache.py): block pools for the layers
+        # that attend, per-slot recurrent state for the layers that
+        # recur (a model says which through ``config.layer_types`` and
+        # ``slot_state_shapes()``; without them every layer attends).
+        # Slot state is not paged: what shares, exports or tiers KV
+        # blocks would serve such a model wrong tokens, so each is
+        # refused here or where it is asked for, and the prefix cache
+        # is simply off.  Apart from that a model may say how many of
+        # its layers route over experts (``routed_expert_layers()``):
+        # their counts come back beside a decode step's tokens.  Either
+        # statement gets the model a ``StepInfo`` after its caches
+        kinds = list(getattr(cfgm, "layer_types", None)
+                     or ["attention"] * cfgm.num_hidden_layers)
+        self._state = None
+        shapes = model.slot_state_shapes() \
+            if hasattr(model, "slot_state_shapes") else []
+        self._expert_layers = int(model.routed_expert_layers()) \
+            if hasattr(model, "routed_expert_layers") else 0
+        self._step_info = bool(shapes) or self._expert_layers > 0
+        if shapes:
+            from paddle_tpu.inference.kv_cache import SlotStatePool
+            for what, asked in (("spec_decode", self.spec_tokens),
+                                ("kv_tier", kv_tier is not None),
+                                (f"role={role!r}", role != "mixed")):
+                if asked:
+                    raise ValueError(
+                        f"{what}: {type(model).__name__} keeps per-slot "
+                        f"recurrent state, which {what} would neither "
+                        f"carry nor roll back (KV blocks only)")
+            prefix_cache = False
+            self._state = SlotStatePool(slots, shapes, self._dtype)
         self._prefix = PrefixCache(self._block_size, self._allocator) \
             if prefix_cache else None
         self._pool = PagedKVPool(
-            cfgm.num_hidden_layers, self._num_blocks,
+            kinds.count("attention"), self._num_blocks,
             self._block_size, cfgm.num_key_value_heads,
             cfgm.head_dim, self._dtype, quant=self.kv_quant)
         # per-slot block table rows; 0 = reserved scratch block
@@ -586,6 +617,35 @@ class ContinuousBatchingEngine:
                   "device bytes held by the paged KV pools "
                   "(K/V payload + quant scale arrays)"
                   ).set_function(lambda e=self: e._pool.nbytes)
+        reg.gauge("paddle_tpu_serving_state_bytes",
+                  "device bytes held by per-slot recurrent state "
+                  "(convolution tails + SSM states of every slot)"
+                  ).set_function(
+            lambda e=self: e._state.nbytes if e._state else 0)
+        reg.gauge("paddle_tpu_serving_state_slots_used",
+                  "slots whose recurrent state belongs to an admitted "
+                  "request").set_function(
+            lambda e=self: sum(r is not None for r in e._active)
+            if e._state else 0)
+        if self._expert_layers:
+            # what a decode step's expert layers count (StepInfo), beside
+            # its tokens: made only for a model that has them
+            self._moe_counters = (
+                reg.counter(
+                    "paddle_tpu_moe_experts_touched",
+                    "held experts that at least one row chose, summed "
+                    "over expert layers and decode steps (stat=sum) "
+                    "beside the number of those layer-steps "
+                    "(stat=layer_steps): their ratio is the mean a "
+                    "layer a step", labelnames=("stat",)),
+                reg.counter(
+                    "paddle_tpu_moe_local_picks_total",
+                    "token-expert picks of decode steps that landed on "
+                    "an expert held here"),
+                reg.counter(
+                    "paddle_tpu_moe_picks_total",
+                    "token-expert picks decode steps made over the "
+                    "router's whole width"))
         reg.gauge("paddle_tpu_serving_sessions_parked",
                   "sessions demoted to the KV tier and awaiting "
                   "resume on this engine").set_function(
@@ -601,7 +661,7 @@ class ContinuousBatchingEngine:
 
         from paddle_tpu.core.dispatch import unwrap
         from paddle_tpu.generation import _sample
-        from paddle_tpu.inference.kv_cache import PagedCache
+        from paddle_tpu.inference.kv_cache import PagedCache, StepInfo
         dtype = self._dtype
         gen_cfg = self._gen_cfg
         K = self.steps_per_sync
@@ -609,8 +669,13 @@ class ContinuousBatchingEngine:
         # kscales/vscales are EMPTY lists on an unquantized pool:
         # they contribute no jaxpr inputs, so the knob-off programs
         # are identical to the pre-quantization engine
+        # ``state`` / ``info``: a model with slot state gets its
+        # recurrent layers' SlotStates between the paged caches, in
+        # layer order; it, and a model with routed expert layers, gets
+        # a StepInfo after them; for any other model both are empty
+        # and the programs are what they were
         def fwd_paged(ps, ids, kpools, vpools, kscales, vscales,
-                      bt, pos):
+                      bt, pos, state=(), info=None):
             if kscales:
                 cc = [PagedCache(kk, vv, bt, ks, vs)
                       for kk, vv, ks, vs in zip(kpools, vpools,
@@ -618,15 +683,27 @@ class ContinuousBatchingEngine:
             else:
                 cc = [PagedCache(kk, vv, bt)
                       for kk, vv in zip(kpools, vpools)]
+            if info is not None:
+                paged, recur = iter(cc), iter(state)
+                cc = [next(paged if k == "attention" else recur)
+                      for k in kinds] + [info]
             logits, new_caches = functional_call(model, ps, ids,
                                                  None, cc, pos)
             raw = unwrap(logits).astype(jnp.float32)
+            counts = None
+            if info is not None:
+                if experts:
+                    counts = new_caches[len(kinds)].moe_counts
+                state = [c for c, k in zip(new_caches, kinds)
+                         if k != "attention"]
+                new_caches = [c for c, k in zip(new_caches, kinds)
+                              if k == "attention"]
             return raw, ([unwrap(c.k) for c in new_caches],
                          [unwrap(c.v) for c in new_caches],
                          [unwrap(c.k_scale) for c in new_caches]
                          if kscales else [],
                          [unwrap(c.v_scale) for c in new_caches]
-                         if kscales else [])
+                         if kscales else []), state, counts
 
         # chunked prefill: ONE executable serves every chunk of
         # every prompt (B=1, fixed width C, per-row [1] position
@@ -634,27 +711,43 @@ class ContinuousBatchingEngine:
         # Non-final chunks ignore the sampled token; the final
         # chunk's sample at the true last prompt position is the
         # request's first generated token.
-        @_ft.partial(jax.jit, donate_argnums=(3, 4, 5, 6))
+        # A model that takes a StepInfo also gives its slot state
+        # (donated like the pools; none without recurrent layers), the
+        # chunk's slot and how many of the chunk's positions are
+        # tokens, and one with slot state gets the state back.
+        stateful = self._state is not None
+        step_info, experts = self._step_info, self._expert_layers > 0
+
+        @_ft.partial(jax.jit, donate_argnums=(3, 4, 5, 6)
+                     + ((11,) if stateful else ()))
         def prefill_chunk(keep, quant, ids, kpools, vpools, kscales,
-                          vscales, bt_row, start, last_idx, key):
+                          vscales, bt_row, start, last_idx, key,
+                          state=(), slot=None, n=None):
             ps = _dequant(keep, quant, dtype)
-            logits, pools = fwd_paged(ps, ids, kpools, vpools,
-                                      kscales, vscales, bt_row,
-                                      start)
+            info = StepInfo(n, slot) if step_info else None
+            logits, pools, state, _ = fwd_paged(
+                ps, ids, kpools, vpools, kscales, vscales, bt_row,
+                start, state, info)
             first = _sample(logits[0, last_idx][None], gen_cfg,
                             key)[0]
+            if stateful:
+                return first.astype(jnp.int32), pools, state
             return first.astype(jnp.int32), pools
 
         def decode_paged(keep, quant, kpools, vpools, kscales,
-                         vscales, bt, toks, pos, active, key):
+                         vscales, bt, toks, pos, active, key, state=()):
             ps = _dequant(keep, quant, dtype)
+            info = StepInfo(active.astype(jnp.int32)) if step_info \
+                else None
 
             def one(carry, _):
-                kpools, vpools, kscales, vscales, toks, pos, key = \
-                    carry
-                logits, (kpools, vpools, kscales, vscales) = \
+                (kpools, vpools, kscales, vscales, toks, pos, key,
+                 state, counts) = carry
+                logits, (kpools, vpools, kscales, vscales), state, n = \
                     fwd_paged(ps, toks[:, None], kpools, vpools,
-                              kscales, vscales, bt, pos)
+                              kscales, vscales, bt, pos, state, info)
+                if experts:
+                    counts = counts + n
                 key, sub = jax.random.split(key)
                 nxt = _sample(logits[:, -1], gen_cfg,
                               sub).astype(jnp.int32)
@@ -664,14 +757,18 @@ class ContinuousBatchingEngine:
                 nxt = jnp.where(active, nxt, toks)
                 pos = jnp.where(active, pos + 1, pos)
                 return (kpools, vpools, kscales, vscales, nxt, pos,
-                        key), nxt
+                        key, state, counts), nxt
 
-            (kpools, vpools, kscales, vscales, _, _, _), seq = \
-                jax.lax.scan(
+            counts = jnp.zeros((3,), jnp.int32) if experts else None
+            (kpools, vpools, kscales, vscales, _, _, _, state,
+             counts), seq = jax.lax.scan(
                     one, (kpools, vpools, kscales, vscales, toks,
-                          pos, key), None, length=K)
-            return (jnp.swapaxes(seq, 0, 1), kpools, vpools,
-                    kscales, vscales)
+                          pos, key, state, counts), None, length=K)
+            out = jnp.swapaxes(seq, 0, 1)
+            if experts:         # the expert layers' counts come back
+                out = (out, counts)     # beside the tokens, in one copy
+            return (out, kpools, vpools, kscales, vscales) \
+                + ((state,) if stateful else ())
 
         # speculative verify: ONE batched forward over
         # [last_token, draft_1..draft_k] per row; argmax at every
@@ -681,7 +778,7 @@ class ContinuousBatchingEngine:
         def spec_verify(keep, quant, kpools, vpools, kscales,
                         vscales, bt, toks, pos, active):
             ps = _dequant(keep, quant, dtype)
-            logits, (kpools, vpools, kscales, vscales) = fwd_paged(
+            logits, (kpools, vpools, kscales, vscales), _, _ = fwd_paged(
                 ps, toks, kpools, vpools, kscales, vscales, bt, pos)
             return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
                     kpools, vpools, kscales, vscales)
@@ -689,8 +786,9 @@ class ContinuousBatchingEngine:
         self._prefill_chunk_fn = prefill_chunk
         # raw (unjitted) decode kept for program analysis
         self._decode_paged_raw = decode_paged
-        self._decode_paged = jax.jit(decode_paged,
-                                     donate_argnums=(2, 3, 4, 5))
+        self._decode_paged = jax.jit(
+            decode_paged, donate_argnums=(2, 3, 4, 5)
+            + ((11,) if stateful else ()))
         self._spec_verify = jax.jit(spec_verify,
                                     donate_argnums=(2, 3, 4, 5))
         # AOT executables from aot_warmup(); dispatch prefers them (no
@@ -757,7 +855,8 @@ class ContinuousBatchingEngine:
         kpools, vpools, kscales, vscales, bt = self._paged_dummies()
         c = warm(self._decode_paged, self._keep, self._quant, kpools,
                  vpools, kscales, vscales, bt, toks, pos, active,
-                 self._key, target="serving.decode")
+                 self._key, *self._state_dummies(),
+                 target="serving.decode")
         if c is not None:
             self._decode_compiled = c
         kpools, vpools, kscales, vscales, bt = self._paged_dummies()
@@ -766,7 +865,8 @@ class ContinuousBatchingEngine:
         c = warm(self._prefill_chunk_fn, self._keep, self._quant, ids,
                  kpools, vpools, kscales, vscales, bt[:1],
                  jnp.zeros((1,), jnp.int32),
-                 jnp.asarray(0, jnp.int32), self._key, target=target)
+                 jnp.asarray(0, jnp.int32), self._key,
+                 *self._state_dummies(chunk=True), target=target)
         if c is not None:
             self._prefill_chunk_compiled = c
         if self.spec_tokens:
@@ -782,6 +882,8 @@ class ContinuousBatchingEngine:
         # one pow-2-bucketed gather/scatter pair per size, compiled now
         # so a fleet's first KV handoff doesn't pay an XLA compile
         self._pool.warm_transfer(self._max_blocks)
+        if self._state is not None and not cache_only:
+            self._state.reset_slot(0)   # admission's reset, compiled now
         return stats
 
     def _paged_dummies(self):
@@ -792,6 +894,19 @@ class ContinuousBatchingEngine:
         vscales = [jnp.zeros_like(p) for p in self._pool.vscales]
         bt = jnp.zeros((self.slots, self._max_blocks), jnp.int32)
         return kpools, vpools, kscales, vscales, bt
+
+    def _state_dummies(self, chunk: bool = False):
+        """The arguments a model that takes a StepInfo adds to a
+        program, to compile against: zero-filled slot state (none
+        without recurrent layers) and, for the prefill chunk, a slot
+        and a token count.  Empty for any other model."""
+        if not self._step_info:
+            return ()
+        state = (self._state.zeros_like() if self._state else [],)
+        if chunk:
+            state += (jnp.asarray(0, jnp.int32),
+                      jnp.ones((1,), jnp.int32))
+        return state
 
     def analyze(self, strict: bool = False, passes=None, options=None):
         """Lint the compiled decode step (the hot serving path) with the
@@ -806,7 +921,8 @@ class ContinuousBatchingEngine:
         return _analysis.check(
             self._decode_paged_raw, self._keep, self._quant, kpools,
             vpools, kscales, vscales, bt, toks, pos, active,
-            self._key, strict=strict, passes=passes, options=options)
+            self._key, *self._state_dummies(), strict=strict,
+            passes=passes, options=options)
 
     def _next_key(self):
         """Advance the sampling stream — greedy mode skips the split
@@ -848,6 +964,8 @@ class ContinuousBatchingEngine:
         if prefill_only and handoff is not None:
             raise ValueError("prefill_only and handoff are the two ends "
                              "of one transfer; a request can't be both")
+        if prefill_only or handoff is not None:
+            self._refuse_with_slot_state("a prefill / decode handoff")
         if handoff is not None and \
                 int(handoff.get("block_size", self._block_size)) \
                 != self._block_size:
@@ -1015,6 +1133,9 @@ class ContinuousBatchingEngine:
         self._seq[slot] = seq
         self._bt[slot, :] = 0
         self._bt[slot, :len(seq.bids)] = seq.bids
+        if self._state is not None:
+            # the slot's last request left its recurrence behind
+            self._state.reset_slot(slot)
         reused = len(reuse_bids) * bs
         req.prefix_reused = reused
         # a recompute-resumed session keeps its ORIGINAL admission
@@ -1172,6 +1293,16 @@ class ContinuousBatchingEngine:
             self._retire(slot)
         return True
 
+    def _refuse_with_slot_state(self, what: str):
+        """Park, resume and handoff move a request's KV blocks and
+        nothing else: a model with per-slot recurrent state would come
+        back with a zeroed recurrence and serve wrong tokens."""
+        if self._state is not None:
+            raise ValueError(
+                f"{what}: {type(self.model).__name__} keeps per-slot "
+                f"recurrent state, which {what} does not carry (KV "
+                f"blocks only)")
+
     def export_handoff(self, rid: int) -> Dict:
         """Package a ``"prefilled"`` request's prompt KV for transfer:
         the exported blocks, the sampled first token, and the lifecycle
@@ -1181,6 +1312,7 @@ class ContinuousBatchingEngine:
         ``add_request(handoff=...)`` directly, or
         :func:`~paddle_tpu.inference.kv_cache.serialize_handoff` for a
         byte transport."""
+        self._refuse_with_slot_state("export_handoff")
         req, seq, first = self._handoff_ready.pop(rid)
         bs = self._block_size
         Lp = len(req.prompt)
@@ -1236,6 +1368,7 @@ class ContinuousBatchingEngine:
         queued, or mid-prefill).  ``detach=True`` hands resume ownership
         to the caller (the router): the engine forgets the request
         entirely."""
+        self._refuse_with_slot_state("park")
         if self._kv_tier is None:
             raise ValueError("park() requires a kv_tier= manager "
                              "attached")
@@ -1280,6 +1413,7 @@ class ContinuousBatchingEngine:
         recompute fallback: the prompt is extended with the tokens
         already emitted and re-prefilled; greedy argmax regenerates the
         same chain, so the final output is token-identical either way."""
+        self._refuse_with_slot_state("resume")
         ent = self._parked.pop(rid, None)
         if ent is None:
             raise KeyError(f"rid {rid} is not parked on this engine")
@@ -1459,17 +1593,24 @@ class ContinuousBatchingEngine:
             last_idx = (Lp - 1 - start) if final else 0
             sub = self._next_key()
         prefill = self._prefill_chunk_compiled or self._prefill_chunk_fn
-        pool = self._pool
+        pool, state = self._pool, self._state
         with tr.span("serving.prefill", parent=req.span,
                      rid=req.rid, chunk_start=start, tokens=n):
             with tr.span("serving.dispatch"):
-                first, (pool.kpools, pool.vpools, pool.kscales,
-                        pool.vscales) = prefill(
+                got = prefill(
                     self._keep, self._quant, jnp.asarray(ids),
                     pool.kpools, pool.vpools, pool.kscales, pool.vscales,
                     jnp.asarray(self._bt[slot:slot + 1]),
                     jnp.asarray([start], jnp.int32),
-                    jnp.asarray(last_idx, jnp.int32), sub)
+                    jnp.asarray(last_idx, jnp.int32), sub,
+                    *(() if not self._step_info else (
+                        state.layers if state else [],
+                        jnp.asarray(slot, jnp.int32),
+                        jnp.asarray([n], jnp.int32))))
+                first, (pool.kpools, pool.vpools, pool.kscales,
+                        pool.vscales) = got[:2]
+                if state is not None:
+                    state.layers = got[2]
             if final:
                 # a chunk that is not the last leaves nothing to wait
                 # for: the device runs it while the host goes on
@@ -1546,14 +1687,16 @@ class ContinuousBatchingEngine:
                     self._bt[i, idx] = seq.bids[idx]
 
     def _run_batched(self, program, decoding: List[int], toks, span: int,
-                     *key):
+                     *more):
         """Upload, call and copy back ONE batched decode-shaped program
         (the fused decode, the speculative verify): ``toks`` is its
         token array, ``span`` the positions it writes from each decoding
-        slot's write head, ``key`` the sampling key where it takes one.
-        Returns the program's first output on the host and the clock
-        reading the dispatch started at.  The only place the engine
-        hands its pools to a batched program and takes them back."""
+        slot's write head, ``more`` what it takes after ``active`` (the
+        fused decode: the sampling key and, for a model that takes a
+        StepInfo, its slot state).  Returns the program's first output
+        on the host and the clock reading the dispatch started at.  The
+        only place the engine hands its pools and its slot state to a
+        batched program and takes them back."""
         tr = self._tracer
         with tr.span("serving.build"):
             active = np.zeros((self.slots,), bool)
@@ -1565,23 +1708,48 @@ class ContinuousBatchingEngine:
             # block, not in a real sequence's (possibly shared) block 0
             bt = np.where(active[:, None], self._bt, 0)
         t0 = time.perf_counter()
-        pool = self._pool
+        pool, state = self._pool, self._state
         with self._recorder.instrumented("serving.decode"):
             with tr.span("serving.dispatch"):
-                (out, pool.kpools, pool.vpools, pool.kscales,
-                 pool.vscales) = program(
+                got = program(
                     self._keep, self._quant, pool.kpools, pool.vpools,
                     pool.kscales, pool.vscales, jnp.asarray(bt),
                     jnp.asarray(toks), jnp.asarray(pos),
-                    jnp.asarray(active), *key)
+                    jnp.asarray(active), *more)
+                (out, pool.kpools, pool.vpools, pool.kscales,
+                 pool.vscales) = got[:5]
+                if len(got) > 5:
+                    state.layers = got[5]
             with tr.span("serving.sync"):
-                return np.asarray(out), t0
+                if not isinstance(out, tuple):
+                    return np.asarray(out), t0
+                out, counts = jax.device_get(out)    # one copy-back
+            self._count_experts(counts, span)
+            return out, t0
+
+    def _count_experts(self, counts, steps: int):
+        """The expert layers' counts of one decode dispatch (summed over
+        its ``steps`` steps and the model's expert layers) into the
+        registry and, while a profiler session is capturing, onto the
+        host plane beside the dispatch that made them."""
+        from paddle_tpu.observability.tracing import host_annotation
+        touched, local, picks = self._moe_counters
+        layer_steps = steps * self._expert_layers
+        touched.labels(stat="sum").inc(int(counts[0]))
+        touched.labels(stat="layer_steps").inc(layer_steps)
+        local.inc(int(counts[1]))
+        picks.inc(int(counts[2]))
+        with host_annotation("serving.moe_counts", touched=int(counts[0]),
+                             layer_steps=layer_steps):
+            pass
 
     def _decode_step(self, decoding: List[int]):
         """One fused K-step decode over every decoding slot."""
         toks, t0 = self._run_batched(
             self._decode_compiled or self._decode_paged, decoding,
-            self._last_tok, self.steps_per_sync, self._next_key())
+            self._last_tok, self.steps_per_sync, self._next_key(),
+            *(() if not self._step_info else (
+                self._state.layers if self._state else [],)))
         with self._tracer.span("serving.emit"):
             self._emit_decoded(                         # toks: [B, K]
                 decoding, [toks[i] for i in decoding], t0, toks.shape[1])
@@ -1730,7 +1898,12 @@ class ContinuousBatchingEngine:
         self._interleave_decode = not self._interleave_decode
         if do_chunk:
             step_span.set_attribute("ran", "prefill_chunk")
-            self._prefill_chunk_step(min(self._prefilling))
+            # the oldest admission first (the dict keeps admission order;
+            # a chunk's update leaves a key in place): by slot number a
+            # later arrival that lands in a lower, just-freed slot would
+            # overtake a prompt already under way, and which slot is
+            # free when it arrives is a matter of milliseconds
+            self._prefill_chunk_step(next(iter(self._prefilling)))
             return True
         if not decoding:
             return True
@@ -1916,6 +2089,8 @@ class ContinuousBatchingEngine:
             if self._kv_tier is not None:
                 self._prefix.on_evict = self._demote_prefix_node
         self._pool.reset()
+        if self._state is not None:
+            self._state.reset()
         self._bt[:] = 0
         self._seq = [None] * self.slots
         self._prefilling.clear()

@@ -12,6 +12,9 @@ from paddle_tpu.models.gpt import (GPTConfig, GPTDecoderLayer, GPTForCausalLM,
                                    GPTModel)
 from paddle_tpu.models.moe_llm import (MoEConfig, MoEDecoderLayer,
                                        MoEForCausalLM, MoEModel)
+from paddle_tpu.models.hybrid import (HybridConfig, HybridDecoderLayer,
+                                      HybridForCausalLM, HybridModel,
+                                      Mamba2Mixer)
 from paddle_tpu.models.dit import DiT, DiTBlock, DiTConfig
 from paddle_tpu.models.ernie import (ErnieConfig, ErnieForCausalLM,
                                      ErnieForMaskedLM,
@@ -22,6 +25,8 @@ __all__ = ["LlamaConfig", "LlamaAttention", "LlamaMLP", "LlamaDecoderLayer",
            "LlamaModel", "LlamaForCausalLM",
            "GPTConfig", "GPTDecoderLayer", "GPTModel", "GPTForCausalLM",
            "MoEConfig", "MoEDecoderLayer", "MoEModel", "MoEForCausalLM",
+           "HybridConfig", "Mamba2Mixer", "HybridDecoderLayer",
+           "HybridModel", "HybridForCausalLM",
            "DiTConfig", "DiTBlock", "DiT",
            "ErnieConfig", "ErnieModel", "ErnieForSequenceClassification",
            "ErnieForMaskedLM", "ErnieForCausalLM", "ernie45_moe_config"]

@@ -1,0 +1,364 @@
+"""A hybrid decoder for serving: Mamba-2 layers and attention layers in
+one stack, each followed by a routed expert layer beside a shared expert
+— the ``granitemoehybrid`` equations (IBM Granite 4.0-H).
+
+Every layer, with ``r = residual_multiplier``:
+
+    x <- x + r * mixer(rms(x))          mixer: Mamba-2 or attention
+    x <- x + r * (experts(rms(x)) + shared(rms(x)))
+
+The embedding's output is scaled by ``embedding_multiplier``, the head is
+the embedding (tied) and the logits are divided by ``logits_scaling``.
+Attention has no rotary positions and a stated score scale
+(``attention_multiplier``): ``LlamaAttention`` with both said.  The
+expert layer is ``distributed.moe.GatedExpertLayer``, told which experts
+this chip holds.  The Mamba-2 mathematics is ``ops/mamba2.py``.
+
+The model serves and does not train (no scan backward, no auxiliary
+loss).  ``forward(input_ids, attn_mask, caches, position_offset)`` is the
+serving engine's signature: ``caches[i]`` is a ``PagedCache`` for an
+attention layer and a ``SlotState`` for a Mamba layer, and one more entry
+after the layers', a ``StepInfo``, says which rows are real and collects
+the expert layers' counts.  Device operations carry the scopes ``embed``,
+``ssm`` (norm + mixer + residual), ``attn``, ``moe`` (norm + router +
+routed + shared + residual) and ``lm_head_ce``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.core.dispatch import unwrap
+from paddle_tpu.distributed.moe import GatedExpertLayer
+from paddle_tpu.models.llama import LlamaAttention, _fused_norm_qkv
+from paddle_tpu.nn.common_layers import Embedding, Linear
+from paddle_tpu.nn.layer import Layer
+from paddle_tpu.nn.norm_layers import RMSNorm
+from paddle_tpu.ops import mamba2
+
+__all__ = ["HybridConfig", "Mamba2Mixer", "HybridDecoderLayer",
+           "HybridModel", "HybridForCausalLM"]
+
+
+@dataclasses.dataclass
+class HybridConfig:
+    vocab_size: int = 100352
+    hidden_size: int = 4096
+    num_hidden_layers: int = 40
+    layer_types: Tuple[str, ...] = ()       # "mamba" | "attention" a layer
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    intermediate_size: int = 768            # one routed expert's width
+    shared_intermediate_size: int = 1536
+    num_local_experts: int = 72             # the router's width
+    num_experts_per_tok: int = 10
+    held_experts: Optional[Tuple[int, ...]] = None  # ids here; None: all
+    mamba_n_heads: int = 128
+    mamba_d_head: int = 64
+    mamba_d_state: int = 128
+    mamba_d_conv: int = 4
+    mamba_n_groups: int = 1
+    mamba_chunk_size: int = 256
+    mamba_conv_bias: bool = True
+    embedding_multiplier: float = 12.0
+    attention_multiplier: float = 0.0078125
+    residual_multiplier: float = 0.22
+    logits_scaling: float = 16.0
+    position_embedding_type: str = "nope"
+    max_position_embeddings: int = 131072
+    rms_norm_eps: float = 1e-5
+    dtype: str = "float32"
+
+    def __post_init__(self):
+        self.layer_types = tuple(self.layer_types) or \
+            ("attention",) * self.num_hidden_layers
+        if len(self.layer_types) != self.num_hidden_layers or \
+                set(self.layer_types) - {"mamba", "attention"}:
+            raise ValueError(f"layer_types {self.layer_types} do not name "
+                             f"{self.num_hidden_layers} mamba / attention "
+                             f"layers")
+        if self.mamba_n_groups != 1:
+            raise NotImplementedError("Mamba-2 with more than one B/C group")
+        if self.position_embedding_type != "nope":
+            raise NotImplementedError("hybrid attention with rotary "
+                                      "positions")
+        if self.held_experts is not None:
+            self.held_experts = tuple(self.held_experts)
+
+    @property
+    def head_dim(self):
+        return self.hidden_size // self.num_attention_heads
+
+    @property
+    def mamba_d_inner(self):
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def mamba_conv_dim(self):
+        return self.mamba_d_inner + \
+            2 * self.mamba_n_groups * self.mamba_d_state
+
+    @staticmethod
+    def tiny(**over):
+        cfg = dict(vocab_size=128, hidden_size=64, num_hidden_layers=3,
+                   layer_types=("mamba", "attention", "mamba"),
+                   num_attention_heads=4, num_key_value_heads=2,
+                   intermediate_size=32, shared_intermediate_size=48,
+                   num_local_experts=8, num_experts_per_tok=2,
+                   mamba_n_heads=8, mamba_d_head=16, mamba_d_state=16,
+                   mamba_chunk_size=8, max_position_embeddings=512)
+        cfg.update(over)
+        return HybridConfig(**cfg)
+
+
+class _Conv1d(Layer):
+    """The depthwise convolution's leaves: weight [d_conv, channels]
+    (row d_conv - 1 multiplies the current position), bias [channels]."""
+
+    def __init__(self, kernel, channels, bias):
+        super().__init__()
+        self.weight = self.create_parameter([kernel, channels])
+        self.bias = self.create_parameter([channels], is_bias=True) \
+            if bias else None
+
+
+class Mamba2Mixer(Layer):
+    """``[z | xBC | dt] = in_proj(u)``; ``xBC = silu(conv(xBC) + b)``;
+    ``dt = softplus(dt + dt_bias)``; the recurrence of ``ops/mamba2.py``
+    a head; ``y = rms_w((h C + D x) * silu(z))`` (gate before norm);
+    ``out_proj(y)``.  With ``state`` (a ``SlotState``) the recurrence
+    starts from the slot's state and the new one comes back; positions
+    at or past ``info.valid`` touch neither the tail nor the state."""
+
+    def __init__(self, c: HybridConfig):
+        super().__init__(dtype=c.dtype)
+        self.heads, self.head_dim = c.mamba_n_heads, c.mamba_d_head
+        self.d_state, self.chunk = c.mamba_d_state, c.mamba_chunk_size
+        self.d_inner, self.conv_dim = c.mamba_d_inner, c.mamba_conv_dim
+        self.in_proj = Linear(c.hidden_size,
+                              self.d_inner + self.conv_dim + self.heads,
+                              bias_attr=False)
+        self.conv1d = _Conv1d(c.mamba_d_conv, self.conv_dim,
+                              c.mamba_conv_bias)
+        self.dt_bias = self.create_parameter([self.heads], is_bias=True)
+        self.A_log = self.create_parameter([self.heads], is_bias=True)
+        self.D = self.create_parameter([self.heads], is_bias=True)
+        self.norm = RMSNorm(self.d_inner, epsilon=c.rms_norm_eps)
+        self.out_proj = Linear(self.d_inner, c.hidden_size, bias_attr=False)
+
+    def state_shapes(self):
+        """(conv tail, SSM state) of one slot, without the slot axis."""
+        return ((self.conv1d.weight.shape[0] - 1, self.conv_dim),
+                (self.heads, self.head_dim, self.d_state))
+
+    def forward(self, u, state=None, info=None):
+        f32 = jnp.float32
+        u = unwrap(u)
+        B, S = u.shape[0], u.shape[1]
+        H, P, N = self.heads, self.head_dim, self.d_state
+        z, xbc, dt = jnp.split(
+            unwrap(self.in_proj(u)),
+            [self.d_inner, self.d_inner + self.conv_dim], axis=-1)
+        valid = jnp.full((B,), S, jnp.int32) if info is None \
+            else info.valid.astype(jnp.int32)
+        one_slot = info is not None and info.slot is not None
+        if state is None:
+            tail = jnp.zeros((B,) + self.state_shapes()[0], u.dtype)
+            h0 = jnp.zeros((B, H, P, N), f32)
+        elif one_slot:          # a B == 1 prefill chunk of slot info.slot
+            tail = jax.lax.dynamic_index_in_dim(
+                unwrap(state.conv), info.slot, 0, keepdims=True)
+            h0 = jax.lax.dynamic_index_in_dim(
+                unwrap(state.ssm), info.slot, 0, keepdims=True)
+        else:                   # row b is slot b
+            tail, h0 = unwrap(state.conv), unwrap(state.ssm)
+        bias = self.conv1d.bias
+        xbc, tail = mamba2.causal_conv(
+            xbc, tail, unwrap(self.conv1d.weight),
+            None if bias is None else unwrap(bias), valid)
+        xbc = jax.nn.silu(xbc)                              # float32
+        x, Bm, Cm = jnp.split(xbc, [self.d_inner, self.d_inner + N], -1)
+        x = x.reshape(B, S, H, P)
+        real = jnp.arange(S)[None] < valid[:, None]         # [B, S]
+        dt = jax.nn.softplus(dt.astype(f32)
+                             + unwrap(self.dt_bias).astype(f32))
+        dt = jnp.where(real[..., None], dt, 0.0)
+        A = -jnp.exp(unwrap(self.A_log).astype(f32))
+        if S == 1:
+            y, h = mamba2.ssm_step(x[:, 0], dt[:, 0], A, Bm[:, 0],
+                                   Cm[:, 0], h0)
+            y = y[:, None]
+        else:
+            Q = min(self.chunk, S)
+            pad = (-S) % Q
+            padded = [jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)]
+                              * (a.ndim - 2)) for a in (x, dt, Bm, Cm)]
+            y, h = mamba2.ssd_scan(padded[0], padded[1], A, padded[2],
+                                   padded[3], h0, Q)
+            y = y[:, :S]
+        y = y + unwrap(self.D).astype(f32)[:, None] * x
+        y = y.reshape(B, S, self.d_inner) * jax.nn.silu(z.astype(f32))
+        y = y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True)
+                              + self.norm._epsilon) \
+            * unwrap(self.norm.weight).astype(f32)
+        out = unwrap(self.out_proj(y.astype(u.dtype)))
+        if state is None:
+            return out
+        from paddle_tpu.inference.kv_cache import SlotState
+        if one_slot:
+            return out, SlotState(
+                jax.lax.dynamic_update_index_in_dim(
+                    unwrap(state.conv), tail[0], info.slot, 0),
+                jax.lax.dynamic_update_index_in_dim(
+                    unwrap(state.ssm), h[0], info.slot, 0))
+        return out, SlotState(tail, h)
+
+
+class _SharedExpert(Layer):
+    """The expert every token passes: the routed experts' gated form at
+    its own width, ``output(silu(g) * u)`` with ``[g | u] = input(h)``."""
+
+    def __init__(self, d_model, width):
+        super().__init__()
+        self.input_linear = Linear(d_model, 2 * width, bias_attr=False)
+        self.output_linear = Linear(width, d_model, bias_attr=False)
+
+    def forward(self, h):
+        g, u = jnp.split(unwrap(self.input_linear(h)), 2, axis=-1)
+        return unwrap(self.output_linear(jax.nn.silu(g) * u))
+
+
+class HybridDecoderLayer(Layer):
+    def __init__(self, c: HybridConfig, kind: str):
+        super().__init__(dtype=c.dtype)
+        self.kind = kind
+        self.residual = float(c.residual_multiplier)
+        self.input_layernorm = RMSNorm(c.hidden_size,
+                                       epsilon=c.rms_norm_eps)
+        if kind == "mamba":
+            self.mamba = Mamba2Mixer(c)
+        else:
+            self.self_attn = LlamaAttention(c)
+        self.post_attention_layernorm = RMSNorm(c.hidden_size,
+                                                epsilon=c.rms_norm_eps)
+        self.block_sparse_moe = GatedExpertLayer(
+            c.hidden_size, c.intermediate_size, c.num_local_experts,
+            c.num_experts_per_tok, held=c.held_experts, dtype=c.dtype)
+        self.shared_mlp = _SharedExpert(c.hidden_size,
+                                        c.shared_intermediate_size)
+
+    def forward(self, x, attn_mask=None, cache=None, position_offset=0,
+                info=None):
+        """-> (x, the layer's new cache or None, the expert layer's
+        counts)."""
+        x = unwrap(x)
+        new_cache = None
+        if self.kind == "mamba":
+            with jax.named_scope("ssm"):
+                h = self.mamba(self.input_layernorm(x), cache, info)
+                if cache is not None:
+                    h, new_cache = h
+                x = x + self.residual * h.astype(x.dtype)
+        else:
+            with jax.named_scope("attn"):
+                qkv = _fused_norm_qkv(self, x)
+                if qkv is not None:
+                    h = self.self_attn.attend(*qkv, None, None, attn_mask,
+                                              cache, position_offset)
+                else:
+                    h = self.self_attn(self.input_layernorm(x), None, None,
+                                       attn_mask, cache, position_offset)
+                if cache is not None:
+                    h, new_cache = h
+                x = x + self.residual * unwrap(h).astype(x.dtype)
+        with jax.named_scope("moe"):
+            h = unwrap(self.post_attention_layernorm(x))
+            real = None if info is None else \
+                jnp.arange(x.shape[1])[None] < info.valid[:, None]
+            y, counts = self.block_sparse_moe(h, real)
+            y = y + self.shared_mlp(h).astype(jnp.float32)
+            x = x + (self.residual * y).astype(x.dtype)
+        return x, new_cache, counts
+
+
+class HybridModel(Layer):
+    def __init__(self, config: HybridConfig):
+        super().__init__(dtype=config.dtype)
+        self.config = config
+        self.embed_tokens = Embedding(config.vocab_size, config.hidden_size)
+        self.layers = []
+        for i, kind in enumerate(config.layer_types):
+            layer = HybridDecoderLayer(config, kind)
+            self.add_sublayer(f"layers_{i}", layer)
+            self.layers.append(layer)
+        self.norm = RMSNorm(config.hidden_size, epsilon=config.rms_norm_eps)
+        if config.dtype != "float32":
+            self.astype(config.dtype)
+
+    def forward(self, input_ids, attn_mask=None, caches=None,
+                position_offset=0):
+        from paddle_tpu.inference.kv_cache import StepInfo
+        L = len(self.layers)
+        info = None
+        if caches is not None and len(caches) > L:
+            info = caches[L]
+        with jax.named_scope("embed"):
+            x = unwrap(self.embed_tokens(input_ids))
+            x = x * jnp.asarray(self.config.embedding_multiplier, x.dtype)
+        new_caches = [] if caches is not None else None
+        counts = jnp.zeros((3,), jnp.int32)
+        for i, layer in enumerate(self.layers):
+            x, c, n = layer(x, attn_mask,
+                            None if caches is None else caches[i],
+                            position_offset, info)
+            counts = counts + n
+            if caches is not None:
+                new_caches.append(c)
+        with jax.named_scope("lm_head_ce"):
+            x = unwrap(self.norm(x))
+        if caches is None:
+            return x
+        if info is not None:
+            new_caches.append(StepInfo(info.valid, info.slot, counts))
+        return x, new_caches
+
+
+class HybridForCausalLM(Layer):
+    """``HybridModel`` under its tied head.  The serving engine asks a
+    model two things, apart: ``slot_state_shapes`` (its recurrent
+    layers' state) and ``routed_expert_layers`` (how many layers add to
+    ``StepInfo.moe_counts``)."""
+
+    def __init__(self, config: HybridConfig):
+        super().__init__(dtype=config.dtype)
+        self.config = config
+        self.model = HybridModel(config)
+
+    def slot_state_shapes(self):
+        """[(conv tail, SSM state)] a Mamba layer, in layer order,
+        without the slot axis."""
+        return [layer.mamba.state_shapes() for layer in self.model.layers
+                if layer.kind == "mamba"]
+
+    def routed_expert_layers(self) -> int:
+        """Layers that route over experts: every layer here."""
+        return len(self.model.layers)
+
+    def forward(self, input_ids, attn_mask=None, caches=None,
+                position_offset=0):
+        h = self.model(input_ids, attn_mask, caches, position_offset)
+        new_caches = None
+        if caches is not None:
+            h, new_caches = h
+        with jax.named_scope("lm_head_ce"):
+            w = unwrap(self.model.embed_tokens.weight)
+            logits = jnp.einsum("bsd,vd->bsv", h, w,
+                                preferred_element_type=jnp.float32) \
+                / self.config.logits_scaling
+        if caches is not None:
+            return logits, new_caches
+        return logits

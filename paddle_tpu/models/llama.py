@@ -84,6 +84,16 @@ class LlamaAttention(Layer):
         self.num_heads = c.num_attention_heads
         self.num_kv_heads = c.num_key_value_heads
         self.head_dim = c.head_dim
+        # two things a config may state and LlamaConfig does not: no
+        # rotary positions (``position_embedding_type`` "nope") and the
+        # score scale (``attention_multiplier``; None: 1 / sqrt(head_dim)).
+        # A stated scale is folded into q before the cache, so the paged
+        # kernel, the flash kernel and sdpa run unchanged
+        self.rotary = getattr(c, "position_embedding_type",
+                              "rope") != "nope"
+        scale = getattr(c, "attention_multiplier", None)
+        self.q_scale = None if scale is None \
+            else float(scale) * self.head_dim ** 0.5
         self.q_proj = Linear(c.hidden_size, self.num_heads * self.head_dim,
                              bias_attr=False)
         self.k_proj = Linear(c.hidden_size, self.num_kv_heads * self.head_dim,
@@ -108,8 +118,11 @@ class LlamaAttention(Layer):
         q = M.reshape(q, [b, s, self.num_heads, self.head_dim])
         k = M.reshape(k, [b, s, self.num_kv_heads, self.head_dim])
         v = M.reshape(v, [b, s, self.num_kv_heads, self.head_dim])
-        q = F.apply_rotary_emb(q, rope_cos, rope_sin, position_offset)
-        k = F.apply_rotary_emb(k, rope_cos, rope_sin, position_offset)
+        if self.rotary:
+            q = F.apply_rotary_emb(q, rope_cos, rope_sin, position_offset)
+            k = F.apply_rotary_emb(k, rope_cos, rope_sin, position_offset)
+        if self.q_scale is not None:
+            q = q * self.q_scale
         new_cache = None
         if cache is not None:
             from paddle_tpu.generation import (StaticCache,

@@ -10,6 +10,16 @@ blocks where the dense SwiGLU MLP is replaced by a routed expert bank
 (DeepSeekMoE §3 / Qwen2-MoE): out = shared_mlp(x) + moe(x).  Expert
 parallelism comes from the ``ep`` axis in the expert-stacked weights
 (distributed/moe.py); aux load-balance losses accumulate on the model.
+
+**Training only.**  ``MoEDecoderLayer.forward(x, rope_cos, rope_sin)`` and
+``MoEForCausalLM.forward(input_ids)`` take no cache, so the serving engine
+cannot run them, and their routed experts are ``ExpertFFN``'s two biased
+matrices under a capacity bound (``MoEConfig.capacity_factor``: a token
+over capacity is dropped, which a served token may never be).  The served
+expert layer is ``distributed.moe.GatedExpertLayer`` (gated, bias-free,
+dropless, told which experts it holds), used by ``models/hybrid.py``; it
+is the only gated routed-expert implementation, and the shared expert
+here is ``LlamaMLP``.
 """
 
 from __future__ import annotations
@@ -43,7 +53,8 @@ class MoEConfig:
     num_experts_per_tok: int = 6
     num_shared_experts: int = 2
     first_k_dense_replace: int = 1       # leading dense layers (DeepSeek)
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25        # training only: tokens over an
+    # expert's capacity are dropped (GShard); the served layer has none
     aux_loss_alpha: float = 0.001
     max_position_embeddings: int = 4096
     rms_norm_eps: float = 1e-5

@@ -8,7 +8,7 @@ from paddle_tpu.nn.clip import (  # noqa: F401
 )
 from paddle_tpu.nn.common_layers import *  # noqa: F401,F403
 from paddle_tpu.nn.conv_layers import *  # noqa: F401,F403
-from paddle_tpu.nn.layer import Layer  # noqa: F401
+from paddle_tpu.nn.layer import Layer, LazyGuard  # noqa: F401
 from paddle_tpu.nn.loss_layers import *  # noqa: F401,F403
 from paddle_tpu.nn.norm_layers import *  # noqa: F401,F403
 from paddle_tpu.nn.pooling_layers import *  # noqa: F401,F403

@@ -17,7 +17,28 @@ import numpy as np
 from paddle_tpu.core import dtypes as _dtypes
 from paddle_tpu.core.tensor import Parameter, Tensor
 
-__all__ = ["Layer"]
+__all__ = ["Layer", "LazyGuard"]
+
+
+class LazyGuard:
+    """Construct a model without device arrays of its own (the reference
+    framework's ``LazyGuard`` role): under the guard ``create_parameter``
+    records a parameter's shape and type (``_data`` is a
+    ``jax.ShapeDtypeStruct``) and runs no initialiser; the first
+    ``_set_data`` materialises it.  For a model whose weights are given
+    after construction (a checkpoint, a seeded set) the peak is then the
+    weights and one leaf, not the weights twice.  A parameter that is
+    read before it is given raises from whatever touches the struct."""
+
+    _depth = 0
+
+    def __enter__(self):
+        LazyGuard._depth += 1
+        return self
+
+    def __exit__(self, *exc):
+        LazyGuard._depth -= 1
+        return False
 
 
 class _HookHandle:
@@ -129,8 +150,12 @@ class Layer:
             init = getattr(attr, "initializer", None)
         if init is None:
             init = I.Constant(0.0) if is_bias else I.XavierNormal()
-        data = init(shape, dtype)
-        p = Parameter(data)
+        if LazyGuard._depth:
+            import jax
+            p = Parameter(jax.ShapeDtypeStruct(
+                tuple(int(n) for n in shape), _dtypes.to_jax(dtype)))
+        else:
+            p = Parameter(init(shape, dtype))
         if attr is not None and getattr(attr, "learning_rate", None) is not None:
             p.optimize_attr["learning_rate"] = attr.learning_rate
         if attr is not None and getattr(attr, "trainable", True) is False:
@@ -270,9 +295,14 @@ class Layer:
 
     def _cast_all(self, dtype):
         jdt = _dtypes.to_jax(dtype)
+        import jax
         import jax.numpy as jnp
         for t in list(self.parameters()) + list(self.buffers()):
-            if jnp.issubdtype(t._data.dtype, jnp.floating):
+            if not jnp.issubdtype(t._data.dtype, jnp.floating):
+                continue
+            if isinstance(t._data, jax.ShapeDtypeStruct):   # LazyGuard
+                t._data = jax.ShapeDtypeStruct(t._data.shape, jdt)
+            else:
                 t._set_data(t._data.astype(jdt))
         for layer in self.sublayers(include_self=True):
             layer._dtype = _dtypes.from_jax(jdt)

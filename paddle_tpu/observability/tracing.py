@@ -44,8 +44,12 @@ also entered as a ``jax.profiler.TraceAnnotation`` of the same name
 ``profiler.RecordEvent`` goes through it too).  While a profiler session
 is capturing, the span therefore sits on its host thread's line of the
 ``.xplane.pb`` beside the device lines, on the profiler's clock, and a
-device-idle gap can be given to the phase the host was in.  The
-annotation carries the name only; attributes stay in the ring.
+device-idle gap can be given to the phase the host was in.  A span's
+annotation carries the name only; attributes stay in the ring.  What a
+reader of the device trace has to find beside one dispatch (a decode
+step's expert counts, ``serving.moe_counts``) is an annotation of its
+own with the numbers as the profiler's stats, which the ``.xplane.pb``
+keeps beside the name, not in it.
 """
 
 from __future__ import annotations
@@ -80,18 +84,18 @@ _TraceAnnotation = None     # jax.profiler.TraceAnnotation, on first use
 _NO_ANNOTATION = nullcontext()
 
 
-def host_annotation(name: str):
+def host_annotation(name: str, **stats):
     """A region of this thread named ``name`` on the jax profiler's host
     plane: a context manager that is an atomic check and nothing else
     while no profiler session is open.  The ONE place the program
     writes host events into a device trace — ``Tracer.span`` and
     ``profiler.RecordEvent`` both come here, so a region appears there
-    once.  The name is all it carries (a ``name#k=v#`` suffix would
-    break lookups by name)."""
+    once.  ``stats`` (numbers) land as the event's stats in the
+    ``.xplane.pb``; its name stays ``name``, so lookups by name hold."""
     global _TraceAnnotation
     if _TraceAnnotation is None:
         from jax.profiler import TraceAnnotation as _TraceAnnotation
-    return _TraceAnnotation(name)
+    return _TraceAnnotation(name, **stats)
 
 
 class SpanContext(NamedTuple):

@@ -235,6 +235,54 @@ def test_paged_decode_compiles_other_pools(one_chip, dtype):
              S((slots,), jnp.int32), *scales)
 
 
+
+# granite-4.0-h-small's widths (serve-rag): 36 held experts of 72 at
+# d4096 / f768, top 10; Mamba-2 128 heads x 64, state 128, chunk 256
+@pytest.mark.parametrize("rows", [512, 24])
+def test_served_expert_layer_compiles_to_grouped_matmuls(one_chip, rows):
+    """A prefill chunk's rows and a decode step's: ``lax.ragged_dot``
+    becomes the compiler's own grouped matmul (a custom call, with its
+    metadata call), not a dense product over every expert — the temporary
+    a dense [rows * 10, 36, 1536] product would need is not there."""
+    import numpy as np
+    from paddle_tpu.distributed.moe import gated_experts_forward
+    local = np.full(72, 36, np.int32)
+    local[:36] = np.arange(36)
+
+    def step(x, router, w_in, w_out, valid):
+        return gated_experts_forward(x, router, w_in, w_out, top_k=10,
+                                     local_of=local, row_valid=valid)
+
+    S = lambda shape, dt=BF16: jax.ShapeDtypeStruct(shape, dt,
+                                                   sharding=one_chip)
+    compiled = _compile(step, S((rows, 4096)), S((4096, 72)),
+                        S((36, 4096, 1536)), S((36, 768, 4096)),
+                        S((rows,), jnp.bool_))
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 3
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        rows * 10 * 36 * 1536 * 2
+
+
+def test_mamba2_scan_and_step_compile(one_chip):
+    """The chunked scan over a 512-token prefill chunk carrying a state,
+    and the one-token update over 24 slots, at the published widths; the
+    update is one pass over the state (its temporaries stay under one
+    copy of it)."""
+    from paddle_tpu.ops import mamba2
+    f32 = jnp.float32
+    S = lambda *shape: jax.ShapeDtypeStruct(shape, f32, sharding=one_chip)
+    scan = jax.jit(lambda x, dt, A, B, C, h: mamba2.ssd_scan(
+        x, dt, A, B, C, h, 256)).lower(
+        S(1, 512, 128, 64), S(1, 512, 128), S(128), S(1, 512, 128),
+        S(1, 512, 128), S(1, 128, 64, 128)).compile()
+    assert scan.memory_analysis().temp_size_in_bytes < 1 << 30
+    step = jax.jit(mamba2.ssm_step, donate_argnums=(5,)).lower(
+        S(24, 128, 64), S(24, 128), S(128), S(24, 128), S(24, 128),
+        S(24, 128, 64, 128)).compile()
+    assert step.memory_analysis().temp_size_in_bytes < 24 * 128 * 64 * 128 * 4
+
+
 def test_paged_engine_warms_the_targets_it_always_has(monkeypatch):
     """The walk's trip count is a run-time scalar read from the lengths:
     a paged engine whose decode step runs the kernel (interpret mode

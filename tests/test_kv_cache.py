@@ -397,6 +397,32 @@ class TestPagedEngineParity:
         rid = eng.add_request(prompt, max_new_tokens=8)
         assert eng.run()[rid][1] == _reference(tiny_model, prompt, 8)
 
+    def test_prefill_goes_to_the_oldest_admission_not_the_lowest_slot(
+            self, tiny_model):
+        """A prompt under way in slot 1 is not overtaken by a later
+        arrival that lands in slot 0, freed a moment before: by slot
+        number the order of two first tokens hung on which slot was free
+        when the second request came (``serve-rag``'s two clusters of
+        TTFT, PERF.md §6 PR 37)."""
+        rng = np.random.default_rng(21)
+        eng = _paged_engine(tiny_model)
+        a = eng.add_request(rng.integers(0, 256, (6,)), max_new_tokens=3)
+        while eng._active[0] is None or 0 in eng._prefilling:
+            eng.step()                       # a: slot 0, first token out
+        long = rng.integers(0, 256, (40,))   # 5 chunks of 8
+        b = eng.add_request(long, max_new_tokens=2)
+        while eng.request_status(a) is None:
+            eng.step()                       # b admitted to slot 1; a retires
+        assert list(eng._prefilling) == [1]
+        c = eng.add_request(rng.integers(0, 256, (6,)), max_new_tokens=2)
+        results = eng.run()
+        assert results[b][1] == _reference(tiny_model, long, 2)
+        first = {r: eng.request_status(r).timings["first_token"]
+                 for r in (b, c)}
+        assert eng.request_status(c).timings["admitted"] > \
+            eng.request_status(b).timings["admitted"]
+        assert first[b] < first[c]
+
     @pytest.mark.slow
     def test_multi_slot_reuse(self, tiny_model):
         rng = np.random.default_rng(11)
