@@ -200,14 +200,30 @@ def gated_experts_forward(x2d, router_w, w_in, w_out, *, top_k: int,
     row route nowhere, so they read no expert).
 
     Every token is routed over all E experts; the picks that land on a
-    held expert are sorted by expert and go through two grouped products
-    (``lax.ragged_dot``: on the TPU a grouped-matmul kernel that visits
-    only the groups that have rows, so an expert no row chose is not
-    read); a pick that lands elsewhere contributes nothing here, which
-    is the part of the layer's result this chip's experts give.  Nothing
-    is dropped: there is no capacity.  Returns (out [T, d] float32,
-    counts int32 [3]: held experts with at least one row, picks that
-    landed here, picks made)."""
+    held expert go, grouped by expert, through the gated product
+    ``W_out[e] (silu(g) * u)``, ``[g | u] = x W_in[e]``; a pick that
+    lands elsewhere contributes nothing here, which is the part of the
+    layer's result this chip's experts give.  Which product runs is a
+    rule on the static shapes, in one place
+    (``ops/pallas/grouped_matmul.py: sorted_ffn_blocks``):
+
+    * from a mean of 32 rows a held expert while the step's rows fit
+      VMEM (a prefill chunk: 512 tokens x 10 picks over 36 experts),
+      one Pallas call, ``sorted_gated_ffn``: every group on a tile
+      boundary of a padded row buffer kept in VMEM, a 128-row tile to
+      one expert, the hidden tile never in HBM;
+    * below that (a decode step's 24 rows) and above it, two
+      ``lax.ragged_dot`` over the picks sorted by expert: on the TPU
+      the compiler's grouped-matmul kernel, a 16-row tile at a decode
+      step's rows and a 512-row tile at a chunk's.
+
+    Either way only the groups that have rows are visited, so an expert
+    no row chose is not read, and nothing is dropped: there is no
+    capacity.  The path taken is counted at trace time
+    (``paddle_tpu_grouped_moe_path_total{path=sorted_kernel|ragged_dot}``).
+    Returns (out [T, d] float32, counts int32 [3]: held experts with at
+    least one row, picks that landed here, picks made)."""
+    from paddle_tpu.ops.pallas import grouped_matmul as GM
     T, d = x2d.shape
     H = w_in.shape[0]
     logits = jnp.dot(x2d, router_w, preferred_element_type=jnp.float32)
@@ -217,21 +233,35 @@ def gated_experts_forward(x2d, router_w, w_in, w_out, *, top_k: int,
     if row_valid is not None:
         loc = jnp.where(row_valid[:, None], loc, H)
     flat = loc.reshape(-1)                                # token-major
-    order = jnp.argsort(flat)                             # stable; H last
     # a compare-and-sum, not ``bincount``: that is a scatter-add, one
     # update at a time on the TPU
     sizes = jnp.sum(flat[:, None] == jnp.arange(H)[None], axis=0,
                     dtype=jnp.int32)
-    xs = x2d[order // top_k]                              # [T*k, d]
-    gu = jax.lax.ragged_dot(xs, w_in, sizes)
-    g, u = jnp.split(gu, 2, axis=-1)
-    ys = jax.lax.ragged_dot((jax.nn.silu(g) * u).astype(x2d.dtype), w_out,
-                            sizes, preferred_element_type=jnp.float32)
-    # rows past the held picks are no expert's: ragged_dot leaves them
-    # unspecified, so they are zeroed before the gates see them
-    ys = jnp.where((flat[order] < H)[:, None], ys, 0.0)
-    back = jnp.argsort(order)                             # un-sort
-    out = jnp.einsum("tk,tkd->td", gates, ys[back].reshape(T, top_k, d))
+    blocks = GM.sorted_ffn_blocks(T, top_k, H, d, w_out.shape[1], x2d.dtype)
+    if blocks is not None:
+        # a prefill chunk's rows: every group starts on a tile boundary of
+        # a padded row buffer and both products are one kernel
+        GM.record_path("sorted_kernel")
+        block_rows, block_f = blocks
+        tile_expert, used, dest = GM.sorted_tile_plan(loc, sizes, block_rows)
+        ys = GM.sorted_gated_ffn(x2d, dest, w_in, w_out, tile_expert, used,
+                                 block_rows=block_rows, block_f=block_f)
+        # a pick that landed elsewhere has no row there: zero
+        ys = jnp.where((dest >= 0)[..., None], ys[jnp.maximum(dest, 0)], 0.0)
+    else:
+        GM.record_path("ragged_dot")
+        order = jnp.argsort(flat)                         # stable; H last
+        xs = x2d[order // top_k]                          # [T*k, d]
+        gu = jax.lax.ragged_dot(xs, w_in, sizes)
+        g, u = jnp.split(gu, 2, axis=-1)
+        ys = jax.lax.ragged_dot((jax.nn.silu(g) * u).astype(x2d.dtype),
+                                w_out, sizes,
+                                preferred_element_type=jnp.float32)
+        # rows past the held picks are no expert's: ragged_dot leaves
+        # them unspecified, so they are zeroed before the gates see them
+        ys = jnp.where((flat[order] < H)[:, None], ys, 0.0)
+        ys = ys[jnp.argsort(order)].reshape(T, top_k, d)  # un-sort
+    out = jnp.einsum("tk,tkd->td", gates, ys)
     picks = T * top_k if row_valid is None \
         else jnp.sum(row_valid.astype(jnp.int32)) * top_k
     counts = jnp.stack([jnp.sum(sizes > 0), jnp.sum(sizes),
@@ -244,9 +274,12 @@ class GatedExpertLayer(Layer):
     (``held``: their ids; default all), each ``W_out (silu(g) * u)`` with
     ``[g | u] = W_in h`` and no bias: the form Mixtral, Granite and the
     DeepSeek family serve.  Inference only (``gated_experts_forward``):
-    dropless, no capacity, no auxiliary loss.  ``MoELayer`` above, with
-    its capacity factor, its five dispatch modes and ``ExpertFFN``'s two
-    biased matrices, is the training path and stays as it is.
+    dropless, no capacity, no auxiliary loss; a prefill chunk's rows go
+    through the repo's own ``sorted_gated_ffn`` kernel and a decode
+    step's through ``lax.ragged_dot``, by the rows alone.  ``MoELayer``
+    above, with its capacity factor, its five dispatch modes and
+    ``ExpertFFN``'s two biased matrices, is the training path and stays
+    as it is.
 
     Told which experts it holds, the layer is what expert parallelism
     asks of a chip: it routes over all experts and computes its own

@@ -27,12 +27,18 @@ grouped FFN as ONE ``pallas_call``:
 * custom VJP: backward is the plain-JAX masked einsum chain, left to
   XLA, with a ``float0`` cotangent for counts.
 
-Routing is trace-time and OFF by default: ``PADDLE_TPU_GROUPED_MOE=1``
-flips ``_expert_ffn`` to this kernel (interpret mode off-TPU); unset or
-0 keeps the dense einsum pair with a byte-identical jaxpr (regression-
-tested).  Block sizes are one more autotune-v2 axis
-(``autotune.grouped_block_sizes``) and the static Mosaic-legality spec
-is in the kernel-verify catalog via :func:`verify_static`.
+Beside it, ``sorted_gated_ffn``: the served (dropless, gated, bias-free)
+expert layer's two products over expert-sorted rows at a prefill chunk's
+row count, chosen by ``sorted_ffn_blocks`` from the static shapes alone
+(its own section below).
+
+Routing of the capacity-grouped kernel is trace-time and OFF by
+default: ``PADDLE_TPU_GROUPED_MOE=1`` flips ``_expert_ffn`` to it
+(interpret mode off-TPU); unset or 0 keeps the dense einsum pair with a
+byte-identical jaxpr (regression-tested).  Block sizes are one more
+autotune-v2 axis (``autotune.grouped_block_sizes``) and the static
+Mosaic-legality specs are in the kernel-verify catalog via
+:func:`verify_static` and :func:`verify_static_sorted`.
 """
 
 from __future__ import annotations
@@ -50,7 +56,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["grouped_expert_ffn", "grouped_expert_ffn_pallas",
            "grouped_expert_ffn_reference", "grouped_moe_enabled",
-           "grouped_ffn_eligible", "record_path"]
+           "grouped_ffn_eligible", "record_path", "sorted_gated_ffn",
+           "sorted_tile_plan", "sorted_ffn_blocks"]
 
 
 def grouped_moe_enabled() -> bool:
@@ -296,6 +303,235 @@ def grouped_expert_ffn(x, w1, b1, w2, b2, *, counts=None, act=None,
 
 
 # ---------------------------------------------------------------------------
+# the served expert layer's product: gated experts over expert-sorted rows
+#
+# ``distributed/moe.py: gated_experts_forward`` routes a step's tokens over
+# the held experts.  At a prefill chunk's rows a group is about a hundred
+# rows, and the compiler's own lowering of ``lax.ragged_dot`` multiplies a
+# 512-row tile for each.  Here every group starts on a tile boundary of a
+# padded row buffer, a tile belongs to one expert (the tile -> expert map
+# is scalar prefetch), and both products run in one call with the hidden
+# tile in VMEM.  The padded buffer is never in HBM: the chunk's rows stay
+# whole in VMEM and a tile takes its own by a one-hot product (exact: one
+# term a row), so no sorted copy of the rows is made or read.
+
+_SORTED_TILE_ROWS = 128       # the MXU's height: the most rows a tile has
+# A held expert's mean rows from which the kernel is taken.  Measured on
+# the chip at serve-rag's widths (benchmarks/served_experts_bench.py,
+# PERF.md section 6, PR 38): the kernel wins at 142 / 71 / 36 rows an
+# expert (512 / 256 / 128 tokens); at a decode step's 6.7 the two are
+# within a tenth and the step keeps the compiler's product.
+_SORTED_MIN_GROUP_ROWS = 32
+# The compiler's own scope is 16 MiB of a v5e core's 128: the kernel asks
+# for this much, and what it holds takes no more than the budget.
+_SORTED_VMEM_LIMIT = 32 * (1 << 20)
+_SORTED_VMEM_BUDGET = 26 * (1 << 20)
+
+
+def sorted_ffn_vmem_bytes(block_rows, block_f, T, top_k, d, itemsize):
+    """What the call holds: every streamed tile twice, the step's rows
+    and their places (whole, fetched once, two buffers all the same) and
+    the gathered tile."""
+    return (2 * (block_rows * d * 4                # float32 output
+                 + 3 * d * block_f * itemsize)     # gate, up, down
+            + 2 * (T * d * itemsize + top_k * T * 4)
+            + block_rows * d * itemsize)
+
+
+def sorted_ffn_blocks(T: int, top_k: int, H: int, d: int, f: int, dtype):
+    """The one place that says which product the served expert layer
+    runs: ``(block_rows, block_f)`` of the sorted kernel, or None for
+    ``lax.ragged_dot``.
+
+    The kernel where the step's picks, all landing here, would give a
+    held expert ``_SORTED_MIN_GROUP_ROWS`` rows or more (a prefill chunk:
+    512 tokens x 10 picks over 36 experts is 142) and the step's rows fit
+    in VMEM beside the weight tiles; ``ragged_dot`` below that (a decode
+    step: 24 x 10 over 36 is 7, which the compiler tiles by 16) and above
+    it (thousands of rows a group fill the compiler's 512-row tile).  On
+    the TPU the widths must tile by lanes.
+
+    The tile is the MXU's height, or the mean group's rows rounded up to
+    a power of two where that is less (never under the dtype's packed
+    tile); the hidden block the widest lane-aligned divisor of the hidden
+    width that fits the budget."""
+    n_rows = T * top_k
+    if n_rows < H * _SORTED_MIN_GROUP_ROWS:
+        return None
+    if jax.default_backend() == "tpu" and (d % 128 or f % 128 or T % 128):
+        return None
+    itemsize = jnp.dtype(dtype).itemsize
+    quantum = 32 // itemsize              # sublanes a packed tile holds
+    mean = -(-n_rows // H)
+    block_rows = min(_SORTED_TILE_ROWS,
+                     max(quantum, 1 << (mean - 1).bit_length()))
+    for n in range(1, max(f // 128, 1) + 1):
+        block_f = f // n
+        if f % n or (n > 1 and block_f % 128):
+            continue
+        if sorted_ffn_vmem_bytes(block_rows, block_f, T, top_k, d,
+                                 itemsize) <= _SORTED_VMEM_BUDGET:
+            return block_rows, block_f
+    return None
+
+
+def sorted_tile_plan(loc, sizes, block_rows: int):
+    """Where a step's picks go in the padded row buffer.
+
+    ``loc`` [T, k] int32: the group of each pick, H for none (a token
+    picks a group at most once); ``sizes`` [H] int32: picks of each
+    group.  Group ``e`` gets ``cdiv(sizes[e], block_rows)`` tiles, its
+    picks in token order from its first tile's first row, so at most
+    ``cdiv(T * k, block_rows) + H - 1`` tiles are used.  No sort and no
+    scatter (one update at a time on the TPU): a pick's place in its
+    group is the count of tokens above it that chose the group.  Returns
+
+    * ``tile_expert`` [tiles] int32 -- the group a tile belongs to (the
+      last used tile's past the used count, so a skipped step asks for
+      no new weight block),
+    * ``num_used`` [1] int32 -- tiles that hold rows,
+    * ``dest`` [T, k] int32 -- the padded row of each pick, -1 for the
+      picks of no group."""
+    T, k = loc.shape
+    H = sizes.shape[0]
+    tm = block_rows
+    nt = -(-(T * k) // tm) + H - 1
+    hot = loc[:, :, None] == jnp.arange(H, dtype=jnp.int32)    # [T, k, H]
+    chose = jnp.sum(hot, axis=1, dtype=jnp.int32)              # [T, H] 0/1
+    above = jnp.cumsum(chose, axis=0) - chose
+    tiles = (sizes + tm - 1) // tm
+    tile_end = jnp.cumsum(tiles)
+    place = ((tile_end - tiles) * tm)[None] + above            # [T, H]
+    dest = jnp.sum(jnp.where(hot, place[:, None], 0), axis=-1)
+    dest = jnp.where(loc < H, dest, -1)
+    num_used = tile_end[-1]
+    t = jnp.minimum(jnp.arange(nt, dtype=jnp.int32),
+                    jnp.maximum(num_used - 1, 0))
+    te = jnp.minimum(jnp.sum(t[:, None] >= tile_end[None], axis=1,
+                             dtype=jnp.int32), H - 1)
+    return te, num_used.reshape(1), dest
+
+
+def _sorted_maps(nf: int):
+    """Index maps of the sorted kernel's weights and output.  A step past
+    the used tiles is sent to the last used step's blocks, so it moves
+    nothing."""
+    def live(t, j, te, nu):
+        used = t < nu[0]
+        t = jnp.where(used, t, jnp.maximum(nu[0] - 1, 0))
+        return t, jnp.where(used, j, nf - 1), te[t]
+
+    def rows(t, j, te, nu):
+        return live(t, j, te, nu)[0], 0
+
+    def gate(t, j, te, nu):
+        _, j, e = live(t, j, te, nu)
+        return e, 0, j
+
+    def up(t, j, te, nu):       # w_in is [gate | up]: the second half
+        _, j, e = live(t, j, te, nu)
+        return e, 0, nf + j
+
+    def down(t, j, te, nu):
+        _, j, e = live(t, j, te, nu)
+        return e, j, 0
+    return rows, gate, up, down
+
+
+def _sorted_gated_kernel(te_ref, nu_ref, dest_ref, x_ref, wg_ref, wu_ref,
+                         wo_ref, o_ref, xs_ref):
+    """One (row tile, hidden block) step.  At a tile's first hidden block
+    its rows are taken from the step's: row ``r`` of tile ``t`` is the
+    token with a pick whose place is ``t * rows + r`` (a one-hot product;
+    a row no pick has is zero).  Then ``[rows, block_f]`` gate and up
+    tiles, ``silu(g) * u`` in float32, one cast, folded into the float32
+    output tile, which stays in VMEM over the hidden blocks."""
+    t, j = pl.program_id(0), pl.program_id(1)
+    dot = functools.partial(jax.lax.dot_general,
+                            dimension_numbers=(((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+
+    @pl.when(t < nu_ref[0])
+    def _compute():
+        @pl.when(j == 0)
+        def _gather():
+            tm, T = xs_ref.shape[0], x_ref.shape[0]
+            row = t * tm + jax.lax.broadcasted_iota(jnp.int32, (tm, T), 0)
+            hit = jnp.zeros((tm, T), jnp.float32)
+            for k in range(dest_ref.shape[0]):
+                hit += jnp.where(dest_ref[k:k + 1, :] == row, 1.0, 0.0)
+            xs_ref[...] = dot(hit.astype(x_ref.dtype),
+                              x_ref[...]).astype(xs_ref.dtype)
+
+        x = xs_ref[...]
+        g = dot(x, wg_ref[0])
+        u = dot(x, wu_ref[0])
+        y = dot((jax.nn.silu(g) * u).astype(x.dtype), wo_ref[0])
+
+        @pl.when(j == 0)
+        def _first():
+            o_ref[...] = y
+
+        @pl.when(j > 0)
+        def _fold():
+            o_ref[...] += y
+
+
+def sorted_gated_ffn(x, dest, w_in, w_out, tile_expert, num_used, *,
+                     block_rows: int, block_f: int, interpret=None):
+    """``W_out[e] (silu(g) * u)``, ``[g | u] = x[token] W_in[e]``, for
+    every pick with a place: ``x`` [T, d] the step's rows, ``dest`` [T, k]
+    the padded row of each pick (-1: none) and tile ``t`` with the
+    weights of group ``tile_expert[t]`` (``sorted_tile_plan``); ``w_in``
+    [H, d, 2f] as ``[gate | up]`` and ``w_out`` [H, f, d] as the layer
+    holds them.  Returns the padded rows, float32 [tiles * block_rows,
+    d]; tiles from ``num_used`` on do no product, read no weight and are
+    left unwritten."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _sorted_gated_call(x, dest, w_in, w_out, tile_expert, num_used,
+                              block_rows=int(block_rows),
+                              block_f=int(block_f),
+                              interpret=bool(interpret))
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("block_rows", "block_f", "interpret"))
+def _sorted_gated_call(x, dest, w_in, w_out, tile_expert, num_used, *,
+                       block_rows, block_f, interpret):
+    """The ``pallas_call`` behind ONE jit (as ``paged_attention``'s): a
+    program whose layers call it at identical shapes lowers one kernel
+    body, and the Pallas -> Mosaic lowering is paid on every start."""
+    T, d = x.shape
+    H, f, _ = w_out.shape
+    nt, nf = tile_expert.shape[0], f // block_f
+    rows, gate, up, down = _sorted_maps(nf)
+    whole = lambda t, j, te, nu: (0, 0)
+    params = {}
+    if not interpret:
+        params["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_SORTED_VMEM_LIMIT)
+    return pl.pallas_call(
+        _sorted_gated_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(nt, nf),
+            in_specs=[pl.BlockSpec((dest.shape[1], T), whole),
+                      pl.BlockSpec((T, d), whole),
+                      pl.BlockSpec((1, d, block_f), gate),
+                      pl.BlockSpec((1, d, block_f), up),
+                      pl.BlockSpec((1, block_f, d), down)],
+            out_specs=pl.BlockSpec((block_rows, d), rows),
+            scratch_shapes=[pltpu.VMEM((block_rows, d), x.dtype)]),
+        out_shape=jax.ShapeDtypeStruct((nt * block_rows, d), jnp.float32),
+        name="sorted_gated_ffn",
+        interpret=interpret,
+        **params,
+    )(tile_expert, num_used, dest.T, x, w_in, w_in, w_out)
+
+
+# ---------------------------------------------------------------------------
 # static verification (analysis/kernel_verify)
 
 
@@ -340,4 +576,50 @@ def verify_static(G, C, d, h, E=None, dtype="bfloat16", block_c=None,
         needs_fp32_acc=True,
         where=f"grouped_matmul[G={G} C={C} d={d} h={h} E={E} "
               f"bc={bc} bf={bf} {dtype}]")
+    return kv.verify_kernel(spec)
+
+
+def verify_static_sorted(T, top_k, H, d, f, dtype="bfloat16",
+                         block_rows=None, block_f=None):
+    """Static Mosaic-legality findings for the sorted gated kernel at
+    this step's shape.  The tile -> expert map is checked on the plan
+    that uses every tile (all groups but the last one row, the last the
+    rest: the bound ``cdiv(T * k, rows) + H - 1`` reached), so that each
+    output block has its one writer; at a real routing the tiles past
+    the used count are left unwritten and the layer reads none of
+    them."""
+    from paddle_tpu.analysis import kernel_verify as kv
+    dtype = str(dtype)
+    if block_rows is None or block_f is None:
+        rule = sorted_ffn_blocks(T, top_k, H, d, f, dtype)
+        block_rows, block_f = block_rows or rule[0], block_f or rule[1]
+    tm, bf = int(block_rows), int(block_f)
+    nt, nf = -(-(T * top_k) // tm) + H - 1, f // bf
+    te = np.minimum(np.arange(nt), H - 1).astype(np.int32)
+    rows, gate, up, down = _sorted_maps(nf)
+    whole = lambda t, j, te, nu: (0, 0)
+    spec = kv.KernelSpec(
+        name="sorted_gated_ffn",
+        grid=(nt, nf),
+        args=[
+            kv.ArgSpec("dest", (top_k, T), (top_k, T), whole, "int32"),
+            kv.ArgSpec("x", (T, d), (T, d), whole, dtype),
+            kv.ArgSpec("w_gate", (H, d, 2 * f), (1, d, bf), gate, dtype,
+                       dma_once=True),
+            kv.ArgSpec("w_up", (H, d, 2 * f), (1, d, bf), up, dtype,
+                       dma_once=True),
+            kv.ArgSpec("w_out", (H, f, d), (1, bf, d), down, dtype,
+                       dma_once=True),
+            kv.ArgSpec("o", (nt * tm, d), (tm, d), rows, "float32",
+                       is_output=True),
+        ],
+        scratch=[kv.ScratchSpec("xs", (tm, d), dtype)],
+        dimension_semantics=("arbitrary", "arbitrary"),
+        scalar_prefetch=(te, np.asarray([nt], np.int32)),
+        vmem_budget=_SORTED_VMEM_BUDGET, vmem_limit=_SORTED_VMEM_LIMIT,
+        # both products accumulate in float32 registers and fold into the
+        # float32 output tile
+        needs_fp32_acc=True, acc_inline=True,
+        where=f"sorted_gated_ffn[T={T} k={top_k} H={H} d={d} f={f} "
+              f"rows={tm} bf={bf} {dtype}]")
     return kv.verify_kernel(spec)

@@ -239,13 +239,19 @@ def test_paged_decode_compiles_other_pools(one_chip, dtype):
 # granite-4.0-h-small's widths (serve-rag): 36 held experts of 72 at
 # d4096 / f768, top 10; Mamba-2 128 heads x 64, state 128, chunk 256
 @pytest.mark.parametrize("rows", [512, 24])
-def test_served_expert_layer_compiles_to_grouped_matmuls(one_chip, rows):
-    """A prefill chunk's rows and a decode step's: ``lax.ragged_dot``
-    becomes the compiler's own grouped matmul (a custom call, with its
-    metadata call), not a dense product over every expert — the temporary
-    a dense [rows * 10, 36, 1536] product would need is not there."""
+def test_served_expert_layer_compiles_to_grouped_matmuls(one_chip, rows,
+                                                         monkeypatch):
+    """A prefill chunk's rows go through the repo's own kernel over the
+    sorted rows (one Mosaic call under its name, no ``ragged-dot`` of the
+    compiler's, and the ``[5120, 1536]`` gate-and-up product never a
+    temporary); a decode step's keep ``lax.ragged_dot``, the compiler's
+    grouped matmul (two custom calls and their metadata call).  Neither
+    is a dense product over every expert — the temporary a dense
+    [rows * 10, 36, 1536] product would need is not there."""
     import numpy as np
     from paddle_tpu.distributed.moe import gated_experts_forward
+    # this process's backend is the CPU; the compile is for the chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     local = np.full(72, 36, np.int32)
     local[:36] = np.arange(36)
 
@@ -258,8 +264,15 @@ def test_served_expert_layer_compiles_to_grouped_matmuls(one_chip, rows):
     compiled = _compile(step, S((rows, 4096)), S((4096, 72)),
                         S((36, 4096, 1536)), S((36, 768, 4096)),
                         S((rows,), jnp.bool_))
-    assert compiled.as_text().count(
-        'custom_call_target="tpu_custom_call"') == 3
+    text = compiled.as_text()
+    calls = text.count('custom_call_target="tpu_custom_call"')
+    if rows == 512:
+        assert calls == 1 and "sorted_gated_ffn" in text
+        assert "ragged-dot" not in text
+        assert "[5120,1536]" not in text
+    else:
+        assert calls == 3 and "ragged-dot" in text
+        assert "sorted_gated_ffn" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < \
         rows * 10 * 36 * 1536 * 2
 

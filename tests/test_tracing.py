@@ -848,7 +848,8 @@ def _pallas_call_names():
 KERNEL_NAMES = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                 "rmsnorm_qkv", "fused_mlp", "fused_decoder",
                 "paged_attention", "rmsnorm", "fused_ce_fwd",
-                "fused_ce_bwd", "grouped_matmul", "quant_matmul")
+                "fused_ce_bwd", "grouped_matmul", "sorted_gated_ffn",
+                "quant_matmul")
 
 
 @pytest.mark.parametrize("kernel", KERNEL_NAMES)
