@@ -187,10 +187,38 @@ def moe_forward_ragged(x2d, logits, w1, b1, w2, b2, *, E: int, top_k: int,
     return out, _gshard_aux(probs, topi, E, k), jnp.zeros((), jnp.float32)
 
 
+ROUTER_RULES = ("softmax_topk", "sigmoid_bias")
+
+
+def router_gates(logits, top_k: int, rule: str = "softmax_topk",
+                 bias=None, scaling: float = 1.0):
+    """The router's rule, a stated property of the layer: float32
+    ``logits`` [T, E] -> (gates [T, k] float32, chosen ids [T, k]).
+
+    * ``softmax_topk`` (Mixtral, Granite): the top k logits, softmax
+      over the chosen.
+    * ``sigmoid_bias`` (the DeepSeek-V3 family's auxiliary-loss-free
+      balancing): ``s = sigmoid(logits)``; the k experts are the top k of
+      ``s + bias`` — ``bias`` [E] steers the choice and is in no weight —
+      and ``g_e = scaling * s_e / sum_chosen s``."""
+    if rule == "softmax_topk":
+        topv, topi = jax.lax.top_k(logits, top_k)
+        return jax.nn.softmax(topv, axis=-1), topi
+    if rule != "sigmoid_bias":
+        raise ValueError(f"router rule {rule!r}: one of {ROUTER_RULES}")
+    s = jax.nn.sigmoid(logits)
+    choice = s if bias is None else s + bias.astype(jnp.float32)[None]
+    _, topi = jax.lax.top_k(choice, top_k)
+    chosen = jnp.take_along_axis(s, topi, axis=-1)
+    return scaling * chosen / jnp.sum(chosen, -1, keepdims=True), topi
+
+
 def gated_experts_forward(x2d, router_w, w_in, w_out, *, top_k: int,
-                          local_of, row_valid=None):
-    """The served expert layer: gated bias-free experts, top-k then
-    softmax over the chosen logits, dropless, over the experts held here.
+                          local_of, row_valid=None, rule="softmax_topk",
+                          router_bias=None, scaling: float = 1.0):
+    """The served expert layer: gated bias-free experts, the router's
+    rule (``router_gates``: top-k then softmax over the chosen logits by
+    default), dropless, over the experts held here.
 
     x2d [T, d]; router_w [d, E]: the router at its published width;
     w_in [H, d, 2f] (``[gate | up]``) and w_out [H, f, d]: the H experts
@@ -227,8 +255,8 @@ def gated_experts_forward(x2d, router_w, w_in, w_out, *, top_k: int,
     T, d = x2d.shape
     H = w_in.shape[0]
     logits = jnp.dot(x2d, router_w, preferred_element_type=jnp.float32)
-    topv, topi = jax.lax.top_k(logits, top_k)             # [T, k]
-    gates = jax.nn.softmax(topv, axis=-1)                 # over the chosen
+    gates, topi = router_gates(logits, top_k, rule, router_bias,
+                               scaling)                   # [T, k]
     loc = jnp.asarray(local_of, jnp.int32)[topi]          # [T, k] in [0, H]
     if row_valid is not None:
         loc = jnp.where(row_valid[:, None], loc, H)
@@ -273,7 +301,9 @@ class GatedExpertLayer(Layer):
     """A router over ``num_experts`` and the experts this chip holds
     (``held``: their ids; default all), each ``W_out (silu(g) * u)`` with
     ``[g | u] = W_in h`` and no bias: the form Mixtral, Granite and the
-    DeepSeek family serve.  Inference only (``gated_experts_forward``):
+    DeepSeek family serve.  ``rule`` states the router's gating
+    (``router_gates``) and ``scaling`` its routed scaling factor.
+    Inference only (``gated_experts_forward``):
     dropless, no capacity, no auxiliary loss; a prefill chunk's rows go
     through the repo's own ``sorted_gated_ffn`` kernel and a decode
     step's through ``lax.ragged_dot``, by the rows alone.  ``MoELayer``
@@ -288,10 +318,14 @@ class GatedExpertLayer(Layer):
     it."""
 
     def __init__(self, d_model: int, d_hidden: int, num_experts: int,
-                 top_k: int, held=None, dtype="float32"):
+                 top_k: int, held=None, dtype="float32",
+                 rule: str = "softmax_topk", scaling: float = 1.0):
         super().__init__(dtype=dtype)
         import numpy as np
         from paddle_tpu.nn.common_layers import Linear
+        if rule not in ROUTER_RULES:
+            raise ValueError(f"router rule {rule!r}: one of {ROUTER_RULES}")
+        self.rule, self.scaling = rule, float(scaling)
         self.top_k = int(top_k)
         self.num_experts = int(num_experts)
         self.held = tuple(range(num_experts)) if held is None \
@@ -304,6 +338,11 @@ class GatedExpertLayer(Layer):
         local[list(self.held)] = np.arange(len(self.held))
         self._local_of = local
         self.router = Linear(d_model, num_experts, bias_attr=False)
+        # the choice bias of ``sigmoid_bias``: a leaf of its own, added
+        # to the scores in float32 whatever type it is stored in
+        self.router_bias = self.create_parameter(
+            [num_experts], is_bias=True) \
+            if rule == "sigmoid_bias" else None
         self.w_in = self.create_parameter(
             [len(self.held), d_model, 2 * d_hidden])
         self.w_out = self.create_parameter(
@@ -320,7 +359,9 @@ class GatedExpertLayer(Layer):
         out, counts = gated_experts_forward(
             x2d, unwrap(self.router.weight), unwrap(self.w_in),
             unwrap(self.w_out), top_k=self.top_k,
-            local_of=self._local_of, row_valid=row_valid)
+            local_of=self._local_of, row_valid=row_valid, rule=self.rule,
+            router_bias=None if self.router_bias is None
+            else unwrap(self.router_bias), scaling=self.scaling)
         return out.reshape(x.shape), counts
 
 

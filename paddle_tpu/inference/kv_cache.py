@@ -38,6 +38,15 @@ rebuilds the memory path vLLM-style around fixed-size **token blocks**:
 
 This is the serving engine's only KV cache (``inference/serving.py``).
 
+A **latent** pool (``PagedKVPool(..., latent=True)``) is the same pool
+for a layer whose attention caches one compressed row a token instead of
+per-head keys and values (latent attention, ``models/latent_attention.py``):
+one ``[num_blocks, block_size, width]`` array a layer — the row
+``[c | k_r]`` padded to whole lanes — and no V pool.  Block ids, the
+allocator, copy-on-write, export / import and the prefix cache work on
+blocks and do not know the difference; :func:`latent_cache_attention` is
+its attention path.  A latent is not quantised to int8 (refused).
+
 A second kind of state lives beside it (:class:`SlotStatePool`): a layer
 with recurrent state (a Mamba-2 mixer's convolution tail and SSM state)
 keeps a fixed-size state **per slot**, not per token.  The engine builds
@@ -63,6 +72,7 @@ import jax.numpy as jnp
 
 __all__ = ["BlockAllocator", "SequenceBlocks", "PrefixCache",
            "PagedKVPool", "PagedCache", "paged_cache_attention",
+           "latent_cache_attention", "query_positions",
            "SlotStatePool", "SlotState", "StepInfo",
            "quant_kv_mode", "serialize_handoff",
            "deserialize_handoff"]
@@ -399,17 +409,40 @@ class PagedKVPool:
 
     def __init__(self, num_layers: int, num_blocks: int, block_size: int,
                  kv_heads: int, head_dim: int, dtype,
-                 quant: Optional[str] = None):
+                 quant: Optional[str] = None, latent: bool = False):
         if quant not in (None, "int8"):
             raise ValueError(f"PagedKVPool quant={quant!r}: only int8")
+        if latent and quant:
+            raise ValueError(
+                "PagedKVPool latent=True with quant='int8': a latent row "
+                "is expanded into every head's keys and values, so one "
+                "scale a token would carry its rounding into all of them; "
+                "no per-head scale exists to quantise against")
+        if latent and kv_heads != 1:
+            raise ValueError(f"a latent pool holds one row a token, not "
+                             f"{kv_heads} kv heads")
         self.num_blocks = num_blocks
         self.block_size = block_size
         self.quant = quant
+        self.latent = bool(latent)
         self.compute_dtype = dtype
         store = jnp.int8 if quant == "int8" else dtype
-        shape = (num_blocks, block_size, kv_heads, head_dim)
+        if latent:
+            # one array a layer, no V pool; a row ``[c | k_r]`` of
+            # ``head_dim`` values is stored padded to whole lanes: the
+            # TPU's tiled HBM layout holds a 576-wide row as 640 whatever
+            # the array's shape says (Mosaic's memref of a
+            # [blocks, 16, 576] bf16 pool reads 16384x16x640, and a DMA
+            # of 576 of them is refused), so the pool says 640 and
+            # ``nbytes`` counts what is held
+            self.row_width = int(head_dim)
+            shape = (num_blocks, block_size, -(-head_dim // 128) * 128)
+            self.vpools = []
+        else:
+            shape = (num_blocks, block_size, kv_heads, head_dim)
+            self.vpools = [jnp.zeros(shape, store)
+                           for _ in range(num_layers)]
         self.kpools = [jnp.zeros(shape, store) for _ in range(num_layers)]
-        self.vpools = [jnp.zeros(shape, store) for _ in range(num_layers)]
         if quant:
             sshape = (num_blocks, block_size, kv_heads)
             self.kscales = [jnp.zeros(sshape, jnp.float32)
@@ -460,7 +493,7 @@ class PagedKVPool:
         shape = self.kpools[0].shape
         n = len(self.kpools)
         self.kpools = [jnp.zeros(shape, dtype) for _ in range(n)]
-        self.vpools = [jnp.zeros(shape, dtype) for _ in range(n)]
+        self.vpools = [jnp.zeros(shape, dtype) for _ in self.vpools]
         if self.quant:
             sshape = self.kscales[0].shape
             self.kscales = [jnp.zeros(sshape, jnp.float32)
@@ -494,10 +527,13 @@ class PagedKVPool:
         idx = jnp.asarray(bids + [0] * (self._bucket(n) - n), jnp.int32)
         outs = self._gather(self._all_pools(), idx)
         L = len(self.kpools)
+        # a latent pool has no V pool: ``"v"`` is the empty list, on the
+        # wire too (``serialize_handoff`` writes the arrays there are)
         payload = {"block_size": int(self.block_size),
                    "dtype": str(jnp.dtype(self.kpools[0].dtype)),
                    "k": [np.asarray(o)[:n] for o in outs[:L]],
-                   "v": [np.asarray(o)[:n] for o in outs[L:2 * L]]}
+                   "v": [np.asarray(o)[:n]
+                         for o in outs[L:L + len(self.vpools)]]}
         if self.quant:
             payload["k_scale"] = [np.asarray(o)[:n]
                                   for o in outs[2 * L:3 * L]]
@@ -525,10 +561,11 @@ class PagedKVPool:
         if not dst_bids:
             return
         L = len(self.kpools)
-        if len(payload["k"]) != L or len(payload["v"]) != L:
+        if len(payload["k"]) != L or len(payload["v"]) != len(self.vpools):
             raise ValueError(
                 f"handoff payload has {len(payload['k'])}/"
-                f"{len(payload['v'])} k/v layers, pool has {L}")
+                f"{len(payload['v'])} k/v layers, pool has {L}/"
+                f"{len(self.vpools)}")
         want = self.kpools[0].shape[1:]
         got = tuple(payload["k"][0].shape[1:])
         if got != want:
@@ -595,7 +632,7 @@ class PagedKVPool:
             vals += [prep(a) for a in list(kscale) + list(vscale)]
             pools += list(self.kscales) + list(self.vscales)
         out = self._scatter(pools, idx, vals)
-        self.kpools, self.vpools = out[:L], out[L:2 * L]
+        self.kpools, self.vpools = out[:L], out[L:L + len(self.vpools)]
         if self.quant:
             self.kscales = out[2 * L:3 * L]
             self.vscales = out[3 * L:]
@@ -693,7 +730,9 @@ def deserialize_handoff(data) -> dict:
         out["kv"] = {
             "block_size": int(meta["scalars"]["kv_block_size"]),
             "k": [arrays[f"kv.k{i}"] for i in range(L)],
-            "v": [arrays[f"kv.v{i}"] for i in range(L)],
+            # a latent pool's export has no V arrays
+            "v": [arrays[f"kv.v{i}"] for i in range(L)
+                  if f"kv.v{i}" in arrays],
         }
         if "kv_dtype" in meta["scalars"]:
             out["kv"]["dtype"] = meta["scalars"]["kv_dtype"]
@@ -789,12 +828,35 @@ class PagedCache(NamedTuple):
     block id; unallocated entries point at scratch block 0).  Quantized
     pools (int8) additionally carry the per-block scale arrays; fp
     pools leave them None (the default keeps every existing
-    3-argument constructor working)."""
+    3-argument constructor working).  A latent pool's view has ``v``
+    None and ``k`` ``[num_blocks, block_size, width]``."""
     k: object                   # [num_blocks, block_size, kv_heads, hd]
     v: object
     block_table: object         # [B, max_blocks] int32
     k_scale: object = None      # [num_blocks, block_size, kv_heads] f32
     v_scale: object = None
+
+
+def query_positions(position_offset, B: int, S: int):
+    """``[B, S]`` positions of a dispatch's queries: ``position_offset``
+    a scalar, or a per-row ``[B]`` vector (continuous batching, chunked
+    prefill: each row sits at its own offset)."""
+    if getattr(position_offset, "ndim", 0) == 1:
+        return position_offset[:, None] + jnp.arange(S)[None]
+    return jnp.broadcast_to(position_offset + jnp.arange(S)[None], (B, S))
+
+
+def _write_targets(bt, qpos, bs: int):
+    """Logical position -> (physical block, slot in it), ``[B, S]`` each.
+    Positions past the table (padded chunk tails near max_len) are routed
+    to the scratch block EXPLICITLY — clamping them into the row's last
+    real block would let a pad row overwrite live prompt KV when a
+    sequence has every block allocated.  Within the table, unallocated
+    entries are 0 (scratch) by construction."""
+    mb = bt.shape[1]
+    lb = qpos // bs
+    bids = jnp.take_along_axis(bt, jnp.minimum(lb, mb - 1), axis=1)
+    return jnp.where(lb < mb, bids, 0), qpos % bs
 
 
 def paged_cache_attention(q, k, v, cache: PagedCache, position_offset,
@@ -824,22 +886,8 @@ def paged_cache_attention(q, k, v, cache: PagedCache, position_offset,
     bt = unwrap(cache.block_table)
     bs = kp.shape[1]
     mb = bt.shape[1]
-    if getattr(position_offset, "ndim", 0) == 1:
-        qpos = position_offset[:, None] + jnp.arange(S)[None]     # [B, S]
-    else:
-        qpos = jnp.broadcast_to(
-            position_offset + jnp.arange(S)[None], (B, S))
-    # write: logical position → (physical block, slot).  Positions past
-    # the table (padded chunk tails near max_len) are routed to the
-    # scratch block EXPLICITLY — clamping them into the row's last real
-    # block would let a pad row overwrite live prompt KV when a
-    # sequence has every block allocated.  Within the table,
-    # unallocated entries are 0 (scratch) by construction.
-    lb = qpos // bs
-    bids = jnp.take_along_axis(bt, jnp.minimum(lb, mb - 1),
-                               axis=1)                            # [B, S]
-    bids = jnp.where(lb < mb, bids, 0)
-    slot = qpos % bs
+    qpos = query_positions(position_offset, B, S)
+    bids, slot = _write_targets(bt, qpos, bs)
     quant = cache.k_scale is not None
     if quant:
         # quantization fused into the block scatter: the step's fp K/V
@@ -899,3 +947,48 @@ def paged_cache_attention(q, k, v, cache: PagedCache, position_offset,
     out = scaled_dot_product_attention(q, wrap_like(kb), wrap_like(vb),
                                        attn_mask=mask, is_causal=False)
     return out, new_cache
+
+
+def latent_cache_attention(q, row, cache: PagedCache, position_offset,
+                           w_kvb, *, rank: int, nope: int, scale: float):
+    """Latent attention over a latent pool: write the step's rows
+    through the block table, then attend what the queries can see.
+
+    q ``[b, s, heads, nope + rope]`` (rotary part rotated, unscaled);
+    row ``[b, s, rank + rope]``, a token's ``[c | k_r]``; ``cache.k``
+    ``[num_blocks, block_size, width]`` with ``width`` the row padded to
+    whole lanes; ``w_kvb`` ``[rank, heads * (nope + v)]``.  Decode
+    (s == 1) on the TPU is the absorbed form as one Pallas call
+    (``ops/pallas/latent_attention.py``: ``[q_n W_uk | q_r]`` against the
+    cached rows, ``P c`` back, then ``W_uv``); anything else — a prefill
+    chunk, a speculative verify, the CPU — walks the tiles of context
+    the queries can see.  Returns ``(out [b, s, heads, v], new_cache)``."""
+    from paddle_tpu.core.dispatch import unwrap, wrap_like
+    from paddle_tpu.ops.pallas import latent_attention as LA
+    q, row, w_kvb = unwrap(q), unwrap(row), unwrap(w_kvb)
+    B, S, h, _ = q.shape
+    pool, bt = unwrap(cache.k), unwrap(cache.block_table)
+    bs, width = pool.shape[1], pool.shape[2]
+    qpos = query_positions(position_offset, B, S)
+    bids, slot = _write_targets(bt, qpos, bs)
+    stored = jnp.pad(row, ((0, 0), (0, 0), (0, width - row.shape[-1])))
+    pool = pool.at[bids, slot].set(stored.astype(pool.dtype))
+    new_cache = PagedCache(wrap_like(pool), None, cache.block_table)
+    if S == 1 and LA.latent_decode_eligible(rank, bs, q.dtype):
+        LA.record_path("decode_kernel")
+        wb = w_kvb.reshape(rank, h, -1)
+        q1 = q[:, 0]
+        qa = jnp.concatenate(
+            [jnp.einsum("bhn,chn->bhc", q1[..., :nope], wb[..., :nope],
+                        preferred_element_type=jnp.float32),
+             q1[..., nope:].astype(jnp.float32),
+             jnp.zeros((B, h, width - row.shape[-1]), jnp.float32)],
+            axis=-1) * scale
+        ol = LA.latent_decode_attention(qa.astype(q.dtype), pool, bt,
+                                        qpos[:, 0] + 1, rank)
+        out = jnp.einsum("bhc,chv->bhv", ol, wb[..., nope:],
+                         preferred_element_type=jnp.float32)
+        return wrap_like(out.astype(q.dtype)[:, None]), new_cache
+    out = LA.latent_chunk_attention(q, pool, bt, qpos, w_kvb, rank=rank,
+                                    nope=nope, scale=scale)
+    return wrap_like(out), new_cache

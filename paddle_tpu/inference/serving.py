@@ -478,8 +478,15 @@ class ContinuousBatchingEngine:
         # its layers route over experts (``routed_expert_layers()``):
         # their counts come back beside a decode step's tokens.  Either
         # statement gets the model a ``StepInfo`` after its caches
-        kinds = list(getattr(cfgm, "layer_types", None)
-                     or ["attention"] * cfgm.num_hidden_layers)
+        # A model whose attention caches one latent row a token
+        # (``config.latent_row``, its width) gets a latent pool: one array
+        # a layer and no V pool, behind the same block ids — the programs
+        # below carry its empty ``vpools`` as they carry an unquantised
+        # pool's empty scale lists
+        kinds = ["attention" if k.endswith("attention") else k
+                 for k in (getattr(cfgm, "layer_types", None)
+                           or ["attention"] * cfgm.num_hidden_layers)]
+        latent_row = int(getattr(cfgm, "latent_row", 0) or 0)
         self._state = None
         shapes = model.slot_state_shapes() \
             if hasattr(model, "slot_state_shapes") else []
@@ -500,10 +507,12 @@ class ContinuousBatchingEngine:
             self._state = SlotStatePool(slots, shapes, self._dtype)
         self._prefix = PrefixCache(self._block_size, self._allocator) \
             if prefix_cache else None
+        heads, width = (1, latent_row) if latent_row else \
+            (cfgm.num_key_value_heads, cfgm.head_dim)
         self._pool = PagedKVPool(
-            kinds.count("attention"), self._num_blocks,
-            self._block_size, cfgm.num_key_value_heads,
-            cfgm.head_dim, self._dtype, quant=self.kv_quant)
+            kinds.count("attention"), self._num_blocks, self._block_size,
+            heads, width, self._dtype, quant=self.kv_quant,
+            latent=bool(latent_row))
         # per-slot block table rows; 0 = reserved scratch block
         self._bt = np.zeros((slots, self._max_blocks), np.int32)
         self._seq: List[Optional[object]] = [None] * slots
@@ -681,8 +690,8 @@ class ContinuousBatchingEngine:
                       for kk, vv, ks, vs in zip(kpools, vpools,
                                                 kscales, vscales)]
             else:
-                cc = [PagedCache(kk, vv, bt)
-                      for kk, vv in zip(kpools, vpools)]
+                cc = [PagedCache(kk, vv, bt) for kk, vv in zip(
+                    kpools, vpools or [None] * len(kpools))]
             if info is not None:
                 paged, recur = iter(cc), iter(state)
                 cc = [next(paged if k == "attention" else recur)
@@ -699,7 +708,8 @@ class ContinuousBatchingEngine:
                 new_caches = [c for c, k in zip(new_caches, kinds)
                               if k == "attention"]
             return raw, ([unwrap(c.k) for c in new_caches],
-                         [unwrap(c.v) for c in new_caches],
+                         [unwrap(c.v) for c in new_caches]
+                         if vpools else [],
                          [unwrap(c.k_scale) for c in new_caches]
                          if kscales else [],
                          [unwrap(c.v_scale) for c in new_caches]
@@ -1580,6 +1590,7 @@ class ContinuousBatchingEngine:
         chunk samples the request's first token at the true last prompt
         position and registers the prompt's full blocks in the prefix
         trie (so the NEXT request with this prompt prefix skips them)."""
+        from paddle_tpu.observability.tracing import host_annotation
         tr = self._tracer
         req = self._active[slot]
         with tr.span("serving.build"):
@@ -1611,6 +1622,14 @@ class ContinuousBatchingEngine:
                         pool.vscales) = got[:2]
                 if state is not None:
                     state.layers = got[2]
+            # the context this chunk attends, on the profiler's host
+            # plane just after its dispatch (an atomic check while no
+            # profiler is capturing): chunks execute in dispatch order,
+            # so a reader pairs the n-th of these from the trace's end
+            # with the n-th prefill-chunk execution from its end
+            with host_annotation("serving.prefill_context", start=start,
+                                 tokens=n):
+                pass
             if final:
                 # a chunk that is not the last leaves nothing to wait
                 # for: the device runs it while the host goes on

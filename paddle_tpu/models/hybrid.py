@@ -1,32 +1,46 @@
-"""A hybrid decoder for serving: Mamba-2 layers and attention layers in
-one stack, each followed by a routed expert layer beside a shared expert
-— the ``granitemoehybrid`` equations (IBM Granite 4.0-H).
+"""The layer-pattern decoder for serving: a layer is a token mixer and a
+feed-forward, each of a kind the config names.
 
-Every layer, with ``r = residual_multiplier``:
+    x <- x + r * mixer(rms(x))     ``layer_types[i]``: ``mamba`` (Mamba-2),
+                                   ``attention`` (grouped-query) or
+                                   ``latent_attention`` (MLA)
+    x <- x + r * ffn(rms(x))       ``ffn_types[i]``: ``experts`` (a router
+                                   over gated experts beside a shared
+                                   expert) or ``dense`` (one gated MLP)
 
-    x <- x + r * mixer(rms(x))          mixer: Mamba-2 or attention
-    x <- x + r * (experts(rms(x)) + shared(rms(x)))
+with ``r = residual_multiplier``.  Two published families are
+configurations of it: ``granitemoehybrid`` (IBM Granite 4.0-H: Mamba-2 and
+attention without rotary positions, experts everywhere, multipliers, a
+tied head) and ``sarvam_mla`` (latent attention with YaRN rotary
+positions, a leading dense layer before expert layers whose router takes
+sigmoid scores and a choice bias, an untied head).
 
 The embedding's output is scaled by ``embedding_multiplier``, the head is
-the embedding (tied) and the logits are divided by ``logits_scaling``.
-Attention has no rotary positions and a stated score scale
-(``attention_multiplier``): ``LlamaAttention`` with both said.  The
-expert layer is ``distributed.moe.GatedExpertLayer``, told which experts
-this chip holds.  The Mamba-2 mathematics is ``ops/mamba2.py``.
+the embedding when ``tie_word_embeddings`` and the logits are divided by
+``logits_scaling``.  ``attention`` is ``LlamaAttention`` with what the
+config states: a head size (``head_dim``), rotary positions or none
+(``position_embedding_type``), a score scale (``attention_multiplier``).
+``latent_attention`` is ``models/latent_attention.py``.  The expert layer
+is ``distributed.moe.GatedExpertLayer``, told which experts this chip
+holds and its router's rule.  The Mamba-2 mathematics is
+``ops/mamba2.py``.
 
 The model serves and does not train (no scan backward, no auxiliary
 loss).  ``forward(input_ids, attn_mask, caches, position_offset)`` is the
 serving engine's signature: ``caches[i]`` is a ``PagedCache`` for an
-attention layer and a ``SlotState`` for a Mamba layer, and one more entry
+attention layer (over a latent pool for ``latent_attention``) and a
+``SlotState`` for a Mamba layer, and one more entry
 after the layers', a ``StepInfo``, says which rows are real and collects
 the expert layers' counts.  Device operations carry the scopes ``embed``,
 ``ssm`` (norm + mixer + residual), ``attn``, ``moe`` (norm + router +
-routed + shared + residual) and ``lm_head_ce``.
+routed + shared + residual), ``mlp`` (norm + dense MLP + residual) and
+``lm_head_ce``.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import types
 from typing import Optional, Tuple
 
 import jax
@@ -34,7 +48,9 @@ import jax.numpy as jnp
 
 from paddle_tpu.core.dispatch import unwrap
 from paddle_tpu.distributed.moe import GatedExpertLayer
-from paddle_tpu.models.llama import LlamaAttention, _fused_norm_qkv
+from paddle_tpu.models.latent_attention import LatentAttention
+from paddle_tpu.models.llama import LlamaAttention, LlamaMLP, \
+    _fused_norm_qkv
 from paddle_tpu.nn.common_layers import Embedding, Linear
 from paddle_tpu.nn.layer import Layer
 from paddle_tpu.nn.norm_layers import RMSNorm
@@ -49,14 +65,30 @@ class HybridConfig:
     vocab_size: int = 100352
     hidden_size: int = 4096
     num_hidden_layers: int = 40
-    layer_types: Tuple[str, ...] = ()       # "mamba" | "attention" a layer
+    # "mamba" | "attention" | "latent_attention" a layer
+    layer_types: Tuple[str, ...] = ()
+    ffn_types: Tuple[str, ...] = ()         # "experts" | "dense" a layer
     num_attention_heads: int = 32
     num_key_value_heads: int = 8
+    head_dim: Optional[int] = None          # stated; None: hidden / heads
     intermediate_size: int = 768            # one routed expert's width
     shared_intermediate_size: int = 1536
+    dense_intermediate_size: int = 0        # a "dense" layer's MLP width
     num_local_experts: int = 72             # the router's width
     num_experts_per_tok: int = 10
     held_experts: Optional[Tuple[int, ...]] = None  # ids here; None: all
+    # the router's rule (distributed/moe.py): "softmax_topk" | "sigmoid_bias"
+    router_rule: str = "softmax_topk"
+    routed_scaling_factor: float = 1.0
+    # latent attention: what a token caches is kv_lora_rank + rope values
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    qk_norm: bool = False                   # latent attention's two norms
+    rope_theta: float = 10000.0
+    rope_scaling: Optional[dict] = None     # YaRN (latent attention)
+    tie_word_embeddings: bool = True
     mamba_n_heads: int = 128
     mamba_d_head: int = 64
     mamba_d_state: int = 128
@@ -76,22 +108,51 @@ class HybridConfig:
     def __post_init__(self):
         self.layer_types = tuple(self.layer_types) or \
             ("attention",) * self.num_hidden_layers
+        self.ffn_types = tuple(self.ffn_types) or \
+            ("experts",) * self.num_hidden_layers
         if len(self.layer_types) != self.num_hidden_layers or \
-                set(self.layer_types) - {"mamba", "attention"}:
+                set(self.layer_types) - {"mamba", "attention",
+                                         "latent_attention"}:
             raise ValueError(f"layer_types {self.layer_types} do not name "
                              f"{self.num_hidden_layers} mamba / attention "
+                             f"/ latent_attention layers")
+        if len(self.ffn_types) != self.num_hidden_layers or \
+                set(self.ffn_types) - {"experts", "dense"}:
+            raise ValueError(f"ffn_types {self.ffn_types} do not name "
+                             f"{self.num_hidden_layers} experts / dense "
                              f"layers")
         if self.mamba_n_groups != 1:
             raise NotImplementedError("Mamba-2 with more than one B/C group")
-        if self.position_embedding_type != "nope":
-            raise NotImplementedError("hybrid attention with rotary "
-                                      "positions")
+        if self.position_embedding_type not in ("nope", "rope"):
+            raise NotImplementedError(
+                f"position_embedding_type "
+                f"{self.position_embedding_type!r}: rotary over the whole "
+                f"head (rope) or none (nope); partial rotary is not served")
+        kinds = set(self.layer_types)
+        if {"attention", "latent_attention"} <= kinds:
+            raise NotImplementedError(
+                "attention and latent_attention layers in one model: the "
+                "engine builds one kind of block pool")
+        if "latent_attention" in kinds and not (
+                self.kv_lora_rank and self.qk_rope_head_dim
+                and self.qk_nope_head_dim and self.v_head_dim):
+            raise ValueError("latent_attention needs kv_lora_rank, "
+                             "qk_nope_head_dim, qk_rope_head_dim and "
+                             "v_head_dim")
+        if "dense" in self.ffn_types and not self.dense_intermediate_size:
+            raise ValueError("a dense layer needs dense_intermediate_size")
+        if self.head_dim is None:
+            self.head_dim = self.hidden_size // self.num_attention_heads
         if self.held_experts is not None:
             self.held_experts = tuple(self.held_experts)
 
     @property
-    def head_dim(self):
-        return self.hidden_size // self.num_attention_heads
+    def latent_row(self):
+        """Values a token leaves in a latent-attention layer's cache:
+        the latent and the shared rotary key (0 without such layers)."""
+        if "latent_attention" not in self.layer_types:
+            return 0
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
     def mamba_d_inner(self):
@@ -233,31 +294,50 @@ class _SharedExpert(Layer):
 
 
 class HybridDecoderLayer(Layer):
-    def __init__(self, c: HybridConfig, kind: str):
+    def __init__(self, c: HybridConfig, kind: str, ffn: str = "experts"):
         super().__init__(dtype=c.dtype)
-        self.kind = kind
+        self.kind, self.ffn = kind, ffn
         self.residual = float(c.residual_multiplier)
         self.input_layernorm = RMSNorm(c.hidden_size,
                                        epsilon=c.rms_norm_eps)
         if kind == "mamba":
             self.mamba = Mamba2Mixer(c)
+        elif kind == "latent_attention":
+            self.self_attn = LatentAttention(c)
         else:
             self.self_attn = LlamaAttention(c)
         self.post_attention_layernorm = RMSNorm(c.hidden_size,
                                                 epsilon=c.rms_norm_eps)
-        self.block_sparse_moe = GatedExpertLayer(
-            c.hidden_size, c.intermediate_size, c.num_local_experts,
-            c.num_experts_per_tok, held=c.held_experts, dtype=c.dtype)
-        self.shared_mlp = _SharedExpert(c.hidden_size,
-                                        c.shared_intermediate_size)
+        if ffn == "dense":
+            self.mlp = LlamaMLP(types.SimpleNamespace(
+                dtype=c.dtype, hidden_size=c.hidden_size,
+                intermediate_size=c.dense_intermediate_size))
+        else:
+            self.block_sparse_moe = GatedExpertLayer(
+                c.hidden_size, c.intermediate_size, c.num_local_experts,
+                c.num_experts_per_tok, held=c.held_experts, dtype=c.dtype,
+                rule=c.router_rule, scaling=c.routed_scaling_factor)
+            self.shared_mlp = _SharedExpert(c.hidden_size,
+                                            c.shared_intermediate_size)
 
     def forward(self, x, attn_mask=None, cache=None, position_offset=0,
-                info=None):
+                info=None, rope=(None, None)):
         """-> (x, the layer's new cache or None, the expert layer's
-        counts)."""
+        counts: zeros for a dense layer)."""
         x = unwrap(x)
         new_cache = None
-        if self.kind == "mamba":
+        if self.kind == "latent_attention":
+            if attn_mask is not None:
+                raise NotImplementedError(
+                    "latent attention under an attn_mask: its paths are "
+                    "causal over the cached context only")
+            with jax.named_scope("attn"):
+                h = self.self_attn(self.input_layernorm(x), cache,
+                                   position_offset)
+                if cache is not None:
+                    h, new_cache = h
+                x = x + self.residual * h.astype(x.dtype)
+        elif self.kind == "mamba":
             with jax.named_scope("ssm"):
                 h = self.mamba(self.input_layernorm(x), cache, info)
                 if cache is not None:
@@ -267,14 +347,19 @@ class HybridDecoderLayer(Layer):
             with jax.named_scope("attn"):
                 qkv = _fused_norm_qkv(self, x)
                 if qkv is not None:
-                    h = self.self_attn.attend(*qkv, None, None, attn_mask,
+                    h = self.self_attn.attend(*qkv, *rope, attn_mask,
                                               cache, position_offset)
                 else:
-                    h = self.self_attn(self.input_layernorm(x), None, None,
+                    h = self.self_attn(self.input_layernorm(x), *rope,
                                        attn_mask, cache, position_offset)
                 if cache is not None:
                     h, new_cache = h
                 x = x + self.residual * unwrap(h).astype(x.dtype)
+        if self.ffn == "dense":
+            with jax.named_scope("mlp"):
+                h = unwrap(self.mlp(self.post_attention_layernorm(x)))
+                x = x + self.residual * h.astype(x.dtype)
+            return x, new_cache, jnp.zeros((3,), jnp.int32)
         with jax.named_scope("moe"):
             h = unwrap(self.post_attention_layernorm(x))
             real = None if info is None else \
@@ -292,10 +377,21 @@ class HybridModel(Layer):
         self.embed_tokens = Embedding(config.vocab_size, config.hidden_size)
         self.layers = []
         for i, kind in enumerate(config.layer_types):
-            layer = HybridDecoderLayer(config, kind)
+            layer = HybridDecoderLayer(config, kind, config.ffn_types[i])
             self.add_sublayer(f"layers_{i}", layer)
             self.layers.append(layer)
         self.norm = RMSNorm(config.hidden_size, epsilon=config.rms_norm_eps)
+        # grouped-query attention with rotary positions reads LlamaModel's
+        # float32 tables (latent attention computes its own from the
+        # positions); kept off the layer tree, so a dtype cast, a
+        # state_dict and a LazyGuard build never see them
+        self._rope = (None, None)
+        if config.position_embedding_type == "rope" and \
+                "attention" in config.layer_types:
+            from paddle_tpu.nn import functional as F
+            self._rope = tuple(unwrap(t) for t in F.rotary_freqs(
+                config.head_dim, config.max_position_embeddings,
+                base=config.rope_theta))
         if config.dtype != "float32":
             self.astype(config.dtype)
 
@@ -314,7 +410,7 @@ class HybridModel(Layer):
         for i, layer in enumerate(self.layers):
             x, c, n = layer(x, attn_mask,
                             None if caches is None else caches[i],
-                            position_offset, info)
+                            position_offset, info, self._rope)
             counts = counts + n
             if caches is not None:
                 new_caches.append(c)
@@ -328,15 +424,21 @@ class HybridModel(Layer):
 
 
 class HybridForCausalLM(Layer):
-    """``HybridModel`` under its tied head.  The serving engine asks a
-    model two things, apart: ``slot_state_shapes`` (its recurrent
-    layers' state) and ``routed_expert_layers`` (how many layers add to
-    ``StepInfo.moe_counts``)."""
+    """``HybridModel`` under its head (the embedding when tied).  The
+    serving engine asks a model three things, apart:
+    ``slot_state_shapes`` (its recurrent layers' state),
+    ``routed_expert_layers`` (how many layers add to
+    ``StepInfo.moe_counts``) and ``config.latent_row`` (the width of a
+    latent-attention layer's cached row: a latent pool, not K and V)."""
 
     def __init__(self, config: HybridConfig):
         super().__init__(dtype=config.dtype)
         self.config = config
         self.model = HybridModel(config)
+        self.lm_head = None if config.tie_word_embeddings else \
+            Linear(config.hidden_size, config.vocab_size, bias_attr=False)
+        if self.lm_head is not None and config.dtype != "float32":
+            self.lm_head.astype(config.dtype)
 
     def slot_state_shapes(self):
         """[(conv tail, SSM state)] a Mamba layer, in layer order,
@@ -345,8 +447,8 @@ class HybridForCausalLM(Layer):
                 if layer.kind == "mamba"]
 
     def routed_expert_layers(self) -> int:
-        """Layers that route over experts: every layer here."""
-        return len(self.model.layers)
+        """Layers that route over experts."""
+        return self.config.ffn_types.count("experts")
 
     def forward(self, input_ids, attn_mask=None, caches=None,
                 position_offset=0):
@@ -355,10 +457,15 @@ class HybridForCausalLM(Layer):
         if caches is not None:
             h, new_caches = h
         with jax.named_scope("lm_head_ce"):
-            w = unwrap(self.model.embed_tokens.weight)
-            logits = jnp.einsum("bsd,vd->bsv", h, w,
-                                preferred_element_type=jnp.float32) \
-                / self.config.logits_scaling
+            if self.lm_head is None:
+                logits = jnp.einsum(
+                    "bsd,vd->bsv", h, unwrap(self.model.embed_tokens.weight),
+                    preferred_element_type=jnp.float32)
+            else:
+                logits = jnp.einsum(
+                    "bsd,dv->bsv", h, unwrap(self.lm_head.weight),
+                    preferred_element_type=jnp.float32)
+            logits = logits / self.config.logits_scaling
         if caches is not None:
             return logits, new_caches
         return logits

@@ -51,14 +51,15 @@ class LlamaConfig:
     # path keeps dense grads (XLA fuses its scatter-add)
     sparse_embed: bool = False
     dtype: str = "float32"
+    # a config may state its head size (q_proj is then heads x head_dim
+    # wide whatever hidden_size is); None: hidden_size / heads
+    head_dim: Optional[int] = None
 
     def __post_init__(self):
         if self.num_key_value_heads is None:
             self.num_key_value_heads = self.num_attention_heads
-
-    @property
-    def head_dim(self):
-        return self.hidden_size // self.num_attention_heads
+        if self.head_dim is None:
+            self.head_dim = self.hidden_size // self.num_attention_heads
 
     @staticmethod
     def llama3_8b():

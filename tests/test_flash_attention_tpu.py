@@ -296,6 +296,60 @@ def test_mamba2_scan_and_step_compile(one_chip):
     assert step.memory_analysis().temp_size_in_bytes < 24 * 128 * 64 * 128 * 4
 
 
+# sarvam-105b's widths (serve-longctx): 64 heads, latent 512 + rotary key
+# 64 stored as 640 lanes, q.k 192 / v 128; 24 slots over a 33,552-token
+# table of 16-token blocks, 16,384 blocks
+def test_latent_decode_kernel_compiles(one_chip):
+    """The absorbed decode kernel over the latent pool's lane-padded rows
+    compiles under its own name; a pool 576 wide does not (the chip's
+    tiled layout holds it as 640 and Mosaic refuses the 576-wide DMA),
+    which is why the pool pads the row."""
+    from paddle_tpu.ops.pallas.latent_attention import \
+        latent_decode_attention
+    slots, block, blocks, table = 24, 16, 16384, 2097
+
+    def decode(q, pool, bt, lengths):
+        return latent_decode_attention(q, pool, bt, lengths, 512,
+                                       interpret=False)
+
+    S = lambda shape, dt=BF16: jax.ShapeDtypeStruct(shape, dt,
+                                                    sharding=one_chip)
+    compiled = _compile(decode, S((slots, 64, 640)),
+                        S((blocks, block, 640)),
+                        S((slots, table), jnp.int32), S((slots,), jnp.int32))
+    assert "latent_attention" in compiled.as_text()
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _compile(decode, S((slots, 64, 576)), S((blocks, block, 576)),
+                 S((slots, table), jnp.int32), S((slots,), jnp.int32))
+
+
+@pytest.mark.parametrize("table", [2097, 1072])
+def test_latent_chunk_walk_compiles_with_a_run_time_trip_count(one_chip,
+                                                                table):
+    """A 512-token prefill chunk's attention over the pool: one while
+    loop whose trip count is read from the positions (no constant bound
+    of 66 tiles), under the scope the reader looks for, its temporaries a
+    few score tiles and not the table's worth of keys — at the cell's
+    table (33,552 positions: not a whole number of 512-token tiles, so
+    the walk pads it) and at half of it."""
+    from paddle_tpu.ops.pallas.latent_attention import \
+        latent_chunk_attention
+
+    def chunk(q, pool, bt, qpos, w):
+        return latent_chunk_attention(q, pool, bt, qpos, w, rank=512,
+                                      nope=128, scale=0.135)
+
+    S = lambda shape, dt=BF16: jax.ShapeDtypeStruct(shape, dt,
+                                                    sharding=one_chip)
+    compiled = jax.jit(chunk).lower(
+        S((1, 512, 64, 192)), S((16384, 16, 640)), S((1, table), jnp.int32),
+        S((1, 512), jnp.int32), S((512, 64 * 256))).compile()
+    text = compiled.as_text()
+    assert "latent_chunk_attention" in text and " while(" in text
+    assert 'known_trip_count' not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 def test_paged_engine_warms_the_targets_it_always_has(monkeypatch):
     """The walk's trip count is a run-time scalar read from the lengths:
     a paged engine whose decode step runs the kernel (interpret mode

@@ -849,7 +849,7 @@ KERNEL_NAMES = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                 "rmsnorm_qkv", "fused_mlp", "fused_decoder",
                 "paged_attention", "rmsnorm", "fused_ce_fwd",
                 "fused_ce_bwd", "grouped_matmul", "sorted_gated_ffn",
-                "quant_matmul")
+                "quant_matmul", "latent_attention")
 
 
 @pytest.mark.parametrize("kernel", KERNEL_NAMES)
