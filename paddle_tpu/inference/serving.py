@@ -36,7 +36,7 @@ from __future__ import annotations
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -130,6 +130,13 @@ def _serving_metrics():
             "paddle_tpu_serving_spec_tokens_total",
             "speculative-decoding draft tokens",
             labelnames=("kind",)),
+        "decode_dispatches": reg.counter(
+            "paddle_tpu_serving_decode_dispatches_total",
+            "batched decode dispatches (the fused decode, the speculative "
+            "verify) by whether an earlier one's output was still unread "
+            "on the host when this one was issued: 'overlapped' went out "
+            "while the host had yet to read its predecessor, 'waited' "
+            "after everything before it was read", labelnames=("kind",)),
         "parks": reg.counter(
             "paddle_tpu_serving_session_parks_total",
             "sessions demoted out of HBM (slot freed, KV spilled to "
@@ -226,6 +233,15 @@ class _Request:
     # token budget before the prompt was extended with generated tokens
     orig_prompt: Optional[np.ndarray] = None
     orig_max_new: int = 0
+
+
+@dataclass
+class _Dispatch:
+    """A batched dispatch whose output the host has not read yet."""
+    out: Any                        # device: tokens [B, S] (, counts)
+    rows: List[Tuple[int, _Request]]   # (slot, request) it advances
+    steps: int                      # positions it writes from each row
+    t0: float                       # perf_counter when it was issued
 
 
 class RequestStatus(str):
@@ -543,6 +559,14 @@ class ContinuousBatchingEngine:
         self._active: List[Optional[_Request]] = [None] * slots
         self._budget = np.zeros((slots,), np.int32)    # tokens remaining
         self._last_tok = np.zeros((slots,), np.int32)
+        # a decode step is dispatched first and collected afterwards: the
+        # one batched dispatch whose output the host has yet to read, the
+        # decode program's last output column (it stays on the device and
+        # is the next dispatch's input), and when the host last read one
+        self._inflight: Optional[_Dispatch] = None
+        self._dev_toks = jnp.zeros((slots,), jnp.int32)
+        self._collected_at = 0.0
+        self._step_span = None
         self._queue: deque = deque()
         self._done: deque = deque()
         self._next_rid = 0
@@ -744,11 +768,16 @@ class ContinuousBatchingEngine:
                 return first.astype(jnp.int32), pools, state
             return first.astype(jnp.int32), pools
 
+        # ``toks`` is this program's own last output column, which never
+        # left the device; a row whose token the host made (a prompt's
+        # last chunk, a resume) takes it from ``host_toks`` instead
         def decode_paged(keep, quant, kpools, vpools, kscales,
-                         vscales, bt, toks, pos, active, key, state=()):
+                         vscales, bt, toks, host_toks, from_host, pos,
+                         active, key, state=()):
             ps = _dequant(keep, quant, dtype)
             info = StepInfo(active.astype(jnp.int32)) if step_info \
                 else None
+            toks = jnp.where(from_host, host_toks, toks)
 
             def one(carry, _):
                 (kpools, vpools, kscales, vscales, toks, pos, key,
@@ -770,14 +799,14 @@ class ContinuousBatchingEngine:
                         key, state, counts), nxt
 
             counts = jnp.zeros((3,), jnp.int32) if experts else None
-            (kpools, vpools, kscales, vscales, _, _, _, state,
+            (kpools, vpools, kscales, vscales, last, _, _, state,
              counts), seq = jax.lax.scan(
                     one, (kpools, vpools, kscales, vscales, toks,
                           pos, key, state, counts), None, length=K)
             out = jnp.swapaxes(seq, 0, 1)
             if experts:         # the expert layers' counts come back
                 out = (out, counts)     # beside the tokens, in one copy
-            return (out, kpools, vpools, kscales, vscales) \
+            return (out, kpools, vpools, kscales, vscales, last) \
                 + ((state,) if stateful else ())
 
         # speculative verify: ONE batched forward over
@@ -798,7 +827,7 @@ class ContinuousBatchingEngine:
         self._decode_paged_raw = decode_paged
         self._decode_paged = jax.jit(
             decode_paged, donate_argnums=(2, 3, 4, 5)
-            + ((11,) if stateful else ()))
+            + ((13,) if stateful else ()))
         self._spec_verify = jax.jit(spec_verify,
                                     donate_argnums=(2, 3, 4, 5))
         # AOT executables from aot_warmup(); dispatch prefers them (no
@@ -864,8 +893,8 @@ class ContinuousBatchingEngine:
         active = jnp.ones((self.slots,), jnp.bool_)
         kpools, vpools, kscales, vscales, bt = self._paged_dummies()
         c = warm(self._decode_paged, self._keep, self._quant, kpools,
-                 vpools, kscales, vscales, bt, toks, pos, active,
-                 self._key, *self._state_dummies(),
+                 vpools, kscales, vscales, bt, toks, toks, active, pos,
+                 active, self._key, *self._state_dummies(),
                  target="serving.decode")
         if c is not None:
             self._decode_compiled = c
@@ -930,7 +959,7 @@ class ContinuousBatchingEngine:
         kpools, vpools, kscales, vscales, bt = self._paged_dummies()
         return _analysis.check(
             self._decode_paged_raw, self._keep, self._quant, kpools,
-            vpools, kscales, vscales, bt, toks, pos, active,
+            vpools, kscales, vscales, bt, toks, toks, active, pos, active,
             self._key, *self._state_dummies(), strict=strict,
             passes=passes, options=options)
 
@@ -1073,9 +1102,11 @@ class ContinuousBatchingEngine:
         # AUTO-parked sessions count: the scheduler owes them a resume,
         # so run() must keep stepping.  Caller-parked sessions don't —
         # they are dormant until the caller's resume().
+        # A dispatch the host has not read counts too: its tokens are
+        # not in ``finished()`` until a step (or ``run``) collects them.
         return len(self._queue) + sum(r is not None for r in self._active) \
             + sum(1 for req, _k in self._parked.values()
-                  if req.auto_parked)
+                  if req.auto_parked) + (self._inflight is not None)
 
     # -- scheduling ----------------------------------------------------------
     def _admit(self, slot: int, req: _Request) -> bool:
@@ -1323,6 +1354,7 @@ class ContinuousBatchingEngine:
         :func:`~paddle_tpu.inference.kv_cache.serialize_handoff` for a
         byte transport."""
         self._refuse_with_slot_state("export_handoff")
+        self._collect()
         req, seq, first = self._handoff_ready.pop(rid)
         bs = self._block_size
         Lp = len(req.prompt)
@@ -1382,6 +1414,7 @@ class ContinuousBatchingEngine:
         if self._kv_tier is None:
             raise ValueError("park() requires a kv_tier= manager "
                              "attached")
+        self._collect()     # the session's tokens and write head, as read
         slot = next((i for i, r in enumerate(self._active)
                      if r is not None and r.rid == rid), None)
         if slot is None or slot in self._prefilling:
@@ -1424,6 +1457,7 @@ class ContinuousBatchingEngine:
         already emitted and re-prefilled; greedy argmax regenerates the
         same chain, so the final output is token-identical either way."""
         self._refuse_with_slot_state("resume")
+        self._collect()
         ent = self._parked.pop(rid, None)
         if ent is None:
             raise KeyError(f"rid {rid} is not parked on this engine")
@@ -1480,6 +1514,7 @@ class ContinuousBatchingEngine:
         tier keys; None skips a session.  Returns sessions shipped."""
         if self._kv_tier is None:
             return 0
+        self._collect()     # a payload holds what the host has read
         shipped = 0
         for slot, req in enumerate(self._active):
             if req is None or slot in self._prefilling or not req.out:
@@ -1630,6 +1665,9 @@ class ContinuousBatchingEngine:
             with host_annotation("serving.prefill_context", start=start,
                                  tokens=n):
                 pass
+            # the decode step before this chunk is read while the device
+            # runs the chunk: its tokens are not held back by a prompt
+            self._collect()
             if final:
                 # a chunk that is not the last leaves nothing to wait
                 # for: the device runs it while the host goes on
@@ -1687,17 +1725,17 @@ class ContinuousBatchingEngine:
                 or self._budget[slot] <= 0:
             self._retire(slot)
 
-    def _ensure_writable_span(self, slots_: List[int], span: int):
+    def _ensure_writable_span(self, slots_: List[int], pos, span: int):
         """COW guard before a dispatch that writes `span` positions from
-        each slot's write head: any still-shared block in the span is
-        copied to a private one (device block copy) and the block table
-        is repointed.  Steady state is a no-op — the engine allocates
-        private decode blocks at admission."""
+        each slot's write head `pos`: any still-shared block in the span
+        is copied to a private one (device block copy) and the block
+        table is repointed.  Steady state is a no-op — the engine
+        allocates private decode blocks at admission."""
         bs = self._block_size
         for i in slots_:
             seq = self._seq[i]
-            first = int(self._pos[i]) // bs
-            last = min((int(self._pos[i]) + span - 1) // bs,
+            first = int(pos[i]) // bs
+            last = min((int(pos[i]) + span - 1) // bs,
                        len(seq.bids) - 1)
             for idx in range(first, last + 1):
                 if seq.ensure_writable(idx,
@@ -1705,52 +1743,107 @@ class ContinuousBatchingEngine:
                     self._metrics["cow"].inc()
                     self._bt[i, idx] = seq.bids[idx]
 
-    def _run_batched(self, program, decoding: List[int], toks, span: int,
-                     *more):
-        """Upload, call and copy back ONE batched decode-shaped program
-        (the fused decode, the speculative verify): ``toks`` is its
-        token array, ``span`` the positions it writes from each decoding
-        slot's write head, ``more`` what it takes after ``active`` (the
-        fused decode: the sampling key and, for a model that takes a
-        StepInfo, its slot state).  Returns the program's first output
-        on the host and the clock reading the dispatch started at.  The
-        only place the engine hands its pools and its slot state to a
-        batched program and takes them back."""
+    def _ahead(self):
+        """Per slot, the positions the outstanding dispatch writes that
+        the host has not read yet: its ``steps`` for a row still held by
+        the request it was dispatched for, 0 for every other.  What a
+        dispatch needs of such a row it knows without the tokens: the
+        write head and the budget move by this much, whatever they are."""
+        ahead = np.zeros((self.slots,), np.int32)
+        d = self._inflight
+        if d is not None:
+            for i, req in d.rows:
+                if self._active[i] is req:
+                    ahead[i] = d.steps
+        return ahead
+
+    def _phase(self, name: str):
+        """A span of the collect: a child of the step under way also
+        where the caller stands inside a request's span (a prompt's last
+        chunk collects between its dispatch and its own read)."""
+        tr = self._tracer
+        return tr.span(name, parent=self._step_span or tr.current_span(),
+                       root_eligible=False)
+
+    def _dispatch_batched(self, program, decoding: List[int], toks,
+                          span: int, *more):
+        """Upload and call ONE batched decode-shaped program (the fused
+        decode, the speculative verify) and start its output's copy to
+        the host; nothing here waits for the device.  ``toks`` are its
+        token arguments, ``span`` the positions it writes from each
+        decoding slot's write head, ``more`` what it takes after
+        ``active`` (the fused decode: the sampling key and, for a model
+        that takes a StepInfo, its slot state).  Returns the dispatch,
+        to be read by :meth:`_read`, and what the program returned
+        after its pools.  The only place the engine hands its pools and
+        its slot state to a batched program and takes them back."""
         tr = self._tracer
         with tr.span("serving.build"):
             active = np.zeros((self.slots,), bool)
             active[decoding] = True
-            self._ensure_writable_span(decoding, span)
-            pos = np.where(active, self._pos, 0).astype(np.int32)
+            pos = self._pos + self._ahead()
+            self._ensure_writable_span(decoding, pos, span)
+            pos = np.where(active, pos, 0).astype(np.int32)
             # non-decoding rows (free OR mid-prefill) get a zeroed
             # block-table row: their masked write lands in the scratch
             # block, not in a real sequence's (possibly shared) block 0
             bt = np.where(active[:, None], self._bt, 0)
+        self._metrics["decode_dispatches"].labels(
+            kind="waited" if self._inflight is None else "overlapped").inc()
         t0 = time.perf_counter()
         pool, state = self._pool, self._state
-        with self._recorder.instrumented("serving.decode"):
-            with tr.span("serving.dispatch"):
-                got = program(
-                    self._keep, self._quant, pool.kpools, pool.vpools,
-                    pool.kscales, pool.vscales, jnp.asarray(bt),
-                    jnp.asarray(toks), jnp.asarray(pos),
-                    jnp.asarray(active), *more)
-                (out, pool.kpools, pool.vpools, pool.kscales,
-                 pool.vscales) = got[:5]
-                if len(got) > 5:
-                    state.layers = got[5]
-            with tr.span("serving.sync"):
-                if not isinstance(out, tuple):
-                    return np.asarray(out), t0
-                out, counts = jax.device_get(out)    # one copy-back
-            self._count_experts(counts, span)
-            return out, t0
+        with self._recorder.instrumented("serving.decode"), \
+                tr.span("serving.dispatch"):
+            got = program(
+                self._keep, self._quant, pool.kpools, pool.vpools,
+                pool.kscales, pool.vscales, jnp.asarray(bt),
+                *(jnp.asarray(t) for t in toks),
+                jnp.asarray(pos), jnp.asarray(active), *more)
+            (out, pool.kpools, pool.vpools, pool.kscales,
+             pool.vscales) = got[:5]
+            rest = list(got[5:])
+            if state is not None:
+                state.layers = rest.pop()
+            for leaf in jax.tree_util.tree_leaves(out):
+                leaf.copy_to_host_async()
+        return _Dispatch(out, [(i, self._active[i]) for i in decoding],
+                         span, t0), rest
+
+    def _read(self, d: _Dispatch):
+        """The tokens of dispatch ``d`` on the host (blocks until the
+        device has run it).  An expert model's counts come in the same
+        copy and are counted here, once a dispatch, in dispatch order."""
+        with self._recorder.instrumented("serving.decode"), \
+                self._phase("serving.sync"):
+            out = jax.device_get(d.out)
+        if isinstance(out, tuple):
+            out, counts = out
+            self._count_experts(counts, d.steps)
+        return out
+
+    def _collect(self):
+        """Read the outstanding decode dispatch, if there is one, and
+        move the host's state by it: ``out``, the write heads, budgets,
+        last tokens and stamps of its rows, and the retirements.  A row
+        whose request left the slot since the dispatch (an ``eos`` the
+        host learned a step late) ran one wasted row-step: its tokens
+        are dropped.  Every method that reads or moves a slot's tokens,
+        blocks or state from outside a step calls this first."""
+        d, self._inflight = self._inflight, None
+        if d is None:
+            return
+        toks = self._read(d)                            # toks: [B, K]
+        with self._phase("serving.emit"):
+            live = [i for i, req in d.rows if self._active[i] is req]
+            self._emit_decoded(live, [toks[i] for i in live],
+                               max(d.t0, self._collected_at),
+                               toks.shape[1])
 
     def _count_experts(self, counts, steps: int):
         """The expert layers' counts of one decode dispatch (summed over
         its ``steps`` steps and the model's expert layers) into the
         registry and, while a profiler session is capturing, onto the
-        host plane beside the dispatch that made them."""
+        host plane once its copy has reached the host."""
         from paddle_tpu.observability.tracing import host_annotation
         touched, local, picks = self._moe_counters
         layer_steps = steps * self._expert_layers
@@ -1763,25 +1856,30 @@ class ContinuousBatchingEngine:
             pass
 
     def _decode_step(self, decoding: List[int]):
-        """One fused K-step decode over every decoding slot."""
-        toks, t0 = self._run_batched(
+        """One fused K-step decode over every decoding slot, issued
+        before the one before it is read: a row that dispatch advanced
+        goes on from the device's own tokens, any other row from the
+        token the host holds for it."""
+        from_host = self._ahead() == 0
+        d, (self._dev_toks,) = self._dispatch_batched(
             self._decode_compiled or self._decode_paged, decoding,
-            self._last_tok, self.steps_per_sync, self._next_key(),
+            (self._dev_toks, self._last_tok.copy(), from_host),
+            self.steps_per_sync, self._next_key(),
             *(() if not self._step_info else (
                 self._state.layers if self._state else [],)))
-        with self._tracer.span("serving.emit"):
-            self._emit_decoded(                         # toks: [B, K]
-                decoding, [toks[i] for i in decoding], t0, toks.shape[1])
+        self._collect()
+        self._inflight = d
 
     def _emit_decoded(self, slots_: List[int], rows, t0: float,
                       per_slot: Optional[int] = None):
-        """The token loop after a decode dispatch: ``rows[n]`` are the
-        tokens slot ``slots_[n]`` got, in order.  ONE clock reading
-        stamps the whole batch's emission; a request that reaches eos
-        or its budget retires here.  The decode-latency histogram gets
-        the dispatch's wall time over the tokens a slot hauled:
-        ``per_slot``, or the mean over the slots when they differ."""
-        now = time.perf_counter()
+        """The token loop after a decode dispatch is read: ``rows[n]``
+        are the tokens slot ``slots_[n]`` got, in order.  ONE clock
+        reading, taken now that the host has them, stamps the whole
+        batch's emission; a request that reaches eos or its budget
+        retires here.  The decode-latency histogram gets the wall time
+        since ``t0`` over the tokens a slot hauled: ``per_slot``, or
+        the mean over the slots when they differ."""
+        now = self._collected_at = time.perf_counter()
         emitted = 0
         for i, row in zip(slots_, rows):
             req = self._active[i]
@@ -1820,9 +1918,13 @@ class ContinuousBatchingEngine:
         chain plus one bonus token.  Greedy-equivalent by construction:
         position j's argmax is conditioned only on tokens the chain has
         already validated."""
-        tr = self._tracer
         k = self.spec_tokens
         S = k + 1
+        # the drafts come from the history the host holds and the next
+        # ones from what this verify accepts: nothing can be left unread
+        # before the build, and this dispatch is read in its own step
+        self._collect()
+        tr = self._tracer
         with tr.span("serving.build"):
             toks = np.zeros((self.slots, S), np.int32)
             proposed = np.zeros((self.slots,), np.int64)
@@ -1837,9 +1939,10 @@ class ContinuousBatchingEngine:
                     toks[i, 1:1 + n] = draft
                     toks[i, 1 + n:] = draft[-1]  # static-shape pad; unused
                     proposed[i] = n
-        greedy, t0 = self._run_batched(                 # greedy: [B, S]
+        d, _ = self._dispatch_batched(
             self._spec_verify_compiled or self._spec_verify, decoding,
-            toks, S)
+            (toks,), S)
+        greedy = self._read(d)                          # greedy: [B, S]
         with tr.span("serving.emit"):
             m = self._metrics
             rows = []
@@ -1860,7 +1963,7 @@ class ContinuousBatchingEngine:
                     m["spec"].labels(kind="proposed").inc(n)
                     if a:
                         m["spec"].labels(kind="accepted").inc(a)
-            self._emit_decoded(decoding, rows, t0)
+            self._emit_decoded(decoding, rows, d.t0)
 
     def _schedule_head(self, step_span):
         """What an engine step starts with, inside the caller's
@@ -1886,11 +1989,18 @@ class ContinuousBatchingEngine:
                 self._maybe_auto_park()
             free = [i for i, r in enumerate(self._active) if r is None]
             # a failed admission leaves the slots as they are, so who
-            # decodes is settled here, in the schedule's own span
+            # decodes is settled here, in the schedule's own span; a row
+            # whose budget ends in the dispatch still unread is left out
+            # (an eos is learned one collect late: that row runs on)
+            left = self._budget - self._ahead()
             decoding = [i for i, r in enumerate(self._active)
-                        if r is not None and i not in self._prefilling]
+                        if r is not None and i not in self._prefilling
+                        and left[i] > 0]
             step_span.set_attribute("decoding", len(decoding))
         if free and self._queue:
+            # blocks are handed out with nothing unread: what a retired
+            # row's last dispatch wrote, it wrote before they are reused
+            self._collect()
             req = self._queue[0]
             with tr.span("serving.admit", rid=req.rid) as sp:
                 admitted = self._admit(free[0], req)
@@ -1907,7 +2017,10 @@ class ContinuousBatchingEngine:
             # already rejected anything the empty pool couldn't hold, so
             # retiring slots / evicting cached prefixes will free enough
             # blocks eventually; deadlines still bound the wait)
+            decoding = [i for i in decoding     # the collect retired some
+                        if self._active[i] is not None]
         if all(r is None for r in self._active):
+            self._collect()     # at most a wasted row-step is left
             return bool(self._queue)
         # chunked prefill interleaves with decode: alternate dispatches
         # so a kilotoken prompt can't stall in-flight requests' TPOT,
@@ -1925,6 +2038,7 @@ class ContinuousBatchingEngine:
             self._prefill_chunk_step(next(iter(self._prefilling)))
             return True
         if not decoding:
+            self._collect()     # every row's budget ends in it
             return True
         if self.spec_tokens:
             step_span.set_attribute("ran", "spec")
@@ -2038,9 +2152,14 @@ class ContinuousBatchingEngine:
         free themselves (the other slots keep decoding), and queued
         requests stop waiting for a slot that isn't coming."""
         now = time.perf_counter()
-        for slot, req in enumerate(self._active):
-            if req is not None and req.deadline is not None \
-                    and now > req.deadline:
+        late = [slot for slot, req in enumerate(self._active)
+                if req is not None and req.deadline is not None
+                and now > req.deadline]
+        if late:
+            self._collect()     # a slot is freed with nothing unread
+        for slot in late:
+            req = self._active[slot]
+            if req is not None:     # the collect may have retired it
                 self._metrics["timeouts"].inc()
                 self._recorder.record("serving.timeout", rid=req.rid,
                                       slot=slot, generated=len(req.out))
@@ -2089,6 +2208,10 @@ class ContinuousBatchingEngine:
         re-raises: that is a persistent fault, not a transient one."""
         self._error_streak += 1
         self._metrics["engine_errors"].inc()
+        try:        # what an earlier dispatch finished is served; one the
+            self._collect()     # fault took with it goes with the batch
+        except Exception:  # noqa: BLE001
+            pass
         self._recorder.record("serving.engine_error",
                               error=type(exc).__name__,
                               message=str(exc)[:200],
@@ -2120,6 +2243,7 @@ class ContinuousBatchingEngine:
         self._pos[:] = 0
         self._budget[:] = 0
         self._last_tok[:] = 0
+        self._dev_toks = jnp.zeros((self.slots,), jnp.int32)
         # restart-after-fault cold start: consult the persistent compile
         # cache so a recovering engine that never warmed (or a future
         # where recovery rebuilds executables) gets its programs back
@@ -2145,16 +2269,20 @@ class ContinuousBatchingEngine:
         # build, dispatch, sync, emit), each opened where the work is
         with tr.span("serving.step", root_eligible=False,
                      ran="none") as sp:
-            with tr.span("serving.schedule"):
-                self._expire()
+            self._step_span = sp
             try:
-                out = self._step_inner(sp)
-            except Exception as e:  # KeyboardInterrupt etc. propagate
-                self._recover(e)
-                return bool(self._queue) or \
-                    any(r is not None for r in self._active)
-            self._error_streak = 0
-            return out
+                with tr.span("serving.schedule"):
+                    self._expire()
+                try:
+                    out = self._step_inner(sp)
+                except Exception as e:  # KeyboardInterrupt etc. propagate
+                    self._recover(e)
+                    return bool(self._queue) or \
+                        any(r is not None for r in self._active)
+                self._error_streak = 0
+                return out
+            finally:
+                self._step_span = None
 
     def run(self):
         """Drain queue + slots; returns {rid: (prompt, tokens)}."""
@@ -2166,7 +2294,9 @@ class ContinuousBatchingEngine:
         """Hand the model back: restores train mode if the engine
         flipped it at construction, and drops this engine's weight-
         quantization reference (the original Linears come back when the
-        last engine holding the conversion closes)."""
+        last engine holding the conversion closes).  A dispatch still
+        unread is collected first: ``finished()`` then holds it."""
+        self._collect()
         if self._quant_converted:
             from paddle_tpu.quantization.serving import \
                 restore_from_serving

@@ -525,3 +525,66 @@ def test_park_resume_and_handoff_are_refused(model):
             call()
     eng.run()       # and the engine is still serving
     assert eng.request_status(rid) == "ok"
+
+
+# -- a decode step dispatched before the one before it is read ----------------
+
+@pytest.mark.parametrize("k", [1, 4])
+def test_dispatch_then_collect_serves_the_references_tokens(
+        arch, model, leaves, k):
+    """Mixed prompt lengths over three slots, one offered late, so chunks
+    run between decode steps whose tokens the host has yet to read: the
+    slot state and the attention layer's blocks go on from the device's
+    own tokens, and every request gets the reference's."""
+    from paddle_tpu.inference.serving import ContinuousBatchingEngine
+    eng = ContinuousBatchingEngine(model, **dict(ENGINE, steps_per_sync=k))
+    prompts = _prompts((21, 5, 43, 18), seed=20 + k)
+    budgets = (9, 12, 6, 7)
+    with jax.default_matmul_precision("highest"):
+        rids = [eng.add_request(p, max_new_tokens=b)
+                for p, b in zip(prompts[:3], budgets)]
+        for _ in range(6):
+            eng.step()
+        rids.append(eng.add_request(prompts[3], max_new_tokens=budgets[3]))
+        res = eng.run()
+    for rid, p, b in zip(rids, prompts, budgets):
+        assert len(res[rid][1]) == b
+        assert _served_gap(arch, leaves, p, res[rid][1]) <= TOL
+    assert eng._inflight is None and not eng.pending
+
+
+def test_expert_counts_are_annotated_once_a_dispatch_in_dispatch_order(
+        model, monkeypatch):
+    """``serving.moe_counts`` is written when a dispatch's counts reach
+    the host: one a dispatch, in the order they were issued, never more
+    than the one unread dispatch and the one just issued behind."""
+    import contextlib
+    from paddle_tpu.inference.serving import ContinuousBatchingEngine
+    from paddle_tpu.observability import tracing
+    events, issued = [], []
+
+    @contextlib.contextmanager
+    def annotation(name, **stats):
+        if name == "serving.moe_counts":
+            events.append(("counts", stats["touched"]))
+        yield
+    monkeypatch.setattr(tracing, "host_annotation", annotation)
+    eng = ContinuousBatchingEngine(model, **ENGINE)
+    dispatch = eng._dispatch_batched
+
+    def dispatch_spy(*a):
+        d, rest = dispatch(*a)
+        issued.append(d.out[1])
+        events.append(("dispatch", None))
+        return d, rest
+    eng._dispatch_batched = dispatch_spy
+    for p in _prompts((19, 7, 25), seed=31):
+        eng.add_request(p, max_new_tokens=8)
+    eng.run()
+    behind = 0
+    for kind, _ in events:
+        behind += 1 if kind == "dispatch" else -1
+        assert 0 <= behind <= 2
+    assert behind == 0 and len(issued) > 8
+    assert [t for kind, t in events if kind == "counts"] == \
+        [int(np.asarray(c)[0]) for c in issued]

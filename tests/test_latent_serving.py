@@ -597,3 +597,54 @@ def test_a_stated_head_size_and_rotary_positions_in_the_hybrid_model():
         nope = np.asarray(m(jnp.asarray(
             np.concatenate([prompt, toks])[None])))[0]
     assert np.abs(nope - lg).max() > 1e-3 * np.abs(lg).max()
+
+
+# -- a decode step dispatched before the one before it is read ----------------
+
+@pytest.mark.parametrize("k", [1, 4])
+def test_dispatch_then_collect_serves_the_references_tokens(
+        arch, model, leaves, k):
+    """Mixed prompt lengths over three slots, one offered late, so chunks
+    run between decode steps whose tokens the host has yet to read: the
+    latent cache goes on from the device's own tokens, and every request
+    gets the reference's."""
+    eng = _engine(model, steps_per_sync=k)
+    prompts = _prompts((37, 9, 20, 26), seed=40 + k)
+    budgets = (7, 12, 9, 6)
+    with jax.default_matmul_precision("highest"):
+        rids = [eng.add_request(p, max_new_tokens=b)
+                for p, b in zip(prompts[:3], budgets)]
+        for _ in range(6):
+            eng.step()
+        rids.append(eng.add_request(prompts[3], max_new_tokens=budgets[3]))
+        res = eng.run()
+    for rid, p, b in zip(rids, prompts, budgets):
+        assert len(res[rid][1]) == b
+        assert _served_gap(arch, leaves, p, res[rid][1]) <= TOL
+    assert eng._inflight is None and not eng.pending
+
+
+def test_a_session_parked_between_two_steps_holds_what_was_unread(model):
+    """``park`` with a decode dispatch unread reads it first: the payload
+    carries those tokens too, and the resumed session serves the tokens
+    of an undisturbed run."""
+    from paddle_tpu.inference.kv_tier import KVTierManager
+    from paddle_tpu.observability.fleet import LocalStore
+    prompt = _prompts([27], seed=13)[0]
+    with jax.default_matmul_precision("highest"):
+        eng = _engine(model)
+        rid = eng.add_request(prompt, max_new_tokens=10)
+        want = list(eng.run()[rid][1])
+        tier = KVTierManager(store=LocalStore())
+        eng = _engine(model, kv_tier=tier)
+        rid = eng.add_request(prompt, max_new_tokens=10)
+        while eng._inflight is None or len(eng._active[0].out) < 3:
+            eng.step()
+        held = len(eng._active[0].out)
+        key = eng.park(rid)
+        assert key is not None and eng._inflight is None
+        snap = tier.fetch(key)
+        assert list(snap["tokens_out"]) == want[:held + 1]
+        assert snap["pos"] == len(prompt) + held
+        eng.resume(rid)
+        assert list(eng.run()[rid][1]) == want

@@ -356,7 +356,7 @@ class TestKnobOffRegression:
         active = jnp.ones((eng.slots,), jnp.bool_)
         jaxpr = str(jax.make_jaxpr(eng._decode_paged_raw)(
             eng._keep, eng._quant, kpools, vpools, kscales, vscales,
-            bt, toks, pos, active, eng._key))
+            bt, toks, toks, active, pos, active, eng._key))
         assert "i8[" not in jaxpr and "f8_e4m3" not in jaxpr
         eng.close()
 
@@ -370,7 +370,7 @@ class TestKnobOffRegression:
         active = jnp.ones((eng.slots,), jnp.bool_)
         jaxpr = str(jax.make_jaxpr(eng._decode_paged_raw)(
             eng._keep, eng._quant, kpools, vpools, kscales, vscales,
-            bt, toks, pos, active, eng._key))
+            bt, toks, toks, active, pos, active, eng._key))
         assert "i8[" in jaxpr
         eng.close()
 

@@ -785,6 +785,68 @@ class TestServingBackpressure:
         assert res[r2][1] == list(np.asarray(ref)[0, len(prompt):])
         assert eng.request_status(r2) == "ok"
 
+    def test_expiry_with_a_dispatch_unread_leaves_the_engine_serving(
+            self, tiny_model):
+        """A slot whose deadline passes while its decode dispatch is
+        unread: the dispatch is collected first, the slot times out with
+        the tokens it had, and the other slot's tokens are the
+        reference's."""
+        rng = np.random.default_rng(5)
+        eng = self._engine(tiny_model, slots=2)
+        ra = eng.add_request(rng.integers(0, 256, (8,)),
+                             max_new_tokens=40, timeout_s=3600)
+        prompt = rng.integers(0, 256, (8,))
+        rb = eng.add_request(prompt, max_new_tokens=12)
+        while eng._inflight is None or len(eng._active[0].out) < 3:
+            eng.step()
+        unread = eng._inflight
+        assert {i for i, _ in unread.rows} == {0, 1}
+        eng._active[0].deadline = time.perf_counter()   # ra's passes
+        eng.step()
+        st = eng.request_status(ra)
+        assert st == "timeout" and eng._active[0] is None
+        # what the unread dispatch made was read before the slot went
+        assert st.timings["generated"] >= 4
+        res = eng.run()
+        ref = tiny_model.generate(np.asarray(prompt, np.int32)[None],
+                                  max_new_tokens=12, do_sample=False)
+        assert res[rb][1] == list(np.asarray(ref)[0, len(prompt):])
+        assert eng.request_status(rb) == "ok"
+
+    def test_a_fault_with_a_dispatch_unread_serves_what_was_finished(
+            self, tiny_model):
+        """The step that faults had a dispatch unread whose tokens end
+        one request's budget: recovery reads it first, so that request
+        is served whole, the other fails with the batch, and the engine
+        goes on serving."""
+        rng = np.random.default_rng(6)
+        eng = self._engine(tiny_model, slots=2)
+        short = rng.integers(0, 256, (8,))
+        r0 = eng.add_request(short, max_new_tokens=3)
+        r1 = eng.add_request(rng.integers(0, 256, (8,)), max_new_tokens=30)
+        for _ in range(50):
+            eng.step()
+            d = eng._inflight
+            if d is not None and eng._active[0] is not None and \
+                    eng._budget[0] - d.steps <= 0:
+                break
+        assert eng.request_status(r0) is None
+        inject("serving.engine_step", times=1)
+        eng.step()
+        assert eng._inflight is None
+        ref = tiny_model.generate(np.asarray(short, np.int32)[None],
+                                  max_new_tokens=3, do_sample=False)
+        assert eng.request_status(r0) == "ok"
+        assert eng.request_status(r1) == "error"
+        done = {rid: out for rid, _, out in eng.finished()}
+        assert done[r0] == list(np.asarray(ref)[0, len(short):])
+        prompt = rng.integers(0, 256, (8,))
+        r2 = eng.add_request(prompt, max_new_tokens=5)
+        res = eng.run()
+        ref = tiny_model.generate(np.asarray(prompt, np.int32)[None],
+                                  max_new_tokens=5, do_sample=False)
+        assert res[r2][1] == list(np.asarray(ref)[0, len(prompt):])
+
     def test_persistent_engine_fault_reraises(self, tiny_model):
         rng = np.random.default_rng(4)
         eng = self._engine(tiny_model, slots=1,

@@ -265,7 +265,7 @@ class TestOneEngine:
         # pools are donated to a program and taken back in two places:
         # the one-row chunk and the batched dispatch both steps share
         assert len(re.findall(r"pool\.vscales\) = ", src)) == 2
-        assert src.count("self._run_batched(") == 2
+        assert src.count("self._dispatch_batched(") == 2
 
 
 class TestSampling:
@@ -305,3 +305,238 @@ class TestSampling:
                 for n in (6, 10)]
         results = eng.run()
         assert all(len(results[r][1]) == 8 for r in rids)
+
+
+def _dispatch_kinds():
+    """{kind: count} of the batched decode dispatches so far."""
+    from paddle_tpu.observability import default_registry
+    c = default_registry().get("paddle_tpu_serving_decode_dispatches_total")
+    return {k[0]: v.value() for k, v in c.series()} if c else {}
+
+
+def _kinds_since(before):
+    now = _dispatch_kinds()
+    return {k: now.get(k, 0) - before.get(k, 0)
+            for k in ("overlapped", "waited")}
+
+
+def _spy_decode(eng):
+    """Every call of the engine's decode program: (``from_host``,
+    ``active``) as the host uploaded them, in dispatch order."""
+    calls, program = [], eng._decode_paged
+
+    def spy(*args):
+        calls.append((np.asarray(args[9]).copy(), np.asarray(args[11]).copy()))
+        return program(*args)
+    eng._decode_paged = spy
+    return calls
+
+
+class TestDispatchThenCollect:
+    """A decode step is dispatched from the device's own tokens before
+    the one before it is read (PR 40): same tokens, other order."""
+
+    @pytest.mark.parametrize("k", [1, 4])
+    def test_interleaved_chunks_serve_the_references_tokens(self,
+                                                            tiny_model, k):
+        """Six prompts of mixed lengths over three slots, two of them
+        offered while others decode: chunks of later prompts run between
+        the decode steps of earlier ones, and every request gets the
+        tokens of a run of its own."""
+        rng = np.random.default_rng(50 + k)
+        prompts = [rng.integers(0, 256, (n,)) for n in (5, 37, 17, 9, 30, 21)]
+        budgets = [9, 6, 11, 5, 8, 7]
+        eng = ContinuousBatchingEngine(tiny_model, slots=3, max_len=96,
+                                       prefill_buckets=(16,),
+                                       steps_per_sync=k)
+        before = _dispatch_kinds()
+        rids = [eng.add_request(p, max_new_tokens=b)
+                for p, b in zip(prompts[:4], budgets)]
+        for _ in range(7):
+            eng.step()
+        rids += [eng.add_request(p, max_new_tokens=b)
+                 for p, b in zip(prompts[4:], budgets[4:])]
+        results = eng.run()
+        for rid, p, b in zip(rids, prompts, budgets):
+            assert results[rid][1] == _reference(tiny_model, p, b), rid
+        kinds = _kinds_since(before)
+        assert kinds["overlapped"] > 0 and kinds["waited"] > 0
+        assert eng._inflight is None and not eng.pending
+
+    def test_a_run_of_decode_steps_overlaps_all_but_its_first(self,
+                                                              tiny_model):
+        eng = ContinuousBatchingEngine(tiny_model, slots=1, max_len=64,
+                                       prefill_buckets=(16,))
+        calls = _spy_decode(eng)
+        before = _dispatch_kinds()
+        prompt = np.random.default_rng(60).integers(0, 256, (9,))
+        rid = eng.add_request(prompt, max_new_tokens=8)
+        assert eng.run()[rid][1] == _reference(tiny_model, prompt, 8)
+        # the first token is the prompt's last chunk's; seven decode steps
+        assert _kinds_since(before) == {"waited": 1, "overlapped": 6}
+        # the first takes the chunk's token from the host, the others go
+        # on from the device's
+        assert [bool(f[0]) for f, _ in calls] == [True] + [False] * 6
+
+    def test_every_speculative_dispatch_waits(self, tiny_model):
+        """The verify drafts from the history the host holds: it is
+        issued with nothing unread and read in its own step."""
+        eng = ContinuousBatchingEngine(tiny_model, slots=2, max_len=96,
+                                       prefill_buckets=(16,), spec_decode=2)
+        rng = np.random.default_rng(61)
+        prompts = [np.tile(rng.integers(0, 256, (4,)), 5),
+                   rng.integers(0, 256, (11,))]
+        before = _dispatch_kinds()
+        rids = [eng.add_request(p, max_new_tokens=10) for p in prompts]
+        unread = []
+        while eng.pending:
+            eng.step()
+            unread.append(eng._inflight)
+        kinds = _kinds_since(before)
+        assert kinds["overlapped"] == 0 and kinds["waited"] > 0
+        assert unread and all(d is None for d in unread)
+        res = {rid: out for rid, _, out in eng.finished()}
+        for rid, p in zip(rids, prompts):
+            assert res[rid] == _reference(tiny_model, p, 10)
+
+    def test_eos_costs_one_wasted_row_step_and_nothing_reaches_out(
+            self, tiny_model):
+        """An ``eos`` is learned one collect late: the row runs once more
+        in the dispatch already issued, its tokens are dropped, and no
+        block is handed out while a dispatch is unread, so what the
+        wasted row-step writes lands in blocks its request still held
+        when it was issued."""
+        rng = np.random.default_rng(62)
+        prompts = [rng.integers(0, 256, (n,)) for n in (7, 12, 19, 6)]
+        refs = [_reference(tiny_model, p, 12) for p in prompts]
+        eos = refs[0][4]
+        want = [r[:r.index(eos) + 1] if eos in r else r for r in refs]
+        assert len(want[0]) <= 5
+        eng = ContinuousBatchingEngine(tiny_model, slots=2, max_len=64,
+                                       prefill_buckets=(16,),
+                                       eos_token_id=int(eos))
+        calls = _spy_decode(eng)
+        admit, unread_at_admit = eng._admit, []
+
+        def admit_spy(slot, req):
+            unread_at_admit.append(eng._inflight)
+            return admit(slot, req)
+        eng._admit = admit_spy
+        dropped = []
+        emit = eng._emit_decoded
+
+        def emit_spy(slots_, rows, *a):
+            dropped.append(len(slots_))
+            return emit(slots_, rows, *a)
+        eng._emit_decoded = emit_spy
+        rids = [eng.add_request(p, max_new_tokens=12) for p in prompts]
+        res = eng.run()
+        for rid, w in zip(rids, want):
+            assert res[rid][1] == w
+            assert eng.request_status(rid) == "ok"
+        assert len(unread_at_admit) == 4 and \
+            all(d is None for d in unread_at_admit)
+        # at least one dispatch carried a row that the collect dropped
+        rows = [int(a.sum()) for _, a in calls]
+        assert len(rows) == len(dropped) and \
+            any(n < r for n, r in zip(dropped, rows))
+
+    def test_a_budget_ended_row_is_absent_from_the_next_dispatch(
+            self, tiny_model):
+        eng = ContinuousBatchingEngine(tiny_model, slots=2, max_len=64,
+                                       prefill_buckets=(16,))
+        calls = _spy_decode(eng)
+        rng = np.random.default_rng(63)
+        short, long_ = rng.integers(0, 256, (8,)), rng.integers(0, 256, (8,))
+        r0 = eng.add_request(short, max_new_tokens=3)
+        r1 = eng.add_request(long_, max_new_tokens=9)
+        res = eng.run()
+        assert res[r0][1] == _reference(tiny_model, short, 3)
+        assert res[r1][1] == _reference(tiny_model, long_, 9)
+        # slot 0 decodes twice after its chunk's token, slot 1 eight times:
+        # no dispatch carries a row whose budget the one before it ended
+        per_slot = np.sum([a for _, a in calls], axis=0)
+        assert per_slot.tolist() == [2, 8]
+
+    def test_pending_while_a_dispatch_is_unread_and_run_returns_it(
+            self, tiny_model):
+        eng = ContinuousBatchingEngine(tiny_model, slots=1, max_len=64,
+                                       prefill_buckets=(16,))
+        prompt = np.random.default_rng(64).integers(0, 256, (6,))
+        rid = eng.add_request(prompt, max_new_tokens=3)
+        want = _reference(tiny_model, prompt, 3)
+        seen = False
+        for _ in range(20):
+            eng.step()
+            d = eng._inflight
+            if d is not None and eng._budget[0] - d.steps <= 0:
+                # the request's last tokens are on the device, unread
+                seen = True
+                assert eng.pending and eng.request_status(rid) is None
+                assert list(eng.finished()) == []
+                assert len(eng._active[0].out) < 3
+                break
+        assert seen
+        assert eng.run()[rid][1] == want and not eng.pending
+        assert eng.request_status(rid).timings["generated"] == 3
+
+    def test_token_stamps_are_not_earlier_than_their_read(self, tiny_model):
+        import time
+        eng = ContinuousBatchingEngine(tiny_model, slots=2, max_len=64,
+                                       prefill_buckets=(16,),
+                                       steps_per_sync=2)
+        read, reads = eng._read, []
+
+        def read_spy(d):
+            out = read(d)
+            reads.append(time.perf_counter())
+            return out
+        eng._read = read_spy
+        rng = np.random.default_rng(65)
+        rids = [eng.add_request(rng.integers(0, 256, (n,)), max_new_tokens=9)
+                for n in (5, 20)]
+        eng.run()
+        stamps = sorted({t for rid in rids for t, _ in
+                         eng.request_status(rid).token_times[1:]})
+        # one clock reading a collect, taken once the host has the tokens
+        assert len(stamps) == len(reads)
+        for t, done, nxt in zip(stamps, reads, reads[1:] + [float("inf")]):
+            assert done <= t < nxt
+
+    def test_the_drain_points_see_the_collected_state(self, tiny_model):
+        """``park``, ``checkpoint_sessions`` and ``export_handoff``
+        between two steps first read what is unread: a payload holds the
+        tokens and the write head of the same moment."""
+        from paddle_tpu.inference.kv_tier import KVTierManager
+        from paddle_tpu.observability.fleet import LocalStore
+        prompt = np.random.default_rng(66).integers(0, 256, (10,))
+        want = _reference(tiny_model, prompt, 12)
+        tier = KVTierManager(store=LocalStore())
+        eng = ContinuousBatchingEngine(tiny_model, slots=2, max_len=64,
+                                       prefill_buckets=(16,), kv_tier=tier)
+        rid = eng.add_request(prompt, max_new_tokens=12)
+        while eng._inflight is None or len(eng._active[0].out) < 3:
+            eng.step()
+        assert eng.checkpoint_sessions() == 1 and eng._inflight is None
+        snap = tier.fetch(f"rid{rid}")
+        out = list(eng._active[0].out)
+        assert list(snap["tokens_out"]) == out == want[:len(out)]
+        assert snap["pos"] == len(prompt) + len(out) - 1 == eng._pos[0]
+        assert snap["last_token"] == out[-1]
+        while eng._inflight is None:
+            eng.step()
+        n = len(eng._active[0].out)
+        assert eng.park(rid) is not None and eng._inflight is None
+        req, _key = eng._parked[rid]
+        assert len(req.out) > n and req.out == want[:len(req.out)]
+        eng.resume(rid)
+        assert eng.run()[rid][1] == want
+        # a prompt prefilled here for another engine, beside a decode
+        other = np.random.default_rng(67).integers(0, 256, (9,))
+        r0 = eng.add_request(other, max_new_tokens=8)
+        r1 = eng.add_request(prompt, max_new_tokens=8, prefill_only=True)
+        while eng.request_status(r1) is None:
+            eng.step()
+        payload = eng.export_handoff(r1)
+        assert eng._inflight is None and payload["first_token"] == want[0]
+        assert eng.run()[r0][1] == _reference(tiny_model, other, 8)
