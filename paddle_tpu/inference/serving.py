@@ -137,6 +137,14 @@ def _serving_metrics():
             "on the host when this one was issued: 'overlapped' went out "
             "while the host had yet to read its predecessor, 'waited' "
             "after everything before it was read", labelnames=("kind",)),
+        "dispatches": reg.counter(
+            "paddle_tpu_serving_dispatches_total",
+            "programs handed to the device (kind: decode, prefill_chunk, "
+            "spec_verify) by what the device held when each was issued: "
+            "'fed' something of this engine's was still running or "
+            "queued, so the device goes straight on; 'drained' the "
+            "newest output was already complete, so the device is idle "
+            "until this program starts", labelnames=("kind", "device")),
         "parks": reg.counter(
             "paddle_tpu_serving_session_parks_total",
             "sessions demoted out of HBM (slot freed, KV spilled to "
@@ -242,6 +250,7 @@ class _Dispatch:
     rows: List[Tuple[int, _Request]]   # (slot, request) it advances
     steps: int                      # positions it writes from each row
     t0: float                       # perf_counter when it was issued
+    seq: int                        # its number among the engine's programs
 
 
 class RequestStatus(str):
@@ -566,6 +575,11 @@ class ContinuousBatchingEngine:
         self._inflight: Optional[_Dispatch] = None
         self._dev_toks = jnp.zeros((slots,), jnp.int32)
         self._collected_at = 0.0
+        # every program handed to the device is numbered, and the newest
+        # one's output says whether the device still has work (one
+        # stream, programs in order: that output complete, nothing left)
+        self._dispatch_seq = 0
+        self._newest_out = None
         self._step_span = None
         self._queue: deque = deque()
         self._done: deque = deque()
@@ -1642,7 +1656,8 @@ class ContinuousBatchingEngine:
         pool, state = self._pool, self._state
         with tr.span("serving.prefill", parent=req.span,
                      rid=req.rid, chunk_start=start, tokens=n):
-            with tr.span("serving.dispatch"):
+            seq = self._count_dispatch("prefill_chunk")
+            with tr.span("serving.dispatch", seq=seq, kind="prefill_chunk"):
                 got = prefill(
                     self._keep, self._quant, jnp.asarray(ids),
                     pool.kpools, pool.vpools, pool.kscales, pool.vscales,
@@ -1657,6 +1672,7 @@ class ContinuousBatchingEngine:
                         pool.vscales) = got[:2]
                 if state is not None:
                     state.layers = got[2]
+                self._newest_out = first
             # the context this chunk attends, on the profiler's host
             # plane just after its dispatch (an atomic check while no
             # profiler is capturing): chunks execute in dispatch order,
@@ -1671,7 +1687,7 @@ class ContinuousBatchingEngine:
             if final:
                 # a chunk that is not the last leaves nothing to wait
                 # for: the device runs it while the host goes on
-                with tr.span("serving.sync"):
+                with tr.span("serving.sync", seq=seq):
                     first = int(first)
         with tr.span("serving.emit"):
             self._emit_chunk(slot, req, first, start, n, final)
@@ -1757,26 +1773,41 @@ class ContinuousBatchingEngine:
                     ahead[i] = d.steps
         return ahead
 
-    def _phase(self, name: str):
+    def _phase(self, name: str, **attrs):
         """A span of the collect: a child of the step under way also
         where the caller stands inside a request's span (a prompt's last
         chunk collects between its dispatch and its own read)."""
         tr = self._tracer
         return tr.span(name, parent=self._step_span or tr.current_span(),
-                       root_eligible=False)
+                       root_eligible=False, **attrs)
 
-    def _dispatch_batched(self, program, decoding: List[int], toks,
-                          span: int, *more):
-        """Upload and call ONE batched decode-shaped program (the fused
-        decode, the speculative verify) and start its output's copy to
-        the host; nothing here waits for the device.  ``toks`` are its
-        token arguments, ``span`` the positions it writes from each
-        decoding slot's write head, ``more`` what it takes after
-        ``active`` (the fused decode: the sampling key and, for a model
-        that takes a StepInfo, its slot state).  Returns the dispatch,
-        to be read by :meth:`_read`, and what the program returned
-        after its pools.  The only place the engine hands its pools and
-        its slot state to a batched program and takes them back."""
+    def _count_dispatch(self, kind: str) -> int:
+        """Number the program about to be handed to the device (the
+        ``seq`` of its ``serving.dispatch`` span and of the
+        ``serving.sync`` that reads it) and count it by whether the
+        device had run dry: programs run in order on one stream, so the
+        newest output being complete means nothing of this engine's is
+        left there.  Non-blocking, and it needs no profiler."""
+        self._dispatch_seq += 1
+        newest = self._newest_out
+        fed = newest is not None and not newest.is_ready()
+        self._metrics["dispatches"].labels(
+            kind=kind, device="fed" if fed else "drained").inc()
+        return self._dispatch_seq
+
+    def _dispatch_batched(self, kind: str, program, decoding: List[int],
+                          toks, span: int, *more):
+        """Upload and call ONE batched decode-shaped program (``kind``:
+        the fused ``decode``, the speculative ``spec_verify``) and start
+        its output's copy to the host; nothing here waits for the
+        device.  ``toks`` are its token arguments, ``span`` the
+        positions it writes from each decoding slot's write head,
+        ``more`` what it takes after ``active`` (the fused decode: the
+        sampling key and, for a model that takes a StepInfo, its slot
+        state).  Returns the dispatch, to be read by :meth:`_read`, and
+        what the program returned after its pools.  The only place the
+        engine hands its pools and its slot state to a batched program
+        and takes them back."""
         tr = self._tracer
         with tr.span("serving.build"):
             active = np.zeros((self.slots,), bool)
@@ -1792,8 +1823,9 @@ class ContinuousBatchingEngine:
             kind="waited" if self._inflight is None else "overlapped").inc()
         t0 = time.perf_counter()
         pool, state = self._pool, self._state
+        seq = self._count_dispatch(kind)
         with self._recorder.instrumented("serving.decode"), \
-                tr.span("serving.dispatch"):
+                tr.span("serving.dispatch", seq=seq, kind=kind):
             got = program(
                 self._keep, self._quant, pool.kpools, pool.vpools,
                 pool.kscales, pool.vscales, jnp.asarray(bt),
@@ -1804,17 +1836,19 @@ class ContinuousBatchingEngine:
             rest = list(got[5:])
             if state is not None:
                 state.layers = rest.pop()
-            for leaf in jax.tree_util.tree_leaves(out):
+            leaves = jax.tree_util.tree_leaves(out)
+            for leaf in leaves:
                 leaf.copy_to_host_async()
+            self._newest_out = leaves[0]
         return _Dispatch(out, [(i, self._active[i]) for i in decoding],
-                         span, t0), rest
+                         span, t0, seq), rest
 
     def _read(self, d: _Dispatch):
         """The tokens of dispatch ``d`` on the host (blocks until the
         device has run it).  An expert model's counts come in the same
         copy and are counted here, once a dispatch, in dispatch order."""
         with self._recorder.instrumented("serving.decode"), \
-                self._phase("serving.sync"):
+                self._phase("serving.sync", seq=d.seq):
             out = jax.device_get(d.out)
         if isinstance(out, tuple):
             out, counts = out
@@ -1862,7 +1896,7 @@ class ContinuousBatchingEngine:
         token the host holds for it."""
         from_host = self._ahead() == 0
         d, (self._dev_toks,) = self._dispatch_batched(
-            self._decode_compiled or self._decode_paged, decoding,
+            "decode", self._decode_compiled or self._decode_paged, decoding,
             (self._dev_toks, self._last_tok.copy(), from_host),
             self.steps_per_sync, self._next_key(),
             *(() if not self._step_info else (
@@ -1940,7 +1974,8 @@ class ContinuousBatchingEngine:
                     toks[i, 1 + n:] = draft[-1]  # static-shape pad; unused
                     proposed[i] = n
         d, _ = self._dispatch_batched(
-            self._spec_verify_compiled or self._spec_verify, decoding,
+            "spec_verify", self._spec_verify_compiled or self._spec_verify,
+            decoding,
             (toks,), S)
         greedy = self._read(d)                          # greedy: [B, S]
         with tr.span("serving.emit"):
@@ -1980,6 +2015,7 @@ class ContinuousBatchingEngine:
     def _step_inner(self, step_span) -> bool:
         tr = self._tracer
         with tr.span("serving.schedule"):
+            self._expire()
             self._schedule_head(step_span)
             if self._auto_park_s is not None:
                 # deadline-aware session scheduling: park the most
@@ -2244,6 +2280,7 @@ class ContinuousBatchingEngine:
         self._budget[:] = 0
         self._last_tok[:] = 0
         self._dev_toks = jnp.zeros((self.slots,), jnp.int32)
+        self._newest_out = None     # the failed call's may never complete
         # restart-after-fault cold start: consult the persistent compile
         # cache so a recovering engine that never warmed (or a future
         # where recovery rebuilds executables) gets its programs back
@@ -2267,12 +2304,12 @@ class ContinuousBatchingEngine:
         # one span per step, whatever the batch: its children are the
         # phases the device waits for the host in (schedule, admit,
         # build, dispatch, sync, emit), each opened where the work is
-        with tr.span("serving.step", root_eligible=False,
-                     ran="none") as sp:
+        with tr.span("serving.step", root_eligible=False) as sp:
+            # what the step ran is known when it ends: set, not opened
+            # with, so the profiler's event carries no placeholder
+            sp.set_attribute("ran", "none")
             self._step_span = sp
             try:
-                with tr.span("serving.schedule"):
-                    self._expire()
                 try:
                     out = self._step_inner(sp)
                 except Exception as e:  # KeyboardInterrupt etc. propagate

@@ -44,12 +44,15 @@ also entered as a ``jax.profiler.TraceAnnotation`` of the same name
 ``profiler.RecordEvent`` goes through it too).  While a profiler session
 is capturing, the span therefore sits on its host thread's line of the
 ``.xplane.pb`` beside the device lines, on the profiler's clock, and a
-device-idle gap can be given to the phase the host was in.  A span's
-annotation carries the name only; attributes stay in the ring.  What a
-reader of the device trace has to find beside one dispatch (a decode
-step's expert counts, ``serving.moe_counts``) is an annotation of its
-own with the numbers as the profiler's stats, which the ``.xplane.pb``
-keeps beside the name, not in it.
+device-idle gap can be given to the phase the host was in.  The
+annotation's name is the span's name and nothing else; the numbers and
+short strings the span was OPENED with go with it as the profiler's
+stats, which the ``.xplane.pb`` keeps beside the name, not in it (the
+engine's ``serving.dispatch`` carries ``seq`` and ``kind`` that way, the
+``serving.sync`` that waits for it the same ``seq``).  Attributes set
+later, and values of any other type, stay in the ring only.  What is
+known only after a region closed (a decode step's expert counts,
+``serving.moe_counts``) is an annotation of its own.
 """
 
 from __future__ import annotations
@@ -82,6 +85,7 @@ def _gen_id() -> str:
 
 _TraceAnnotation = None     # jax.profiler.TraceAnnotation, on first use
 _NO_ANNOTATION = nullcontext()
+_STAT_STR_MAX = 64          # a label, not a payload
 
 
 def host_annotation(name: str, **stats):
@@ -90,12 +94,21 @@ def host_annotation(name: str, **stats):
     while no profiler session is open.  The ONE place the program
     writes host events into a device trace — ``Tracer.span`` and
     ``profiler.RecordEvent`` both come here, so a region appears there
-    once.  ``stats`` (numbers) land as the event's stats in the
-    ``.xplane.pb``; its name stays ``name``, so lookups by name hold."""
+    once.  ``stats`` (numbers, strings) land as the event's stats in
+    the ``.xplane.pb``; its name stays ``name``, so lookups by name
+    hold."""
     global _TraceAnnotation
     if _TraceAnnotation is None:
         from jax.profiler import TraceAnnotation as _TraceAnnotation
     return _TraceAnnotation(name, **stats)
+
+
+def _stats(attrs: Dict[str, Any]) -> Dict[str, Any]:
+    """Of the attributes a span is opened with, those the profiler can
+    keep as an event's stats: numbers and short strings."""
+    return {k: v for k, v in attrs.items()
+            if isinstance(v, (int, float))
+            or (isinstance(v, str) and len(v) <= _STAT_STR_MAX)}
 
 
 class SpanContext(NamedTuple):
@@ -285,7 +298,9 @@ class Tracer:
         inside auto-parent to it), ended on exit; an escaping exception
         is stamped into the ``error`` attribute before re-raising.  A
         sampled span is also a :func:`host_annotation` for its duration
-        (the device trace's clock)."""
+        (the device trace's clock), with the numbers and short strings
+        among ``attrs`` as its stats; what ``set_attribute`` adds later
+        stays in the ring."""
         s = self.start_span(name, parent=parent,
                             root_eligible=root_eligible, **attrs)
         if s is _NOOP:
@@ -293,8 +308,14 @@ class Tracer:
             return
         stack = self._stack()
         stack.append(s)
+        if not s.sampled:
+            region = _NO_ANNOTATION
+        elif attrs:
+            region = host_annotation(name, **_stats(attrs))
+        else:
+            region = host_annotation(name)
         try:
-            with host_annotation(name) if s.sampled else _NO_ANNOTATION:
+            with region:
                 yield s
         except BaseException as e:
             s.set_attribute("error", type(e).__name__)

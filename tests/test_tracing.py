@@ -667,6 +667,37 @@ class TestProfilerBridge:
         assert s["parent_id"] == tr.finished_spans(
             name="bridge.outer")[0]["span_id"]
 
+    def test_opening_attributes_are_the_events_stats(self, tr, tmp_path):
+        """The numbers and short strings a span is OPENED with go to the
+        profiler as the event's stats, beside a name that stays the
+        span's; what is set later, and what is neither, stays in the
+        ring."""
+        import glob
+
+        import jax
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with tr.span("bridge.numbered", seq=41, kind="decode",
+                         share=0.5, rows=[1, 2], note="x" * 65) as s:
+                s.set_attribute("late", 7)
+            with tr.span("bridge.bare"):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(f"{tmp_path}/plugins/profile/*/*.xplane.pb")
+        stats = {e.name: dict(e.stats)
+                 for plane in jax.profiler.ProfileData.from_file(path).planes
+                 if plane.name.startswith("/host:")
+                 for line in plane.lines for e in line.events
+                 if e.name.startswith("bridge.")}
+        assert stats == {"bridge.numbered": {"seq": 41, "kind": "decode",
+                                             "share": 0.5},
+                         "bridge.bare": {}}
+        (kept,) = tr.finished_spans(name="bridge.numbered")
+        assert kept["attrs"] == {"seq": 41, "kind": "decode", "share": 0.5,
+                                 "rows": [1, 2], "note": "x" * 65,
+                                 "late": 7}
+
     def test_disabled_tracer_writes_nothing(self, tmp_path):
         import jax
         off = Tracer(sample=0.0)
@@ -749,6 +780,10 @@ class TestEngineStepPhases:
         assert {frozenset(names) for names in per_batch[4]} == {
             frozenset(issued),
             frozenset(issued | {"serving.sync", "serving.emit"})}
+        # each phase once: expiry and the free-slot scan share ONE
+        # serving.schedule span, so a decode step is the step and three
+        # children, five once it has a dispatch to read
+        assert {len(names) for names in per_batch[4]} == {3, 5}
 
     def test_every_phase_parents_to_a_step(self, tr, tiny_model, kind):
         _run_engine(tiny_model, 4, **ENGINES[kind])
@@ -790,6 +825,100 @@ class TestEngineStepPhases:
             "paddle_tpu_serving_inter_token_seconds")
         # one observation per token after a request's first
         assert sum(c.count() for _, c in itl.series()) - before == 4 * 5
+
+
+def _dispatch_counts():
+    from paddle_tpu.observability import default_registry
+    m = default_registry().get("paddle_tpu_serving_dispatches_total")
+    return {k: c.value() for k, c in m.series()} if m is not None else {}
+
+
+def _counted_since(before):
+    return {k: v - before.get(k, 0) for k, v in _dispatch_counts().items()
+            if v != before.get(k, 0)}
+
+
+class TestDispatchPipeline:
+    """Every program handed to the device is numbered from the host's
+    dispatch to the read that waits for it, and counted by whether the
+    device had run dry."""
+
+    @pytest.mark.parametrize("spec", [0, 2])
+    def test_seq_runs_through_every_kind_and_each_sync_names_its_dispatch(
+            self, tr, tiny_model, spec):
+        from paddle_tpu.inference.serving import ContinuousBatchingEngine
+        eng = ContinuousBatchingEngine(tiny_model, slots=4, max_len=64,
+                                       prefill_chunk=16, kv_block_size=8,
+                                       prefill_buckets=(16,),
+                                       spec_decode=spec)
+        rng = np.random.default_rng(2)
+        for n in (5, 21, 9):            # the second prompt is two chunks
+            eng.add_request(rng.integers(0, 128, (n,)), max_new_tokens=6)
+        eng.run()
+        disp = [s["attrs"] for s in tr.finished_spans("serving.dispatch")]
+        assert [d["seq"] for d in disp] == list(range(1, len(disp) + 1))
+        kind = {d["seq"]: d["kind"] for d in disp}
+        batched = "spec_verify" if spec else "decode"
+        assert set(kind.values()) == {"prefill_chunk", batched}
+        assert list(kind.values()).count("prefill_chunk") == 4
+        read = [s["attrs"]["seq"] for s in tr.finished_spans("serving.sync")]
+        assert read == sorted(read) and len(set(read)) == len(read)
+        # every batched dispatch is read, a chunk's only when it was its
+        # prompt's last
+        assert [q for q in read if kind[q] == batched] == \
+            [q for q in kind if kind[q] == batched]
+        assert sum(kind[q] == "prefill_chunk" for q in read) == 3
+        # a sync closes after the dispatch it names opened
+        opened = {s["attrs"]["seq"]: s["t0"]
+                  for s in tr.finished_spans("serving.dispatch")}
+        assert all(s["t1"] >= opened[s["attrs"]["seq"]]
+                   for s in tr.finished_spans("serving.sync"))
+
+    def test_drained_and_fed_are_counted_where_the_program_is_called(
+            self, tiny_model):
+        """A stand-in decode program that takes a few tenths of a second:
+        the first dispatch and one after a blocking read find the device
+        drained, one issued with the dispatch before it unread and
+        unfinished finds it fed."""
+        import jax
+        import jax.numpy as jnp
+        from paddle_tpu.inference.serving import ContinuousBatchingEngine
+        eng = ContinuousBatchingEngine(tiny_model, slots=4, max_len=64,
+                                       prefill_buckets=(16,))
+        real = eng._decode_compiled or eng._decode_paged
+
+        @jax.jit
+        def slow(toks):
+            m = jax.lax.fori_loop(
+                0, 400, lambda _, m: jnp.tanh(m @ m),
+                jnp.full((256, 256), 1e-3, jnp.float32))
+            return toks + (m[0, 0] > 2).astype(toks.dtype)   # + 0, late
+        slow(jnp.zeros((eng.slots, eng.steps_per_sync),
+                       jnp.int32)).block_until_ready()       # compiled
+
+        def program(*args):
+            got = real(*args)
+            return (slow(got[0]), *got[1:])
+        eng._decode_compiled, eng._decode_paged = None, program
+        before = _dispatch_counts()
+        eng.add_request(np.arange(5), max_new_tokens=8)
+        eng.step()                      # admit
+        eng.step()                      # the prompt's one chunk, and its read
+        assert _counted_since(before) == {("prefill_chunk", "drained"): 1}
+        eng.step()      # the first token was read: nothing left out there
+        assert _counted_since(before)[("decode", "drained")] == 1
+        unfinished = not eng._newest_out.is_ready()
+        eng.step()      # issued with that one unread, then reads it
+        assert unfinished, "the stand-in finished before it could be tested"
+        assert _counted_since(before)[("decode", "fed")] == 1
+        eng._collect()                  # a blocking read: the device is dry
+        eng._newest_out.block_until_ready()
+        drained = _counted_since(before)[("decode", "drained")]
+        eng.step()
+        assert _counted_since(before)[("decode", "drained")] == drained + 1
+        eng.run()
+        assert eng.request_status(0) == "ok"
+        assert sum(_counted_since(before).values()) == eng._dispatch_seq
 
 
 # ---------------------------------------------- stable names on the device
