@@ -829,10 +829,16 @@ def _spec_from_eqn(eqn, where: str) -> Optional[KernelSpec]:
     else:  # pragma: no cover - newer jax carries a params object
         semantics = getattr(cp, "dimension_semantics", None)
 
+    # the scope the call asks the compiler for, where it asks
+    mosaic = cp.get("mosaic_tpu") if hasattr(cp, "get") else cp
+    limit = getattr(mosaic, "vmem_limit_bytes", None)
+
     name = str(eqn.params.get("name_and_src_info", "pallas_call"))
     name = name.split(" ")[0] or "pallas_call"
     return KernelSpec(name=name, grid=grid, args=args, scratch=scratch,
-                      dimension_semantics=semantics, where=where)
+                      dimension_semantics=semantics,
+                      vmem_limit=int(limit or VMEM_LIMIT_BYTES),
+                      where=where)
 
 
 @register_pass(PASS_ID)

@@ -452,26 +452,21 @@ def ce_block_sizes(t: int, v: int, dtype: str) -> Tuple[int, int]:
 # -- fused rmsnorm + QKV -----------------------------------------------------
 
 def _qkv_candidates(t, d, dq, dk, dv, dtype) -> list:
-    itemsize = 2 if "bfloat16" in dtype or "float16" in dtype else 4
-    out = []
-    for bo in (128, 256, 512):
-        if dq % bo or dk % bo or dv % bo:
-            continue
-        for bt in (64, 128, 256, 512):
-            if t % bt:
-                continue
-            vmem = (2 * bt * d * itemsize + bt * d * 4
-                    + 6 * d * bo * itemsize + 6 * bt * bo * itemsize)
-            if vmem < 10 * (1 << 20):
-                out.append((bt, bo))
-    if not out:
-        from paddle_tpu.ops.pallas.fused_block import _default_qkv_blocks
-        out = [_default_qkv_blocks(t, d, dq, dk, dv, dtype)]
-    return out
+    from paddle_tpu.ops.pallas.fused_block import _block_candidates
+    return _block_candidates("qkv", t, (dq, dk, dv), d, dtype)
+
+
+def _fused_block_scope() -> str:
+    """Part of the two per-segment kernels' keys: the most VMEM their calls
+    ask the compiler for.  A winner taller than the compiler's own scope
+    holds compiles only under code that asks, so a cache shared with a
+    checkout that does not (or asks for less) must not hand it over."""
+    from paddle_tpu.ops.pallas.fused_block import _VMEM_LIMIT
+    return f"+vmem{_VMEM_LIMIT >> 20}"
 
 
 def qkv_key(t, d, dq, dk, dv, dtype, backend=None, interpret=None):
-    return f"t{t}d{d}q{dq}k{dk}v{dv}{dtype}" \
+    return f"t{t}d{d}q{dq}k{dk}v{dv}{dtype}{_fused_block_scope()}" \
            f"@{backend or backend_tag(interpret)}"
 
 
@@ -531,26 +526,13 @@ def qkv_block_sizes(t: int, d: int, dq: int, dk: int, dv: int,
 # -- fused MLP ---------------------------------------------------------------
 
 def _mlp_candidates(t, d, f, dtype) -> list:
-    itemsize = 2 if "bfloat16" in dtype or "float16" in dtype else 4
-    out = []
-    for bf in (128, 256, 512):
-        if f % bf:
-            continue
-        for bt in (64, 128, 256, 512):
-            if t % bt:
-                continue
-            vmem = (4 * bt * d * itemsize + bt * d * 4
-                    + 6 * d * bf * itemsize)
-            if vmem < 10 * (1 << 20):
-                out.append((bt, bf))
-    if not out:
-        from paddle_tpu.ops.pallas.fused_block import _default_mlp_blocks
-        out = [_default_mlp_blocks(t, d, f, dtype)]
-    return out
+    from paddle_tpu.ops.pallas.fused_block import _block_candidates
+    return _block_candidates("mlp", t, (f,), d, dtype)
 
 
 def mlp_key(t, d, f, dtype, backend=None, interpret=None):
-    return f"t{t}d{d}f{f}{dtype}@{backend or backend_tag(interpret)}"
+    return f"t{t}d{d}f{f}{dtype}{_fused_block_scope()}" \
+           f"@{backend or backend_tag(interpret)}"
 
 
 def mlp_block_sizes(t: int, d: int, f: int, dtype: str) -> Tuple[int, int]:

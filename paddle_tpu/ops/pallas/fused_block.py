@@ -25,6 +25,11 @@ fused lm-head CE are in place:
   non-gated variant (``act(x@w1 + b1) @ w2 + b2``) for the classic
   Transformer encoder/decoder feed-forward.
 
+The two per-segment kernels' blocks follow one rule read from the shape
+(``_choose_blocks``): a token block tall enough that the weights are
+read once, or at the chip's ridge where ``t`` does not fit the declared
+VMEM — every token block walks all of the weights again.
+
 All three carry custom VJPs: the backward recomputes the cheap
 forward intermediates from the saved inputs (rmsnorm scale, gate/up
 activations) in plain jax — XLA fuses those chains well, and the HBM
@@ -224,6 +229,160 @@ def record_path(kernel: str, fused: bool):
         kernel=kernel, path="fused" if fused else "reference").inc()
 
 
+def _passes_counter():
+    from paddle_tpu.observability import default_registry
+    return default_registry().counter(
+        "paddle_tpu_fused_block_weight_passes_total",
+        "times a fused-block kernel walks its weights (rows over the "
+        "token block chosen at trace time)",
+        labelnames=("kernel", "passes"))
+
+
+def record_weight_passes(kernel: str, t: int, block_t: int):
+    """Trace-time telemetry beside :func:`record_path`: a grid of
+    ``t / block_t`` token blocks reads every weight that many times."""
+    _passes_counter().labels(kernel=kernel, passes=str(t // block_t)).inc()
+
+
+# ---------------------------------------------------------------------------
+# the block rule of the two per-segment kernels
+# ---------------------------------------------------------------------------
+#
+# The grid is (token blocks, column blocks) with the weights' index maps
+# following the column axis alone: every token block walks all of the
+# weights again.  ``t / block_t`` passes cost ``passes * weight bytes /
+# HBM bandwidth`` against ``flops / peak``, and a token block of r rows
+# does ``2 r / itemsize`` operations a weight byte, so below the chip's
+# ridge the kernel waits for its weights.  The rule reads the shape and
+# nothing else.
+
+# A v5e core has 128 MiB of VMEM and the compiler's own scope is 16: where
+# the blocks need more, both calls ask for it (``vmem_limit_bytes``, and
+# the verifier is told the same number: :func:`_vmem_limit`), never more
+# than the limit; a block choice holds no more than the budget, which
+# leaves the ask room for the float32 temporaries of a step.
+_VMEM_LIMIT = 64 * (1 << 20)
+_VMEM_BUDGET = 40 * (1 << 20)
+# what the compiler's own scope holds with room to spare: the bound of the
+# blocks as they were chosen before the calls asked for more, and of the
+# calls that ask for nothing (a decode step's program is the one it was)
+_COMPILER_SCOPE = 10 * (1 << 20)
+
+
+def block_vmem_bytes(kernel: str, block_t: int, block_c: int, d: int,
+                     itemsize: int, residuals: bool = False) -> int:
+    """What a call of ``kernel`` ("mlp" | "qkv") holds in VMEM: the
+    streamed blocks twice (the grid's pipeline double-buffers them), the
+    float32 scratch once.  ``block_c`` is the hidden block of the MLP and
+    the out block of the projections; ``residuals`` adds what the
+    differentiated QKV call also writes."""
+    rows = block_t * d
+    weights = 6 * d * block_c * itemsize         # 3 weight blocks, 2x
+    if kernel == "mlp":
+        return (4 * rows * itemsize              # x and y, 2x each
+                + rows * 4                       # fp32 accumulator
+                + weights)
+    saved = 2 * rows * itemsize + 8 * block_t    # xn and 1/rms out, 2x
+    return (2 * rows * itemsize                  # x, 2x
+            + rows * 4                           # fp32 normalized scratch
+            + d * itemsize                       # the norm's weight, once
+            + weights
+            + 6 * block_t * block_c * itemsize   # 3 out blocks, 2x
+            + (saved if residuals else 0))
+
+
+def _vmem_limit(kernel, block_t, block_c, d, itemsize, residuals=False):
+    """The scope a call asks the compiler for: ``None`` (its own) where
+    that holds the blocks, else half again the working set in whole MiB
+    (the float32 temporaries of a step), at most the limit.  What a call
+    reserves the compiler cannot use around it: ``serve-rag``'s chunk read
+    3.5 % slower with one call asking 64 MiB where 32 did (PERF.md
+    section 6, PR 42)."""
+    held = block_vmem_bytes(kernel, block_t, block_c, d, itemsize)
+    if held < _COMPILER_SCOPE:
+        return None
+    held = block_vmem_bytes(kernel, block_t, block_c, d, itemsize, residuals)
+    return min(_VMEM_LIMIT, -(-3 * held // (2 << 20)) << 20)
+
+
+def _verify_scope(kernel, block_t, block_c, d, dtype, residuals=False):
+    """The budget and limit a verifier spec of these blocks carries: what
+    the call asks for, or the verifier's own where it asks for nothing."""
+    from paddle_tpu.analysis import kernel_verify as kv
+    limit = _vmem_limit(kernel, block_t, block_c, d,
+                        jnp.dtype(dtype).itemsize, residuals)
+    if limit is None:
+        return dict(vmem_budget=kv.VMEM_BUDGET_BYTES,
+                    vmem_limit=kv.VMEM_LIMIT_BYTES)
+    return dict(vmem_budget=_VMEM_BUDGET, vmem_limit=limit)
+
+
+def _ridge_rows(itemsize: int) -> int:
+    """Rows of a token block at which a pass over the weights takes as
+    long as its arithmetic (240 in a 16-bit type on a v5e)."""
+    from paddle_tpu.analysis.passes.cost_model import (DEFAULT_HBM_BW,
+                                                       DEFAULT_PEAK_FLOPS)
+    return int(DEFAULT_PEAK_FLOPS / DEFAULT_HBM_BW * itemsize / 2)
+
+
+def _scope_blocks(kernel, t, widths, d, dtype) -> list:
+    """Every (token, column) block pair the compiler's own scope holds,
+    widest column block first, then tallest token block."""
+    itemsize = jnp.dtype(dtype).itemsize
+    return [(bt, bc)
+            for bc in (512, 256, 128) if not any(w % bc for w in widths)
+            for bt in (512, 256, 128, 64, 32, 16, 8)
+            if bt >= _row_quantum(dtype) and t % bt == 0
+            and block_vmem_bytes(kernel, bt, bc, d, itemsize)
+            < _COMPILER_SCOPE]
+
+
+def _taller_blocks(kernel, t, bc, d, dtype) -> list:
+    """Token blocks, shortest first, that the budget holds at column
+    block ``bc`` and that read the weights once (``t`` itself) or stand
+    at the ridge or above it."""
+    itemsize = jnp.dtype(dtype).itemsize
+    ridge = _ridge_rows(itemsize)
+    return [bt for bt in sorted({256, 512, 1024, t})
+            if t % bt == 0 and (bt == t or bt >= ridge)
+            and block_vmem_bytes(kernel, bt, bc, d, itemsize)
+            <= _VMEM_BUDGET]
+
+
+def _choose_blocks(kernel, t, widths, d, dtype):
+    """The first pair of :func:`_scope_blocks` where it reads the weights
+    once or its token block stands at the ridge: such a shape keeps the
+    blocks it had.  Otherwise the same column block under a taller token
+    block — all of ``t`` where the budget holds it (one pass), else the
+    shortest at the ridge or above."""
+    scope = _scope_blocks(kernel, t, widths, d, dtype)
+    bt, bc = scope[0] if scope else (_row_quantum(dtype), 128)
+    if bt == t or bt >= _ridge_rows(jnp.dtype(dtype).itemsize):
+        return bt, bc
+    taller = _taller_blocks(kernel, t, bc, d, dtype)
+    if t in taller:
+        return t, bc
+    return (taller[0] if taller else bt), bc
+
+
+def _block_candidates(kernel, t, widths, d, dtype) -> list:
+    """What a sweep is offered.  One pass over the weights is the
+    arithmetic's own answer and is offered alone.  A shape of several
+    passes is timed: the pairs of 64 rows and more that the compiler's
+    scope holds (narrowest column block first, the order swept since
+    these kernels had a sweep), the rule's own pair and its double."""
+    best = _choose_blocks(kernel, t, widths, d, dtype)
+    if best[0] == t:
+        return [best]
+    out = sorted((c for c in _scope_blocks(kernel, t, widths, d, dtype)
+                  if c[0] >= 64), key=lambda c: (c[1], c[0]))
+    taller = _taller_blocks(kernel, t, best[1], d, dtype)
+    for bt in (best[0], 2 * best[0]):
+        if (bt == best[0] or bt in taller) and (bt, best[1]) not in out:
+            out.append((bt, best[1]))
+    return out
+
+
 # ---------------------------------------------------------------------------
 # clamped index maps — the DMA-once idiom shared by the fused kernels
 # and their static-verifier specs (analysis/kernel_verify checks the
@@ -325,7 +484,9 @@ def _qkv_pallas(x2d, wn, wq, wk, wv, *, eps, block_t, block_o, interpret,
     params = {}
     if not interpret:
         params["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit("qkv", block_t, block_o, d,
+                                         x2d.dtype.itemsize, residuals))
 
     return pl.pallas_call(
         functools.partial(_qkv_kernel, eps=eps, nq=nq, nk=nkb,
@@ -426,27 +587,8 @@ _qkv_core.defvjp(_qkv_fwd, _qkv_bwd)
 
 
 def _default_qkv_blocks(t, d, dq, dk, dv, dtype):
-    """Heuristic fallback: the first (token, out) block pair — widest
-    out block first, then tallest token block — whose working set (x +
-    fp32 normalized scratch + weight/out blocks, double-buffered io)
-    stays under ~10 MB of VMEM."""
-    itemsize = 2 if "bfloat16" in dtype or "float16" in dtype else 4
-    # 16-bit dtypes tile (16, 128): never offer an 8-row block there
-    bts = (512, 256, 128, 64, 32, 16) if itemsize == 2 else \
-        (512, 256, 128, 64, 32, 16, 8)
-    for bo in (512, 256, 128):
-        if dq % bo or dk % bo or dv % bo:
-            continue
-        for bt in bts:
-            if t % bt:
-                continue
-            vmem = (2 * bt * d * itemsize        # x, double-buffered
-                    + bt * d * 4                 # fp32 xn scratch
-                    + 6 * d * bo * itemsize      # 3 weight blocks, 2x
-                    + 6 * bt * bo * itemsize)    # 3 out blocks, 2x
-            if vmem < 10 * (1 << 20):
-                return bt, bo
-    return bts[-1], 128
+    """(block_t, block_o) by the block rule (:func:`_choose_blocks`)."""
+    return _choose_blocks("qkv", t, (dq, dk, dv), d, dtype)
 
 
 def fused_rmsnorm_qkv(x, norm_weight, wq, wk, wv, epsilon: float = 1e-5,
@@ -484,6 +626,8 @@ def fused_rmsnorm_qkv(x, norm_weight, wq, wk, wv, epsilon: float = 1e-5,
         raise ValueError(
             f"shapes t={t} dq={dq} dk={dk} dv={dv} not divisible by "
             f"blocks ({block_t}, {block_o})")
+    if use_pallas:
+        record_weight_passes("rmsnorm_qkv", t, block_t)
     q, k, v = _qkv_core(x2d, norm_weight, wq, wk, wv, float(epsilon),
                         bool(use_pallas), bool(interpret),
                         int(block_t or 0), int(block_o or 0))
@@ -563,7 +707,9 @@ def _mlp_pallas(x2d, weights, biases, *, act, gated, block_t, block_f,
     params = {}
     if not interpret:
         params["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit("mlp", block_t, block_f, d,
+                                         x2d.dtype.itemsize))
 
     return pl.pallas_call(
         functools.partial(_mlp_kernel, act=act, gated=gated,
@@ -681,30 +827,12 @@ _ffn_core.defvjp(_ffn_fwd, _ffn_bwd)
 
 
 def _default_mlp_blocks(t, d, f, dtype):
-    """Heuristic fallback: the first (token, hidden) block pair — widest
-    hidden block first, then tallest token block — whose working set (x
-    + y + fp32 accumulator + gate/up/down weight blocks, double-buffered
-    io) stays under ~10 MB of VMEM."""
-    itemsize = 2 if "bfloat16" in dtype or "float16" in dtype else 4
-    # 16-bit dtypes tile (16, 128): never offer an 8-row block there
-    bts = (512, 256, 128, 64, 32, 16) if itemsize == 2 else \
-        (512, 256, 128, 64, 32, 16, 8)
-    for bf in (512, 256, 128):
-        if f % bf:
-            continue
-        for bt in bts:
-            if t % bt:
-                continue
-            vmem = (2 * bt * d * itemsize        # x, double-buffered
-                    + bt * d * 4                 # fp32 accumulator
-                    + 2 * bt * d * itemsize      # y, double-buffered
-                    + 6 * d * bf * itemsize)     # 3 weight blocks, 2x
-            if vmem < 10 * (1 << 20):
-                return bt, bf
-    return bts[-1], 128
+    """(block_t, block_f) by the block rule (:func:`_choose_blocks`)."""
+    return _choose_blocks("mlp", t, (f,), d, dtype)
 
 
-def _mlp_blocks(t, d, f, dtype, block_t, block_f, interpret, autotune):
+def _mlp_blocks(kernel, t, d, f, dtype, block_t, block_f, interpret,
+                autotune):
     if block_t is None or block_f is None:
         if autotune and not interpret:
             from paddle_tpu.ops.pallas.autotune import mlp_block_sizes
@@ -716,6 +844,7 @@ def _mlp_blocks(t, d, f, dtype, block_t, block_f, interpret, autotune):
     if t % block_t or f % block_f:
         raise ValueError(f"shapes t={t} f={f} not divisible by blocks "
                          f"({block_t}, {block_f})")
+    record_weight_passes(kernel, t, block_t)
     return int(block_t), int(block_f)
 
 
@@ -744,8 +873,9 @@ def fused_mlp(x, w_gate, w_up, w_down, activation: str = "silu",
     if autotune is None:
         autotune = not interpret
     if use_pallas:
-        block_t, block_f = _mlp_blocks(t, d, f, str(x.dtype), block_t,
-                                       block_f, interpret, autotune)
+        block_t, block_f = _mlp_blocks("mlp", t, d, f, str(x.dtype),
+                                       block_t, block_f, interpret,
+                                       autotune)
     y = _mlp_gated_core(x2d, w_gate, w_up, w_down, str(activation),
                         bool(use_pallas), bool(interpret),
                         int(block_t or 0), int(block_f or 0))
@@ -1198,8 +1328,9 @@ def fused_ffn(x, w1, w2, b1=None, b2=None, activation: str = "relu",
     if autotune is None:
         autotune = not interpret
     if use_pallas:
-        block_t, block_f = _mlp_blocks(t, d, f, str(x.dtype), block_t,
-                                       block_f, interpret, autotune)
+        block_t, block_f = _mlp_blocks("ffn", t, d, f, str(x.dtype),
+                                       block_t, block_f, interpret,
+                                       autotune)
     if b1 is None:
         b1 = jnp.zeros((f,), x2d.dtype)
     if b2 is None:
@@ -1250,6 +1381,7 @@ def _qkv_verify_spec(t, d, dq, dk, dv, bt, bo, dtype, residuals=True):
         name="fused_qkv", grid=(nt, nq + nkb + nvb), args=args,
         scratch=[kv.ScratchSpec("xn_scr", (bt, d), "float32")],
         dimension_semantics=("parallel", "arbitrary"),
+        **_verify_scope("qkv", bt, bo, d, dtype, residuals),
         needs_fp32_acc=True,
         where=f"fused_qkv[t={t} d={d} dq={dq} dk={dk} dv={dv} "
               f"bt={bt} bo={bo} {dtype}]")
@@ -1289,6 +1421,7 @@ def _mlp_verify_spec(t, d, f, bt, bf, dtype, gated=True):
         grid=(nt, nf), args=args,
         scratch=[kv.ScratchSpec("acc", (bt, d), "float32")],
         dimension_semantics=("parallel", "arbitrary"),
+        **_verify_scope("mlp", bt, bf, d, dtype),
         needs_fp32_acc=True,
         where=f"fused_mlp[t={t} d={d} f={f} bt={bt} bf={bf} {dtype}]")
 

@@ -152,6 +152,30 @@ def test_fused_mlp_fwd_bwd_compiles(one_chip, widths):
     _compile(step, S(TOKENS, dm), S(dm, ffn), S(dm, ffn), S(ffn, dm))
 
 
+@pytest.mark.parametrize("rows,ffn", [(256, 14336), (512, 16384)])
+def test_fused_block_prefill_chunk_compiles_at_one_pass(one_chip, rows,
+                                                        ffn):
+    """A prefill chunk's rows at d = 4096 (mistral-7b's 256, sarvam's
+    dense layer's and granite's attention layer's 512) as ONE token block:
+    the weights are read once, inside the scope the calls declare — the
+    compiler's own 16 MiB refuses the QKV kernel at either."""
+    from paddle_tpu.ops.pallas import fused_block as FB
+    dm, h, hk, d = 4096, 32, 8, 128
+    assert FB._default_mlp_blocks(rows, dm, ffn, "bfloat16")[0] == rows
+    assert FB._default_qkv_blocks(rows, dm, h * d, hk * d, hk * d,
+                                  "bfloat16")[0] == rows
+    S = lambda *shape: jax.ShapeDtypeStruct(shape, BF16, sharding=one_chip)
+    mlp = _compile(lambda *a: FB.fused_mlp(*a, autotune=False,
+                                           interpret=False),
+                   S(rows, dm), S(dm, ffn), S(dm, ffn), S(ffn, dm))
+    qkv = _compile(lambda *a: FB.fused_rmsnorm_qkv(*a, autotune=False,
+                                                   interpret=False),
+                   S(rows, dm), S(dm), S(dm, h * d), S(dm, hk * d),
+                   S(dm, hk * d))
+    for compiled in (mlp, qkv):
+        assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
 @pytest.mark.parametrize("widths", sorted(WIDTHS))
 def test_fused_cross_entropy_fwd_bwd_compiles(one_chip, widths):
     from paddle_tpu.ops.pallas.cross_entropy import \

@@ -514,6 +514,20 @@ class TestAutotuneCache:
                     lambda c: benched.append(c) or 0.1, (8, 128))
         assert benched, "TPU key was served from the CPU entry"
 
+    def test_the_fused_block_keys_carry_the_vmem_scope(self, monkeypatch):
+        monkeypatch.delenv("PADDLE_TPU_AUTOTUNE_SEED", raising=False)
+        # a winner taller than the compiler's own scope holds compiles
+        # only under code that asks for VMEM: a cache a checkout of
+        # before PR 42 also reads and writes must not exchange entries
+        for key in (at.mlp_key(16384, 2048, 8192, "bfloat16",
+                               backend="tpu:TPU_v5_lite"),
+                    at.qkv_key(16384, 2048, 2048, 1024, 1024, "bfloat16",
+                               backend="tpu:TPU_v5_lite")):
+            assert f"+vmem{FB._VMEM_LIMIT >> 20}@tpu:" in key
+        seed = at._parse(at.seed_path())
+        assert all("+vmem64@" in k for k in seed
+                   if k.startswith(("fused_mlp|", "fused_qkv|")))
+
     def test_dtype_in_keys(self, tuned):
         a = at.mlp_key(512, 128, 512, "bfloat16", interpret=True)
         b = at.mlp_key(512, 128, 512, "float32", interpret=True)
@@ -586,3 +600,208 @@ class TestAutotuneCache:
                         (64, 128, 512)]:
             bt, bf = _default_mlp_blocks(t, d, f, "bfloat16")
             assert t % bt == 0 and f % bf == 0
+
+
+# ---------------------------------------------------------------------------
+# the block rule (PR 42): weight passes by the shape's arithmetic
+# ---------------------------------------------------------------------------
+
+def _rule_as_it_was(kernel, t, widths, d, dtype):
+    """The rule of PR 8 - PR 41: the first pair, widest column block
+    first, then tallest token block, under 10 MB."""
+    item = 2 if "16" in dtype else 4
+    bts = (512, 256, 128, 64, 32, 16) if item == 2 else \
+        (512, 256, 128, 64, 32, 16, 8)
+    for bc in (512, 256, 128):
+        if any(w % bc for w in widths):
+            continue
+        for bt in bts:
+            if t % bt == 0 and FB.block_vmem_bytes(kernel, bt, bc, d,
+                                                   item) < 10 * (1 << 20):
+                return bt, bc
+    return bts[-1], 128
+
+
+def _rule(kernel, shape, dtype="bfloat16"):
+    if kernel == "mlp":
+        t, d, f = shape
+        return (FB._default_mlp_blocks(t, d, f, dtype),
+                at._mlp_candidates(t, d, f, dtype))
+    t, d, dq, dk, dv = shape
+    return (FB._default_qkv_blocks(t, d, dq, dk, dv, dtype),
+            at._qkv_candidates(t, d, dq, dk, dv, dtype))
+
+
+# the shapes the benchmark's cells hand the two kernels: what the rule
+# answered until PR 41, what it answers, and the passes over the weights
+CELL_SHAPES = [
+    # mistral-7b, serve-chat: a prefill chunk, a decode step
+    ("mlp", (256, 4096, 14336), (64, 128), (256, 128), 1),
+    ("mlp", (32, 4096, 14336), (32, 128), (32, 128), 1),
+    ("qkv", (256, 4096, 4096, 1024, 1024), (64, 128), (256, 128), 1),
+    ("qkv", (32, 4096, 4096, 1024, 1024), (32, 128), (32, 128), 1),
+    # sarvam's dense layer, serve-longctx: a prefill chunk
+    ("mlp", (512, 4096, 16384), (64, 128), (512, 128), 1),
+    # granite's one attention layer, serve-rag: a prefill chunk
+    ("qkv", (512, 4096, 4096, 1024, 1024), (64, 128), (512, 128), 1),
+    # internlm2, train-1chip: swept
+    ("mlp", (16384, 2048, 8192), (128, 256), (256, 256), 64),
+    ("qkv", (16384, 2048, 2048, 1024, 1024), (128, 256), (256, 256), 64),
+]
+SWEPT_UNTIL_PR41 = [(64, 128), (128, 128), (256, 128), (64, 256), (128, 256)]
+
+
+class TestBlockRule:
+    @pytest.mark.parametrize("kernel,shape,was,now,passes", CELL_SHAPES)
+    def test_the_rule_at_the_cells_shapes(self, kernel, shape, was, now,
+                                          passes):
+        t, d = shape[:2]
+        assert _rule_as_it_was(kernel, t, shape[2:], d, "bfloat16") == was
+        blocks, cands = _rule(kernel, shape)
+        assert blocks == now and t // blocks[0] == passes
+        assert FB.block_vmem_bytes(kernel, *blocks, d, 2) <= FB._VMEM_BUDGET
+        assert blocks in cands
+        if passes == 1:
+            # one pass is the arithmetic's answer: nothing to sweep, and a
+            # shape that read its weights once keeps its blocks
+            assert cands == [blocks]
+            assert blocks == was or t // was[0] > 1
+        else:
+            # the train shape: what the sweep held stays, in its order,
+            # the blocks it ran by default among them; taller ones join
+            assert cands[:5] == SWEPT_UNTIL_PR41 and was in cands
+            assert blocks[0] >= FB._ridge_rows(2) > was[0]
+            assert all(c[0] >= blocks[0] for c in cands[5:])
+
+    def test_one_pass_or_at_the_ridge_comes_out_as_it_went_in(self):
+        moved = kept = 0
+        for dtype in ("bfloat16", "float32"):
+            ridge = FB._ridge_rows(2 if dtype == "bfloat16" else 4)
+            for d in (128, 512, 1024, 2048, 4096):
+                for t in (16, 32, 64, 256, 512, 4096, 16384):
+                    for kernel, widths in (("mlp", (4 * d,)),
+                                           ("qkv", (d, d // 4, d // 4))):
+                        was = _rule_as_it_was(kernel, t, widths, d, dtype)
+                        now = FB._choose_blocks(kernel, t, widths, d, dtype)
+                        if was[0] == t or was[0] >= ridge:
+                            assert now == was, (kernel, t, d, dtype)
+                            kept += 1
+                        else:
+                            # only the token block grows: the columns are
+                            # walked in the order they were
+                            assert now[1] == was[1] and now[0] >= was[0]
+                            assert now[0] == t or now[0] >= ridge or \
+                                now == was, (kernel, t, d, dtype, now)
+                            moved += now != was
+        assert kept > 50 and moved > 20
+
+    def test_the_working_set_is_the_issues(self):
+        mib = 1 << 20
+        assert FB.block_vmem_bytes("mlp", 64, 128, 4096, 2) < 10 * mib
+        assert FB.block_vmem_bytes("mlp", 128, 128, 4096, 2) > 10 * mib
+        assert FB.block_vmem_bytes("mlp", 256, 128, 4096, 2) == 18 * mib
+        assert FB.block_vmem_bytes("mlp", 512, 128, 4096, 2) == 30 * mib
+        assert FB._ridge_rows(2) == 240
+        # the ask: nothing inside the compiler's own scope, else half
+        # again the working set, and what the budget holds stays under
+        # the limit
+        assert FB._vmem_limit("mlp", 32, 128, 4096, 2) is None
+        assert FB._vmem_limit("mlp", 256, 128, 4096, 2) == 27 * mib
+        assert FB._vmem_limit("mlp", 512, 128, 4096, 2) == 45 * mib
+        assert FB._vmem_limit("qkv", 512, 128, 4096, 2) == 35 * mib
+        assert FB._vmem_limit("qkv", 512, 128, 4096, 2, True) == 47 * mib
+        assert FB._vmem_limit("mlp", 2048, 128, 4096, 2) == FB._VMEM_LIMIT
+        assert 3 * FB._VMEM_BUDGET // 2 <= FB._VMEM_LIMIT
+
+    @pytest.mark.parametrize("kernel,shape,was,now,passes", CELL_SHAPES)
+    def test_the_verifier_counts_no_more_than_the_rule(self, kernel, shape,
+                                                       was, now, passes):
+        from paddle_tpu.analysis import kernel_verify as kv
+        t, d = shape[:2]
+        spec = (FB._mlp_verify_spec(*shape, *now, "bfloat16")
+                if kernel == "mlp" else
+                FB._qkv_verify_spec(*shape, *now, "bfloat16",
+                                    residuals=False))
+        # the spec carries the scope the call asks for: the compiler's
+        # own where that holds the blocks (a decode step: the call as it
+        # was), the declared limit where they need it
+        asks = FB._vmem_limit(kernel, *now, d, 2)
+        assert (asks is None) == (now == was)
+        assert asks is None or FB.block_vmem_bytes(kernel, *now, d, 2) \
+            < asks <= FB._VMEM_LIMIT
+        assert spec.vmem_limit == (asks or kv.VMEM_LIMIT_BYTES)
+        assert kv.footprint_bytes(spec) <= \
+            FB.block_vmem_bytes(kernel, *now, d, 2)
+
+    @pytest.mark.parametrize("rows,asks", [(256, (27, 22)),
+                                           (32, (None, None))])
+    def test_both_calls_ask_the_compiler_for_what_the_blocks_need(
+            self, rows, asks):
+        # mistral-7b's prefill chunk asks for 27 and 22 MiB; its decode
+        # step asks for nothing, as before PR 42
+        S = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+        d, f = 4096, 14336
+        mlp = jax.make_jaxpr(lambda *a: FB.fused_mlp(
+            *a, interpret=False, autotune=False))(
+            S(rows, d), S(d, f), S(d, f), S(f, d))
+        qkv = jax.make_jaxpr(lambda *a: FB.fused_rmsnorm_qkv(
+            *a, interpret=False, autotune=False))(
+            S(rows, d), S(d), S(d, d), S(d, 1024), S(d, 1024))
+        for jaxpr, mib in zip((mlp, qkv), asks):
+            want = mib and mib << 20
+            assert f"vmem_limit_bytes={want}," in str(jaxpr)
+
+    @pytest.mark.parametrize("t", [256, 512])
+    def test_mlp_of_one_token_block_matches_reference_and_64_rows(self, t):
+        rng = np.random.default_rng(42)
+        d, f = 256, 512
+        x = jnp.asarray(rng.standard_normal((t, d)), jnp.bfloat16)
+        wg, wu, wd = (jnp.asarray(rng.standard_normal(s) * 0.05,
+                                  jnp.bfloat16)
+                      for s in ((d, f), (d, f), (f, d)))
+        y = FB.fused_mlp(x, wg, wu, wd, block_t=t, block_f=128)
+        yr = _mlp_ref(x, wg, wu, wd).astype(jnp.float32)
+        scale = float(jnp.abs(yr).max())
+        assert float(jnp.abs(y.astype(jnp.float32) - yr).max()) / scale \
+            < 2e-2
+        # a row's sum is formed in the order it was: the same bits
+        y64 = FB.fused_mlp(x, wg, wu, wd, block_t=64, block_f=128)
+        assert bool(jnp.array_equal(y, y64))
+
+    @pytest.mark.parametrize("t", [256, 512])
+    def test_qkv_of_one_token_block_matches_reference_and_64_rows(self, t):
+        rng = np.random.default_rng(43)
+        d, dq, dkv = 256, 256, 128
+        x = jnp.asarray(rng.standard_normal((t, d)), jnp.bfloat16)
+        w = _qkv_weights(rng, d, dq, dkv, jnp.bfloat16)
+        got = FB.fused_rmsnorm_qkv(x, *w, epsilon=EPS, block_t=t,
+                                   block_o=128)
+        for a, b in zip(got, _qkv_ref(x, *w)):
+            b = b.astype(jnp.float32)
+            assert float(jnp.abs(a.astype(jnp.float32) - b).max()) \
+                / float(jnp.abs(b).max()) < 2e-2
+        old = FB.fused_rmsnorm_qkv(x, *w, epsilon=EPS, block_t=64,
+                                   block_o=128)
+        assert all(bool(jnp.array_equal(a, b)) for a, b in zip(got, old))
+
+    @pytest.mark.parametrize("kernel,shape,was,now,passes", CELL_SHAPES)
+    def test_the_weight_passes_counter(self, kernel, shape, was, now,
+                                       passes):
+        label = "mlp" if kernel == "mlp" else "rmsnorm_qkv"
+        read = lambda n: FB._passes_counter().labels(
+            kernel=label, passes=str(n)).value()
+        before = {n: read(n) for n in (passes, shape[0] // was[0])}
+        S = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+        t, d = shape[:2]
+        if kernel == "mlp":
+            f = shape[2]
+            jax.eval_shape(lambda *a: FB.fused_mlp(*a, autotune=False),
+                           S(t, d), S(d, f), S(d, f), S(f, d))
+        else:
+            dq, dk, dv = shape[2:]
+            jax.eval_shape(
+                lambda *a: FB.fused_rmsnorm_qkv(*a, autotune=False),
+                S(t, d), S(d), S(d, dq), S(d, dk), S(d, dv))
+        assert read(passes) == before[passes] + 1
+        if shape[0] // was[0] != passes:
+            assert read(shape[0] // was[0]) == before[shape[0] // was[0]]

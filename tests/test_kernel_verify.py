@@ -267,6 +267,75 @@ class TestSharedVmemModel:
             2 * 128 * 128 * 4 + 1 * 128 * 4
 
 
+# the serving shapes of the two per-segment kernels (PR 42): mistral-7b's
+# prefill chunk and decode step, sarvam's dense layer and granite's
+# attention layer at a chunk of 512 rows
+SERVING_MLP = [(256, 4096, 14336), (32, 4096, 14336), (512, 4096, 16384)]
+SERVING_QKV = [(256, 4096, 4096, 1024, 1024), (32, 4096, 4096, 1024, 1024),
+               (512, 4096, 4096, 1024, 1024)]
+
+
+class TestFusedBlockDeclaredScope:
+    """The per-segment fused kernels ask the compiler for more VMEM than
+    its own scope and tell the verifier the same number."""
+
+    @pytest.mark.parametrize("shape", SERVING_MLP)
+    def test_mlp_passes_at_the_serving_shapes(self, shape):
+        diags = FB.verify_static_mlp(*shape, dtype="bfloat16")
+        assert not [d for d in diags if d.severity >= Severity.WARNING], \
+            diags
+
+    @pytest.mark.parametrize("shape", SERVING_QKV)
+    @pytest.mark.parametrize("residuals", [False, True])
+    def test_qkv_passes_at_the_serving_shapes(self, shape, residuals):
+        diags = FB.verify_static_qkv(*shape, dtype="bfloat16",
+                                     residuals=residuals)
+        assert not [d for d in diags if d.severity >= Severity.WARNING], \
+            diags
+
+    def test_the_rules_blocks_would_not_pass_the_compilers_scope(self):
+        # the check reads the declared scope: the same spec under the
+        # verifier's default limit is the error it was
+        spec = FB._mlp_verify_spec(512, 4096, 16384, 512, 128, "bfloat16")
+        assert FB._VMEM_LIMIT >= spec.vmem_limit == \
+            FB._vmem_limit("mlp", 512, 128, 4096, 2) > kv.VMEM_LIMIT_BYTES
+        assert not error_codes_of(kv.verify_kernel(spec,
+                                                   record_metric=False))
+        spec.vmem_limit = kv.VMEM_LIMIT_BYTES
+        assert kv.VMEM_EXCEEDED in error_codes_of(
+            kv.verify_kernel(spec, record_metric=False))
+
+    @pytest.mark.parametrize("kernel", ["mlp", "qkv"])
+    def test_blocks_over_the_declared_scope_are_still_reported(self,
+                                                               kernel):
+        if kernel == "mlp":
+            diags = FB.verify_static_mlp(2048, 4096, 14336, "bfloat16",
+                                         block_t=2048, block_f=128)
+        else:
+            diags = FB.verify_static_qkv(2048, 4096, 4096, 1024, 1024,
+                                         "bfloat16", block_t=2048,
+                                         block_o=128)
+        assert kv.VMEM_EXCEEDED in error_codes_of(diags)
+
+    def test_blocks_over_the_budget_warn(self):
+        # 1024 rows at d = 4096: inside the declared scope, over what the
+        # rule lets a choice hold
+        diags = FB.verify_static_mlp(2048, 4096, 14336, "bfloat16",
+                                     block_t=1024, block_f=128)
+        assert not error_codes_of(diags)
+        assert kv.VMEM_OVER_BUDGET in codes_of(diags)
+
+    def test_the_traced_call_is_checked_against_what_it_asks_for(self):
+        import paddle_tpu.analysis as analysis
+        S = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+        report = analysis.check(
+            lambda *a: FB.fused_mlp(*a, interpret=False, autotune=False),
+            S(512, 4096), S(4096, 2048), S(4096, 2048), S(2048, 4096),
+            passes=["kernel-verify"])
+        assert report.by_pass("kernel-verify"), report.format()
+        assert not report.errors(), report.format()
+
+
 # ---------------------------------------------------------------------------
 # autotune pruning (satellite: verify-before-bench)
 
