@@ -14,7 +14,7 @@ from paddle_tpu.models.moe_llm import (MoEConfig, MoEDecoderLayer,
                                        MoEForCausalLM, MoEModel)
 from paddle_tpu.models.hybrid import (HybridConfig, HybridDecoderLayer,
                                       HybridForCausalLM, HybridModel,
-                                      Mamba2Mixer)
+                                      KDAMixer, Mamba2Mixer)
 from paddle_tpu.models.dit import DiT, DiTBlock, DiTConfig
 from paddle_tpu.models.ernie import (ErnieConfig, ErnieForCausalLM,
                                      ErnieForMaskedLM,
@@ -25,7 +25,7 @@ __all__ = ["LlamaConfig", "LlamaAttention", "LlamaMLP", "LlamaDecoderLayer",
            "LlamaModel", "LlamaForCausalLM",
            "GPTConfig", "GPTDecoderLayer", "GPTModel", "GPTForCausalLM",
            "MoEConfig", "MoEDecoderLayer", "MoEModel", "MoEForCausalLM",
-           "HybridConfig", "Mamba2Mixer", "HybridDecoderLayer",
+           "HybridConfig", "Mamba2Mixer", "KDAMixer", "HybridDecoderLayer",
            "HybridModel", "HybridForCausalLM",
            "DiTConfig", "DiTBlock", "DiT",
            "ErnieConfig", "ErnieModel", "ErnieForSequenceClassification",

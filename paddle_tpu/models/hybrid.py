@@ -2,18 +2,21 @@
 feed-forward, each of a kind the config names.
 
     x <- x + r * mixer(rms(x))     ``layer_types[i]``: ``mamba`` (Mamba-2),
+                                   ``kda`` (Kimi Delta Attention),
                                    ``attention`` (grouped-query) or
                                    ``latent_attention`` (MLA)
     x <- x + r * ffn(rms(x))       ``ffn_types[i]``: ``experts`` (a router
                                    over gated experts beside a shared
                                    expert) or ``dense`` (one gated MLP)
 
-with ``r = residual_multiplier``.  Two published families are
+with ``r = residual_multiplier``.  Three published families are
 configurations of it: ``granitemoehybrid`` (IBM Granite 4.0-H: Mamba-2 and
 attention without rotary positions, experts everywhere, multipliers, a
-tied head) and ``sarvam_mla`` (latent attention with YaRN rotary
+tied head), ``sarvam_mla`` (latent attention with YaRN rotary
 positions, a leading dense layer before expert layers whose router takes
-sigmoid scores and a choice bias, an untied head).
+sigmoid scores and a choice bias, an untied head) and ``kimi_linear``
+(three KDA layers to one of latent attention without rotary positions,
+the same dense layer, router and head).
 
 The embedding's output is scaled by ``embedding_multiplier``, the head is
 the embedding when ``tie_word_embeddings`` and the logits are divided by
@@ -23,18 +26,19 @@ config states: a head size (``head_dim``), rotary positions or none
 ``latent_attention`` is ``models/latent_attention.py``.  The expert layer
 is ``distributed.moe.GatedExpertLayer``, told which experts this chip
 holds and its router's rule.  The Mamba-2 mathematics is
-``ops/mamba2.py``.
+``ops/mamba2.py``, the gated delta rule's ``ops/kda.py``.
 
 The model serves and does not train (no scan backward, no auxiliary
 loss).  ``forward(input_ids, attn_mask, caches, position_offset)`` is the
 serving engine's signature: ``caches[i]`` is a ``PagedCache`` for an
 attention layer (over a latent pool for ``latent_attention``) and a
-``SlotState`` for a Mamba layer, and one more entry
-after the layers', a ``StepInfo``, says which rows are real and collects
-the expert layers' counts.  Device operations carry the scopes ``embed``,
-``ssm`` (norm + mixer + residual), ``attn``, ``moe`` (norm + router +
-routed + shared + residual), ``mlp`` (norm + dense MLP + residual) and
-``lm_head_ce``.
+``SlotState`` for a recurrent layer (``mamba``, ``kda``), and one more
+entry after the layers', a ``StepInfo``, says which rows are real and
+collects the expert layers' counts.  Device operations carry the scopes
+``embed``, ``ssm`` (a recurrent layer's norm + mixer + residual; the
+delta rule's recurrence alone is ``kda`` inside it), ``attn``, ``moe``
+(norm + router + routed + shared + residual), ``mlp`` (norm + dense MLP +
+residual) and ``lm_head_ce``.
 """
 
 from __future__ import annotations
@@ -54,10 +58,12 @@ from paddle_tpu.models.llama import LlamaAttention, LlamaMLP, \
 from paddle_tpu.nn.common_layers import Embedding, Linear
 from paddle_tpu.nn.layer import Layer
 from paddle_tpu.nn.norm_layers import RMSNorm
-from paddle_tpu.ops import mamba2
+from paddle_tpu.ops import kda, mamba2
 
-__all__ = ["HybridConfig", "Mamba2Mixer", "HybridDecoderLayer",
+__all__ = ["HybridConfig", "Mamba2Mixer", "KDAMixer", "HybridDecoderLayer",
            "HybridModel", "HybridForCausalLM"]
+
+RECURRENT = ("mamba", "kda")    # layer kinds that keep per-slot state
 
 
 @dataclasses.dataclass
@@ -65,7 +71,7 @@ class HybridConfig:
     vocab_size: int = 100352
     hidden_size: int = 4096
     num_hidden_layers: int = 40
-    # "mamba" | "attention" | "latent_attention" a layer
+    # "mamba" | "kda" | "attention" | "latent_attention" a layer
     layer_types: Tuple[str, ...] = ()
     ffn_types: Tuple[str, ...] = ()         # "experts" | "dense" a layer
     num_attention_heads: int = 32
@@ -96,6 +102,9 @@ class HybridConfig:
     mamba_n_groups: int = 1
     mamba_chunk_size: int = 256
     mamba_conv_bias: bool = True
+    kda_n_heads: int = 0                    # a "kda" layer: heads of
+    kda_head_dim: int = 0                   # kda_head_dim keys and values
+    kda_d_conv: int = 4
     embedding_multiplier: float = 12.0
     attention_multiplier: float = 0.0078125
     residual_multiplier: float = 0.22
@@ -111,11 +120,11 @@ class HybridConfig:
         self.ffn_types = tuple(self.ffn_types) or \
             ("experts",) * self.num_hidden_layers
         if len(self.layer_types) != self.num_hidden_layers or \
-                set(self.layer_types) - {"mamba", "attention",
+                set(self.layer_types) - {*RECURRENT, "attention",
                                          "latent_attention"}:
             raise ValueError(f"layer_types {self.layer_types} do not name "
-                             f"{self.num_hidden_layers} mamba / attention "
-                             f"/ latent_attention layers")
+                             f"{self.num_hidden_layers} mamba / kda / "
+                             f"attention / latent_attention layers")
         if len(self.ffn_types) != self.num_hidden_layers or \
                 set(self.ffn_types) - {"experts", "dense"}:
             raise ValueError(f"ffn_types {self.ffn_types} do not name "
@@ -139,6 +148,9 @@ class HybridConfig:
             raise ValueError("latent_attention needs kv_lora_rank, "
                              "qk_nope_head_dim, qk_rope_head_dim and "
                              "v_head_dim")
+        if "kda" in kinds and not (self.kda_n_heads and self.kda_head_dim):
+            raise ValueError("a kda layer needs kda_n_heads and "
+                             "kda_head_dim")
         if "dense" in self.ffn_types and not self.dense_intermediate_size:
             raise ValueError("a dense layer needs dense_intermediate_size")
         if self.head_dim is None:
@@ -187,6 +199,30 @@ class _Conv1d(Layer):
             if bias else None
 
 
+def _state_in(state, info, B, shapes, dtype):
+    """(conv tail, recurrent state) a span of ``B`` rows starts from:
+    zeros without ``state`` (a ``SlotState``), slot ``info.slot``'s for a
+    B == 1 prefill chunk, else row b is slot b."""
+    if state is None:
+        return (jnp.zeros((B,) + tuple(shapes[0]), dtype),
+                jnp.zeros((B,) + tuple(shapes[1]), jnp.float32))
+    if info is not None and info.slot is not None:
+        return tuple(jax.lax.dynamic_index_in_dim(
+            unwrap(a), info.slot, 0, keepdims=True) for a in state)
+    return unwrap(state.conv), unwrap(state.ssm)
+
+
+def _state_out(state, info, tail, h):
+    """``state`` with the span's tail and recurrent state written back
+    where ``_state_in`` read them."""
+    from paddle_tpu.inference.kv_cache import SlotState
+    if info is not None and info.slot is not None:
+        return SlotState(*(jax.lax.dynamic_update_index_in_dim(
+            unwrap(old), new[0], info.slot, 0)
+            for old, new in zip(state, (tail, h))))
+    return SlotState(tail, h)
+
+
 class Mamba2Mixer(Layer):
     """``[z | xBC | dt] = in_proj(u)``; ``xBC = silu(conv(xBC) + b)``;
     ``dt = softplus(dt + dt_bias)``; the recurrence of ``ops/mamba2.py``
@@ -226,17 +262,7 @@ class Mamba2Mixer(Layer):
             [self.d_inner, self.d_inner + self.conv_dim], axis=-1)
         valid = jnp.full((B,), S, jnp.int32) if info is None \
             else info.valid.astype(jnp.int32)
-        one_slot = info is not None and info.slot is not None
-        if state is None:
-            tail = jnp.zeros((B,) + self.state_shapes()[0], u.dtype)
-            h0 = jnp.zeros((B, H, P, N), f32)
-        elif one_slot:          # a B == 1 prefill chunk of slot info.slot
-            tail = jax.lax.dynamic_index_in_dim(
-                unwrap(state.conv), info.slot, 0, keepdims=True)
-            h0 = jax.lax.dynamic_index_in_dim(
-                unwrap(state.ssm), info.slot, 0, keepdims=True)
-        else:                   # row b is slot b
-            tail, h0 = unwrap(state.conv), unwrap(state.ssm)
+        tail, h0 = _state_in(state, info, B, self.state_shapes(), u.dtype)
         bias = self.conv1d.bias
         xbc, tail = mamba2.causal_conv(
             xbc, tail, unwrap(self.conv1d.weight),
@@ -269,14 +295,89 @@ class Mamba2Mixer(Layer):
         out = unwrap(self.out_proj(y.astype(u.dtype)))
         if state is None:
             return out
-        from paddle_tpu.inference.kv_cache import SlotState
-        if one_slot:
-            return out, SlotState(
-                jax.lax.dynamic_update_index_in_dim(
-                    unwrap(state.conv), tail[0], info.slot, 0),
-                jax.lax.dynamic_update_index_in_dim(
-                    unwrap(state.ssm), h[0], info.slot, 0))
-        return out, SlotState(tail, h)
+        return out, _state_out(state, info, tail, h)
+
+
+class KDAMixer(Layer):
+    """Kimi Delta Attention, ``H`` heads of ``d`` keys and values:
+    ``[q | k | v | f | g | b] = in_proj(u)``; ``[q | k | v] = silu(conv(
+    .))`` (depthwise, causal, no bias); ``q <- q / |q| d^-1/2``, ``k <- k
+    / |k|`` a head; the log-decay ``a = -exp(A_log) softplus(f_proj(f) +
+    dt_bias)`` a channel of the keys; ``beta = sigmoid(b)`` a head; the
+    recurrence of ``ops/kda.py`` a head; ``y = rms_w(o) sigmoid(g_proj(
+    g))`` a head; ``o_proj(y)``.  ``f`` and ``g`` are the two low-rank
+    gates' inner values, ``d`` wide.  State and masking as
+    ``Mamba2Mixer``'s: a ``SlotState`` of the convolution's tail over the
+    ``q | k | v`` channels and the ``[H, d, d]`` float32 state."""
+
+    CHUNK = 64      # positions a step of the scan over chunks
+
+    def __init__(self, c: HybridConfig):
+        super().__init__(dtype=c.dtype)
+        self.heads, self.head_dim = c.kda_n_heads, c.kda_head_dim
+        self.d_inner = self.heads * self.head_dim
+        self.eps = c.rms_norm_eps
+        self.in_proj = Linear(
+            c.hidden_size,
+            3 * self.d_inner + 2 * self.head_dim + self.heads,
+            bias_attr=False)
+        self.conv1d = _Conv1d(c.kda_d_conv, 3 * self.d_inner, False)
+        self.f_proj = Linear(self.head_dim, self.d_inner, bias_attr=False)
+        self.g_proj = Linear(self.head_dim, self.d_inner, bias_attr=False)
+        self.dt_bias = self.create_parameter([self.d_inner], is_bias=True)
+        self.A_log = self.create_parameter([self.heads], is_bias=True)
+        self.o_norm = RMSNorm(self.head_dim, epsilon=c.rms_norm_eps)
+        self.o_proj = Linear(self.d_inner, c.hidden_size, bias_attr=False)
+
+    def state_shapes(self):
+        """(conv tail, delta-rule state) of one slot, without the slot
+        axis."""
+        return ((self.conv1d.weight.shape[0] - 1, 3 * self.d_inner),
+                (self.heads, self.head_dim, self.head_dim))
+
+    def forward(self, u, state=None, info=None):
+        f32 = jnp.float32
+        u = unwrap(u)
+        B, S = u.shape[0], u.shape[1]
+        H, D, P = self.heads, self.head_dim, self.d_inner
+        qkv, f, g, b = jnp.split(unwrap(self.in_proj(u)),
+                                 [3 * P, 3 * P + D, 3 * P + 2 * D], axis=-1)
+        valid = jnp.full((B,), S, jnp.int32) if info is None \
+            else info.valid.astype(jnp.int32)
+        tail, S0 = _state_in(state, info, B, self.state_shapes(), u.dtype)
+        qkv, tail = mamba2.causal_conv(qkv, tail,
+                                       unwrap(self.conv1d.weight), None,
+                                       valid)
+        q, k, v = (x.reshape(B, S, H, D) for x in
+                   jnp.split(jax.nn.silu(qkv), 3, axis=-1))     # float32
+        q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6) \
+            * D ** -0.5
+        k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
+        a = jax.nn.softplus(unwrap(self.f_proj(f)).astype(f32)
+                            + unwrap(self.dt_bias).astype(f32))
+        a = -jnp.exp(unwrap(self.A_log).astype(f32))[:, None] \
+            * a.reshape(B, S, H, D)
+        beta = jax.nn.sigmoid(b.astype(f32))
+        real = (jnp.arange(S)[None] < valid[:, None])[..., None]
+        a = jnp.where(real[..., None], a, 0.0)
+        beta = jnp.where(real, beta, 0.0)
+        with jax.named_scope("kda"):
+            if S == 1:
+                o, h = kda.kda_step(q[:, 0], k[:, 0], v[:, 0], a[:, 0],
+                                    beta[:, 0], S0)
+                o = o[:, None]
+            else:
+                o, h = kda.kda_scan(q, k, v, a, beta, S0,
+                                    min(self.CHUNK, S))
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
+                              + self.eps) \
+            * unwrap(self.o_norm.weight).astype(f32)
+        y = o.reshape(B, S, P) * jax.nn.sigmoid(
+            unwrap(self.g_proj(g)).astype(f32))
+        out = unwrap(self.o_proj(y.astype(u.dtype)))
+        if state is None:
+            return out
+        return out, _state_out(state, info, tail, h)
 
 
 class _SharedExpert(Layer):
@@ -302,6 +403,8 @@ class HybridDecoderLayer(Layer):
                                        epsilon=c.rms_norm_eps)
         if kind == "mamba":
             self.mamba = Mamba2Mixer(c)
+        elif kind == "kda":
+            self.kda = KDAMixer(c)
         elif kind == "latent_attention":
             self.self_attn = LatentAttention(c)
         else:
@@ -337,9 +440,10 @@ class HybridDecoderLayer(Layer):
                 if cache is not None:
                     h, new_cache = h
                 x = x + self.residual * h.astype(x.dtype)
-        elif self.kind == "mamba":
+        elif self.kind in RECURRENT:
             with jax.named_scope("ssm"):
-                h = self.mamba(self.input_layernorm(x), cache, info)
+                h = getattr(self, self.kind)(self.input_layernorm(x),
+                                             cache, info)
                 if cache is not None:
                     h, new_cache = h
                 x = x + self.residual * h.astype(x.dtype)
@@ -441,10 +545,10 @@ class HybridForCausalLM(Layer):
             self.lm_head.astype(config.dtype)
 
     def slot_state_shapes(self):
-        """[(conv tail, SSM state)] a Mamba layer, in layer order,
-        without the slot axis."""
-        return [layer.mamba.state_shapes() for layer in self.model.layers
-                if layer.kind == "mamba"]
+        """[(conv tail, recurrent state)] a recurrent layer, in layer
+        order, without the slot axis."""
+        return [getattr(layer, layer.kind).state_shapes()
+                for layer in self.model.layers if layer.kind in RECURRENT]
 
     def routed_expert_layers(self) -> int:
         """Layers that route over experts."""

@@ -21,7 +21,10 @@ Without a cache the layer attends expanded, every position at once.
 Rotary positions are "rotate halves" over the ``rope`` dims, with
 YaRN-scaled frequencies when the config's ``rope_scaling`` asks
 (:func:`yarn_inv_freq`, :func:`yarn_mscale`); cos and sin are computed
-from the positions, so no table bounds ``max_len``.
+from the positions, so no table bounds ``max_len``.  A config whose
+``position_embedding_type`` is ``nope`` turns nothing: ``q_r`` and
+``k_r`` are 64 more dims of the score, and the cached row and the
+absorbed kernel are what they were.
 """
 
 from __future__ import annotations
@@ -118,6 +121,8 @@ class LatentAttention(Layer):
         self.eps = c.rms_norm_eps
         qk = self.nope + self.rope
         scaling = c.rope_scaling
+        self.rotary = getattr(c, "position_embedding_type",
+                              "rope") != "nope"
         self.inv_freq = yarn_inv_freq(self.rope, c.rope_theta, scaling)
         self.mscale = 1.0
         if scaling and float(scaling.get("factor", 1.0)) != 1.0:
@@ -146,17 +151,20 @@ class LatentAttention(Layer):
         x = unwrap(x)
         B, S = x.shape[0], x.shape[1]
         H, rank, nope = self.heads, self.rank, self.nope
-        pos = query_positions(position_offset, B, S)
         q = unwrap(self.q_proj(x)).reshape(B, S, H, -1)
         if self.q_norm is not None:
             q = _rms(q, self.q_norm.weight, self.eps)
-        q = jnp.concatenate(
-            [q[..., :nope], rotate_halves(q[..., nope:], pos,
-                                          self.inv_freq, self.mscale)], -1)
         ckr = unwrap(self.kv_a_proj_with_mqa(x))
         c = _rms(ckr[..., :rank], self.kv_a_layernorm.weight, self.eps)
-        k_r = rotate_halves(ckr[..., None, rank:], pos, self.inv_freq,
-                            self.mscale)[:, :, 0]
+        k_r = ckr[..., rank:]
+        if self.rotary:
+            pos = query_positions(position_offset, B, S)
+            q = jnp.concatenate(
+                [q[..., :nope], rotate_halves(q[..., nope:], pos,
+                                              self.inv_freq, self.mscale)],
+                -1)
+            k_r = rotate_halves(k_r[:, :, None], pos, self.inv_freq,
+                                self.mscale)[:, :, 0]
         w_kvb = unwrap(self.kv_b_proj.weight)
         new_cache = None
         if cache is not None:

@@ -48,8 +48,15 @@ def causal_conv(x, tail, w, b, valid):
               for k in range(K))
     if b is not None:
         out = out + b.astype(jnp.float32)
-    new_tail = jax.vmap(lambda p, v: jax.lax.dynamic_slice_in_dim(
-        p, v, K - 1, 0))(padded, valid.astype(jnp.int32))
+    if S == 1:
+        # a decode step: the tail moves by one position or stays.  The
+        # batched slice below is a gather, which the TPU compiler can run
+        # as a loop over the rows (~10 device operations a slot a layer).
+        new_tail = jnp.where((valid > 0)[:, None, None],
+                             padded[:, 1:], padded[:, :-1])
+    else:
+        new_tail = jax.vmap(lambda p, v: jax.lax.dynamic_slice_in_dim(
+            p, v, K - 1, 0))(padded, valid.astype(jnp.int32))
     return out, new_tail.astype(tail.dtype)
 
 

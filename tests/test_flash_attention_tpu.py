@@ -374,6 +374,61 @@ def test_latent_chunk_walk_compiles_with_a_run_time_trip_count(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+# kimi-linear-48b-a3b's widths (serve-reason): KDA 32 heads x 128, a
+# 512-token prefill chunk in chunks of 64, 48 slots; latent attention at
+# 32 heads over the same 640-lane rows, a 17,424-token table
+def test_kda_scan_and_step_compile(one_chip):
+    """The chunked delta rule over a 512-token prefill chunk carrying a
+    state, and the one-token update over 48 slots, at the published
+    widths: the [t, s, channel] decays of a chunk (0.5 GB a layer) are
+    never a temporary, and the update's temporaries stay under one copy
+    of the state."""
+    from paddle_tpu.ops import kda
+    f32 = jnp.float32
+    S = lambda *shape: jax.ShapeDtypeStruct(shape, f32, sharding=one_chip)
+    scan = jax.jit(lambda q, k, v, a, beta, S0: kda.kda_scan(
+        q, k, v, a, beta, S0, 64)).lower(
+        S(1, 512, 32, 128), S(1, 512, 32, 128), S(1, 512, 32, 128),
+        S(1, 512, 32, 128), S(1, 512, 32), S(1, 32, 128, 128)).compile()
+    assert scan.memory_analysis().temp_size_in_bytes < 256 << 20
+    entry = scan.as_text().split("ENTRY", 1)[1]
+    assert "64,64,128]" not in entry
+    step = jax.jit(kda.kda_step, donate_argnums=(5,)).lower(
+        S(48, 32, 128), S(48, 32, 128), S(48, 32, 128), S(48, 32, 128),
+        S(48, 32), S(48, 32, 128, 128)).compile()
+    assert step.memory_analysis().temp_size_in_bytes < 48 * 32 * 128 * 128 * 4
+
+
+def test_a_decode_steps_convolution_tail_is_no_loop_over_the_slots(one_chip):
+    """A decode step's new tail over 48 slots x 12,288 channels is one
+    select: a batched ``dynamic_slice`` there is a gather, which the TPU
+    compiler runs as a ``while`` over the rows — 2200 of the 3600 device
+    operations of a serve-reason decode step (PERF.md section 6, PR 43)."""
+    from paddle_tpu.ops import mamba2
+    S = lambda shape, dt=BF16: jax.ShapeDtypeStruct(shape, dt,
+                                                    sharding=one_chip)
+    text = jax.jit(lambda x, t, w, v: mamba2.causal_conv(
+        x, t, w, None, v)).lower(
+        S((48, 1, 12288)), S((48, 3, 12288)), S((4, 12288)),
+        S((48,), jnp.int32)).compile().as_text()
+    assert " while(" not in text and "gather" not in text
+
+
+def test_latent_decode_kernel_compiles_at_32_heads(one_chip):
+    from paddle_tpu.ops.pallas.latent_attention import \
+        latent_decode_attention
+
+    def decode(q, pool, bt, lengths):
+        return latent_decode_attention(q, pool, bt, lengths, 512,
+                                       interpret=False)
+
+    S = lambda shape, dt=BF16: jax.ShapeDtypeStruct(shape, dt,
+                                                    sharding=one_chip)
+    compiled = _compile(decode, S((48, 32, 640)), S((32768, 16, 640)),
+                        S((48, 1089), jnp.int32), S((48,), jnp.int32))
+    assert "latent_attention" in compiled.as_text()
+
+
 def test_paged_engine_warms_the_targets_it_always_has(monkeypatch):
     """The walk's trip count is a run-time scalar read from the lengths:
     a paged engine whose decode step runs the kernel (interpret mode
