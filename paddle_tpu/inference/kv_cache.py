@@ -24,8 +24,10 @@ rebuilds the memory path vLLM-style around fixed-size **token blocks**:
   dry.
 * :class:`PagedKVPool` — the device-side pools, one
   ``[num_blocks, block_size, kv_heads, head_dim]`` pair (k, v) per layer.
-  Physical block ids are shared across layers: one logical allocation
-  covers a token's KV in every layer.
+  Physical block ids are shared across the layers of a group: one
+  logical allocation covers a token's KV in every layer of it.  A model
+  whose layers all see the whole context has one group; one with
+  sliding-window layers has two (below).
 * :func:`paged_cache_attention` — the decode/prefill attention path over
   the pools: writes land through the block table
   (``pool[bt[pos//bs], pos%bs] = kv``), reads gather the table back into
@@ -46,6 +48,25 @@ one ``[num_blocks, block_size, width]`` array a layer — the row
 allocator, copy-on-write, export / import and the prefix cache work on
 blocks and do not know the difference; :func:`latent_cache_attention` is
 its attention path.  A latent is not quantised to int8 (refused).
+
+**Two block groups.**  A layer whose attention sees only the last
+``window`` positions needs ``window`` of them cached, not the request:
+under one table a 32k-token request would hold 32k positions in every
+layer, 45 of 60 of them for nothing.  For a model with such layers the
+engine builds a *full* group (the layers that see everything:
+``num_kv_blocks`` ids, ``ceil(total / block)`` of them a request) and a
+*window* group (its own ids, allocator and per-slot table): a request
+reserves a **ring** of ``R = min(ceil(total / block), ceil((window +
+longest write of one dispatch) / block) + 1)`` window blocks at
+admission, and its window table names them over and over (``table[lb] =
+ring[lb % R]``), so writes go through the table as everywhere
+(``_write_targets``) and a block is overwritten only once every key in
+it is older than any later query's window — which is why the readers
+(the decode kernel, :func:`paged_cache_attention`'s walk and its gather)
+take the ``window`` and mask below it.  Nothing is paged per layer
+inside a group.  What moves blocks by id (prefix sharing, copy-on-write,
+park / resume, handoff, the tier, speculative verify's roll-back) knows
+one group and is refused for such a model.
 
 A second kind of state lives beside it (:class:`SlotStatePool`): a layer
 with recurrent state (a Mamba-2 mixer's convolution tail and SSM state)
@@ -397,7 +418,18 @@ class PrefixCache:
 class PagedKVPool:
     """Per-layer ``[num_blocks, block_size, kv_heads, head_dim]`` k/v
     pools.  One physical block id addresses the same slice in every
-    layer, so host bookkeeping is per-token-block, not per-layer.
+    layer of its group, so host bookkeeping is per-token-block, not
+    per-layer.
+
+    There is one group unless ``window_layers`` names layers (indices
+    among this pool's) of a second, the **window group**: layers whose
+    attention sees a sliding window keep ``window_blocks`` blocks of
+    their own, handed out by an allocator of their own, because a
+    request holds a ring of them and not a block a 16 tokens of its
+    length.  The arrays stay one list in layer order (the programs take
+    and return them as they always have); what copies, exports or
+    imports a block by one id — copy-on-write, handoff, the tier —
+    addresses one group and is refused for a pool with two.
 
     ``quant="int8"`` stores the pools as int8 plus per-layer
     ``[num_blocks, block_size, kv_heads]`` fp32 scale arrays (one scale
@@ -409,7 +441,8 @@ class PagedKVPool:
 
     def __init__(self, num_layers: int, num_blocks: int, block_size: int,
                  kv_heads: int, head_dim: int, dtype,
-                 quant: Optional[str] = None, latent: bool = False):
+                 quant: Optional[str] = None, latent: bool = False,
+                 window_layers: Sequence[int] = (), window_blocks: int = 0):
         if quant not in (None, "int8"):
             raise ValueError(f"PagedKVPool quant={quant!r}: only int8")
         if latent and quant:
@@ -421,12 +454,20 @@ class PagedKVPool:
         if latent and kv_heads != 1:
             raise ValueError(f"a latent pool holds one row a token, not "
                              f"{kv_heads} kv heads")
+        self.window_layers = tuple(sorted(window_layers))
+        if self.window_layers and (latent or quant or window_blocks < 2):
+            raise ValueError(
+                "a window group holds keys and values as computed "
+                "(neither latent nor int8) in window_blocks >= 2 blocks "
+                "of its own")
         self.num_blocks = num_blocks
+        self.window_blocks = int(window_blocks)
         self.block_size = block_size
         self.quant = quant
         self.latent = bool(latent)
         self.compute_dtype = dtype
         store = jnp.int8 if quant == "int8" else dtype
+        self._store = store
         if latent:
             # one array a layer, no V pool; a row ``[c | k_r]`` of
             # ``head_dim`` values is stored padded to whole lanes: the
@@ -437,12 +478,16 @@ class PagedKVPool:
             # ``nbytes`` counts what is held
             self.row_width = int(head_dim)
             shape = (num_blocks, block_size, -(-head_dim // 128) * 128)
-            self.vpools = []
         else:
             shape = (num_blocks, block_size, kv_heads, head_dim)
-            self.vpools = [jnp.zeros(shape, store)
-                           for _ in range(num_layers)]
-        self.kpools = [jnp.zeros(shape, store) for _ in range(num_layers)]
+        # a layer's pool shape: its group's blocks of the common block
+        self._shapes = [
+            ((self.window_blocks,) + shape[1:])
+            if i in self.window_layers else shape
+            for i in range(num_layers)]
+        self.vpools = [] if latent else \
+            [jnp.zeros(sh, store) for sh in self._shapes]
+        self.kpools = [jnp.zeros(sh, store) for sh in self._shapes]
         if quant:
             sshape = (num_blocks, block_size, kv_heads)
             self.kscales = [jnp.zeros(sshape, jnp.float32)
@@ -475,10 +520,17 @@ class PagedKVPool:
     def _all_pools(self):
         return self.kpools + self.vpools + self.kscales + self.vscales
 
+    def _one_group(self, what: str):
+        if self.window_layers:
+            raise RuntimeError(
+                f"{what}: this pool has a window group beside the full "
+                f"one, and a block id names a block of one group only")
+
     def copy_block(self, src: int, dst: int):
         """Device-side COW body: duplicate physical block `src` into
         `dst` across every layer's k and v pool (scales included when
         quantized — a copied block keeps its dequant factors)."""
+        self._one_group("copy_block")
         s = jnp.asarray(src, jnp.int32)
         d = jnp.asarray(dst, jnp.int32)
         self.kpools = [self._copy(p, s, d) for p in self.kpools]
@@ -489,11 +541,10 @@ class PagedKVPool:
         self.cow_copies += 1
 
     def reset(self):
-        dtype = self.kpools[0].dtype
-        shape = self.kpools[0].shape
         n = len(self.kpools)
-        self.kpools = [jnp.zeros(shape, dtype) for _ in range(n)]
-        self.vpools = [jnp.zeros(shape, dtype) for _ in self.vpools]
+        self.kpools = [jnp.zeros(sh, self._store) for sh in self._shapes]
+        self.vpools = [jnp.zeros(sh, self._store)
+                       for sh in self._shapes[:len(self.vpools)]]
         if self.quant:
             sshape = self.kscales[0].shape
             self.kscales = [jnp.zeros(sshape, jnp.float32)
@@ -522,6 +573,7 @@ class PagedKVPool:
         device gather runs at the padded bucket size (pad ids = scratch
         block 0), but the returned arrays are trimmed to the real count
         so the wire payload carries no padding."""
+        self._one_group("export_blocks")
         bids = list(bids)
         n = len(bids)
         idx = jnp.asarray(bids + [0] * (self._bucket(n) - n), jnp.int32)
@@ -557,6 +609,7 @@ class PagedKVPool:
         is dequantized via its shipped scales.  A quantized payload
         WITHOUT scales is rejected loudly — a wire format that lost its
         scales can only produce garbage KV."""
+        self._one_group("import_blocks")
         dst_bids = list(dst_bids)
         if not dst_bids:
             return
@@ -641,7 +694,10 @@ class PagedKVPool:
         """Compile the export/import executables for every pow-2 bucket
         up to `max_blocks` (pad target = scratch block, so the dummy
         import is invisible) — keeps XLA compiles out of the first real
-        handoff's latency."""
+        handoff's latency.  Nothing to warm with two groups: no block
+        is handed off."""
+        if self.window_layers:
+            return
         b = 1
         while b <= max(1, max_blocks):
             payload = self.export_blocks([0] * b)
@@ -859,8 +915,17 @@ def _write_targets(bt, qpos, bs: int):
     return jnp.where(lb < mb, bids, 0), qpos % bs
 
 
+# The gather fallback below holds ``[B, heads, S, max_len]`` float32
+# scores at once.  Past this many bytes (a 512-token chunk of 48 heads
+# over 33,552 positions is 3.3 GB; the largest a served model had made
+# before, 32 heads over 8,704, is 0.57 GB) the chunk walks the context's
+# tiles instead, as a layer with a window always does: most of its table
+# is masked.
+_GATHER_SCORES_BYTES = 1 << 30
+
+
 def paged_cache_attention(q, k, v, cache: PagedCache, position_offset,
-                          attn_mask=None):
+                          attn_mask=None, window=None):
     """Paged analog of ``static_cache_attention``: write the step's k/v
     through the block table, gather the table back into logical order,
     attend under the causal bound.
@@ -875,7 +940,18 @@ def paged_cache_attention(q, k, v, cache: PagedCache, position_offset,
     paged-attention kernel when eligible; the ``jnp.take`` gather
     fallback runs elsewhere and is numerically identical (the gathered
     values are bitwise the static buffer's, the extra masked tail
-    contributes exact zeros)."""
+    contributes exact zeros).
+
+    ``window`` (a static count of positions; None: none): a query at
+    position t sees key j iff ``0 <= t - j < window``, on every path;
+    the kernel and the walk read no table entry older than that, so the
+    table may be a ring (logical block ``lb`` under physical block
+    ``ring[lb % R]``, ``R`` blocks covering the window and the longest
+    write of one dispatch).  What the kernel does not take walks the
+    tiles of context the queries can see with an online softmax
+    (``paged_chunk_attention``) instead of gathering the table where the
+    layer has a window or the gathered scores would pass
+    ``_GATHER_SCORES_BYTES``; an int8 pool and a caller's mask gather."""
     from paddle_tpu.core.dispatch import unwrap, wrap_like
     from paddle_tpu.generation import reject_scalar_mask
     from paddle_tpu.nn.functional.attention import \
@@ -917,11 +993,17 @@ def paged_cache_attention(q, k, v, cache: PagedCache, position_offset,
         if quant:
             out = PA.paged_decode_attention(uq[:, 0], kp, vp, bt,
                                             lengths, k_scale=ksc,
-                                            v_scale=vsc)
+                                            v_scale=vsc, window=window)
         else:
             out = PA.paged_decode_attention(uq[:, 0], kp, vp, bt,
-                                            lengths)
+                                            lengths, window=window)
         return wrap_like(out[:, None]), new_cache
+    scores = 4 * B * uq.shape[2] * S * mb * bs
+    if attn_mask is None and not quant and \
+            (window is not None or scores > _GATHER_SCORES_BYTES):
+        PA.record_path("walk")
+        return wrap_like(PA.paged_chunk_attention(
+            uq, kp, vp, bt, qpos, window=window)), new_cache
     PA.record_path("fallback")
 
     # gather the block table back into logical order: [B, mb*bs, kvh, hd]
@@ -938,6 +1020,8 @@ def paged_cache_attention(q, k, v, cache: PagedCache, position_offset,
     vb = jnp.reshape(vb, (B, mb * bs) + vp.shape[2:])
     kpos = jnp.arange(mb * bs)
     mask = kpos[None, None, None, :] <= qpos[:, None, :, None]  # [B,1,S,T]
+    if window is not None:
+        mask &= kpos[None, None, None, :] > qpos[:, None, :, None] - window
     if attn_mask is not None:
         am = reject_scalar_mask(attn_mask)
         if am.dtype == jnp.bool_:

@@ -123,6 +123,12 @@ def _serving_metrics():
             "paddle_tpu_serving_kv_alloc_failures_total",
             "admissions deferred because the block pool was exhausted "
             "(load shed back into the bounded queue)"),
+        "deferred": reg.counter(
+            "paddle_tpu_serving_admissions_deferred_total",
+            "admission attempts deferred, by the block group that "
+            "lacked the blocks (full: the layers that see the whole "
+            "context; window: the sliding-window layers' rings)",
+            labelnames=("group",)),
         "chunks": reg.counter(
             "paddle_tpu_serving_prefill_chunks_total",
             "chunked-prefill dispatches"),
@@ -359,6 +365,12 @@ class ContinuousBatchingEngine:
     tokens (single compiled decode step).  finished() yields completed
     (rid, prompt, tokens) triples.
 
+    A model with sliding-window layers (``model.attention_windows()``)
+    gets a second block group for them — ``num_window_blocks`` ids, an
+    allocator and a table of their own, a ring of blocks a request
+    (kv_cache.py, "Two block groups") — and, like a model with per-slot
+    state, none of what moves blocks by id.
+
     ``paged_kv`` and ``prefill_buckets`` are kept for the benchmark's
     traffic file and harness, which still pass them (ROADMAP D2a):
     ``paged_kv`` takes ``None`` / ``True`` and nothing reads it;
@@ -380,6 +392,7 @@ class ContinuousBatchingEngine:
                  paged_kv: Optional[bool] = None,
                  kv_block_size: int = 16,
                  num_kv_blocks: Optional[int] = None,
+                 num_window_blocks: Optional[int] = None,
                  prefill_chunk: Optional[int] = None,
                  prefix_cache: bool = True,
                  spec_decode: int = 0,
@@ -512,40 +525,83 @@ class ContinuousBatchingEngine:
                  for k in (getattr(cfgm, "layer_types", None)
                            or ["attention"] * cfgm.num_hidden_layers)]
         latent_row = int(getattr(cfgm, "latent_row", 0) or 0)
+        # a third statement: which attention layers see a sliding window
+        # (``attention_windows()``: positions a layer, 0 for none).  Their
+        # keys and values live in a block group of their own, a ring a
+        # request (kv_cache.py, "Two block groups"); what moves blocks by
+        # id knows one group, so it is refused as for slot state
+        windows = [int(w or 0) for w in model.attention_windows()] \
+            if hasattr(model, "attention_windows") else []
+        self._window = max(windows, default=0)
+        win_layers = tuple(i for i, w in enumerate(windows) if w)
         self._state = None
         shapes = model.slot_state_shapes() \
             if hasattr(model, "slot_state_shapes") else []
         self._expert_layers = int(model.routed_expert_layers()) \
             if hasattr(model, "routed_expert_layers") else 0
         self._step_info = bool(shapes) or self._expert_layers > 0
+        self._blocks_only = None        # why block-moving features are off
         if shapes:
-            from paddle_tpu.inference.kv_cache import SlotStatePool
+            self._blocks_only = "keeps per-slot recurrent state"
+        elif self._window:
+            self._blocks_only = "keeps a second block group for its " \
+                "sliding-window layers"
+        if self._blocks_only:
             for what, asked in (("spec_decode", self.spec_tokens),
                                 ("kv_tier", kv_tier is not None),
-                                (f"role={role!r}", role != "mixed")):
+                                (f"role={role!r}", role != "mixed"),
+                                ("quant_kv", self.kv_quant
+                                 and self._window)):
                 if asked:
                     raise ValueError(
-                        f"{what}: {type(model).__name__} keeps per-slot "
-                        f"recurrent state, which {what} would neither "
-                        f"carry nor roll back (KV blocks only)")
+                        f"{what}: {type(model).__name__} "
+                        f"{self._blocks_only}, which {what} would neither "
+                        f"carry nor roll back (the full group's KV blocks "
+                        f"only)")
             prefix_cache = False
+        if shapes:
+            from paddle_tpu.inference.kv_cache import SlotStatePool
             self._state = SlotStatePool(slots, shapes, self._dtype)
         self._prefix = PrefixCache(self._block_size, self._allocator) \
             if prefix_cache else None
         heads, width = (1, latent_row) if latent_row else \
             (cfgm.num_key_value_heads, cfgm.head_dim)
-        self._pool = PagedKVPool(
-            kinds.count("attention"), self._num_blocks, self._block_size,
-            heads, width, self._dtype, quant=self.kv_quant,
-            latent=bool(latent_row))
-        # per-slot block table rows; 0 = reserved scratch block
-        self._bt = np.zeros((slots, self._max_blocks), np.int32)
-        self._seq: List[Optional[object]] = [None] * slots
-        self._prefilling: Dict[int, int] = {}  # slot -> next pos
         self._chunk = int(prefill_chunk) if prefill_chunk else largest
         if not 1 <= self._chunk < max_len:
             raise ValueError(f"prefill_chunk must be in [1, "
                              f"max_len), got {prefill_chunk}")
+        # the window group: a request's ring covers the window and the
+        # longest write of one dispatch, plus the block both may share;
+        # default ids: a whole ring a slot beside the scratch block
+        self._ring = min(self._max_blocks, -(-(
+            self._window + max(self._chunk, self.steps_per_sync))
+            // self._block_size) + 1) if self._window else 0
+        self._num_window_blocks = 0
+        if self._window:
+            self._num_window_blocks = int(num_window_blocks) \
+                if num_window_blocks else 1 + slots * self._ring
+        elif num_window_blocks:
+            raise ValueError(
+                f"num_window_blocks={num_window_blocks}: "
+                f"{type(model).__name__} has no sliding-window layer")
+        self._allocator_w = BlockAllocator(self._num_window_blocks) \
+            if self._window else None
+        kw = {"window_layers": win_layers,
+              "window_blocks": self._num_window_blocks} \
+            if self._window else {}
+        self._pool = PagedKVPool(
+            kinds.count("attention"), self._num_blocks, self._block_size,
+            heads, width, self._dtype, quant=self.kv_quant,
+            latent=bool(latent_row), **kw)
+        # per-slot block table rows; 0 = reserved scratch block (a group
+        # has its own: ``_bt_w`` / ``_seq_w`` are the window group's)
+        self._bt = np.zeros((slots, self._max_blocks), np.int32)
+        self._seq: List[Optional[object]] = [None] * slots
+        self._bt_w = np.zeros((slots, self._max_blocks), np.int32) \
+            if self._window else None
+        self._seq_w: List[Optional[object]] = [None] * slots
+        self._blocks_used_peak_w = 0
+        self._prefilling: Dict[int, int] = {}  # slot -> next pos
         self._interleave_decode = False
         self._blocks_used_peak = 0
         # session survivability (kv_tier.py): demoted sessions live in
@@ -655,6 +711,26 @@ class ContinuousBatchingEngine:
                   "paged KV blocks held by sequences or the prefix "
                   "cache").set_function(
             lambda e=self: e._allocator.used_blocks)
+        # the full group's peak, and the window group's gauges (the
+        # registry holds one set of labels a name, so a second group has
+        # names of its own); zeros without a window group
+        reg.gauge("paddle_tpu_serving_kv_blocks_used_peak",
+                  "most paged KV blocks (the full group's) held at one "
+                  "time since the engine was built").set_function(
+            lambda e=self: e._blocks_used_peak)
+        for what, help_, fn in (
+                ("free", "window-group KV blocks on the free list",
+                 lambda a: a.free_blocks),
+                ("used", "window-group KV blocks held by requests' rings",
+                 lambda a: a.used_blocks)):
+            reg.gauge(f"paddle_tpu_serving_kv_window_blocks_{what}",
+                      help_).set_function(
+                lambda e=self, fn=fn: fn(e._allocator_w)
+                if e._allocator_w is not None else 0)
+        reg.gauge("paddle_tpu_serving_kv_window_blocks_used_peak",
+                  "most window-group KV blocks held at one time since "
+                  "the engine was built").set_function(
+            lambda e=self: e._blocks_used_peak_w)
         reg.gauge("paddle_tpu_serving_prefix_cache_blocks",
                   "blocks registered in the prefix trie"
                   ).set_function(
@@ -721,12 +797,18 @@ class ContinuousBatchingEngine:
         # layer order; it, and a model with routed expert layers, gets
         # a StepInfo after them; for any other model both are empty
         # and the programs are what they were
+        # ``bt`` is the block table, or for a model with a window group
+        # the pair (full group's, window group's): a layer's cache gets
+        # its group's
         def fwd_paged(ps, ids, kpools, vpools, kscales, vscales,
                       bt, pos, state=(), info=None):
             if kscales:
                 cc = [PagedCache(kk, vv, bt, ks, vs)
                       for kk, vv, ks, vs in zip(kpools, vpools,
                                                 kscales, vscales)]
+            elif win_layers:
+                cc = [PagedCache(kk, vv, bt[1 if j in win_layers else 0])
+                      for j, (kk, vv) in enumerate(zip(kpools, vpools))]
             else:
                 cc = [PagedCache(kk, vv, bt) for kk, vv in zip(
                     kpools, vpools or [None] * len(kpools))]
@@ -916,7 +998,8 @@ class ContinuousBatchingEngine:
         ids = jnp.zeros((1, self._chunk), jnp.int32)
         target = f"serving.prefill_chunk[{self._chunk}]"
         c = warm(self._prefill_chunk_fn, self._keep, self._quant, ids,
-                 kpools, vpools, kscales, vscales, bt[:1],
+                 kpools, vpools, kscales, vscales,
+                 jax.tree.map(lambda t: t[:1], bt),
                  jnp.zeros((1,), jnp.int32),
                  jnp.asarray(0, jnp.int32), self._key,
                  *self._state_dummies(chunk=True), target=target)
@@ -946,6 +1029,8 @@ class ContinuousBatchingEngine:
         kscales = [jnp.zeros_like(p) for p in self._pool.kscales]
         vscales = [jnp.zeros_like(p) for p in self._pool.vscales]
         bt = jnp.zeros((self.slots, self._max_blocks), jnp.int32)
+        if self._window:
+            bt = (bt, jnp.zeros_like(bt))
         return kpools, vpools, kscales, vscales, bt
 
     def _state_dummies(self, chunk: bool = False):
@@ -1073,6 +1158,13 @@ class ContinuousBatchingEngine:
                 f"prompt {len(p)} + generation span {span} needs "
                 f"{worst} KV blocks but the pool holds "
                 f"{self._num_blocks - 1}; raise num_kv_blocks")
+        if self._window and \
+                min(worst, self._ring) > self._num_window_blocks - 1:
+            raise ValueError(
+                f"prompt {len(p)} + generation span {span} needs a ring "
+                f"of {min(worst, self._ring)} window-group KV blocks but "
+                f"the group holds {self._num_window_blocks - 1}; raise "
+                f"num_window_blocks")
         rid = self._next_rid
         self._next_rid += 1
         timeout = timeout_s if timeout_s is not None \
@@ -1161,14 +1253,24 @@ class ContinuousBatchingEngine:
             m["prefix_lookups"].labels(
                 result="hit" if reuse_bids else "miss").inc()
         need = -(-total // bs) - len(reuse_bids)
+        # the window group's part: a ring, or every block of a request
+        # shorter than one; both groups have the blocks or neither is
+        # touched (a request never waits holding half)
+        ring = min(-(-total // bs), self._ring)
         exhausted = fault_fires("serving.kv_alloc", slot=slot,
                                 rid=req.rid, need=need)
         if not exhausted and self._allocator.free_blocks < need and \
                 self._prefix is not None:
             m["evictions"].inc(
                 self._prefix.evict(need - self._allocator.free_blocks))
-        if exhausted or self._allocator.free_blocks < need:
+        short = [g for g, lacks in (
+            ("full", exhausted or self._allocator.free_blocks < need),
+            ("window", ring and self._allocator_w.free_blocks < ring))
+            if lacks]
+        if short:
             m["alloc_failures"].inc()
+            for g in short:
+                m["deferred"].labels(group=g).inc()
             self._recorder.record(
                 "serving.kv_alloc_exhausted", rid=req.rid, need=need,
                 free=self._allocator.free_blocks,
@@ -1188,6 +1290,19 @@ class ContinuousBatchingEngine:
         self._seq[slot] = seq
         self._bt[slot, :] = 0
         self._bt[slot, :len(seq.bids)] = seq.bids
+        if ring:
+            # the ring, named over and over along the logical table:
+            # writes go through it like any table, and a block comes
+            # round again only when all it holds is out of every later
+            # query's window
+            seq_w = SequenceBlocks(self._allocator_w, bs)
+            seq_w.ensure_capacity(ring * bs)
+            self._seq_w[slot] = seq_w
+            self._bt_w[slot, :] = 0
+            self._bt_w[slot, :len(seq.bids)] = np.asarray(
+                seq_w.bids, np.int32)[np.arange(len(seq.bids)) % ring]
+            self._blocks_used_peak_w = max(self._blocks_used_peak_w,
+                                           self._allocator_w.used_blocks)
         if self._state is not None:
             # the slot's last request left its recurrence behind
             self._state.reset_slot(slot)
@@ -1349,14 +1464,15 @@ class ContinuousBatchingEngine:
         return True
 
     def _refuse_with_slot_state(self, what: str):
-        """Park, resume and handoff move a request's KV blocks and
+        """Park, resume and handoff move the full group's KV blocks and
         nothing else: a model with per-slot recurrent state would come
-        back with a zeroed recurrence and serve wrong tokens."""
-        if self._state is not None:
+        back with a zeroed recurrence, one with sliding-window layers
+        with empty rings, and serve wrong tokens."""
+        if self._blocks_only:
             raise ValueError(
-                f"{what}: {type(self.model).__name__} keeps per-slot "
-                f"recurrent state, which {what} does not carry (KV "
-                f"blocks only)")
+                f"{what}: {type(self.model).__name__} "
+                f"{self._blocks_only}, which {what} does not carry (the "
+                f"full group's KV blocks only)")
 
     def export_handoff(self, rid: int) -> Dict:
         """Package a ``"prefilled"`` request's prompt KV for transfer:
@@ -1661,7 +1777,8 @@ class ContinuousBatchingEngine:
                 got = prefill(
                     self._keep, self._quant, jnp.asarray(ids),
                     pool.kpools, pool.vpools, pool.kscales, pool.vscales,
-                    jnp.asarray(self._bt[slot:slot + 1]),
+                    jax.tree.map(jnp.asarray,
+                                 self._tables(slice(slot, slot + 1))),
                     jnp.asarray([start], jnp.int32),
                     jnp.asarray(last_idx, jnp.int32), sub,
                     *(() if not self._step_info else (
@@ -1759,6 +1876,20 @@ class ContinuousBatchingEngine:
                     self._metrics["cow"].inc()
                     self._bt[i, idx] = seq.bids[idx]
 
+    def _tables(self, rows):
+        """The block table a program is handed, on the host — for a
+        model with a window group the pair (full, window); the caller
+        uploads it (``jax.tree.map(jnp.asarray, .)``).  ``rows``: a slice
+        of slots (a prefill chunk's one), or which slots are active in a
+        batched dispatch (the others' rows are zeroed)."""
+        def pick(bt):
+            if isinstance(rows, slice):
+                return bt[rows]
+            return np.where(rows[:, None], bt, 0)
+        if self._bt_w is None:
+            return pick(self._bt)
+        return pick(self._bt), pick(self._bt_w)
+
     def _ahead(self):
         """Per slot, the positions the outstanding dispatch writes that
         the host has not read yet: its ``steps`` for a row still held by
@@ -1818,7 +1949,7 @@ class ContinuousBatchingEngine:
             # non-decoding rows (free OR mid-prefill) get a zeroed
             # block-table row: their masked write lands in the scratch
             # block, not in a real sequence's (possibly shared) block 0
-            bt = np.where(active[:, None], self._bt, 0)
+            bt = self._tables(active)
         self._metrics["decode_dispatches"].labels(
             kind="waited" if self._inflight is None else "overlapped").inc()
         t0 = time.perf_counter()
@@ -1828,7 +1959,8 @@ class ContinuousBatchingEngine:
                 tr.span("serving.dispatch", seq=seq, kind=kind):
             got = program(
                 self._keep, self._quant, pool.kpools, pool.vpools,
-                pool.kscales, pool.vscales, jnp.asarray(bt),
+                pool.kscales, pool.vscales,
+                jax.tree.map(jnp.asarray, bt),
                 *(jnp.asarray(t) for t in toks),
                 jnp.asarray(pos), jnp.asarray(active), *more)
             (out, pool.kpools, pool.vpools, pool.kscales,
@@ -1895,12 +2027,26 @@ class ContinuousBatchingEngine:
         goes on from the device's own tokens, any other row from the
         token the host holds for it."""
         from_host = self._ahead() == 0
+        if self._window:
+            lens = (self._pos + self._ahead())[decoding].astype(np.int64) + 1
         d, (self._dev_toks,) = self._dispatch_batched(
             "decode", self._decode_compiled or self._decode_paged, decoding,
             (self._dev_toks, self._last_tok.copy(), from_host),
             self.steps_per_sync, self._next_key(),
             *(() if not self._step_info else (
                 self._state.layers if self._state else [],)))
+        if self._window:
+            # what this dispatch's first step attends, on the profiler's
+            # host plane just after it (as ``serving.prefill_context``):
+            # the rows, their keys in a layer that sees everything and in
+            # one that sees a window
+            from paddle_tpu.observability.tracing import host_annotation
+            with host_annotation(
+                    "serving.kv_live", rows=len(decoding),
+                    tokens=int(lens.sum()),
+                    window_tokens=int(np.minimum(lens,
+                                                 self._window).sum())):
+                pass
         self._collect()
         self._inflight = d
 
@@ -2093,6 +2239,10 @@ class ContinuousBatchingEngine:
             seq.release()   # shared prefix blocks stay in the trie
         self._seq[slot] = None
         self._bt[slot, :] = 0
+        if self._seq_w[slot] is not None:
+            self._seq_w[slot].release()     # the window group's ring
+            self._seq_w[slot] = None
+            self._bt_w[slot, :] = 0
         self._finish(req, slot=slot, status=status)
 
     def _finish(self, req: _Request, slot: Optional[int] = None,
@@ -2271,6 +2421,10 @@ class ContinuousBatchingEngine:
             self._state.reset()
         self._bt[:] = 0
         self._seq = [None] * self.slots
+        if self._window:
+            self._allocator_w = BlockAllocator(self._num_window_blocks)
+            self._bt_w[:] = 0
+            self._seq_w = [None] * self.slots
         self._prefilling.clear()
         # parked handoffs reference the replaced allocator/pool —
         # they are gone with it (the router's transfer will fail

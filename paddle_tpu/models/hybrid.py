@@ -9,20 +9,34 @@ feed-forward, each of a kind the config names.
                                    over gated experts beside a shared
                                    expert) or ``dense`` (one gated MLP)
 
-with ``r = residual_multiplier``.  Three published families are
-configurations of it: ``granitemoehybrid`` (IBM Granite 4.0-H: Mamba-2 and
-attention without rotary positions, experts everywhere, multipliers, a
-tied head), ``sarvam_mla`` (latent attention with YaRN rotary
-positions, a leading dense layer before expert layers whose router takes
-sigmoid scores and a choice bias, an untied head) and ``kimi_linear``
-(three KDA layers to one of latent attention without rotary positions,
-the same dense layer, router and head).
+with ``r = residual_multiplier``, or, where the config states
+``sandwich_norm``, with four norms a layer and the sum taken after the
+second of each pair:
+
+    x <- x + rms_post(mixer(rms_in(x)))     x <- x + rms_post(ffn(rms_pre(x)))
+
+Four published families are configurations of it: ``granitemoehybrid``
+(IBM Granite 4.0-H: Mamba-2 and attention without rotary positions,
+experts everywhere, multipliers, a tied head), ``sarvam_mla`` (latent
+attention with YaRN rotary positions, a leading dense layer before expert
+layers whose router takes sigmoid scores and a choice bias, an untied
+head), ``kimi_linear`` (three KDA layers to one of latent attention
+without rotary positions, the same dense layer, router and head) and
+``afmoe`` (Arcee Trinity: grouped-query attention with a norm on every
+query and key head and a sigmoid gate on its output, three layers that
+see a sliding window and turn rotary positions to one that sees
+everything and turns none, sandwich norms, the same router).
 
 The embedding's output is scaled by ``embedding_multiplier``, the head is
 the embedding when ``tie_word_embeddings`` and the logits are divided by
 ``logits_scaling``.  ``attention`` is ``LlamaAttention`` with what the
 config states: a head size (``head_dim``), rotary positions or none
-(``position_embedding_type``), a score scale (``attention_multiplier``).
+(``position_embedding_type``; a layer at a time where ``layer_rotary``
+says so), a score scale (``attention_multiplier``), head norms
+(``qk_norm``), an output gate (``attention_gate``), and a layer at a time
+a sliding window (``layer_windows``; the serving engine keeps such
+layers' keys and values in a block group of their own, a ring a request:
+``inference/kv_cache.py``).
 ``latent_attention`` is ``models/latent_attention.py``.  The expert layer
 is ``distributed.moe.GatedExpertLayer``, told which experts this chip
 holds and its router's rule.  The Mamba-2 mathematics is
@@ -43,6 +57,7 @@ residual) and ``lm_head_ce``.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import types
 from typing import Optional, Tuple
@@ -91,7 +106,17 @@ class HybridConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
-    qk_norm: bool = False                   # latent attention's two norms
+    # latent attention's two norms; grouped-query attention's norm on
+    # each query and key head
+    qk_norm: bool = False
+    attention_gate: bool = False    # attention's output x sigmoid(W_g h)
+    # a layer's sliding window in positions (0: it sees everything) and
+    # whether it turns rotary positions; empty: no window anywhere, and
+    # ``position_embedding_type`` for every layer
+    layer_windows: Tuple[int, ...] = ()
+    layer_rotary: Tuple[bool, ...] = ()
+    # x + rms(mixer(rms(x))): a second norm on each branch's output
+    sandwich_norm: bool = False
     rope_theta: float = 10000.0
     rope_scaling: Optional[dict] = None     # YaRN (latent attention)
     tie_word_embeddings: bool = True
@@ -137,6 +162,27 @@ class HybridConfig:
                 f"position_embedding_type "
                 f"{self.position_embedding_type!r}: rotary over the whole "
                 f"head (rope) or none (nope); partial rotary is not served")
+        self.layer_windows = tuple(int(w or 0) for w in self.layer_windows) \
+            or (0,) * self.num_hidden_layers
+        self.layer_rotary = tuple(bool(r) for r in self.layer_rotary) or \
+            (self.position_embedding_type == "rope",) \
+            * self.num_hidden_layers
+        for name in ("layer_windows", "layer_rotary"):
+            if len(getattr(self, name)) != self.num_hidden_layers:
+                raise ValueError(f"{name} {getattr(self, name)} does not "
+                                 f"name {self.num_hidden_layers} layers")
+        if any(w and k != "attention"
+               for w, k in zip(self.layer_windows, self.layer_types)):
+            raise NotImplementedError(
+                "a sliding window on a layer that is not grouped-query "
+                "attention")
+        if len({w for w in self.layer_windows if w}) > 1:
+            raise NotImplementedError(
+                f"sliding windows of several sizes {self.layer_windows}: "
+                f"the engine keeps one window group, of one ring size")
+        if self.sandwich_norm and self.residual_multiplier != 1.0:
+            raise ValueError("sandwich_norm with a residual_multiplier: "
+                             "a published family states one or the other")
         kinds = set(self.layer_types)
         if {"attention", "latent_attention"} <= kinds:
             raise NotImplementedError(
@@ -395,7 +441,8 @@ class _SharedExpert(Layer):
 
 
 class HybridDecoderLayer(Layer):
-    def __init__(self, c: HybridConfig, kind: str, ffn: str = "experts"):
+    def __init__(self, c: HybridConfig, kind: str, ffn: str = "experts",
+                 window: int = 0, rotary: Optional[bool] = None):
         super().__init__(dtype=c.dtype)
         self.kind, self.ffn = kind, ffn
         self.residual = float(c.residual_multiplier)
@@ -408,9 +455,24 @@ class HybridDecoderLayer(Layer):
         elif kind == "latent_attention":
             self.self_attn = LatentAttention(c)
         else:
-            self.self_attn = LlamaAttention(c)
+            self.self_attn = LlamaAttention(c, window, rotary)
+        # with windows in the model, a layer's attention carries a scope
+        # of its kind inside ``attn``
+        self.attn_scope = None if not any(c.layer_windows) else \
+            "attn_window" if window else "attn_full"
         self.post_attention_layernorm = RMSNorm(c.hidden_size,
                                                 epsilon=c.rms_norm_eps)
+        # the sandwich form: ``post_attention_layernorm`` is then the norm
+        # of attention's output, and the feed-forward has a pair of its own
+        self.pre_mlp_layernorm = self.post_mlp_layernorm = None
+        if c.sandwich_norm:
+            if kind != "attention":
+                raise NotImplementedError(
+                    f"sandwich norms around a {kind} layer")
+            self.pre_mlp_layernorm = RMSNorm(c.hidden_size,
+                                             epsilon=c.rms_norm_eps)
+            self.post_mlp_layernorm = RMSNorm(c.hidden_size,
+                                              epsilon=c.rms_norm_eps)
         if ffn == "dense":
             self.mlp = LlamaMLP(types.SimpleNamespace(
                 dtype=c.dtype, hidden_size=c.hidden_size,
@@ -449,27 +511,43 @@ class HybridDecoderLayer(Layer):
                 x = x + self.residual * h.astype(x.dtype)
         else:
             with jax.named_scope("attn"):
-                qkv = _fused_norm_qkv(self, x)
-                if qkv is not None:
-                    h = self.self_attn.attend(*qkv, *rope, attn_mask,
-                                              cache, position_offset)
-                else:
-                    h = self.self_attn(self.input_layernorm(x), *rope,
-                                       attn_mask, cache, position_offset)
-                if cache is not None:
-                    h, new_cache = h
+                with jax.named_scope(self.attn_scope) if self.attn_scope \
+                        else contextlib.nullcontext():
+                    # a gate's fifth projection reads the normed input:
+                    # such a layer takes plain matmuls, not the fused
+                    # norm + QKV kernel
+                    qkv = _fused_norm_qkv(self, x) \
+                        if self.self_attn.gate_proj is None else None
+                    if qkv is not None:
+                        h = self.self_attn.attend(*qkv, *rope, attn_mask,
+                                                  cache, position_offset)
+                    else:
+                        h = self.self_attn(self.input_layernorm(x), *rope,
+                                           attn_mask, cache,
+                                           position_offset)
+                    if cache is not None:
+                        h, new_cache = h
+                if self.pre_mlp_layernorm is not None:
+                    h = self.post_attention_layernorm(h)
                 x = x + self.residual * unwrap(h).astype(x.dtype)
+        sandwich = self.pre_mlp_layernorm is not None
+        pre = self.pre_mlp_layernorm if sandwich \
+            else self.post_attention_layernorm
         if self.ffn == "dense":
             with jax.named_scope("mlp"):
-                h = unwrap(self.mlp(self.post_attention_layernorm(x)))
-                x = x + self.residual * h.astype(x.dtype)
+                h = self.mlp(pre(x))
+                if sandwich:
+                    h = self.post_mlp_layernorm(h)
+                x = x + self.residual * unwrap(h).astype(x.dtype)
             return x, new_cache, jnp.zeros((3,), jnp.int32)
         with jax.named_scope("moe"):
-            h = unwrap(self.post_attention_layernorm(x))
+            h = unwrap(pre(x))
             real = None if info is None else \
                 jnp.arange(x.shape[1])[None] < info.valid[:, None]
             y, counts = self.block_sparse_moe(h, real)
             y = y + self.shared_mlp(h).astype(jnp.float32)
+            if sandwich:
+                y = unwrap(self.post_mlp_layernorm(y))
             x = x + (self.residual * y).astype(x.dtype)
         return x, new_cache, counts
 
@@ -481,7 +559,9 @@ class HybridModel(Layer):
         self.embed_tokens = Embedding(config.vocab_size, config.hidden_size)
         self.layers = []
         for i, kind in enumerate(config.layer_types):
-            layer = HybridDecoderLayer(config, kind, config.ffn_types[i])
+            layer = HybridDecoderLayer(config, kind, config.ffn_types[i],
+                                       config.layer_windows[i],
+                                       config.layer_rotary[i])
             self.add_sublayer(f"layers_{i}", layer)
             self.layers.append(layer)
         self.norm = RMSNorm(config.hidden_size, epsilon=config.rms_norm_eps)
@@ -490,8 +570,8 @@ class HybridModel(Layer):
         # positions); kept off the layer tree, so a dtype cast, a
         # state_dict and a LazyGuard build never see them
         self._rope = (None, None)
-        if config.position_embedding_type == "rope" and \
-                "attention" in config.layer_types:
+        if any(r and k == "attention" for r, k in
+               zip(config.layer_rotary, config.layer_types)):
             from paddle_tpu.nn import functional as F
             self._rope = tuple(unwrap(t) for t in F.rotary_freqs(
                 config.head_dim, config.max_position_embeddings,
@@ -532,8 +612,10 @@ class HybridForCausalLM(Layer):
     serving engine asks a model three things, apart:
     ``slot_state_shapes`` (its recurrent layers' state),
     ``routed_expert_layers`` (how many layers add to
-    ``StepInfo.moe_counts``) and ``config.latent_row`` (the width of a
-    latent-attention layer's cached row: a latent pool, not K and V)."""
+    ``StepInfo.moe_counts``), ``config.latent_row`` (the width of a
+    latent-attention layer's cached row: a latent pool, not K and V) and
+    ``attention_windows`` (which attention layers keep a window: a block
+    group of their own)."""
 
     def __init__(self, config: HybridConfig):
         super().__init__(dtype=config.dtype)
@@ -549,6 +631,14 @@ class HybridForCausalLM(Layer):
         order, without the slot axis."""
         return [getattr(layer, layer.kind).state_shapes()
                 for layer in self.model.layers if layer.kind in RECURRENT]
+
+    def attention_windows(self):
+        """[window in positions, or 0] an attention layer (of either
+        kind), in layer order: which of the engine's block groups holds
+        the layer's cache."""
+        c = self.config
+        return [w for w, k in zip(c.layer_windows, c.layer_types)
+                if k.endswith("attention")]
 
     def routed_expert_layers(self) -> int:
         """Layers that route over experts."""

@@ -79,7 +79,18 @@ class LlamaConfig:
 
 
 class LlamaAttention(Layer):
-    def __init__(self, config: LlamaConfig):
+    """Grouped-query attention.  Beside ``LlamaConfig``'s keys a config
+    may state ``position_embedding_type`` / ``attention_multiplier``
+    (below), ``qk_norm`` (a learned-gain RMSNorm over each query and key
+    head, before the rotary turn) and ``attention_gate`` (the output
+    times the sigmoid of a fifth projection ``gate_proj``, ``hidden ->
+    heads x head_dim``, before ``o_proj``).  A layer may differ from its
+    config in two things, given here: ``window`` (key j is seen by query
+    t iff ``0 <= t - j < window``; None: everything before it) and
+    ``rotary`` (None: the config's)."""
+
+    def __init__(self, config: LlamaConfig, window: Optional[int] = None,
+                 rotary: Optional[bool] = None):
         super().__init__(dtype=config.dtype)
         c = config
         self.num_heads = c.num_attention_heads
@@ -91,7 +102,9 @@ class LlamaAttention(Layer):
         # A stated scale is folded into q before the cache, so the paged
         # kernel, the flash kernel and sdpa run unchanged
         self.rotary = getattr(c, "position_embedding_type",
-                              "rope") != "nope"
+                              "rope") != "nope" if rotary is None \
+            else bool(rotary)
+        self.window = int(window) if window else None
         scale = getattr(c, "attention_multiplier", None)
         self.q_scale = None if scale is None \
             else float(scale) * self.head_dim ** 0.5
@@ -103,22 +116,43 @@ class LlamaAttention(Layer):
                              bias_attr=False)
         self.o_proj = Linear(self.num_heads * self.head_dim, c.hidden_size,
                              bias_attr=False)
+        self.gate_proj = None
+        if getattr(c, "attention_gate", False):
+            self.gate_proj = Linear(c.hidden_size,
+                                    self.num_heads * self.head_dim,
+                                    bias_attr=False)
+        self.q_norm = self.k_norm = None
+        if getattr(c, "qk_norm", False):
+            self.q_norm = RMSNorm(self.head_dim, epsilon=c.rms_norm_eps)
+            self.k_norm = RMSNorm(self.head_dim, epsilon=c.rms_norm_eps)
 
     def forward(self, x, rope_cos, rope_sin, attn_mask=None, cache=None,
                 position_offset=0):
+        gate = None if self.gate_proj is None else self.gate_proj(x)
         return self.attend(self.q_proj(x), self.k_proj(x), self.v_proj(x),
                            rope_cos, rope_sin, attn_mask, cache,
-                           position_offset)
+                           position_offset, gate)
+
+    def _out(self, out, gate, b, s):
+        """The heads' outputs, gated where the layer has a gate, through
+        ``o_proj``."""
+        out = M.reshape(out, [b, s, self.num_heads * self.head_dim])
+        if gate is not None:
+            out = out * F.sigmoid(gate)
+        return self.o_proj(out)
 
     def attend(self, q, k, v, rope_cos, rope_sin, attn_mask=None,
-               cache=None, position_offset=0):
-        """Everything after the projections (RoPE, cache, sdpa, o_proj)
-        — split out so the decoder layer's fused rmsnorm+QKV path can
-        feed projections straight from the Pallas kernel."""
+               cache=None, position_offset=0, gate=None):
+        """Everything after the projections (head norms, RoPE, cache,
+        sdpa, gate, o_proj) — split out so the decoder layer's fused
+        rmsnorm+QKV path can feed projections straight from the Pallas
+        kernel."""
         b, s = q.shape[0], q.shape[1]
         q = M.reshape(q, [b, s, self.num_heads, self.head_dim])
         k = M.reshape(k, [b, s, self.num_kv_heads, self.head_dim])
         v = M.reshape(v, [b, s, self.num_kv_heads, self.head_dim])
+        if self.q_norm is not None:
+            q, k = self.q_norm(q), self.k_norm(k)
         if self.rotary:
             q = F.apply_rotary_emb(q, rope_cos, rope_sin, position_offset)
             k = F.apply_rotary_emb(k, rope_cos, rope_sin, position_offset)
@@ -128,15 +162,18 @@ class LlamaAttention(Layer):
         if cache is not None:
             from paddle_tpu.generation import (StaticCache,
                                                static_cache_attention)
+            if self.window is not None and \
+                    not hasattr(cache, "block_table"):
+                raise NotImplementedError(
+                    "a sliding window over a static or a growing cache: "
+                    "the paged cache is the one that keeps a window")
             if isinstance(cache, StaticCache):
                 # TPU decode path: fixed-size buffers + dynamic_update_slice
                 # — one compiled step serves every position (the concat path
                 # below grows shapes and recompiles per token)
                 out, new_cache = static_cache_attention(
                     q, k, v, cache, position_offset, attn_mask)
-                out = M.reshape(out,
-                                [b, s, self.num_heads * self.head_dim])
-                return self.o_proj(out), new_cache
+                return self._out(out, gate, b, s), new_cache
             from paddle_tpu.inference.kv_cache import (PagedCache,
                                                        paged_cache_attention)
             if isinstance(cache, PagedCache):
@@ -145,10 +182,9 @@ class LlamaAttention(Layer):
                 # requests); supports per-row offsets at s > 1, which is
                 # what chunked prefill and batched speculative verify need
                 out, new_cache = paged_cache_attention(
-                    q, k, v, cache, position_offset, attn_mask)
-                out = M.reshape(out,
-                                [b, s, self.num_heads * self.head_dim])
-                return self.o_proj(out), new_cache
+                    q, k, v, cache, position_offset, attn_mask,
+                    window=self.window)
+                return self._out(out, gate, b, s), new_cache
             pk, pv = cache
             k = M.concat([pk, k], axis=1)
             v = M.concat([pv, v], axis=1)
@@ -158,10 +194,16 @@ class LlamaAttention(Layer):
         # the XLA fallback repeats internally.
         # is_causal stays on for cached prefill too: the tril mask in sdpa
         # offsets by sk-sq, so a multi-token query over past KV is causal
+        if self.window is not None:
+            if attn_mask is not None:
+                raise NotImplementedError(
+                    "a sliding window under an attn_mask of the caller's")
+            t = jnp.arange(s)
+            attn_mask = (t[None, :] <= t[:, None]) & \
+                (t[None, :] > t[:, None] - self.window)
         out = F.scaled_dot_product_attention(
             q, k, v, attn_mask=attn_mask, is_causal=(attn_mask is None))
-        out = M.reshape(out, [b, s, self.num_heads * self.head_dim])
-        out = self.o_proj(out)
+        out = self._out(out, gate, b, s)
         if cache is not None:
             return out, new_cache
         return out

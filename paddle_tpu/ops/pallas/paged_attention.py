@@ -51,8 +51,8 @@ from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["paged_decode_attention", "paged_decode_eligible",
-           "paged_attention_env", "record_path"]
+__all__ = ["paged_decode_attention", "paged_chunk_attention",
+           "paged_decode_eligible", "paged_attention_env", "record_path"]
 
 _NEG_INF = -1e30
 
@@ -125,9 +125,12 @@ def _scale_lanes(kv_heads):
     return -(-kv_heads // 128) * 128
 
 
-def _decode_kernel(bt_ref, len_ref, q_ref, *refs, scale, quant):
+def _decode_kernel(bt_ref, len_ref, q_ref, *refs, scale, quant, window=None):
     """Grid (batch,), sequential.  Row b walks ``cdiv(lengths[b], T)``
-    chunks of T = C·block_size tokens; a chunk's live blocks are copied
+    chunks of T = C·block_size tokens (with ``window``, a static count
+    of positions, only from the chunk that holds position
+    ``max(lengths[b] - window, 0)``: nothing older is copied, and the
+    keys below that position are masked); a chunk's live blocks are copied
     HBM -> VMEM by the kernel's own DMAs (one per physical block, named
     by the block table), into one of two buffers, so the next chunk —
     this row's, or the next row's first — is in flight while this one
@@ -157,9 +160,17 @@ def _decode_kernel(bt_ref, len_ref, q_ref, *refs, scale, quant):
     heads = q_ref.shape[1]
     group = heads // kv_heads
 
+    # without a window every branch below is the Python of the kernel
+    # as it was: its jaxpr, and so its lowering, do not change
+    def low(row):
+        """The first position ``row`` sees under the window."""
+        return jnp.maximum(len_ref[row] - window, 0)
+
     def each_copy(row, i, slot, do):
         """``do`` every DMA of chunk i of ``row``: live blocks only."""
         live = jnp.minimum(pl.cdiv(len_ref[row], bs) - i * C, C)
+        first = 0 if window is None else \
+            jnp.maximum(low(row) // bs - i * C, 0)
 
         def block(c, carry):
             blk = bt_ref[row, i * C + c]
@@ -169,7 +180,7 @@ def _decode_kernel(bt_ref, len_ref, q_ref, *refs, scale, quant):
                     sem.at[slot]))
             return carry
 
-        jax.lax.fori_loop(0, live, block, 0)
+        jax.lax.fori_loop(first, live, block, 0)
 
     def start(row, i, slot):
         each_copy(row, i, slot, lambda cp: cp.start())
@@ -181,26 +192,34 @@ def _decode_kernel(bt_ref, len_ref, q_ref, *refs, scale, quant):
         for _, buf in streams:
             buf[...] = jnp.zeros_like(buf)
         slot_ref[0] = 0
-        start(0, 0, 0)
+        start(0, 0 if window is None else low(0) // T, 0)
 
     plen = len_ref[b]                     # valid tokens in this row
     # a zero-length row still takes its turn, so the buffer parity and
     # the prefetch chain never skip a row
     n = jnp.maximum(pl.cdiv(plen, T), 1)
+    if window is not None:
+        lo = low(b)
+        i0 = lo // T
+        n = n - i0                        # chunks from the window's first
     slot0 = slot_ref[0]
     q = q_ref[0]                                       # [h, hd]
 
-    def chunk(i, carry):
+    def chunk(j, carry):
         m_prev, l_prev, acc = carry
-        slot = (slot0 + i) % 2
+        slot = (slot0 + j) % 2
+        i = j if window is None else i0 + j
 
-        @pl.when(i + 1 < n)
+        @pl.when(j + 1 < n)
         def _next_chunk():
             start(b, i + 1, 1 - slot)
 
-        @pl.when((i + 1 == n) & (b + 1 < rows))
+        @pl.when((j + 1 == n) & (b + 1 < rows))
         def _next_row():
-            start(b + 1, 0, 1 - slot)
+            if window is None:
+                start(b + 1, 0, 1 - slot)
+            else:
+                start(b + 1, low(b + 1) // T, 1 - slot)
 
         each_copy(b, i, slot, lambda cp: cp.wait())
         k, v = kbuf[slot], vbuf[slot]                  # [T, kvh, hd]
@@ -217,8 +236,10 @@ def _decode_kernel(bt_ref, len_ref, q_ref, *refs, scale, quant):
         # column c is token c // kvh of the chunk under kv head c % kvh
         col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        live = ((i * T + col // kv_heads) < plen) & \
-            ((col % kv_heads) == (row // group))
+        kpos = i * T + col // kv_heads
+        live = (kpos < plen) & ((col % kv_heads) == (row // group))
+        if window is not None:
+            live = live & (kpos >= lo)
         s = jnp.where(live, s, _NEG_INF)
 
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -239,15 +260,17 @@ def _decode_kernel(bt_ref, len_ref, q_ref, *refs, scale, quant):
 
 
 @functools.lru_cache(maxsize=None)
-def _kernel(scale, quant):
+def _kernel(scale, quant, window=None):
     """One kernel object per static configuration, so jax's trace cache
     sees the same function at every call site and in every program."""
-    return functools.partial(_decode_kernel, scale=scale, quant=quant)
+    return functools.partial(_decode_kernel, scale=scale, quant=quant,
+                             window=window)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "chunk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "chunk", "interpret",
+                                             "window"))
 def _paged_decode(q, k_pool, v_pool, block_table, lengths, k_scale, v_scale,
-                  *, scale, chunk, interpret):
+                  *, scale, chunk, interpret, window=None):
     """The ``pallas_call`` behind ONE jit: a program that calls it at
     identical shapes from every layer traces it once and lowers one
     kernel body that the layers share."""
@@ -278,7 +301,7 @@ def _paged_decode(q, k_pool, v_pool, block_table, lengths, k_scale, v_scale,
             dimension_semantics=("arbitrary",))
 
     return pl.pallas_call(
-        _kernel(scale, quant),
+        _kernel(scale, quant, window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B,),
@@ -295,7 +318,7 @@ def _paged_decode(q, k_pool, v_pool, block_table, lengths, k_scale, v_scale,
 
 def paged_decode_attention(q, k_pool, v_pool, block_table, lengths,
                            scale=None, interpret=None,
-                           k_scale=None, v_scale=None):
+                           k_scale=None, v_scale=None, window=None):
     """Single-token paged attention.
 
     q: ``[B, heads, head_dim]`` (the step's one query row per sequence,
@@ -307,7 +330,11 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, lengths,
     ``k_scale/v_scale`` (``[num_blocks, block_size, kv_heads]`` fp32)
     mark an int8-quantized pool: a block's scales ride the same DMAs
     and dequantize at the load.  Table entries past a row's last live
-    block are never read.  Returns ``[B, heads, hd]``."""
+    block are never read.  ``window`` (a static count, None: none): row
+    b attends positions ``lengths[b] - window <= . < lengths[b]`` only,
+    and no table entry before the block of the first is read — what a
+    sliding-window layer's ring table (one physical block under several
+    logical entries) needs.  Returns ``[B, heads, hd]``."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     hd = q.shape[-1]
@@ -318,7 +345,79 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, lengths,
                          jnp.dtype(k_pool.dtype).itemsize)
     return _paged_decode(q, k_pool, v_pool, block_table, lengths, k_scale,
                          v_scale, scale=float(scale), chunk=chunk,
-                         interpret=bool(interpret))
+                         interpret=bool(interpret),
+                         window=None if window is None else int(window))
+
+
+# A tile of the walk below, by measurement on a v5e at 48 heads and a
+# 512-query chunk (PERF.md, PR 46): at 512 keys XLA fuses the mask, the
+# exponent and the running maximum into the two matmuls' neighbours and
+# the walk reads 47 % of its arithmetic's peak; at 1024 a window layer's
+# walk is 8 % slower (its tiles overhang a window of 4096 further) and a
+# full layer's within 3 % either way; at 2048 the fusion is lost (9 %,
+# the chunk 1.6 x slower) and a tile's float32 scores are 200 MB.
+_WALK_TILE_TOKENS = 512
+
+
+def paged_chunk_attention(q, k_pool, v_pool, block_table, qpos, scale=None,
+                          window=None):
+    """Causal grouped-query attention of ``S`` queries a row over the
+    paged context they can see, a tile of whole blocks at a time with an
+    online softmax in float32: the ``[S, max_len]`` scores never exist.
+
+    q ``[B, S, heads, hd]``; pools ``[num_blocks, block_size, kv_heads,
+    hd]`` with this dispatch's keys and values already written;
+    block_table ``[B, max_blocks]``; qpos ``[B, S]`` the queries'
+    positions.  The walk runs from the tile of the oldest position any
+    query sees — 0, or ``min(qpos) - window + 1`` under a ``window`` — to
+    the tile of ``max(qpos)``: run-time trip counts, one program whatever
+    the context.  Under a window key ``j`` is seen by query ``t`` iff
+    ``0 <= t - j < window``, and no older table entry is read (a ring
+    table may name one physical block under several logical entries).
+    Plain XLA: it runs on every backend.  Returns ``[B, S, heads, hd]``."""
+    with jax.named_scope("paged_chunk_attention"):
+        B, S, h, hd = q.shape
+        _, bs, kvh, _ = k_pool.shape
+        g = h // kvh
+        mb = block_table.shape[1]
+        cb = max(1, min(_WALK_TILE_TOKENS // bs, mb))
+        tile = cb * bs
+        bt = jnp.pad(block_table, ((0, 0), (0, (-mb) % cb)))
+        f32 = jnp.float32
+        if scale is None:
+            scale = hd ** -0.5
+        qg = (q.astype(f32) * scale).astype(q.dtype).reshape(B, S, kvh, g, hd)
+        last = jnp.minimum((jnp.max(qpos) + tile) // tile, bt.shape[1] // cb)
+        first = 0 if window is None else \
+            jnp.maximum(jnp.min(qpos) - (window - 1), 0) // tile
+        qp = qpos[:, None, None, :, None]               # [B, 1, 1, S, 1]
+
+        def body(i, carry):
+            m_prev, l_prev, acc = carry
+            blocks = jax.lax.dynamic_slice_in_dim(bt, i * cb, cb, axis=1)
+            k = k_pool[blocks].reshape(B, tile, kvh, hd)
+            v = v_pool[blocks].reshape(B, tile, kvh, hd)
+            kpos = i * tile + jnp.arange(tile)
+            live = kpos <= qp                           # [B, 1, 1, S, tile]
+            if window is not None:
+                live = live & (kpos > qp - window)
+            s = jnp.einsum("bskgd,btkd->bkgst", qg, k,
+                           preferred_element_type=f32)
+            s = jnp.where(live, s, _NEG_INF)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_new = corr * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            pv = jnp.einsum("bkgst,btkd->bkgsd", p.astype(v.dtype), v,
+                            preferred_element_type=f32)
+            return m_new, l_new, acc * corr + pv
+
+        _, l, acc = jax.lax.fori_loop(first, last, body, (
+            jnp.full((B, kvh, g, S, 1), _NEG_INF, f32),
+            jnp.zeros((B, kvh, g, S, 1), f32),
+            jnp.zeros((B, kvh, g, S, hd), f32)))
+        out = acc / jnp.maximum(l, 1e-30)               # [B, kvh, g, S, hd]
+        return jnp.moveaxis(out, 3, 1).reshape(B, S, h, hd).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -260,6 +260,62 @@ def test_paged_decode_compiles_other_pools(one_chip, dtype):
 
 
 
+# trinity-large-preview's widths (serve-mixed): 48 query heads over 8
+# key-value heads x 128, 32 slots, a 33,552-token table of 16-token blocks,
+# a window of 4096; the window layers' pools hold 6,144 blocks
+@pytest.mark.parametrize("window", [4096, None])
+def test_paged_decode_compiles_under_a_window_at_48_heads(one_chip, window):
+    """The decode kernel with the window's lower bound (a static count:
+    the walk starts at the chunk of ``len - window``) and without it, at
+    the cell's shapes; two kernel bodies in a program that has layers of
+    both kinds, one a kind."""
+    from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
+    slots, block, table, h, hk, d = 32, 16, 2097, 48, 8, 128
+    blocks = 6144 if window else 16384
+    S = lambda shape, dt=BF16: jax.ShapeDtypeStruct(shape, dt,
+                                                    sharding=one_chip)
+
+    def decode(q, kp, vp, bt, lengths):
+        more = {"window": window} if window else {}
+        q = paged_decode_attention(q, kp, vp, bt, lengths, interpret=False,
+                                   **more)
+        return paged_decode_attention(q, kp, vp, bt, lengths,
+                                      interpret=False, **more)
+
+    pool = S((blocks, block, hk, d))
+    lowered = jax.jit(decode).lower(
+        S((slots, h, d)), pool, pool, S((slots, table), jnp.int32),
+        S((slots,), jnp.int32))
+    assert lowered.as_text().count("@tpu_custom_call") == 1
+    assert lowered.compile().as_text().count(
+        'custom_call_target="tpu_custom_call"') == 2
+
+
+@pytest.mark.parametrize("window", [4096, None])
+def test_paged_chunk_walk_compiles_with_run_time_trip_counts(one_chip,
+                                                             window):
+    """A 512-token prefill chunk's grouped-query attention over the paged
+    context: one while loop whose bounds are read from the positions (no
+    constant bound of 66 tiles), under the scope the reader looks for,
+    its temporaries a few score tiles ([48, 512, 512] float32: 50 MB) and
+    never the ``[48, 512, 33552]`` scores of a gathered table (3.3 GB)."""
+    from paddle_tpu.ops.pallas.paged_attention import paged_chunk_attention
+
+    def chunk(q, kp, vp, bt, qpos):
+        return paged_chunk_attention(q, kp, vp, bt, qpos, window=window)
+
+    S = lambda shape, dt=BF16: jax.ShapeDtypeStruct(shape, dt,
+                                                    sharding=one_chip)
+    pool = S((6144 if window else 16384, 16, 8, 128))
+    compiled = jax.jit(chunk).lower(
+        S((1, 512, 48, 128)), pool, pool, S((1, 2097), jnp.int32),
+        S((1, 512), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "paged_chunk_attention" in text and " while(" in text
+    assert 'known_trip_count' not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 28
+
+
 # granite-4.0-h-small's widths (serve-rag): 36 held experts of 72 at
 # d4096 / f768, top 10; Mamba-2 128 heads x 64, state 128, chunk 256
 @pytest.mark.parametrize("rows", [512, 24])
