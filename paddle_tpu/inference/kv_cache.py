@@ -915,15 +915,6 @@ def _write_targets(bt, qpos, bs: int):
     return jnp.where(lb < mb, bids, 0), qpos % bs
 
 
-# The gather fallback below holds ``[B, heads, S, max_len]`` float32
-# scores at once.  Past this many bytes (a 512-token chunk of 48 heads
-# over 33,552 positions is 3.3 GB; the largest a served model had made
-# before, 32 heads over 8,704, is 0.57 GB) the chunk walks the context's
-# tiles instead, as a layer with a window always does: most of its table
-# is masked.
-_GATHER_SCORES_BYTES = 1 << 30
-
-
 def paged_cache_attention(q, k, v, cache: PagedCache, position_offset,
                           attn_mask=None, window=None):
     """Paged analog of ``static_cache_attention``: write the step's k/v
@@ -950,8 +941,9 @@ def paged_cache_attention(q, k, v, cache: PagedCache, position_offset,
     write of one dispatch).  What the kernel does not take walks the
     tiles of context the queries can see with an online softmax
     (``paged_chunk_attention``) instead of gathering the table where the
-    layer has a window or the gathered scores would pass
-    ``_GATHER_SCORES_BYTES``; an int8 pool and a caller's mask gather."""
+    layer has a window, or the span is more than one query and the table
+    longer than one tile of the walk; an int8 pool and a caller's mask
+    gather, as does a table of one tile."""
     from paddle_tpu.core.dispatch import unwrap, wrap_like
     from paddle_tpu.generation import reject_scalar_mask
     from paddle_tpu.nn.functional.attention import \
@@ -998,9 +990,17 @@ def paged_cache_attention(q, k, v, cache: PagedCache, position_offset,
             out = PA.paged_decode_attention(uq[:, 0], kp, vp, bt,
                                             lengths, window=window)
         return wrap_like(out[:, None]), new_cache
-    scores = 4 * B * uq.shape[2] * S * mb * bs
+    # The gather below holds ``[B, heads, S, max_len]`` float32 scores at
+    # once and costs the whole table whatever the queries can see (32
+    # heads, a 256-query chunk, 2,576 positions: 84 MB a layer where the
+    # median prompt fills one tile in five).  So a span of more than one
+    # query — a prefill chunk, a speculative verify — walks the tiles it
+    # can see wherever the table is longer than one tile of the walk, as a
+    # layer with a window always does; a table of one tile has nothing to
+    # skip.  The rule reads shapes only.
     if attn_mask is None and not quant and \
-            (window is not None or scores > _GATHER_SCORES_BYTES):
+            (window is not None
+             or (S > 1 and mb * bs > PA._WALK_TILE_TOKENS)):
         PA.record_path("walk")
         return wrap_like(PA.paged_chunk_attention(
             uq, kp, vp, bt, qpos, window=window)), new_cache

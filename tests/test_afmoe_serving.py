@@ -621,27 +621,39 @@ def test_the_decode_kernel_the_walk_and_the_gather_agree_under_a_window():
     assert np.abs(unbounded[3] - want[3]).max() > 1e-2
 
 
-@pytest.mark.parametrize("window,budget,mask,path", [
-    (24, 1 << 30, False, "walk"),       # a layer with a window walks
-    (None, 1 << 30, False, "fallback"),  # small scores gather, as before
-    (None, 1 << 10, False, "walk"),     # scores past the budget walk
-    (24, 1 << 30, True, "fallback"),    # a caller's mask gathers
+@pytest.mark.parametrize("window,tile,S,mask,int8,path", [
+    (24, 512, 4, False, False, "walk"),      # a layer with a window walks
+    (24, 512, 1, False, False, "walk"),      # ... a single query too
+    (None, 32, 4, False, False, "walk"),     # a span over several tiles
+    (None, 512, 4, False, False, "fallback"),  # one tile: nothing to skip
+    (None, 104, 4, False, False, "fallback"),  # ... the table exactly
+    (None, 32, 4, True, False, "fallback"),  # a caller's mask gathers
+    (24, 32, 4, True, False, "fallback"),    # ... under a window too
+    (None, 32, 4, False, True, "fallback"),  # an int8 pool gathers
+    (None, 32, 1, False, False, "fallback"),  # one query, no kernel here
 ])
-def test_a_chunk_walks_where_it_has_a_window_or_its_scores_pass_the_budget(
-        monkeypatch, window, budget, mask, path):
+def test_a_chunk_walks_where_it_has_a_window_or_a_table_past_one_tile(
+        monkeypatch, window, tile, S, mask, int8, path):
     """``paged_cache_attention`` chooses between the walk and the gather
-    from what it sees — the layer's window and the bytes the gathered
-    float32 scores would take — and no model states it."""
+    from what it sees — the layer's window, the span's queries and the
+    table against one tile of the walk — and no model states it."""
     from paddle_tpu.inference import kv_cache as KV
     from paddle_tpu.ops.pallas import paged_attention as PA
     rng = np.random.default_rng(9)
     q, kp, vp, bt, lengths, keys, vals = _ring_case(rng)
-    B, S = len(lengths), 4
+    assert bt.shape[1] * kp.shape[1] == 104
+    B = len(lengths)
     took = []
     monkeypatch.setattr(PA, "record_path", took.append)
-    monkeypatch.setattr(KV, "_GATHER_SCORES_BYTES", budget)
+    monkeypatch.setattr(PA, "_WALK_TILE_TOKENS", tile)
     x = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
-    cache = KV.PagedCache(jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(bt))
+    if int8:
+        (kq, ks), (vq, vs) = KV._quantize_kv(jnp.asarray(kp)), \
+            KV._quantize_kv(jnp.asarray(vp))
+        cache = KV.PagedCache(kq, vq, jnp.asarray(bt), ks, vs)
+    else:
+        cache = KV.PagedCache(jnp.asarray(kp), jnp.asarray(vp),
+                              jnp.asarray(bt))
     am = jnp.ones((B, 1, S, bt.shape[1] * kp.shape[1]), bool) if mask \
         else None
     KV.paged_cache_attention(
@@ -649,6 +661,42 @@ def test_a_chunk_walks_where_it_has_a_window_or_its_scores_pass_the_budget(
         x(B, S, *kp.shape[2:]), cache, jnp.asarray(lengths),
         window=window, attn_mask=am)
     assert took == [path]
+
+
+@pytest.mark.parametrize("offsets,S", [
+    ([24], 16),          # a last chunk padded past the prompt's end
+    ([40], 16),          # ... and past the table's (those go to scratch)
+    ([0, 13, 41], 4),    # a verify: rows at offsets of their own
+    ([47, 5, 30], 1 + 2),
+])
+def test_the_walk_and_the_gather_agree_over_a_table_of_three_tiles(
+        monkeypatch, offsets, S):
+    """The two readers of a span without a window, through the rule: a
+    table of three tiles (``_WALK_TILE_TOKENS`` made 16) takes the walk,
+    the same call with the tile past the table the gather; the outputs
+    and the pools they leave agree to rounding, padded queries and all."""
+    from paddle_tpu.inference import kv_cache as KV
+    from paddle_tpu.ops.pallas import paged_attention as PA
+    rng = np.random.default_rng(11)
+    bs, kvh, h, hd, mb, B = 8, 2, 4, 32, 6, len(offsets)
+    x = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    kp, vp = x(1 + B * mb, bs, kvh, hd), x(1 + B * mb, bs, kvh, hd)
+    bt = jnp.asarray(1 + rng.permutation(B * mb).reshape(B, mb), jnp.int32)
+    q, k, v = x(B, S, h, hd), x(B, S, kvh, hd), x(B, S, kvh, hd)
+    took, got = [], {}
+    monkeypatch.setattr(PA, "record_path", took.append)
+    for name, tile in (("walk", 16), ("fallback", 1 << 30)):
+        monkeypatch.setattr(PA, "_WALK_TILE_TOKENS", tile)
+        with jax.default_matmul_precision("highest"):
+            out, new = KV.paged_cache_attention(
+                q, k, v, KV.PagedCache(kp, vp, bt), jnp.asarray(offsets))
+        got[name] = [np.asarray(getattr(a, "_data", a))
+                     for a in (out, new.k, new.v)]
+    assert took == ["walk", "fallback"]
+    assert np.abs(got["walk"][0] - got["fallback"][0]).max() < 2e-6
+    assert np.abs(got["fallback"][0]).max() > 1e-2
+    for a, b in zip(got["walk"][1:], got["fallback"][1:]):
+        assert np.array_equal(a, b)
 
 
 def test_the_walk_over_a_chunk_is_causal_attention_bounded_by_the_window():

@@ -19,14 +19,18 @@ from paddle_tpu.inference.serving import (ContinuousBatchingEngine,
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 
 
-@pytest.fixture(scope="module")
-def tiny_model():
+def _tiny_model(max_positions=128):
     pp.seed(0)
     cfg = LlamaConfig.tiny(vocab_size=256, hidden_size=64,
                            intermediate_size=128, num_hidden_layers=2,
                            num_attention_heads=4, num_key_value_heads=2,
-                           max_position_embeddings=128)
+                           max_position_embeddings=max_positions)
     return LlamaForCausalLM(cfg)
+
+
+@pytest.fixture(scope="module")
+def tiny_model():
+    return _tiny_model()
 
 
 def _reference(model, prompt, n):
@@ -516,6 +520,39 @@ class TestPagedEngineParity:
             kv_block_size=4, prefill_chunk=16)
         rid = eng.add_request(prompt, max_new_tokens=2)
         assert eng.run()[rid][1] == _reference(tiny_model, prompt, 2)
+
+    @pytest.mark.parametrize("spec", [0, 3])
+    def test_a_chunk_over_a_table_past_one_tile_walks_and_serves_the_gathers_tokens(
+            self, monkeypatch, spec):
+        """An engine whose ``max_len`` passes one tile of the walk takes
+        ``paged_chunk_attention`` for its prefill chunk (and for a
+        speculative verify, ``S = k + 1``) and serves what the same
+        engine serves with the rule held to the gather; prompts end on
+        both sides of the tile's edge.  Decode (``S == 1``, no kernel on
+        the CPU) gathers on both sides; with drafts every step is a
+        verify."""
+        from paddle_tpu.ops.pallas import paged_attention as PA
+        model = _tiny_model(max_positions=768)
+        tile = PA._WALK_TILE_TOKENS
+        rng = np.random.default_rng(23)
+        prompts = [rng.integers(0, 256, (n,))
+                   for n in (40, tile - 12, tile, tile + 1, tile + 88)]
+        took, served = [], {}
+        monkeypatch.setattr(PA, "record_path", took.append)
+        for side, t in (("walk", tile), ("gather", 1 << 30)):
+            monkeypatch.setattr(PA, "_WALK_TILE_TOKENS", t)
+            del took[:]
+            eng = ContinuousBatchingEngine(
+                model, slots=2, max_len=768, prefill_buckets=(128,),
+                kv_block_size=16, prefill_chunk=128, spec_decode=spec)
+            rids = [eng.add_request(p, max_new_tokens=6) for p in prompts]
+            out = eng.run()
+            served[side] = [out[r][1] for r in rids]
+            assert ("walk" in took) == (side == "walk"), took
+            if not spec:                 # decode gathers on either side;
+                assert "fallback" in took    # a verify is its own decode
+        assert served["walk"] == served["gather"]
+        assert all(len(t) == 6 for t in served["walk"])
 
     def test_sampling_near_zero_temperature(self, tiny_model):
         rng = np.random.default_rng(17)
