@@ -710,8 +710,12 @@ def _catalog_entries() -> List[Dict[str, Any]]:
             f"bc{bc} bf{bf_}",
             lambda g=g, c=c, d=d_, h=h_, dtype=dtype:
             gm.verify_static(g, c, d, h, dtype=dtype))
-    # the benchmark's serve-rag: a prefill chunk over 36 held experts
-    for t, k, h_, d_, f_, dtype in ((512, 10, 36, 4096, 768, "bfloat16"),):
+    # a prefill chunk over the held experts of the benchmark's serve-rag,
+    # serve-longctx, serve-reason and serve-mixed
+    for t, k, h_, d_, f_, dtype in ((512, 10, 36, 4096, 768, "bfloat16"),
+                                    (512, 8, 16, 4096, 2048, "bfloat16"),
+                                    (512, 8, 64, 2304, 1024, "bfloat16"),
+                                    (512, 4, 16, 3072, 3072, "bfloat16")):
         br, bf_ = gm.sorted_ffn_blocks(t, k, h_, d_, f_, dtype)
         add("sorted_gated_ffn", f"t{t} k{k} h{h_} d{d_} f{f_} {dtype}",
             f"br{br} bf{bf_}",

@@ -235,15 +235,20 @@ def gated_experts_forward(x2d, router_w, w_in, w_out, *, top_k: int,
     rule on the static shapes, in one place
     (``ops/pallas/grouped_matmul.py: sorted_ffn_blocks``):
 
-    * from a mean of 32 rows a held expert while the step's rows fit
-      VMEM (a prefill chunk: 512 tokens x 10 picks over 36 experts),
-      one Pallas call, ``sorted_gated_ffn``: every group on a tile
-      boundary of a padded row buffer kept in VMEM, a 128-row tile to
-      one expert, the hidden tile never in HBM;
+    * from a mean of 32 rows a held expert while the step's float32
+      output and its rows fit VMEM (a prefill chunk: 512 tokens x 10
+      picks over 36 experts), one Pallas call, ``sorted_gated_ffn``, and
+      nothing after it: every group on a tile boundary of a padded row
+      buffer kept in VMEM, a 128-row tile to one expert, the hidden tile
+      never in HBM, and each tile's rows added under their gates into
+      the step's ``[T, d]``, which stays in VMEM over the call -- no
+      padded rows, no gather back to the picks and no sum over them in
+      HBM;
     * below that (a decode step's 24 rows) and above it, two
       ``lax.ragged_dot`` over the picks sorted by expert: on the TPU
       the compiler's grouped-matmul kernel, a 16-row tile at a decode
-      step's rows and a 512-row tile at a chunk's.
+      step's rows and a 512-row tile at a chunk's; the rows go back to
+      their picks and are summed under the gates by XLA.
 
     Either way only the groups that have rows are visited, so an expert
     no row chose is not read, and nothing is dropped: there is no
@@ -268,14 +273,15 @@ def gated_experts_forward(x2d, router_w, w_in, w_out, *, top_k: int,
     blocks = GM.sorted_ffn_blocks(T, top_k, H, d, w_out.shape[1], x2d.dtype)
     if blocks is not None:
         # a prefill chunk's rows: every group starts on a tile boundary of
-        # a padded row buffer and both products are one kernel
+        # a padded row buffer, and both products, the gates and the sum
+        # over a token's picks are one kernel
         GM.record_path("sorted_kernel")
         block_rows, block_f = blocks
-        tile_expert, used, dest = GM.sorted_tile_plan(loc, sizes, block_rows)
-        ys = GM.sorted_gated_ffn(x2d, dest, w_in, w_out, tile_expert, used,
-                                 block_rows=block_rows, block_f=block_f)
-        # a pick that landed elsewhere has no row there: zero
-        ys = jnp.where((dest >= 0)[..., None], ys[jnp.maximum(dest, 0)], 0.0)
+        tile_expert, used, dest, src, row_gate = GM.sorted_tile_plan(
+            loc, sizes, block_rows, gates)
+        out = GM.sorted_gated_ffn(x2d, dest, src, row_gate, w_in, w_out,
+                                  tile_expert, used, block_rows=block_rows,
+                                  block_f=block_f)
     else:
         GM.record_path("ragged_dot")
         order = jnp.argsort(flat)                         # stable; H last
@@ -289,7 +295,7 @@ def gated_experts_forward(x2d, router_w, w_in, w_out, *, top_k: int,
         # them unspecified, so they are zeroed before the gates see them
         ys = jnp.where((flat[order] < H)[:, None], ys, 0.0)
         ys = ys[jnp.argsort(order)].reshape(T, top_k, d)  # un-sort
-    out = jnp.einsum("tk,tkd->td", gates, ys)
+        out = jnp.einsum("tk,tkd->td", gates, ys)
     picks = T * top_k if row_valid is None \
         else jnp.sum(row_valid.astype(jnp.int32)) * top_k
     counts = jnp.stack([jnp.sum(sizes > 0), jnp.sum(sizes),
@@ -305,8 +311,9 @@ class GatedExpertLayer(Layer):
     (``router_gates``) and ``scaling`` its routed scaling factor.
     Inference only (``gated_experts_forward``):
     dropless, no capacity, no auxiliary loss; a prefill chunk's rows go
-    through the repo's own ``sorted_gated_ffn`` kernel and a decode
-    step's through ``lax.ragged_dot``, by the rows alone.  ``MoELayer``
+    through the repo's own ``sorted_gated_ffn`` kernel, which returns
+    the layer's gated sum, and a decode step's through
+    ``lax.ragged_dot``, by the rows alone.  ``MoELayer``
     above, with its capacity factor, its five dispatch modes and
     ``ExpertFFN``'s two biased matrices, is the training path and stays
     as it is.

@@ -28,9 +28,9 @@ grouped FFN as ONE ``pallas_call``:
   XLA, with a ``float0`` cotangent for counts.
 
 Beside it, ``sorted_gated_ffn``: the served (dropless, gated, bias-free)
-expert layer's two products over expert-sorted rows at a prefill chunk's
-row count, chosen by ``sorted_ffn_blocks`` from the static shapes alone
-(its own section below).
+expert layer's two products over expert-sorted rows and their sum under
+the gates at a prefill chunk's row count, chosen by ``sorted_ffn_blocks``
+from the static shapes alone (its own section below).
 
 Routing of the capacity-grouped kernel is trace-time and OFF by
 default: ``PADDLE_TPU_GROUPED_MOE=1`` flips ``_expert_ffn`` to it
@@ -311,9 +311,14 @@ def grouped_expert_ffn(x, w1, b1, w2, b2, *, counts=None, act=None,
 # 512-row tile for each.  Here every group starts on a tile boundary of a
 # padded row buffer, a tile belongs to one expert (the tile -> expert map
 # is scalar prefetch), and both products run in one call with the hidden
-# tile in VMEM.  The padded buffer is never in HBM: the chunk's rows stay
-# whole in VMEM and a tile takes its own by a one-hot product (exact: one
-# term a row), so no sorted copy of the rows is made or read.
+# tile in VMEM.  The padded buffer is never in HBM, on the way in or out:
+# the chunk's rows stay whole in VMEM and a tile takes its own by a
+# one-hot product (exact: one term a row), so no sorted copy of the rows
+# is made or read; and the call's output is the step's ``[T, d]`` float32,
+# resident in VMEM over the grid, into which each tile's rows are added
+# under their gates (a padded row's token and gate are scalar prefetch
+# too), so no padded result is written, gathered back to the picks or
+# summed in HBM.
 
 _SORTED_TILE_ROWS = 128       # the MXU's height: the most rows a tile has
 # A held expert's mean rows from which the kernel is taken.  Measured on
@@ -323,19 +328,21 @@ _SORTED_TILE_ROWS = 128       # the MXU's height: the most rows a tile has
 # within a tenth and the step keeps the compiler's product.
 _SORTED_MIN_GROUP_ROWS = 32
 # The compiler's own scope is 16 MiB of a v5e core's 128: the kernel asks
-# for this much, and what it holds takes no more than the budget.
+# for this much, and the buffers it holds take no more than the budget.
+# The rest is the compiler's own (a tile's float32 product before it is
+# folded: under 2 MiB at 128 rows x d 4096, compiled for a v5e).
 _SORTED_VMEM_LIMIT = 32 * (1 << 20)
-_SORTED_VMEM_BUDGET = 26 * (1 << 20)
+_SORTED_VMEM_BUDGET = 28 * (1 << 20)
 
 
 def sorted_ffn_vmem_bytes(block_rows, block_f, T, top_k, d, itemsize):
-    """What the call holds: every streamed tile twice, the step's rows
-    and their places (whole, fetched once, two buffers all the same) and
-    the gathered tile."""
-    return (2 * (block_rows * d * 4                # float32 output
-                 + 3 * d * block_f * itemsize)     # gate, up, down
-            + 2 * (T * d * itemsize + top_k * T * 4)
-            + block_rows * d * itemsize)
+    """What the call holds: the streamed weight tiles twice; once each
+    (a whole array at a constant index has one buffer) the float32
+    output, resident over the grid, the step's rows and their places;
+    the gathered tile and its float32 rows."""
+    return (2 * 3 * d * block_f * itemsize         # gate, up, down
+            + T * d * 4 + T * d * itemsize + top_k * T * 4
+            + block_rows * d * (itemsize + 4))
 
 
 def sorted_ffn_blocks(T: int, top_k: int, H: int, d: int, f: int, dtype):
@@ -345,11 +352,12 @@ def sorted_ffn_blocks(T: int, top_k: int, H: int, d: int, f: int, dtype):
 
     The kernel where the step's picks, all landing here, would give a
     held expert ``_SORTED_MIN_GROUP_ROWS`` rows or more (a prefill chunk:
-    512 tokens x 10 picks over 36 experts is 142) and the step's rows fit
-    in VMEM beside the weight tiles; ``ragged_dot`` below that (a decode
-    step: 24 x 10 over 36 is 7, which the compiler tiles by 16) and above
-    it (thousands of rows a group fill the compiler's 512-row tile).  On
-    the TPU the widths must tile by lanes.
+    512 tokens x 10 picks over 36 experts is 142) and the step's float32
+    output and its rows fit in VMEM beside the weight tiles;
+    ``ragged_dot`` below that (a decode step: 24 x 10 over 36 is 7, which
+    the compiler tiles by 16) and above it (thousands of rows a group
+    fill the compiler's 512-row tile).  On the TPU the widths must tile
+    by lanes.
 
     The tile is the MXU's height, or the mean group's rows rounded up to
     a power of two where that is less (never under the dtype's packed
@@ -375,23 +383,34 @@ def sorted_ffn_blocks(T: int, top_k: int, H: int, d: int, f: int, dtype):
     return None
 
 
-def sorted_tile_plan(loc, sizes, block_rows: int):
-    """Where a step's picks go in the padded row buffer.
+@functools.partial(jax.jit, static_argnames=("block_rows",))
+def sorted_tile_plan(loc, sizes, block_rows: int, gates):
+    """Where a step's picks go in the padded row buffer, and whose each
+    padded row is.
 
     ``loc`` [T, k] int32: the group of each pick, H for none (a token
     picks a group at most once); ``sizes`` [H] int32: picks of each
-    group.  Group ``e`` gets ``cdiv(sizes[e], block_rows)`` tiles, its
-    picks in token order from its first tile's first row, so at most
+    group; ``gates`` [T, k] float32: each pick's weight.  Group ``e``
+    gets ``cdiv(sizes[e], block_rows)`` tiles, its picks in token order
+    from its first tile's first row, so at most
     ``cdiv(T * k, block_rows) + H - 1`` tiles are used.  No sort and no
     scatter (one update at a time on the TPU): a pick's place in its
-    group is the count of tokens above it that chose the group.  Returns
+    group is the count of tokens above it that chose the group, and a
+    padded row's token is the one of its tile's group with that many
+    above it.  Returns
 
     * ``tile_expert`` [tiles] int32 -- the group a tile belongs to (the
       last used tile's past the used count, so a skipped step asks for
       no new weight block),
     * ``num_used`` [1] int32 -- tiles that hold rows,
     * ``dest`` [T, k] int32 -- the padded row of each pick, -1 for the
-      picks of no group."""
+      picks of no group,
+    * ``src`` [tiles * block_rows] int32 -- the token of each padded
+      row, -1 for a row no pick has (a tile's rows fill from its first),
+    * ``row_gate`` [tiles * block_rows] float32 -- that pick's gate, 0
+      for a row no pick has.
+
+    Behind one jit, as the call is: a program's layers lower one plan."""
     T, k = loc.shape
     H = sizes.shape[0]
     tm = block_rows
@@ -401,61 +420,95 @@ def sorted_tile_plan(loc, sizes, block_rows: int):
     above = jnp.cumsum(chose, axis=0) - chose
     tiles = (sizes + tm - 1) // tm
     tile_end = jnp.cumsum(tiles)
-    place = ((tile_end - tiles) * tm)[None] + above            # [T, H]
+    first = tile_end - tiles
+    place = (first * tm)[None] + above                         # [T, H]
     dest = jnp.sum(jnp.where(hot, place[:, None], 0), axis=-1)
     dest = jnp.where(loc < H, dest, -1)
     num_used = tile_end[-1]
-    t = jnp.minimum(jnp.arange(nt, dtype=jnp.int32),
-                    jnp.maximum(num_used - 1, 0))
-    te = jnp.minimum(jnp.sum(t[:, None] >= tile_end[None], axis=1,
+    t = jnp.arange(nt, dtype=jnp.int32)
+    live = jnp.minimum(t, jnp.maximum(num_used - 1, 0))
+    te = jnp.minimum(jnp.sum(live[:, None] >= tile_end[None], axis=1,
                              dtype=jnp.int32), H - 1)
-    return te, num_used.reshape(1), dest
+    # row r of used tile t is its group's pick with (t - first) * tm + r
+    # of the group's picks above it; the columns of a tile's group by a
+    # one-hot sum (a gather of columns is a loop on the TPU)
+    mine = (te[:, None] == jnp.arange(H, dtype=jnp.int32)) \
+        & (t < num_used)[:, None]                              # [tiles, H]
+    pick = lambda a: jnp.sum(jnp.where(mine[:, None], a[None], 0), axis=-1)
+    rank = ((t - first[te]) * tm)[:, None] \
+        + jnp.arange(tm, dtype=jnp.int32)                      # [tiles, tm]
+    # above + 1 where the token chose the group, 0 where not: one compare
+    is_row = pick(jnp.where(chose > 0, above + 1, 0))[:, None, :] \
+        == rank[:, :, None] + 1                                # [tiles, tm, T]
+    weight = jnp.sum(jnp.where(hot, gates.astype(jnp.float32)[:, :, None],
+                               0.0), axis=1)
+    tok = jnp.arange(T, dtype=jnp.int32)
+    src = jnp.max(jnp.where(is_row, tok, -1), axis=-1)
+    row_gate = jnp.sum(jnp.where(is_row, pick(weight)[:, None, :], 0.0),
+                       axis=-1)
+    return (te, num_used.reshape(1), dest, src.reshape(-1),
+            row_gate.reshape(-1))
+
+
+def _whole(*_):
+    """A whole array at a constant index: fetched (or written) once, and
+    one buffer."""
+    return 0, 0
 
 
 def _sorted_maps(nf: int):
-    """Index maps of the sorted kernel's weights and output.  A step past
-    the used tiles is sent to the last used step's blocks, so it moves
-    nothing."""
+    """Index maps of the sorted kernel's weights (grid indices, then the
+    scalar-prefetch operands, of which the tile -> expert map and the
+    used count are read).  A step past the used tiles is sent to the last
+    used step's blocks, so it moves nothing."""
     def live(t, j, te, nu):
         used = t < nu[0]
         t = jnp.where(used, t, jnp.maximum(nu[0] - 1, 0))
-        return t, jnp.where(used, j, nf - 1), te[t]
+        return jnp.where(used, j, nf - 1), te[t]
 
-    def rows(t, j, te, nu):
-        return live(t, j, te, nu)[0], 0
-
-    def gate(t, j, te, nu):
-        _, j, e = live(t, j, te, nu)
+    def gate(t, j, te, nu, *_):
+        j, e = live(t, j, te, nu)
         return e, 0, j
 
-    def up(t, j, te, nu):       # w_in is [gate | up]: the second half
-        _, j, e = live(t, j, te, nu)
+    def up(t, j, te, nu, *_):   # w_in is [gate | up]: the second half
+        j, e = live(t, j, te, nu)
         return e, 0, nf + j
 
-    def down(t, j, te, nu):
-        _, j, e = live(t, j, te, nu)
+    def down(t, j, te, nu, *_):
+        j, e = live(t, j, te, nu)
         return e, j, 0
-    return rows, gate, up, down
+    return gate, up, down
 
 
-def _sorted_gated_kernel(te_ref, nu_ref, dest_ref, x_ref, wg_ref, wu_ref,
-                         wo_ref, o_ref, xs_ref):
-    """One (row tile, hidden block) step.  At a tile's first hidden block
-    its rows are taken from the step's: row ``r`` of tile ``t`` is the
-    token with a pick whose place is ``t * rows + r`` (a one-hot product;
-    a row no pick has is zero).  Then ``[rows, block_f]`` gate and up
-    tiles, ``silu(g) * u`` in float32, one cast, folded into the float32
-    output tile, which stays in VMEM over the hidden blocks."""
+def _sorted_gated_kernel(te_ref, nu_ref, src_ref, rg_ref, dest_ref, x_ref,
+                         wg_ref, wu_ref, wo_ref, o_ref, xs_ref, ys_ref):
+    """One (row tile, hidden block) step.  The output, the step's whole
+    ``[T, d]`` float32, stays in VMEM over the grid: zeroed at the first
+    step, written to HBM once after the last.  At a tile's first hidden
+    block its rows are taken from the step's: row ``r`` of tile ``t`` is
+    the token with a pick whose place is ``t * rows + r`` (a one-hot
+    product; a row no pick has is zero).  Then ``[rows, block_f]`` gate
+    and up tiles, ``silu(g) * u`` in float32, one cast, folded into the
+    tile's float32 rows in VMEM.  At the last hidden block each row a
+    pick has is multiplied by its gate and added to its token's row of
+    the output: a tile is one expert's and a token picks an expert once,
+    so a tile's tokens are distinct, and tiles run in order."""
     t, j = pl.program_id(0), pl.program_id(1)
+    nf = pl.num_programs(1)
+    tm = xs_ref.shape[0]
     dot = functools.partial(jax.lax.dot_general,
                             dimension_numbers=(((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
+
+    @pl.when((t == 0) & (j == 0))
+    def _zero():
+        o_ref[...] = jnp.zeros_like(o_ref)
 
     @pl.when(t < nu_ref[0])
     def _compute():
         @pl.when(j == 0)
         def _gather():
-            tm, T = xs_ref.shape[0], x_ref.shape[0]
+            T = x_ref.shape[0]
             row = t * tm + jax.lax.broadcasted_iota(jnp.int32, (tm, T), 0)
             hit = jnp.zeros((tm, T), jnp.float32)
             for k in range(dest_ref.shape[0]):
@@ -470,26 +523,47 @@ def _sorted_gated_kernel(te_ref, nu_ref, dest_ref, x_ref, wg_ref, wu_ref,
 
         @pl.when(j == 0)
         def _first():
-            o_ref[...] = y
+            ys_ref[...] = y
 
         @pl.when(j > 0)
         def _fold():
-            o_ref[...] += y
+            ys_ref[...] += y
+
+        @pl.when(j == nf - 1)
+        def _combine():
+            def add_row(r):
+                tok = src_ref[t * tm + r]
+                o_ref[pl.ds(tok, 1), :] += \
+                    rg_ref[t * tm + r] * ys_ref[pl.ds(r, 1), :]
+                return r + 1
+
+            # a tile's rows fill from its first: stop at the first empty
+            jax.lax.while_loop(
+                lambda r: (r < tm)
+                & (src_ref[t * tm + jnp.minimum(r, tm - 1)] >= 0),
+                add_row, jnp.int32(0))
 
 
-def sorted_gated_ffn(x, dest, w_in, w_out, tile_expert, num_used, *,
-                     block_rows: int, block_f: int, interpret=None):
-    """``W_out[e] (silu(g) * u)``, ``[g | u] = x[token] W_in[e]``, for
-    every pick with a place: ``x`` [T, d] the step's rows, ``dest`` [T, k]
-    the padded row of each pick (-1: none) and tile ``t`` with the
-    weights of group ``tile_expert[t]`` (``sorted_tile_plan``); ``w_in``
-    [H, d, 2f] as ``[gate | up]`` and ``w_out`` [H, f, d] as the layer
-    holds them.  Returns the padded rows, float32 [tiles * block_rows,
-    d]; tiles from ``num_used`` on do no product, read no weight and are
-    left unwritten."""
+def sorted_gated_ffn(x, dest, src, row_gate, w_in, w_out, tile_expert,
+                     num_used, *, block_rows: int, block_f: int,
+                     interpret=None):
+    """The held experts' part of the layer's result, float32 [T, d]: each
+    token's row the sum over its picks with a place of
+    ``gate * W_out[e] (silu(g) * u)``, ``[g | u] = x[token] W_in[e]``.
+
+    ``x`` [T, d] the step's rows; ``dest`` [T, k] the padded row of each
+    pick (-1: none), ``src`` / ``row_gate`` [tiles * block_rows] the
+    token and the gate of each padded row, and tile ``t`` with the
+    weights of group ``tile_expert[t]`` (all ``sorted_tile_plan``'s);
+    ``w_in`` [H, d, 2f] as ``[gate | up]`` and ``w_out`` [H, f, d] as the
+    layer holds them.  The padded rows never leave VMEM, and the gates'
+    sum is in float32 in the order of the tiles.  Tiles from
+    ``num_used`` on do no product and read no weight; a token with no
+    pick here reads exactly zero."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    return _sorted_gated_call(x, dest, w_in, w_out, tile_expert, num_used,
+    return _sorted_gated_call(x, dest, src, row_gate, w_in, w_out,
+                              tile_expert, num_used,
                               block_rows=int(block_rows),
                               block_f=int(block_f),
                               interpret=bool(interpret))
@@ -497,16 +571,15 @@ def sorted_gated_ffn(x, dest, w_in, w_out, tile_expert, num_used, *,
 
 @functools.partial(jax.jit,
                    static_argnames=("block_rows", "block_f", "interpret"))
-def _sorted_gated_call(x, dest, w_in, w_out, tile_expert, num_used, *,
-                       block_rows, block_f, interpret):
+def _sorted_gated_call(x, dest, src, row_gate, w_in, w_out, tile_expert,
+                       num_used, *, block_rows, block_f, interpret):
     """The ``pallas_call`` behind ONE jit (as ``paged_attention``'s): a
     program whose layers call it at identical shapes lowers one kernel
     body, and the Pallas -> Mosaic lowering is paid on every start."""
     T, d = x.shape
     H, f, _ = w_out.shape
     nt, nf = tile_expert.shape[0], f // block_f
-    rows, gate, up, down = _sorted_maps(nf)
-    whole = lambda t, j, te, nu: (0, 0)
+    gate, up, down = _sorted_maps(nf)
     params = {}
     if not interpret:
         params["compiler_params"] = pltpu.CompilerParams(
@@ -515,20 +588,21 @@ def _sorted_gated_call(x, dest, w_in, w_out, tile_expert, num_used, *,
     return pl.pallas_call(
         _sorted_gated_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=4,
             grid=(nt, nf),
-            in_specs=[pl.BlockSpec((dest.shape[1], T), whole),
-                      pl.BlockSpec((T, d), whole),
+            in_specs=[pl.BlockSpec((dest.shape[1], T), _whole),
+                      pl.BlockSpec((T, d), _whole),
                       pl.BlockSpec((1, d, block_f), gate),
                       pl.BlockSpec((1, d, block_f), up),
                       pl.BlockSpec((1, block_f, d), down)],
-            out_specs=pl.BlockSpec((block_rows, d), rows),
-            scratch_shapes=[pltpu.VMEM((block_rows, d), x.dtype)]),
-        out_shape=jax.ShapeDtypeStruct((nt * block_rows, d), jnp.float32),
+            out_specs=pl.BlockSpec((T, d), _whole),
+            scratch_shapes=[pltpu.VMEM((block_rows, d), x.dtype),
+                            pltpu.VMEM((block_rows, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((T, d), jnp.float32),
         name="sorted_gated_ffn",
         interpret=interpret,
         **params,
-    )(tile_expert, num_used, dest.T, x, w_in, w_in, w_out)
+    )(tile_expert, num_used, src, row_gate, dest.T, x, w_in, w_in, w_out)
 
 
 # ---------------------------------------------------------------------------
@@ -582,12 +656,14 @@ def verify_static(G, C, d, h, E=None, dtype="bfloat16", block_c=None,
 def verify_static_sorted(T, top_k, H, d, f, dtype="bfloat16",
                          block_rows=None, block_f=None):
     """Static Mosaic-legality findings for the sorted gated kernel at
-    this step's shape.  The tile -> expert map is checked on the plan
-    that uses every tile (all groups but the last one row, the last the
-    rest: the bound ``cdiv(T * k, rows) + H - 1`` reached), so that each
-    output block has its one writer; at a real routing the tiles past
-    the used count are left unwritten and the layer reads none of
-    them."""
+    this step's shape: the step's rows, their places and the float32
+    ``[T, d]`` output each one whole block at a constant index (one
+    buffer; the output revisited along both sequential axes, the
+    accumulator pattern), the weight tiles streamed, the gathered tile
+    and its float32 rows as scratch, the plan's four scalar operands.
+    The tile -> expert map is checked on the plan that uses every tile
+    (all groups but the last one row, the last the rest: the bound
+    ``cdiv(T * k, rows) + H - 1`` reached)."""
     from paddle_tpu.analysis import kernel_verify as kv
     dtype = str(dtype)
     if block_rows is None or block_f is None:
@@ -596,29 +672,31 @@ def verify_static_sorted(T, top_k, H, d, f, dtype="bfloat16",
     tm, bf = int(block_rows), int(block_f)
     nt, nf = -(-(T * top_k) // tm) + H - 1, f // bf
     te = np.minimum(np.arange(nt), H - 1).astype(np.int32)
-    rows, gate, up, down = _sorted_maps(nf)
-    whole = lambda t, j, te, nu: (0, 0)
+    gate, up, down = _sorted_maps(nf)
     spec = kv.KernelSpec(
         name="sorted_gated_ffn",
         grid=(nt, nf),
         args=[
-            kv.ArgSpec("dest", (top_k, T), (top_k, T), whole, "int32"),
-            kv.ArgSpec("x", (T, d), (T, d), whole, dtype),
+            kv.ArgSpec("dest", (top_k, T), (top_k, T), _whole, "int32"),
+            kv.ArgSpec("x", (T, d), (T, d), _whole, dtype),
             kv.ArgSpec("w_gate", (H, d, 2 * f), (1, d, bf), gate, dtype,
                        dma_once=True),
             kv.ArgSpec("w_up", (H, d, 2 * f), (1, d, bf), up, dtype,
                        dma_once=True),
             kv.ArgSpec("w_out", (H, f, d), (1, bf, d), down, dtype,
                        dma_once=True),
-            kv.ArgSpec("o", (nt * tm, d), (tm, d), rows, "float32",
+            kv.ArgSpec("o", (T, d), (T, d), _whole, "float32",
                        is_output=True),
         ],
-        scratch=[kv.ScratchSpec("xs", (tm, d), dtype)],
+        scratch=[kv.ScratchSpec("xs", (tm, d), dtype),
+                 kv.ScratchSpec("ys", (tm, d), "float32")],
         dimension_semantics=("arbitrary", "arbitrary"),
-        scalar_prefetch=(te, np.asarray([nt], np.int32)),
+        scalar_prefetch=(te, np.asarray([nt], np.int32),
+                         np.zeros(nt * tm, np.int32),
+                         np.zeros(nt * tm, np.float32)),
         vmem_budget=_SORTED_VMEM_BUDGET, vmem_limit=_SORTED_VMEM_LIMIT,
         # both products accumulate in float32 registers and fold into the
-        # float32 output tile
+        # tile's float32 scratch rows; the gates' sum is float32 too
         needs_fp32_acc=True, acc_inline=True,
         where=f"sorted_gated_ffn[T={T} k={top_k} H={H} d={d} f={f} "
               f"rows={tm} bf={bf} {dtype}]")

@@ -322,9 +322,10 @@ def test_paged_chunk_walk_compiles_with_run_time_trip_counts(one_chip,
 def test_served_expert_layer_compiles_to_grouped_matmuls(one_chip, rows,
                                                          monkeypatch):
     """A prefill chunk's rows go through the repo's own kernel over the
-    sorted rows (one Mosaic call under its name, no ``ragged-dot`` of the
-    compiler's, and the ``[5120, 1536]`` gate-and-up product never a
-    temporary); a decode step's keep ``lax.ragged_dot``, the compiler's
+    sorted rows (one Mosaic call under its name whose output is the gated
+    ``[512, 4096]`` sum, no ``ragged-dot`` of the compiler's, and neither
+    the ``[5120, 1536]`` gate-and-up product nor the padded or gathered
+    float32 rows ever a temporary); a decode step's keep ``lax.ragged_dot``, the compiler's
     grouped matmul (two custom calls and their metadata call).  Neither
     is a dense product over every expert — the temporary a dense
     [rows * 10, 36, 1536] product would need is not there."""
@@ -350,6 +351,10 @@ def test_served_expert_layer_compiles_to_grouped_matmuls(one_chip, rows,
         assert calls == 1 and "sorted_gated_ffn" in text
         assert "ragged-dot" not in text
         assert "[5120,1536]" not in text
+        # the kernel's output is the step's [512, 4096]: no padded rows
+        # (75 tiles of 128), no picks' rows gathered back to be summed
+        assert "f32[9600,4096]" not in text
+        assert "f32[512,10,4096]" not in text and "f32[5120,4096]" not in text
     else:
         assert calls == 3 and "ragged-dot" in text
         assert "sorted_gated_ffn" not in text

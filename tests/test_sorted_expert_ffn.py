@@ -1,8 +1,11 @@
-"""The served expert layer's sorted kernel (ISSUE 38): the held experts'
-two grouped products as one Pallas call over expert-sorted rows on tile
-boundaries (``ops/pallas/grouped_matmul.py: sorted_gated_ffn``), against
-the layer's ``lax.ragged_dot`` path and against a dense per-expert sum;
-the shape rule that picks one or the other; the tile plan.
+"""The served expert layer's sorted kernel (ISSUE 38, 48): the held
+experts' two grouped products, the gates and the sum over a token's
+picks as one Pallas call over expert-sorted rows on tile boundaries
+(``ops/pallas/grouped_matmul.py: sorted_gated_ffn``), against the layer's
+``lax.ragged_dot`` path, against a dense per-expert sum and against the
+un-fused form (padded rows, ``ys[dest]``, ``einsum`` under the gates:
+kept here as the reference); the shape rule that picks one or the other;
+the tile plan.
 
 Interpret mode on the CPU (conftest pins JAX_PLATFORMS).  The compile for
 the chip at the published widths is in ``test_flash_attention_tpu.py``.
@@ -154,10 +157,14 @@ def test_tile_plan_puts_every_group_on_a_tile_boundary(sizes, T, k, tile):
     sizes = np.asarray(sizes, np.int32)
     H = len(sizes)
     loc = _picks(sizes, T, k, seed=int(sizes.sum()))
-    te, used, dest = (np.asarray(a) for a in GM.sorted_tile_plan(
-        jnp.asarray(loc), jnp.asarray(sizes), tile))
+    gates = np.random.default_rng(T).uniform(0.1, 1.0, (T, k)).astype(
+        np.float32)
+    te, used, dest, src, row_gate = (np.asarray(a) for a in
+                                     GM.sorted_tile_plan(
+        jnp.asarray(loc), jnp.asarray(sizes), tile, jnp.asarray(gates)))
     tiles = -(-sizes // tile)
     assert te.shape[0] == -(-(T * k) // tile) + H - 1
+    assert src.shape == row_gate.shape == (te.shape[0] * tile,)
     assert int(used[0]) == tiles.sum() <= te.shape[0]
     assert list(te[:used[0]]) == list(np.repeat(np.arange(H), tiles))
     # a skipped tile names the last used one's expert: no new weight block
@@ -168,36 +175,161 @@ def test_tile_plan_puts_every_group_on_a_tile_boundary(sizes, T, k, tile):
         at = dest[loc == e]             # first row on, in token order
         assert list(at) == list(first[e] + np.arange(n))
         assert (te[at // tile] == e).all()
+    # a padded row knows its token and its gate; a row no pick has, neither
+    tok, col = np.nonzero(loc < H)
+    assert (src[dest[tok, col]] == tok).all()
+    assert (row_gate[dest[tok, col]] == gates[tok, col]).all()
+    assert (src >= 0).sum() == sizes.sum()
+    assert (src[used[0] * tile:] == -1).all()
+    assert (row_gate[src < 0] == 0.0).all()
+
+
+def _unfused(x, loc, gates, w_in, w_out, te, dest, tile):
+    """What the layer did before the kernel combined, in plain jnp: the
+    padded rows (each tile its expert's product over its rows, float32
+    out), ``ys[dest]`` back to the picks, the sum under the gates.  Also
+    returns the padded rows."""
+    T, k = loc.shape
+    H = w_in.shape[0]
+    tok, col = np.nonzero(np.asarray(loc) < H)
+    at = np.asarray(dest)[tok, col]
+    xp = jnp.zeros((te.shape[0] * tile, x.shape[1]), x.dtype).at[at].set(
+        x[tok])
+    e = jnp.repeat(jnp.asarray(te), tile)
+    g, u = jnp.split(jnp.einsum("nd,ndf->nf", xp, w_in[e],
+                                preferred_element_type=jnp.float32), 2,
+                     axis=-1)
+    ys = jnp.einsum("nf,nfd->nd", (jax.nn.silu(g) * u).astype(x.dtype),
+                    w_out[e], preferred_element_type=jnp.float32)
+    picked = jnp.where((dest >= 0)[..., None], ys[jnp.maximum(dest, 0)], 0.0)
+    return jnp.einsum("tk,tkd->td", gates, picked), ys
+
+
+# the four expert cells' expert layers at toy widths: (tokens, top k,
+# routed, held, tile rows, rows that are tokens).  The tile is the one the
+# rule gives the cell's chunk; held / routed is the cell's (a half, a
+# quarter, an eighth, a sixteenth of the picks land here); d 128, f 256
+# in two hidden blocks; column 1 raised so that eight tokens in nine pick
+# expert 1: a group larger than a tile, and tokens with picks in several
+# tiles; the ninth's picks may all land elsewhere.
+CELLS = {
+    "serve-rag": (160, 4, 8, 4, 128, 149),
+    "serve-reason": (96, 4, 16, 4, 64, 96),
+    "serve-longctx": (160, 4, 16, 2, 128, 155),
+    "serve-mixed": (192, 2, 32, 2, 128, 192),
+}
+
+
+def _cell(name, dtype):
+    T, k, routed, held, tile, live = CELLS[name]
+    d, f = 128, 256
+    rng = np.random.default_rng(sorted(CELLS).index(name))
+    x = rng.normal(size=(T, d))
+    x[:, 0] = 1.0
+    x[::9, 0] = 0.0
+    router = rng.normal(size=(d, routed)) * 0.3
+    router[0, 1] += 40.0
+    logits = jnp.asarray(x @ router, jnp.float32)
+    topv, topi = jax.lax.top_k(logits, k)
+    gates = jax.nn.softmax(topv, axis=-1)
+    loc = jnp.where(topi < held, topi, held).astype(jnp.int32)
+    loc = jnp.where((jnp.arange(T) < live)[:, None], loc, held)
+    sizes = jnp.sum(loc.reshape(-1)[:, None] == jnp.arange(held)[None],
+                    axis=0, dtype=jnp.int32)
+    w_in = jnp.asarray(rng.normal(size=(held, d, 2 * f)) * 0.2, dtype)
+    w_out = jnp.asarray(rng.normal(size=(held, f, d)) * 0.2, dtype)
+    return jnp.asarray(x, dtype), loc, sizes, gates, w_in, w_out, tile
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("name", sorted(CELLS))
+def test_kernel_gives_the_unfused_forms_sum_at_each_cells_shape(name, dtype):
+    """The kernel's ``[T, d]`` is the padded rows gathered back and summed
+    under the gates: to float32 round-off of a reordered k-term sum in
+    float32, to blocked-accumulation noise (two hidden blocks folded in
+    float32 against one product) in bf16.  A padded tail row and a token
+    whose picks all land elsewhere read exactly zero."""
+    x, loc, sizes, gates, w_in, w_out, tile = _cell(name, jnp.dtype(dtype))
+    held = w_in.shape[0]
+    te, used, dest, src, row_gate = GM.sorted_tile_plan(loc, sizes, tile,
+                                                        gates)
+    with jax.default_matmul_precision("highest"):
+        out = GM.sorted_gated_ffn(x, dest, src, row_gate, w_in, w_out, te,
+                                  used, block_rows=tile, block_f=128)
+        want, _ = _unfused(x, loc, gates, w_in, w_out, te, dest, tile)
+    assert out.shape == want.shape and out.dtype == jnp.float32
+    scale = float(jnp.abs(want).max())
+    assert scale > 0.1
+    tol = 2e-6 if dtype == "float32" else 1e-3
+    assert float(jnp.abs(out - want).max()) <= tol * scale
+    here = np.asarray((loc < held).any(axis=1))
+    assert (~here).any() and here.any()
+    assert float(jnp.abs(out[np.flatnonzero(~here)]).max()) == 0.0
+    # picks land elsewhere, a group is larger than a tile and some
+    # token's picks are in different tiles
+    assert 0 < int(sizes.sum()) < int((jnp.arange(x.shape[0])
+                                       < CELLS[name][5]).sum()) * loc.shape[1]
+    assert int(sizes.max()) > tile
+    spread = np.asarray(jnp.where(dest >= 0, dest // tile, -1))
+    assert any(len(set(r[r >= 0])) > 1 for r in spread)
+
+
+def test_tiles_past_the_used_count_do_nothing():
+    """Told one tile fewer than the plan used, the kernel leaves that
+    tile's rows out of the sum and touches nothing else: a step past
+    ``num_used`` computes nothing and adds nothing."""
+    x, loc, sizes, gates, w_in, w_out, tile = _cell("serve-rag",
+                                                    jnp.float32)
+    te, used, dest, src, row_gate = GM.sorted_tile_plan(loc, sizes, tile,
+                                                        gates)
+    assert int(used[0]) >= 3 and te.shape[0] > int(used[0])
+    with jax.default_matmul_precision("highest"):
+        out = GM.sorted_gated_ffn(x, dest, src, row_gate, w_in, w_out, te,
+                                  used - 1, block_rows=tile, block_f=128)
+        last = dest // tile == used[0] - 1
+        want, _ = _unfused(x, loc, jnp.where(last, 0.0, gates), w_in, w_out,
+                           te, dest, tile)
+    assert bool(last.any())
+    assert float(jnp.abs(out - want).max()) <= 2e-6 * float(
+        jnp.abs(want).max())
 
 
 @pytest.mark.parametrize("block_f", [128, 256])
 def test_hidden_blocks_fold_into_one_output_tile(block_f):
     """The kernel alone: the hidden width walked in blocks gives what one
-    block gives, every pick's row is its token's, and a tile past the
-    used count is not computed."""
+    block gives, every pick's row is its token's and carries its gate,
+    and a token's picks in different groups add up."""
     rng = np.random.default_rng(3)
     d, f, tile, T, k = 64, 256, 16, 24, 2
     sizes = np.asarray([20, 0, 7], np.int32)
     loc = _picks(sizes, T, k, seed=5)
-    te, used, dest = GM.sorted_tile_plan(jnp.asarray(loc),
-                                         jnp.asarray(sizes), tile)
+    gates = jnp.asarray(rng.uniform(0.1, 1.0, (T, k)), jnp.float32)
+    te, used, dest, src, row_gate = GM.sorted_tile_plan(
+        jnp.asarray(loc), jnp.asarray(sizes), tile, gates)
     x = jnp.asarray(rng.normal(size=(T, d)), jnp.float32)
     w_in = jnp.asarray(rng.normal(size=(3, d, 2 * f)) * 0.1, jnp.float32)
     w_out = jnp.asarray(rng.normal(size=(3, f, d)) * 0.1, jnp.float32)
     tok, col = np.nonzero(loc < 3)
     group = loc[tok, col]
     with jax.default_matmul_precision("highest"):
-        ys = GM.sorted_gated_ffn(x, dest, w_in, w_out, te, used,
-                                 block_rows=tile, block_f=block_f)
+        out = GM.sorted_gated_ffn(x, dest, src, row_gate, w_in, w_out, te,
+                                  used, block_rows=tile, block_f=block_f)
         g, u = jnp.split(jnp.einsum("nd,ndf->nf", x[tok], w_in[group]), 2,
                          axis=-1)
-        want = jnp.einsum("nf,nfd->nd", jax.nn.silu(g) * u, w_out[group])
-    got = ys[np.asarray(dest)[tok, col]]
-    assert float(jnp.abs(got - want).max()) <= 5e-6 * float(
-        jnp.abs(want).max())
-    assert ys.shape == (te.shape[0] * tile, d) and int(used[0]) == 3
-    # the rows of a used tile that no pick has are zero rows' product
+        y = jnp.einsum("nf,nfd->nd", jax.nn.silu(g) * u, w_out[group])
+        want = jnp.zeros((T, d), jnp.float32).at[tok].add(
+            gates[tok, col][:, None] * y)
+        unfused, ys = _unfused(x, jnp.asarray(loc), gates, w_in, w_out, te,
+                               dest, tile)
+    scale = float(jnp.abs(want).max())
+    assert float(jnp.abs(out - want).max()) <= 5e-6 * scale
+    assert float(jnp.abs(out - unfused).max()) <= 5e-6 * scale
+    assert out.shape == (T, d) and int(used[0]) == 3
+    # the reference's padded rows: a used tile's rows that no pick has
+    # are zero rows' product
+    assert ys.shape == (te.shape[0] * tile, d)
     assert float(jnp.abs(ys[20:32]).max()) == 0.0
+    assert len(set(tok)) < len(tok)           # a token with two held picks
 
 
 def _path_counts():
@@ -228,11 +360,16 @@ def test_the_shape_rule_sends_a_chunk_to_the_kernel_and_a_step_to_ragged_dot(
     other = "ragged_dot" if path == "sorted_kernel" else "sorted_kernel"
     assert after.get(path, 0) == before.get(path, 0) + 1
     assert after.get(other, 0) == before.get(other, 0)
-    assert ("pallas_call" in text) == (path == "sorted_kernel")
-    assert ("ragged_dot" in text) == (path == "ragged_dot")
+    assert text.count("pallas_call") == (1 if path == "sorted_kernel" else 0)
+    assert text.count("= ragged_dot") == (2 if path == "ragged_dot" else 0)
+    # the picks' rows, float32 [T, k, d]: the decode step's un-sort and
+    # sum have them, the chunk's path holds none (nor the padded rows)
+    assert (f"f32[{rows},{k},{D}]" in text) == (path == "ragged_dot")
     if path == "sorted_kernel":
-        assert "sorted_gated_ffn" in text
+        assert text.count("sorted_gated_ffn") == 1
         assert blocks[0] == 128
+        tiles = -(-(rows * k) // 128) + H - 1
+        assert f"f32[{tiles * 128},{D}]" not in text
         with jax.default_matmul_precision("highest"):
             out, _ = gated_experts_forward(*args, top_k=k, local_of=LOCAL)
             dense = _dense(args, k, None)
@@ -240,34 +377,59 @@ def test_the_shape_rule_sends_a_chunk_to_the_kernel_and_a_step_to_ragged_dot(
             jnp.abs(dense).max())
 
 
-def test_tile_and_hidden_block_come_from_the_shape():
-    """serve-rag's chunk: a 128-row tile and a hidden block that divides
-    f in lanes, inside the budget; fewer rows an expert, a smaller tile;
-    a decode step's rows, and rows that do not fit VMEM whole, are
+# (tokens, top k, held, d, f) of a prefill chunk's expert layer in the four
+# cells that hold experts (perf/configs/, perf/traffic/: chunk 512)
+PUBLISHED = {
+    "serve-rag": (512, 10, 36, 4096, 768),
+    "serve-longctx": (512, 8, 16, 4096, 2048),
+    "serve-reason": (512, 8, 64, 2304, 1024),
+    "serve-mixed": (512, 4, 16, 3072, 3072),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(PUBLISHED))
+def test_tile_and_hidden_block_come_from_the_shape(cell):
+    """A cell's chunk: the MXU's 128-row tile (64 where a held expert's
+    mean rows are fewer) and a hidden block that divides f in lanes, what
+    the call holds inside the budget with the float32 output resident;
+    fewer rows an expert, a smaller tile; a decode step's rows, and a
+    step whose resident output and rows do not fit VMEM, are
     ``ragged_dot``'s."""
     bf16 = jnp.bfloat16
-    rows, bf = GM.sorted_ffn_blocks(512, 10, 36, 4096, 768, bf16)
-    assert rows == 128 and 768 % bf == 0 and bf % 128 == 0
-    assert GM.sorted_ffn_vmem_bytes(rows, bf, 512, 10, 4096, 2) \
-        <= GM._SORTED_VMEM_BUDGET < GM._SORTED_VMEM_LIMIT
-    assert GM.sorted_ffn_blocks(128, 10, 36, 4096, 768, bf16)[0] == 64
-    assert GM.sorted_ffn_blocks(24, 10, 36, 4096, 768, bf16) is None
-    assert GM.sorted_ffn_blocks(8192, 10, 36, 4096, 768, bf16) is None
+    T, k, H_, d, f = PUBLISHED[cell]
+    rows, bf = GM.sorted_ffn_blocks(T, k, H_, d, f, bf16)
+    assert rows == (64 if cell == "serve-reason" else 128)
+    assert f % bf == 0 and bf % 128 == 0
+    held = GM.sorted_ffn_vmem_bytes(rows, bf, T, k, d, 2)
+    assert T * d * 4 < held <= GM._SORTED_VMEM_BUDGET < GM._SORTED_VMEM_LIMIT
+    fewer = GM.sorted_ffn_blocks(128, k, H_, d, f, bf16)
+    assert fewer is None or fewer[0] < rows
+    assert GM.sorted_ffn_blocks(24, k, H_, d, f, bf16) is None
+    for big in (2048, 8192):
+        # the resident output and the rows leave no room for a weight tile
+        assert GM.sorted_ffn_vmem_bytes(128, 128, big, k, d, 2) \
+            > GM._SORTED_VMEM_BUDGET
+        assert GM.sorted_ffn_blocks(big, k, H_, d, f, bf16) is None
 
 
-def test_static_verification_at_serve_rags_chunk():
-    """The catalog holds the kernel at serve-rag's shape and finds it
-    clean under the scope it asks for; a hidden block whose tiles do not
-    fit that scope is an error, and the rule never offers it."""
+@pytest.mark.parametrize("cell", sorted(PUBLISHED))
+def test_static_verification_at_each_cells_chunk(cell):
+    """The catalog holds the kernel at each cell's shape and finds it
+    clean under the scope it asks for (the resident output, the gates'
+    scalar operands and both scratch tiles in the count); a hidden block
+    whose tiles do not fit that scope is an error, and the rule never
+    offers it."""
     from paddle_tpu.analysis import kernel_verify as kv
-    assert GM.verify_static_sorted(512, 10, 36, 4096, 768) == []
-    over = GM.verify_static_sorted(512, 10, 36, 4096, 768, block_f=768)
-    assert [d.message.split(":")[0] for d in over] == [kv.VMEM_EXCEEDED]
+    T, k, H_, d, f = PUBLISHED[cell]
+    assert GM.verify_static_sorted(T, k, H_, d, f) == []
+    over = GM.verify_static_sorted(T, k, H_, d, f, block_f=f)
+    assert [d_.message.split(":")[0] for d_ in over] == [kv.VMEM_EXCEEDED]
     rows = [r for r in kv.catalog_report()
-            if r["kernel"] == "sorted_gated_ffn"]
+            if r["kernel"] == "sorted_gated_ffn"
+            and r["shape"].startswith(f"t{T} k{k} h{H_} d{d} f{f} ")]
     assert len(rows) == 1 and rows[0]["verdict"] == "OK"
     assert rows[0]["config"] == "br{} bf{}".format(*GM.sorted_ffn_blocks(
-        512, 10, 36, 4096, 768, jnp.bfloat16))
+        T, k, H_, d, f, jnp.bfloat16))
 
 
 def test_the_engine_prefills_through_the_kernel_and_decodes_by_ragged_dot():
